@@ -180,7 +180,8 @@ def _check_note_signature(sf: SourceFile,
     for node in ast.walk(sf.tree):
         if isinstance(node, ast.Call):
             text = expr_text(node.func) or ""
-            if text.rsplit(".", 1)[-1] == "note_signature":
+            if text.rsplit(".", 1)[-1] in ("note_signature",
+                                           "program_call"):
                 has_note = True
             elif _is_jit_call(node):
                 qual = _enclosing_func(sf.tree, node)

@@ -301,9 +301,9 @@ class SupervisedExecutor(Executor):
         self.supervisor_pid = proc.pid
         self._sup_proc = proc
         # Wait for the task pid (or an immediate launch failure).  The
-        # supervisor is a fresh interpreter: its startup alone costs
-        # 2-4s on this image (jax pre-import), and full-suite load can
-        # multiply that — a 15s bound flaked roughly once per suite run.
+        # supervisor is a fresh interpreter, and full-suite load can
+        # multiply its startup — a 15s bound flaked roughly once per
+        # suite run.
         pid_path = os.path.join(self.ctl_dir, "task.pid")
         deadline = time.time() + 45.0
         while time.time() < deadline:

@@ -129,6 +129,30 @@ def _load(name: str, source: str) -> ctypes.CDLL:
         return lib
 
 
+# Every native component: name → (library, source).  The bindings live
+# with their users (wal/ids below, codec/native.py, ops/decode.py).
+COMPONENTS = {
+    "wal": ("nomadwal", "wal.cc"),
+    "codec": ("nomadcodec", "codec.cc"),
+    "decode": ("nomaddecode", "decode.cc"),
+    "ids": ("nomadids", "ids.cc"),
+}
+
+
+def load_report() -> dict:
+    """Build/load every native component now: {name: None when loaded,
+    else why not}.  Start-up diagnostics (chip_smoke.py) — the importers
+    themselves fall back to their python twins silently."""
+    out = {}
+    for comp, (name, source) in COMPONENTS.items():
+        try:
+            _load(name, source)
+            out[comp] = None
+        except NativeUnavailable as exc:
+            out[comp] = str(exc)
+    return out
+
+
 # ---------------------------------------------------------------------------
 # Group-commit WAL (wal.cc)
 # ---------------------------------------------------------------------------

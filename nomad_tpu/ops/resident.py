@@ -150,7 +150,7 @@ def _delta_apply_mesh_fn(mesh):
         from ..parallel import sharded as shmod
 
         @functools.partial(
-            shmod._shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(shmod.NODE_AXIS), P(shmod.NODE_AXIS),
                       P(shmod.NODE_AXIS)),
             out_specs=P(shmod.NODE_AXIS))
@@ -377,7 +377,7 @@ def _apply_device_deltas(used_dev, dev_rows, mesh=None):
     if used_dev is None or not dev_rows:
         return used_dev
     from .encode import pow2_bucket, route_shard_deltas
-    from .kernels import note_signature
+    from .kernels import program_call
 
     try:
         if mesh is not None:
@@ -392,12 +392,12 @@ def _apply_device_deltas(used_dev, dev_rows, mesh=None):
                                             dims=RES_DIMS)
             DEV_APPLIES += 1
             DEV_H2D_BYTES += rows.nbytes + vals.nbytes
-            note_signature("resident_delta_mesh",
-                           (used_dev.shape, rows.shape[1], d))
             spec = NamedSharding(mesh, P(shmod.NODE_AXIS))
-            return _delta_apply_mesh_fn(mesh)(
-                used_dev, jax.device_put(rows, spec),
-                jax.device_put(vals, spec))
+            with program_call("resident_delta_mesh",
+                              (used_dev.shape, rows.shape[1], d)):
+                return _delta_apply_mesh_fn(mesh)(
+                    used_dev, jax.device_put(rows, spec),
+                    jax.device_put(vals, spec))
         k_b = pow2_bucket(len(dev_rows))
         rows = np.full(k_b, -1, dtype=np.int32)
         vals = np.zeros((k_b, RES_DIMS), dtype=np.int32)
@@ -409,8 +409,8 @@ def _apply_device_deltas(used_dev, dev_rows, mesh=None):
             vals[j, 3] = vec[3]
         DEV_APPLIES += 1
         DEV_H2D_BYTES += rows.nbytes + vals.nbytes
-        note_signature("resident_delta", (used_dev.shape, k_b))
-        return _delta_apply_fn()(used_dev, rows, vals)
+        with program_call("resident_delta", (used_dev.shape, k_b)):
+            return _delta_apply_fn()(used_dev, rows, vals)
     except Exception:
         # The donated input is consumed even on failure — a dead handle
         # must not linger in the slot (the next take reinstalls from

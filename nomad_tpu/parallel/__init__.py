@@ -4,7 +4,6 @@ from .sharded import (
     BATCH_AXIS,
     NODE_AXIS,
     make_node_mesh,
-    sharded_candidate_scores,
     sharded_fused_pass,
     sharded_placement_rounds,
     sharded_schedule_step,

@@ -33,29 +33,6 @@ from ..ops.kernels import (
 )
 from ..ops.encode import MISSING
 
-# shard_map moved to the jax top level (and check_rep became check_vma)
-# in newer releases; support both so the mesh path runs on whichever
-# jax the image bakes in.
-_SMAP_LEGACY = not hasattr(jax, "shard_map")
-if not _SMAP_LEGACY:
-    _shard_map = jax.shard_map
-    _SMAP_CHECK_OFF = {"check_vma": False}
-else:  # pragma: no cover — depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # Legacy check_rep has no replication rule for while_loop at all, so
-    # the placement-rounds call site also needs it off (the new vma
-    # checker handles while fine and stays on there).
-    _SMAP_CHECK_OFF = {"check_rep": False}
-
-
-def _mark_varying(x):
-    """Mark a freshly-created array as node-axis-varying inside the
-    mapped function.  Only the new varying-manual-axes jax needs the
-    explicit cast; older shard_map has no vma tracking, so identity."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (NODE_AXIS,), to="varying")
-    return x
-
 NEG_INF = -1e30
 
 # Mesh axis names: 'nodes' shards the node dimension of the score matrix
@@ -71,103 +48,10 @@ def make_node_mesh(devices=None) -> Mesh:
     return Mesh(np.array(devices), (NODE_AXIS,))
 
 
-def _local_topk_scores(
-    feas_local: jnp.ndarray,     # [U, N_local] bool
-    used_local: jnp.ndarray,     # [N_local, 4] int32
-    capacity_local: jnp.ndarray, # [N_local, 4] int32
-    denom_local: jnp.ndarray,    # [N_local, 2] float32
-    ask: jnp.ndarray,            # [U, 4] int32 (replicated)
-    k: int,
-    use_pallas: bool = False,
-    pallas_interpret: "bool | None" = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-shard scoring + top-k: the FLOPs-heavy part of the scheduler.
-
-    With ``use_pallas`` the mask+score computes in the fused pallas
-    kernel (ops/pallas_score.py, one HBM pass over the node tensors);
-    both paths are bit-identical (differential-tested).
-
-    Returns (scores[U, k], local_idx[U, k]).
-    """
-    u = ask.shape[0]
-
-    if use_pallas:
-        from ..ops.pallas_score import masked_score_matrix
-
-        scored = masked_score_matrix(
-            feas_local, used_local, capacity_local, denom_local, ask,
-            interpret=pallas_interpret)
-        return jax.vmap(lambda s: lax.top_k(s, k))(scored)
-
-    def score_one(u_idx):
-        cap_left = capacity_local - used_local
-        fits = jnp.all(ask[u_idx][None, :] <= cap_left, axis=1)
-        ok = feas_local[u_idx] & fits
-        score = _score_fit(used_local, ask[u_idx], denom_local)
-        scored = jnp.where(ok, score, NEG_INF)
-        return lax.top_k(scored, k)
-
-    scores, idx = jax.vmap(score_one)(jnp.arange(u))
-    return scores, idx
-
-
-def sharded_candidate_scores(
-    mesh: Mesh,
-    feas: jax.Array,       # [U, N] bool  — sharded on N
-    used: jax.Array,       # [N, 4] int32 — sharded on N
-    capacity: jax.Array,   # [N, 4] int32 — sharded on N
-    denom: jax.Array,      # [N, 2] f32   — sharded on N
-    ask: jax.Array,        # [U, 4] int32 — replicated
-    k: int = 64,
-    use_pallas: "bool | None" = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Score all (spec, node) pairs across the mesh and return the global
-    top-(k·D) candidates per spec as (scores[U, k*D], node_idx[U, k*D]).
-
-    XLA inserts the all-gather over ICI; node indices are translated from
-    shard-local to global inside the mapped function.  ``use_pallas``
-    routes the shard-local mask+score through the fused pallas kernel
-    (default: the NOMAD_TPU_PALLAS env opt-in).
-    """
-    if use_pallas is None:
-        from ..ops.pallas_score import pallas_enabled
-
-        use_pallas = pallas_enabled()
-    n_per_shard = used.shape[0] // mesh.devices.size
-
-    # Route by the MESH's devices, not the default backend: a CPU mesh
-    # on a TPU host must interpret, and vice versa.
-    from ..utils.platform import is_tpu_platform
-
-    mesh_on_tpu = is_tpu_platform(mesh.devices.flat[0].platform)
-    smap_kwargs = {}
-    if use_pallas and not mesh_on_tpu:
-        # Pallas interpret mode's internal block slicing carries no
-        # varying-manual-axes info, which trips shard_map's vma checker
-        # on CPU; the compiled TPU path keeps full checking.
-        smap_kwargs.update(_SMAP_CHECK_OFF)
-
-    @functools.partial(
-        _shard_map,
-        mesh=mesh,
-        in_specs=(P(None, NODE_AXIS), P(NODE_AXIS), P(NODE_AXIS),
-                  P(NODE_AXIS), P(None)),
-        out_specs=(P(None, NODE_AXIS), P(None, NODE_AXIS)),
-        **smap_kwargs,
-    )
-    def _shard_fn(feas_l, used_l, cap_l, denom_l, ask_r):
-        scores, local_idx = _local_topk_scores(
-            feas_l, used_l, cap_l, denom_l, ask_r, k,
-            use_pallas=use_pallas, pallas_interpret=not mesh_on_tpu)
-        shard = lax.axis_index(NODE_AXIS)
-        global_idx = local_idx + shard * n_per_shard
-        return scores, global_idx
-
-    # out_specs concatenate along the (sharded) second axis: result is the
-    # gathered [U, k*D] candidate table, replicated to every device by the
-    # final all-gather below.
-    scores, idx = _shard_fn(feas, used, capacity, denom, ask)
-    return scores, idx
+def _mark_varying(x):
+    """Mark a freshly-created array as node-axis-varying inside the
+    mapped function (shard_map's varying-manual-axes typing)."""
+    return lax.pcast(x, (NODE_AXIS,), to="varying")
 
 
 def sharded_placement_rounds(
@@ -248,7 +132,7 @@ def sharded_placement_rounds(
     jit_seed = jitter_seed(rng_key)
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(None, NODE_AXIS), P(NODE_AXIS), P(NODE_AXIS),
                   P(NODE_AXIS), P(None), P(None), P(None), P(None),
@@ -259,7 +143,6 @@ def sharded_placement_rounds(
                   # dp: per-spec replicated, node attrs sharded
                   P(None), P(None), P(None), P(NODE_AXIS)),
         out_specs=(P(None, NODE_AXIS), P(None), P(NODE_AXIS), P()),
-        **(_SMAP_CHECK_OFF if _SMAP_LEGACY else {}),
     )
     def _run(feas_l, used_l, cap_l, denom_l, ask_r, count_r, penalty_r,
              dh_r, job_index_r, jc_l, jit_seed_r,
@@ -493,7 +376,6 @@ def sharded_fused_pass(
            max_rounds, window_nnz, compact_u16, use_used_dev)
     from ..ops import kernels as _kernels
 
-    _kernels.note_signature("sharded_fused_pass", key)
     fn = _FUSED_MESH_CACHE.get(key)
     if fn is None:
         fn = _build_fused_mesh_fn(
@@ -507,8 +389,9 @@ def sharded_fused_pass(
         # Shardable dummy ([1, 4] per device) keeps one program shape;
         # the aliased output is discarded.
         used_dev = jnp.zeros((d, 4), dtype=jnp.int32)
-    buf, slots, sscores, scoll, feas, used_out = fn(
-        static_shards, dyn_buf, used_dev)
+    with _kernels.program_call("sharded_fused_pass", key):
+        buf, slots, sscores, scoll, feas, used_out = fn(
+            static_shards, dyn_buf, used_dev)
     return (buf, (slots, sscores, scoll), feas, meta,
             (used_out if use_used_dev else None))
 
@@ -530,11 +413,10 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
     big_idx = jnp.int32(n_pad + 1)
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(NODE_AXIS), P(), P(NODE_AXIS)),
         out_specs=(P(), P(), P(), P(), P(None, NODE_AXIS), P(NODE_AXIS)),
-        **(_SMAP_CHECK_OFF if _SMAP_LEGACY else {}),
     )
     def _run(sbuf_l, dyn, used_dev_l):
         ds = xfer.unpack_device(sbuf_l.reshape(-1), meta_s)
@@ -676,7 +558,11 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
             sel_i = sel.astype(jnp.int32)
             placed_l = jnp.sum(sel_i)
             counts_g = lax.all_gather(placed_l, NODE_AXIS)      # [D]
-            placed = jnp.sum(counts_g)
+            # The total comes from psum, not jnp.sum(counts_g): an
+            # all_gather result is typed node-varying, and ``remaining``
+            # / the while-loop progress flag must stay replicated-typed
+            # to match their carry inputs and the P() out_specs.
+            placed = lax.psum(placed_l, NODE_AXIS)
             # Global commit positions in the single-chip kernel's
             # ascending-node order: allocs placed so far + lower-shard
             # prefix + within-shard ascending-node rank.

@@ -11,6 +11,8 @@ BatchWorker   — the TPU-native replacement: drains the broker into
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import logging
 import os
 import threading
@@ -498,6 +500,11 @@ class BatchWorker(Worker):
         # Optional device mesh: placement passes run node-sharded over it
         # (each federated region schedules on its own slice).
         self.mesh = mesh
+        # Before this process compiles anything: JAX latches the
+        # persistent cache off at the first compile without a directory.
+        from ..utils.platform import ensure_compile_cache
+
+        ensure_compile_cache()
 
     def sched_name(self, ev: s.Evaluation) -> str:
         if ev.type == s.JOB_TYPE_SYSTEM:
@@ -562,6 +569,38 @@ class BatchWorker(Worker):
                        fetch_bytes=stats.fetch_bytes,
                        commit_s=round(stats.commit_seconds, 4))
 
+    @contextlib.contextmanager
+    def _nack_clocks_held(self, batch):
+        """Hold the batch's nack clocks; resuming restarts each eval's
+        full timeout (the WorkerPlanner.submit_plan discipline)."""
+        held = []
+        for ev, token in batch:
+            try:
+                self.broker.pause_nack_timeout(ev.id, token)
+                held.append((ev.id, token))
+            except EvalBrokerError:
+                pass
+        try:
+            yield
+        finally:
+            for eval_id, token in held:
+                try:
+                    self.broker.resume_nack_timeout(eval_id, token)
+                except EvalBrokerError:
+                    pass
+
+    def _compile_guard(self, batch):
+        """XLA compilation runs outside the nack clock: a cold shape
+        bucket compiles for tens of seconds on a TPU (69 s for the
+        64-spec bucket at 10k nodes, chip run of PR 21) — past the 60 s
+        nack timeout, which redelivered the whole batch mid-compile.
+        The clock guards processing; kernels.program_call enters this
+        only around a program signature's first invocation."""
+        from ..ops import kernels
+
+        return kernels.compile_guard(
+            functools.partial(self._nack_clocks_held, batch))
+
     def _process_batch(self, batch: List[Tuple[s.Evaluation, str]]):
         """Returns the batch's BatchStats, or None when the batch was
         nacked."""
@@ -594,7 +633,8 @@ class BatchWorker(Worker):
             ev.id: self.broker.delivery_attempts(ev.id)
             for ev, _ in batch}
         try:
-            stats = sched.schedule_batch([ev for ev, _ in batch])
+            with self._compile_guard(batch):
+                stats = sched.schedule_batch([ev for ev, _ in batch])
         except Exception as exc:
             self.logger.exception("batch scheduling failed; nacking batch")
             self.record_eval_failures([ev for ev, _ in batch], exc)
@@ -702,7 +742,8 @@ class BatchWorker(Worker):
             # Fresh snapshot for the usage delta: the previous batch's
             # plans are applied by now (its _pipeline_finish ran first).
             ctx.sched.state = self.raft.fsm.state.snapshot()
-            ctx.sched._dispatch_prepared(ctx.prep)
+            with self._compile_guard(ctx.batch):
+                ctx.sched._dispatch_prepared(ctx.prep)
             return ctx
         except Exception as exc:
             self._nack_batch(ctx.batch, ctx.attempts, exc)

@@ -118,7 +118,7 @@ def _frame_subsystem(path: str, func: str) -> Optional[str]:
             return "ops.dispatch"
         return "plan.evaluate"
     if path.endswith(("/ops/kernels.py", "/ops/xfer.py",
-                      "/ops/resident.py", "/ops/pallas_score.py")):
+                      "/ops/resident.py")):
         return "ops.fetch" if "fetch" in fl or "unpack" in fl \
             else "ops.dispatch"
     if "/ops/" in path:

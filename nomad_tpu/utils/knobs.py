@@ -91,9 +91,6 @@ _knob("NOMAD_TPU_FUSED", "bool", True,
 _knob("NOMAD_TPU_QUANT", "bool", True,
       "Quantized int8/int16 static resource rows (exact-or-absent "
       "round-trip, guarded)")
-_knob("NOMAD_TPU_PALLAS", "bool", False,
-      "Opt into the Pallas kernels (OFF pending hardware go/no-go, "
-      "see README)")
 _knob("NOMAD_TPU_RNG_SEED", "int", None,
       "Pin the per-batch tie-break jitter seed for deterministic "
       "placement reproduction")
@@ -105,9 +102,6 @@ _knob("NOMAD_TPU_PREEMPTION", "bool", False,
       "preemption flag")
 _knob("NOMAD_TPU_NO_COMPILE_CACHE", "bool", False,
       "Disable the persistent XLA compilation cache")
-_knob("NOMAD_TPU_COMPILE_CACHE_DIR", "str", None,
-      "Persistent XLA compile cache location",
-      default_label="~/.cache/nomad_tpu/xla")
 _knob("NOMAD_TPU_PIPELINE", "bool", False,
       "Pipelined BatchWorker drain: prepare batch k+1 overlaps batch "
       "k's device pass")
@@ -298,8 +292,6 @@ _knob("NOMAD_TPU_BENCH_PARTIAL", "str", None,
       "every phase")
 _knob("NOMAD_TPU_BENCH_CHILD", "str", None,
       "Internal: marks a bench trajectory child process")
-_knob("NOMAD_TPU_BENCH_TPU_RETRY", "str", None,
-      "Internal: marks the bench core-phases-on-TPU retry child")
 _knob("NOMAD_TPU_BENCH_MESH_CHILD", "str", None,
       "Internal: marks the forced-8-device config_mesh child")
 _knob("NOMAD_TPU_BENCH_MESH_STEADY_CHILD", "str", None,

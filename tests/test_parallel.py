@@ -7,11 +7,10 @@ import pytest
 
 from nomad_tpu.parallel import (
     make_node_mesh,
-    sharded_candidate_scores,
     sharded_placement_rounds,
     sharded_schedule_step,
 )
-from nomad_tpu.ops.kernels import _score_fit, placement_rounds
+from nomad_tpu.ops.kernels import placement_rounds
 
 # Heavy integration/differential module: quick tier skips it (pytest.ini).
 pytestmark = pytest.mark.slow
@@ -34,45 +33,6 @@ def _mk_problem(n=256, u=4, seed=0):
     ask = np.tile(np.array([500, 256, 150, 0], dtype=np.int32), (u, 1))
     count = np.full(u, 20, dtype=np.int32)
     return feas, used, capacity, denom, ask, count
-
-
-def test_sharded_scores_match_single_device(mesh):
-    feas, used, capacity, denom, ask, count = _mk_problem()
-    k = 16
-    scores, idx = sharded_candidate_scores(
-        mesh, jnp.asarray(feas), jnp.asarray(used), jnp.asarray(capacity),
-        jnp.asarray(denom), jnp.asarray(ask), k=k)
-    scores, idx = np.asarray(scores), np.asarray(idx)
-    assert scores.shape == (4, k * 8)
-    # Every candidate's score must equal the single-device score at that node.
-    for u_i in range(4):
-        full = np.asarray(_score_fit(
-            jnp.asarray(used), jnp.asarray(ask[u_i]), jnp.asarray(denom)))
-        cap_left = capacity - used
-        fits = np.all(ask[u_i][None, :] <= cap_left, axis=1)
-        ok = feas[u_i] & fits
-        for c in range(k * 8):
-            n_idx = idx[u_i, c]
-            if scores[u_i, c] > -1e29:
-                assert ok[n_idx]
-                assert scores[u_i, c] == pytest.approx(full[n_idx], abs=1e-4)
-
-
-def test_sharded_topk_contains_global_best(mesh):
-    feas, used, capacity, denom, ask, count = _mk_problem(seed=3)
-    scores, idx = sharded_candidate_scores(
-        mesh, jnp.asarray(feas), jnp.asarray(used), jnp.asarray(capacity),
-        jnp.asarray(denom), jnp.asarray(ask), k=16)
-    scores, idx = np.asarray(scores), np.asarray(idx)
-    for u_i in range(4):
-        full = np.asarray(_score_fit(
-            jnp.asarray(used), jnp.asarray(ask[u_i]), jnp.asarray(denom)))
-        cap_left = capacity - used
-        fits = np.all(ask[u_i][None, :] <= cap_left, axis=1)
-        ok = feas[u_i] & fits
-        masked = np.where(ok, full, -np.inf)
-        best_node = int(np.argmax(masked))
-        assert best_node in idx[u_i], "global best node missing from candidates"
 
 
 def _mk_full_problem(n=256, u=12, j=6, seed=11, tight=False):
