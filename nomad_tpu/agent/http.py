@@ -28,6 +28,7 @@ from ..server.eval_broker import BrokerLimitError
 from ..server.rpc import NoPathToRegion
 from ..state.state_store import WatchSet
 from ..structs import structs as s
+from ..utils import tracing
 
 MAX_BLOCKING_WAIT = 300.0  # 5m default / 10m cap like the reference
 
@@ -148,51 +149,89 @@ class HTTPServer:
         r("/debug/pprof/trace", self.debug_trace_request)
 
     def _route(self, pattern: str, fn: Callable) -> None:
-        self.routes.append((pattern, re.compile("^" + pattern + "$"), fn))
+        # The route's name is its first path segment under /v1/
+        # (``jobs``, ``job``, ``node``; ``debug`` for /debug/pprof): the
+        # tail of its sample key, so the route table bounds the key set.
+        parts = pattern.strip("/").split("/")
+        name = parts[1] if parts[0] == "v1" and len(parts) > 1 else parts[0]
+        self.routes.append((name, re.compile("^" + pattern + "$"), fn))
 
     def _dispatch(self, req: BaseHTTPRequestHandler) -> None:
         parsed = urlparse(req.path)
         query = {k: v[0] for k, v in parse_qs(
             parsed.query, keep_blank_values=True).items()}
-        for _pat, rx, fn in self.routes:
+        for route, rx, fn in self.routes:
             m = rx.match(parsed.path)
             if m is None:
                 continue
-            try:
-                obj, index = fn(req, query, **m.groupdict())
-            except CodedError as e:
-                self._reply_error(req, e.code, str(e), e.headers)
-                return
-            except BrokerLimitError as e:
-                # Admission NACK: 429 + Retry-After so well-behaved
-                # clients back off (jittered client-side) instead of
-                # retrying into the saturated broker.
-                self._reply_error(req, 429, str(e),
-                                  {"Retry-After": f"{e.retry_after:.2f}"})
-                return
-            except NoPathToRegion as e:
-                # Federation degradation contract: a down region is a
-                # retryable 429 with a Retry-After hint, never a hang or
-                # an opaque 500 — callers can distinguish "region
-                # unreachable" from "no leader" by the typed body.
-                self._reply_error(req, 429, str(e),
-                                  {"Retry-After": f"{e.retry_after:.2f}"})
-                return
-            except (ValueError, KeyError) as e:
-                self._reply_error(req, 400, str(e))
-                return
-            except Exception as e:  # 500 like wrap (http.go:224)
-                self.agent.logger.exception("http: request failed")
-                self._reply_error(req, 500, str(e))
-                return
-            if isinstance(obj, StreamResponse):
-                self._reply_stream(req, obj)
-            elif isinstance(obj, TextResponse):
-                self._reply_text(req, obj)
-            else:
-                self._reply_json(req, obj, index)
+            self._timed(req, query, route, fn, m.groupdict())
             return
         self._reply_error(req, 404, "Invalid URL")
+
+    def _timed(self, req, query, route: str, fn: Callable,
+               groups: dict) -> None:
+        """One matched request, timed from here until its reply is
+        written: sample ``http.request.<METHOD>.<route>`` always (a
+        blocking query reads long, which is right) and, when the tracer
+        is armed, an ``http.request`` span from the same two stamps that
+        takes the eval id of a reply that carries one, so an eval's
+        timeline starts at the request that made it."""
+        tr = tracing.TRACER
+        t0 = tracing.now()
+        if tr is None:
+            self._serve(req, query, fn, groups)
+            t1 = tracing.now()
+        else:
+            with tr.span("http.request", start=t0, method=req.command,
+                         route=route) as sp:
+                eval_id = self._serve(req, query, fn, groups)
+                if eval_id:
+                    sp.set(eval_id=eval_id)
+            t1 = sp.end
+        server = self.agent.server
+        if server is not None:
+            server.metrics.add_sample(
+                f"http.request.{req.command}.{route}", (t1 - t0) * 1000.0)
+
+    def _serve(self, req, query, fn: Callable, groups: dict):
+        """Run the handler and write its reply; returns the ``EvalID``
+        of a JSON reply that has one."""
+        try:
+            obj, index = fn(req, query, **groups)
+        except CodedError as e:
+            self._reply_error(req, e.code, str(e), e.headers)
+            return None
+        except BrokerLimitError as e:
+            # Admission NACK: 429 + Retry-After so well-behaved
+            # clients back off (jittered client-side) instead of
+            # retrying into the saturated broker.
+            self._reply_error(req, 429, str(e),
+                              {"Retry-After": f"{e.retry_after:.2f}"})
+            return None
+        except NoPathToRegion as e:
+            # Federation degradation contract: a down region is a
+            # retryable 429 with a Retry-After hint, never a hang or
+            # an opaque 500 — callers can distinguish "region
+            # unreachable" from "no leader" by the typed body.
+            self._reply_error(req, 429, str(e),
+                              {"Retry-After": f"{e.retry_after:.2f}"})
+            return None
+        except (ValueError, KeyError) as e:
+            self._reply_error(req, 400, str(e))
+            return None
+        except Exception as e:  # 500 like wrap (http.go:224)
+            self.agent.logger.exception("http: request failed")
+            self._reply_error(req, 500, str(e))
+            return None
+        if isinstance(obj, StreamResponse):
+            self._reply_stream(req, obj)
+        elif isinstance(obj, TextResponse):
+            self._reply_text(req, obj)
+        else:
+            self._reply_json(req, obj, index)
+            if isinstance(obj, dict):
+                return obj.get("EvalID")
+        return None
 
     def _reply_stream(self, req, stream: StreamResponse) -> None:
         """One NDJSON line per frame, flushed immediately; the connection
@@ -913,6 +952,7 @@ class HTTPServer:
 
         n = min(int(query.get("recent", 100) or 100), 1000)
         return {"Enabled": tracing.enabled(),
+                "Dropped": tracing.dropped(),
                 "Spans": tracing.recent(n)}, None
 
     def trace_eval_request(self, req, query, id: str):

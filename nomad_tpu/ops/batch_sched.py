@@ -21,6 +21,7 @@ already guarantees at most one outstanding eval per job).
 """
 from __future__ import annotations
 
+import hashlib
 import logging
 import time
 from collections import defaultdict
@@ -250,6 +251,71 @@ class _CollectingScheduler(GenericScheduler):
             for tg, names, prevs in order]
 
 
+DEVICE_STAGES = ("stage", "dispatch", "wait", "fetch", "decode")
+
+
+class _DeviceStages:
+    """The device call's five contiguous stages (DEVICE_STAGES), timed
+    for the sink always and for the tracer when it is armed.
+
+    A boundary is ONE ``perf_counter`` stamp that closes a stage and
+    opens the next, so the stages tile ``t1 → t1 + device_seconds`` and
+    a stage's sample and span share their stamps.  Armed, each stage is
+    a live ``batch.device.<stage>`` span (entered as a profiler
+    TraceAnnotation too) whose parent is the ``batch.device`` span that
+    ``_finalize_device_outputs`` records afterwards under the id
+    reserved here.  Disarmed, a boundary costs its stamp and a dict
+    store.  ``with stages:`` closes whatever stage an exception left
+    open.  stage + dispatch run in ``_dispatch_device`` /
+    ``_dispatch_mesh``, the other three in ``_fetch_device`` (one
+    thread, possibly with another batch's prepare in between when the
+    drain is pipelined)."""
+
+    __slots__ = ("seconds", "parent_id", "_tr", "_open", "_name", "_t")
+
+    def __init__(self) -> None:
+        self.seconds: Dict[str, float] = {}
+        self._tr = tracing.TRACER
+        self.parent_id = (self._tr.reserve_id()
+                          if self._tr is not None else 0)
+        self._open = None
+        self._name = ""
+        self._t = 0.0
+
+    def begin(self, name: str, t: Optional[float] = None) -> float:
+        """Open ``name`` at ``t`` (now when not given), closing the
+        stage that was running at the same stamp."""
+        if t is None:
+            t = time.perf_counter()
+        if self._name:
+            self.end(t)
+        self._name, self._t = name, t
+        if self._tr is not None:
+            self._open = self._tr.span("batch.device." + name,
+                                       parent_id=self.parent_id,
+                                       annotate=True, start=t)
+            self._open.__enter__()
+        return t
+
+    def end(self, t: Optional[float] = None) -> float:
+        if t is None:
+            t = time.perf_counter()
+        if self._name:
+            self.seconds[self._name] = t - self._t
+            self._name = ""
+            if self._open is not None:
+                self._open.finish(t)
+                self._open = None
+        return t
+
+    def __enter__(self) -> "_DeviceStages":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
+
+
 class _PreparedBatch:
     """One batch between prepare and complete: the host-phase outputs
     plus the in-flight device handle (schedule_stream pipelining keeps
@@ -333,7 +399,7 @@ class TPUBatchScheduler:
         if tr is None:
             stats = self._schedule_batch(evals)
         else:
-            with tr.span("batch.schedule",
+            with tr.span("batch.schedule", annotate=True,
                          num_evals=len(evals),
                          **tracing.eval_id_attrs(evals, len(evals))) as sp:
                 stats = self._schedule_batch(evals)
@@ -355,6 +421,8 @@ class TPUBatchScheduler:
         # sibling in the family (DEFAULT_BUCKETS is ms-calibrated).
         m.add_sample("worker.invoke_scheduler",
                      stats.total_seconds * 1000.0)
+        m.add_sample("worker.invoke_scheduler.prepare",
+                     stats.prepare_seconds * 1000.0)
         m.add_sample("worker.invoke_scheduler.phase1",
                      stats.phase1_seconds * 1000.0)
         m.add_sample("worker.invoke_scheduler.phase2",
@@ -367,11 +435,15 @@ class TPUBatchScheduler:
                          stats.encode_seconds * 1000.0)
             m.add_sample("worker.invoke_scheduler.device",
                          stats.device_seconds * 1000.0)
+            # The device call split into its five contiguous stages
+            # (they sum to .device) and what follows it before finalize.
+            for name in DEVICE_STAGES:
+                m.add_sample("worker.invoke_scheduler.device." + name,
+                             stats.device_stage_seconds.get(name, 0.0)
+                             * 1000.0)
+            m.add_sample("worker.invoke_scheduler.expand",
+                         stats.metrics_seconds * 1000.0)
             m.add_sample("worker.invoke_scheduler.rounds", stats.rounds)
-            m.add_sample("worker.invoke_scheduler.commit",
-                         stats.commit_seconds * 1000.0)
-            m.add_sample("worker.invoke_scheduler.fetch",
-                         stats.fetch_seconds * 1000.0)
             # Bytes are a COUNTER (rate-derivable total), not a sample:
             # the percentile histogram's buckets are ms-calibrated and
             # would quantize MB-scale values into the top bucket.
@@ -393,6 +465,14 @@ class TPUBatchScheduler:
         if not stats.oracle_routed:
             m.add_sample("worker.invoke_scheduler.finalize",
                          stats.finalize_seconds * 1000.0)
+            # finalize per stage, each summed over the batch's evals
+            # (they sum to .finalize less the loop's own overhead).
+            m.add_sample("worker.invoke_scheduler.finalize.build",
+                         stats.finalize_build_seconds * 1000.0)
+            m.add_sample("worker.invoke_scheduler.finalize.submit",
+                         stats.finalize_submit_seconds * 1000.0)
+            m.add_sample("worker.invoke_scheduler.finalize.status",
+                         stats.finalize_status_seconds * 1000.0)
         m.add_sample("worker.invoke_scheduler.asks", stats.num_asks)
         # Residency counters: per-batch samples plus the process-lifetime
         # gauges (ops/resident.py module counters).
@@ -522,7 +602,25 @@ class TPUBatchScheduler:
         return stats
 
     def _prepare_batch(self, evals: List[s.Evaluation]) -> "_PreparedBatch":
+        """Stage 1: host reconciliation per eval and the dedup of their
+        asks into specs (phase 1 and 2), timed as one: ``prepare_seconds``
+        from ``prep.t0``, and a ``batch.prepare`` span of the same two
+        stamps, parent of batch.phase1/2, when the tracer is armed."""
         prep = _PreparedBatch(evals)
+        tr = tracing.TRACER
+        if tr is None:
+            self._fill_prepared(prep, evals)
+            t_end = time.perf_counter()
+        else:
+            with tr.span("batch.prepare", annotate=True, start=prep.t0,
+                         num_evals=len(evals)) as sp:
+                self._fill_prepared(prep, evals)
+            t_end = sp.end
+        prep.stats.prepare_seconds = t_end - prep.t0
+        return prep
+
+    def _fill_prepared(self, prep: "_PreparedBatch",
+                       evals: List[s.Evaluation]) -> None:
         stats = prep.stats
 
         # Phase 1: host reconciliation per eval (shared oracle code).
@@ -603,7 +701,6 @@ class TPUBatchScheduler:
         prep.scheds = scheds
         prep.specs = specs
         prep.spec_list = spec_list
-        return prep
 
     def _dispatch_prepared(self, prep: "_PreparedBatch") -> None:
         """Stage 2: breaker gate + encode/delta-build + async device
@@ -716,6 +813,7 @@ class TPUBatchScheduler:
             stats.device_seconds = kstats["device_seconds"]
             stats.encode_seconds = kstats["encode_seconds"]
             stats.metrics_seconds = kstats["metrics_seconds"]
+            stats.device_stage_seconds = kstats["stage_seconds"]
             stats.rounds = kstats["rounds"]
             stats.commit_seconds = kstats.get("commit_seconds", 0.0)
             stats.dispatch_seconds = kstats.get("dispatch_seconds", 0.0)
@@ -736,7 +834,7 @@ class TPUBatchScheduler:
         net_index_cache: Dict[str, "NetworkIndex"] = {}
         for ev, sched in scheds:
             self._finalize(ev, sched, prep.specs, expanded, unplaced,
-                           per_spec_metrics, net_index_cache)
+                           per_spec_metrics, net_index_cache, stats)
         stats.finalize_seconds = time.perf_counter() - t_final
         if tr is not None:
             tr.record("batch.finalize", t_final,
@@ -1108,8 +1206,7 @@ class TPUBatchScheduler:
             # slot-budget check, so a degraded batch never strands a
             # loan.
             res_key = snap_index = None
-            if (use_resident
-                    and knobs.get_str("NOMAD_TPU_TIMING") != "2"):
+            if use_resident:
                 res_key = cache_key[:2] + (base.n_pad,)
                 snap_index = self.state.table_index("allocs")
             handle = self._dispatch_mesh(
@@ -1132,12 +1229,10 @@ class TPUBatchScheduler:
         # trips in place (the kernel returns the aliased buffer).
         # The mesh path has its own sharded twin of this loan inside
         # _dispatch_mesh (ISSUE 14); this branch is the single-chip
-        # layout only, and the timing2 diagnostics split keeps the
-        # delta upload.
+        # layout only.
         used_dev = None
         res_key = snap_index = None
-        if (use_resident and self.mesh is None
-                and knobs.get_str("NOMAD_TPU_TIMING") != "2"):
+        if use_resident and self.mesh is None:
             res_key = cache_key[:2] + (base.n_pad,)
             snap_index = self.state.table_index("allocs")
             used_dev = resident.take_device_used(res_key, snap_index,
@@ -1149,16 +1244,6 @@ class TPUBatchScheduler:
         dbuf, meta_d = xfer.pack_host(dyn)
         encode_seconds = time.perf_counter() - t0
         t1 = time.perf_counter()
-
-        import hashlib
-        digest = (hashlib.blake2b(sbuf.tobytes(), digest_size=16).hexdigest(),
-                  meta_s)
-        static_dev = _DEVICE_STATIC_CACHE.get(digest)
-        static_h2d = 0
-        if static_dev is None:
-            static_dev = jax.device_put(sbuf)
-            static_h2d = sbuf.nbytes
-        _DEVICE_STATIC_CACHE.put(digest, static_dev)
 
         # Canonical shape-class plan (ISSUE 13 compile-cache audit): ONE
         # pow2 bucketing for (U, slot record, COO capacity) shared with
@@ -1173,58 +1258,50 @@ class TPUBatchScheduler:
             st.u_pad, ct.n_pad, ct.n_real, max_count, total_asks)
         fused_buf = fused_meta = fused_overflow = None
         summary_buf = coo_mat = None
-        used_out = None
-        if knobs.get_str("NOMAD_TPU_TIMING") == "2":
-            # Staged sync (diagnostics only): force the schedule program
-            # to finish before compaction dispatch so the log splits
-            # schedule vs compact+fetch.  This branch always produces COO
-            # output, so slot mode must be OFF — otherwise the decode
-            # below would misread COO triplets as a slot matrix.
-            slot_m = 0
-            from .kernels import _device_compact, _device_schedule
-            t_s0 = time.perf_counter()
-            result, feas, _ = _device_schedule(
-                static_dev, jax.device_put(dbuf),
-                jnp.zeros((1, 4), dtype=jnp.int32), meta_s=meta_s,
-                meta_d=meta_d, u_pad=st.u_pad, n_pad=ct.n_pad,
-                with_networks=with_networks, with_dp=with_dp,
-                with_scores=with_scores)
-            jax.device_get(result.unplaced)
-            logger.warning("timing2: schedule %.3fs",
-                           time.perf_counter() - t_s0)
-            t_s1 = time.perf_counter()
-            compact_u16 = (not with_scores and st.u_pad <= 65536
-                           and ct.n_pad <= 65536)
-            summary_buf, coo_mat = _device_compact(
-                result, feas, with_scores=with_scores, max_nnz=max_nnz,
-                compact_u16=compact_u16)
-            jax.device_get(summary_buf[:4])
-            logger.warning("timing2: compact %.3fs",
-                           time.perf_counter() - t_s1)
-        elif fused_enabled():
-            # Tentpole path: score + commit + compaction as ONE device
-            # dispatch emitting ONE packed result buffer, fetched in a
-            # single transfer by _fetch_device (the aux overflow source
-            # stays device-resident, touched only on window overflow).
-            fused_buf, fused_aux, feas, fused_meta, used_out = \
-                kernels.fused_pass(
-                    static_dev, jax.device_put(dbuf), used_dev,
+        stages = _DeviceStages()
+        with stages:
+            # stage: content digest of the static pack, the device-side
+            # cache of it, and the uploads (static on a miss, dynamic
+            # always).
+            stages.begin("stage", t1)
+            digest = (hashlib.blake2b(sbuf.tobytes(),
+                                      digest_size=16).hexdigest(), meta_s)
+            static_dev = _DEVICE_STATIC_CACHE.get(digest)
+            static_h2d = 0
+            if static_dev is None:
+                static_dev = jax.device_put(sbuf)
+                static_h2d = sbuf.nbytes
+            _DEVICE_STATIC_CACHE.put(digest, static_dev)
+            dyn_dev = jax.device_put(dbuf)
+
+            # dispatch: the program call until it returns (asynchronous:
+            # the host's cost only) and the mirror loan handed back.
+            stages.begin("dispatch")
+            if fused_enabled():
+                # Tentpole path: score + commit + compaction as ONE
+                # device dispatch emitting ONE packed result buffer,
+                # fetched in a single transfer by _fetch_device (the aux
+                # overflow source stays device-resident, touched only on
+                # window overflow).
+                fused_buf, fused_aux, feas, fused_meta, used_out = \
+                    kernels.fused_pass(
+                        static_dev, dyn_dev, used_dev,
+                        meta_s=meta_s, meta_d=meta_d, u_pad=st.u_pad,
+                        n_pad=ct.n_pad, with_networks=with_networks,
+                        with_dp=with_dp, with_scores=with_scores,
+                        max_nnz=max_nnz, slot_m=slot_m)
+                fused_overflow = ("slots" if slot_m else "coo", fused_aux)
+            else:
+                summary_buf, coo_mat, feas, used_out = device_pass(
+                    static_dev, dyn_dev, used_dev,
                     meta_s=meta_s, meta_d=meta_d, u_pad=st.u_pad,
                     n_pad=ct.n_pad, with_networks=with_networks,
                     with_dp=with_dp, with_scores=with_scores,
                     max_nnz=max_nnz, slot_m=slot_m)
-            fused_overflow = ("slots" if slot_m else "coo", fused_aux)
-        else:
-            summary_buf, coo_mat, feas, used_out = device_pass(
-                static_dev, jax.device_put(dbuf), used_dev,
-                meta_s=meta_s, meta_d=meta_d, u_pad=st.u_pad,
-                n_pad=ct.n_pad, with_networks=with_networks,
-                with_dp=with_dp, with_scores=with_scores,
-                max_nnz=max_nnz, slot_m=slot_m)
-        if used_out is not None:
-            # The kernel aliased the donated mirror back out — return
-            # the loan so the next batch's delta apply lands in place.
-            resident.give_device_used(res_key, snap_index, used_out)
+            if used_out is not None:
+                # The kernel aliased the donated mirror back out — return
+                # the loan so the next batch's delta apply lands in place.
+                resident.give_device_used(res_key, snap_index, used_out)
         # Device pass is dispatched (JAX async); the blocking fetch lives
         # in _fetch_device so a pipelining caller can overlap host work.
         return {
@@ -1235,7 +1312,7 @@ class TPUBatchScheduler:
             "fused_overflow": fused_overflow,
             "quantized": 0 if quant is None else 1,
             "with_scores": with_scores, "max_nnz": max_nnz,
-            "encode_seconds": encode_seconds, "t1": t1,
+            "encode_seconds": encode_seconds, "t1": t1, "stages": stages,
             "resident": resident_info,
             "h2d_bytes": (dbuf.nbytes + static_h2d
                           + (resident.DEV_H2D_BYTES - h2d0)),
@@ -1271,6 +1348,11 @@ class TPUBatchScheduler:
     def _fetch_device(self, handle):
         """Blocking fetch + decode + shared post-processing of an
         in-flight _dispatch_device / _dispatch_mesh handle."""
+        with handle["stages"]:      # closes the stage an error leaves open
+            return self._fetch_decode(handle)
+
+    def _fetch_decode(self, handle):
+        stages = handle["stages"]
         spec_list = handle["spec_list"]
         all_nodes = handle["all_nodes"]
         ct, st = handle["ct"], handle["st"]
@@ -1279,8 +1361,18 @@ class TPUBatchScheduler:
         with_scores = handle["with_scores"]
         max_nnz = handle["max_nnz"]
 
-        t_disp = time.perf_counter()
-        dbg = knobs.get_str("NOMAD_TPU_TIMING") or None
+        # wait: until the result is ready on the device (compute drains
+        # here).  The one transfer is queued behind the compute first,
+        # exactly as the device_get below would queue it, so waiting for
+        # the compute costs the transfer no host round trip.  fetch: the
+        # rest of the transfer and the unpack.
+        t_disp = stages.begin("wait")
+        result_buf = (handle["fused_buf"]
+                      if handle.get("fused_buf") is not None
+                      else summary_buf)
+        result_buf.copy_to_host_async()
+        result_buf.block_until_ready()
+        stages.begin("fetch")
         fetch_bytes = 0
         if handle.get("fused_buf") is not None:
             # Fused path: the WHOLE batch result — summary + COO
@@ -1290,7 +1382,7 @@ class TPUBatchScheduler:
             # Only when nnz overflows the payload window (>8MB of
             # placements) does a second fetch of the overflow source
             # run, inside the same span.
-            with tracing.span("batch.fetch", fused=1):
+            with tracing.span("batch.fetch", annotate=True, fused=1):
                 raw = np.asarray(jax.device_get(handle["fused_buf"]))
                 fetch_bytes = raw.nbytes
                 summary = xfer.unpack_host(raw, handle["fused_meta"])
@@ -1325,9 +1417,6 @@ class TPUBatchScheduler:
                         coo = np.asarray(jax.device_get(ov_coo))[:nnz]
                         fetch_bytes += (nnz_b * coo.shape[1]
                                         * coo.dtype.itemsize)
-            if dbg:
-                logger.warning("timing: fused fetch %.3fs (%d B)",
-                               time.perf_counter() - t_disp, fetch_bytes)
         else:
             ncols = 5 if with_scores else 3
             # dtype truth comes from the device array itself (uint16 when
@@ -1341,7 +1430,7 @@ class TPUBatchScheduler:
             # Both rounds live under ONE batch.fetch span: this is the
             # non-fused fallback's one logical batched fetch.
             if max_nnz * ncols * isz <= (4 << 20):
-                with tracing.span("batch.fetch"):
+                with tracing.span("batch.fetch", annotate=True):
                     sraw, coo_full = jax.device_get((summary_buf, coo_mat))
                 summary = xfer.unpack_host(
                     np.asarray(sraw), summary_layout(st.u_pad, ct.n_pad))
@@ -1349,15 +1438,11 @@ class TPUBatchScheduler:
                 coo = np.asarray(coo_full[:nnz])
                 fetch_bytes = (np.asarray(sraw).nbytes
                                + np.asarray(coo_full).nbytes)
-                if dbg:
-                    logger.warning("timing: summary+coo fetch %.3fs",
-                                   time.perf_counter() - t_disp)
             else:
-                with tracing.span("batch.fetch"):
+                with tracing.span("batch.fetch", annotate=True):
                     sraw = np.asarray(jax.device_get(summary_buf))
                     summary = xfer.unpack_host(
                         sraw, summary_layout(st.u_pad, ct.n_pad))
-                    t_sum = time.perf_counter()
                     nnz = int(summary["scalars"][0])
                     if nnz:
                         nnz_b = min(max_nnz,
@@ -1369,12 +1454,10 @@ class TPUBatchScheduler:
                         coo = np.zeros((0, ncols),
                                        dtype=np.dtype(coo_mat.dtype))
                         fetch_bytes = sraw.nbytes
-                if dbg:
-                    logger.warning(
-                        "timing: summary fetch (compute wait) %.3fs; coo "
-                        "fetch %.3fs (%d entries x %d cols x %d B)",
-                        t_sum - t_disp, time.perf_counter() - t_sum, nnz,
-                        ncols, isz)
+        # decode: from here to the device_seconds stamp in
+        # _finalize_device_outputs (COO split, validation, expansion,
+        # the forensics fetch).
+        stages.begin("decode")
         # Wall time of the whole score-and-commit dispatch: upload +
         # device compute + the result transfer (t1 marks the post-encode
         # dispatch point in _dispatch_device).  dispatch_seconds is the
@@ -1401,7 +1484,8 @@ class TPUBatchScheduler:
         expanded, unplaced, metrics, kstats = self._finalize_device_outputs(
             spec_list, all_nodes, ct, st, feas, unplaced_arr, feas_count,
             coo_rows, coo_cols, coo_counts, coo_scores, coo_coll,
-            rounds, with_scores, handle["encode_seconds"], handle["t1"])
+            rounds, with_scores, handle["encode_seconds"], handle["t1"],
+            stages)
         kstats["commit_seconds"] = commit_seconds
         kstats["dispatch_seconds"] = dispatch_seconds
         kstats["fetch_seconds"] = (fetch_seconds
@@ -1506,31 +1590,34 @@ class TPUBatchScheduler:
         encode_seconds = time.perf_counter() - t0
         t1 = time.perf_counter()
 
-        import hashlib
-        digest = (hashlib.blake2b(sbuf.tobytes(),
-                                  digest_size=16).hexdigest(),
-                  meta_s, shmod._mesh_cache_key(mesh))
-        static_dev = _DEVICE_STATIC_CACHE.get(digest)
-        static_h2d = 0
-        if static_dev is None:
-            static_dev = jax.device_put(
-                sbuf, NamedSharding(mesh, P(shmod.NODE_AXIS)))
-            static_h2d = sbuf.nbytes
-        _DEVICE_STATIC_CACHE.put(digest, static_dev)
-        dyn_dev = jax.device_put(dbuf, NamedSharding(mesh, P()))
+        stages = _DeviceStages()
+        with stages:
+            stages.begin("stage", t1)
+            digest = (hashlib.blake2b(sbuf.tobytes(),
+                                      digest_size=16).hexdigest(),
+                      meta_s, shmod._mesh_cache_key(mesh))
+            static_dev = _DEVICE_STATIC_CACHE.get(digest)
+            static_h2d = 0
+            if static_dev is None:
+                static_dev = jax.device_put(
+                    sbuf, NamedSharding(mesh, P(shmod.NODE_AXIS)))
+                static_h2d = sbuf.nbytes
+            _DEVICE_STATIC_CACHE.put(digest, static_dev)
+            dyn_dev = jax.device_put(dbuf, NamedSharding(mesh, P()))
 
-        fused_buf, aux, feas, fused_meta, used_out = \
-            shmod.sharded_fused_pass(
-                mesh, static_dev, dyn_dev, used_dev, meta_s=meta_s,
-                meta_d=meta_d, u_pad=st.u_pad, n_pad=ct.n_pad,
-                with_networks=with_networks, with_dp=with_dp,
-                with_scores=with_scores, max_nnz=max_nnz,
-                slot_m=slot_m, k_cand=k_cand)
-        if used_out is not None:
-            # The program aliased every shard's donated buffer back out
-            # — return the loan so the next batch's shard-routed delta
-            # apply lands in place.
-            resident.give_device_used(res_key, snap_index, used_out)
+            stages.begin("dispatch")
+            fused_buf, aux, feas, fused_meta, used_out = \
+                shmod.sharded_fused_pass(
+                    mesh, static_dev, dyn_dev, used_dev, meta_s=meta_s,
+                    meta_d=meta_d, u_pad=st.u_pad, n_pad=ct.n_pad,
+                    with_networks=with_networks, with_dp=with_dp,
+                    with_scores=with_scores, max_nnz=max_nnz,
+                    slot_m=slot_m, k_cand=k_cand)
+            if used_out is not None:
+                # The program aliased every shard's donated buffer back
+                # out — return the loan so the next batch's shard-routed
+                # delta apply lands in place.
+                resident.give_device_used(res_key, snap_index, used_out)
         MESH_PASSES += 1
         return {
             "spec_list": spec_list, "all_nodes": all_nodes, "ct": ct,
@@ -1540,7 +1627,7 @@ class TPUBatchScheduler:
             "fused_overflow": ("slots", aux),
             "quantized": quantized, "mesh_shards": d,
             "with_scores": with_scores, "max_nnz": max_nnz,
-            "encode_seconds": encode_seconds, "t1": t1,
+            "encode_seconds": encode_seconds, "t1": t1, "stages": stages,
             "resident": resident_info,
             "h2d_bytes": (dbuf.nbytes + static_h2d
                           + (resident.DEV_H2D_BYTES - h2d0)),
@@ -1549,10 +1636,13 @@ class TPUBatchScheduler:
     def _finalize_device_outputs(self, spec_list, all_nodes, ct, st, feas,
                                  unplaced_arr, feas_count, coo_rows,
                                  coo_cols, coo_counts, coo_scores, coo_coll,
-                                 rounds, with_scores, encode_seconds, t1):
+                                 rounds, with_scores, encode_seconds, t1,
+                                 stages):
         """Shared device→host post-processing for the single-chip and
         mesh placement paths: lazy failure-forensics row fetch, COO →
-        per-spec slots, AllocMetric assembly."""
+        per-spec slots, AllocMetric assembly.  ``stages`` arrives with
+        its ``decode`` stage running; the device_seconds stamp below
+        closes it."""
         # Chaos hook: corrupt the fetched kernel outputs (the damage a
         # flaky accelerator / bad HBM would do), THEN validate — the
         # validation below is exactly what protects production from the
@@ -1671,7 +1761,7 @@ class TPUBatchScheduler:
             if preempt_ctx is not None:
                 gets["preempt"] = preempt_ctx["dev"]
             t_fx = time.perf_counter()
-            with tracing.span("batch.fetch_forensics",
+            with tracing.span("batch.fetch_forensics", annotate=True,
                               feas_rows=len(need_rows),
                               preempt=int(preempt_ctx is not None)):
                 fetched = jax.device_get(gets)
@@ -1685,14 +1775,14 @@ class TPUBatchScheduler:
                 kstats_fetch_b += sum(
                     np.asarray(a).nbytes
                     for a in jax.tree_util.tree_leaves(fetched["preempt"]))
-        device_seconds = time.perf_counter() - t1
+        device_seconds = stages.end() - t1
         t_metrics = time.perf_counter()
 
         # Preemption commit (host greedy pass over the fetched eviction
         # sets; mutates unplaced_arr/used_after so the failure forensics
         # below see the post-preemption truth).
         if preempt_ctx is not None:
-            with tracing.span("batch.preempt"):
+            with tracing.span("batch.preempt", annotate=True):
                 preempt_stats = self._preempt_commit(
                     preempt_ctx, fetched["preempt"], spec_list, ct,
                     unplaced_arr, used_after)
@@ -1780,6 +1870,7 @@ class TPUBatchScheduler:
             "rounds": rounds,
             "fetch_seconds": kstats_fetch_s,
             "fetch_bytes": kstats_fetch_b,
+            "stage_seconds": stages.seconds,
         }
         kstats.update(preempt_stats)
         tr = tracing.TRACER
@@ -1788,7 +1879,7 @@ class TPUBatchScheduler:
             # the encode→device boundary, t_metrics the device→host one.
             tr.record("batch.encode", t1 - encode_seconds, t1)
             tr.record("batch.device", t1, t1 + device_seconds,
-                      rounds=rounds)
+                      span_id=stages.parent_id, rounds=rounds)
             tr.record("batch.metrics", t_metrics,
                       t_metrics + kstats["metrics_seconds"],
                       preempt_placed=kstats.get("preempt_placed", 0))
@@ -2123,9 +2214,38 @@ class TPUBatchScheduler:
         return idx
 
     def _finalize(self, ev, sched, specs, expanded, unplaced,
-                  per_spec_metrics, net_index_cache) -> None:
+                  per_spec_metrics, net_index_cache, stats) -> None:
         """Materialize this eval's assigned slots into its plan, then submit
-        + set status, mirroring generic_sched.go:104 Process."""
+        + set status, mirroring generic_sched.go:104 Process.  Its three
+        stages (build the plan, the submit_plan round trip, the status
+        write) are timed into ``stats`` and, armed, recorded as
+        batch.finalize.* spans of this eval."""
+        t_in = time.perf_counter()
+        t_sub = t_st = self._finalize_build(
+            ev, sched, specs, expanded, per_spec_metrics, net_index_cache)
+        if sched.plan.is_no_op() and not ev.annotate_plan:
+            set_status(self.logger, self.planner, ev, sched.next_eval,
+                       sched.blocked, sched.failed_tg_allocs,
+                       s.EVAL_STATUS_COMPLETE, "", sched.queued_allocs)
+        else:
+            settled = self._finalize_submit(ev, sched)
+            t_st = time.perf_counter()
+            if not settled:
+                self._finalize_status(ev, sched)
+        t_out = time.perf_counter()
+        stats.finalize_build_seconds += t_sub - t_in
+        stats.finalize_submit_seconds += t_st - t_sub
+        stats.finalize_status_seconds += t_out - t_st
+        tr = tracing.TRACER
+        if tr is not None:
+            tr.record("batch.finalize.build", t_in, t_sub, eval_id=ev.id)
+            tr.record("batch.finalize.submit", t_sub, t_st, eval_id=ev.id)
+            tr.record("batch.finalize.status", t_st, t_out, eval_id=ev.id)
+
+    def _finalize_build(self, ev, sched, specs, expanded, per_spec_metrics,
+                        net_index_cache) -> float:
+        """Slots → plan (slab or per-alloc), blocked and follow-up evals.
+        Returns the stamp at which the plan stood ready to submit."""
         # Prototype alloc per spec: the metric, task_resources, resources and
         # shared_resources objects are shared by every alloc of the spec —
         # legal because stored objects are immutable snapshots by convention
@@ -2275,12 +2395,12 @@ class TPUBatchScheduler:
             sched.next_eval = ev.next_rolling_eval(sched.job.update.stagger)
             self.planner.create_eval(sched.next_eval)
 
-        if sched.plan.is_no_op() and not ev.annotate_plan:
-            set_status(self.logger, self.planner, ev, sched.next_eval,
-                       sched.blocked, sched.failed_tg_allocs,
-                       s.EVAL_STATUS_COMPLETE, "", sched.queued_allocs)
-            return
+        return time.perf_counter()
 
+    def _finalize_submit(self, ev, sched) -> bool:
+        """The plan round trip as the worker sees it.  True when a
+        conflict sent the eval through the oracle, which then wrote the
+        eval's status itself."""
         result, new_state = self.planner.submit_plan(sched.plan)
         from ..scheduler.util import adjust_queued_allocations
 
@@ -2297,8 +2417,11 @@ class TPUBatchScheduler:
                                       batch=(ev.type == s.JOB_TYPE_BATCH),
                                       preemption_enabled=self.preemption_enabled)
             oracle.process(ev)
-            return
+            return True
+        return False
 
+    def _finalize_status(self, ev, sched) -> None:
+        """The eval's own raft write: reblock, or complete."""
         if ev.status == s.EVAL_STATUS_BLOCKED and sched.failed_tg_allocs:
             e = sched.ctx.eligibility()
             new_eval = ev.copy()
@@ -2322,10 +2445,20 @@ class BatchStats:
         self.num_asks = 0
         self.encode_seconds = 0.0
         self.device_seconds = 0.0
+        # prepare = phase1 + phase2 (the whole of _prepare_batch).
+        self.prepare_seconds = 0.0
         self.phase1_seconds = 0.0
         self.phase2_seconds = 0.0
+        # device_seconds split into DEVICE_STAGES (name → seconds).
+        self.device_stage_seconds: Dict[str, float] = {}
         self.metrics_seconds = 0.0
         self.finalize_seconds = 0.0
+        # finalize split, each summed over the batch's evals: building
+        # the plan, the submit_plan round trip (an oracle retry on
+        # conflict with it), and the eval's status write.
+        self.finalize_build_seconds = 0.0
+        self.finalize_submit_seconds = 0.0
+        self.finalize_status_seconds = 0.0
         self.total_seconds = 0.0
         self.rounds = 0
         # Fused score-and-commit path (PR 6): whether this batch ran the

@@ -96,6 +96,10 @@ class _HeapEntry:
     # min-heap: higher priority first, then older create index, then seq.
     sort_key: Tuple[int, int, int]
     eval: s.Evaluation = field(compare=False)
+    # perf_counter stamp of the push onto a READY queue (first enqueue,
+    # promotion of a deferred same-job eval, a requeue): dequeue reads
+    # it for the broker.wait sample.
+    ready_at: float = field(default=0.0, compare=False)
 
 
 class _Unack:
@@ -305,7 +309,9 @@ class EvalBroker:
         q = self.ready.get(queue)
         if q is None:
             q = self.ready[queue] = TenantQueue(self.fairness)
-        q.push(self._entry(ev))
+        entry = self._entry(ev)
+        entry.ready_at = time.perf_counter()
+        q.push(entry)
         self._cond.notify_all()
 
     def _coalesce_deferred(self, ev: s.Evaluation) -> bool:
@@ -541,7 +547,9 @@ class EvalBroker:
         return self._dequeue_for_sched(sched)
 
     def _dequeue_for_sched(self, sched: str) -> Tuple[s.Evaluation, str]:
-        ev = self.ready[sched].pop().eval
+        entry = self.ready[sched].pop()
+        wait_ms = (time.perf_counter() - entry.ready_at) * 1000.0
+        ev = entry.eval
         token = s.generate_uuid()
 
         deadline = (time.monotonic() + self.nack_timeout
@@ -551,8 +559,11 @@ class EvalBroker:
         tr = tracing.TRACER
         if tr is not None:
             tr.event("broker.dequeue", eval_id=ev.id, job_id=ev.job_id,
-                     eval_type=ev.type, attempt=self.evals[ev.id])
+                     eval_type=ev.type, attempt=self.evals[ev.id],
+                     wait_ms=round(wait_ms, 4))
         self.metrics.incr_counter("broker.dequeue")
+        # Ready → handed to a worker: the queue in front of the worker.
+        self.metrics.add_sample("broker.wait", wait_ms)
         return ev, token
 
     # -- outstanding / ack / nack -----------------------------------------

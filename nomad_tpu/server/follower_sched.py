@@ -383,7 +383,10 @@ class RemotePlanQueue:
         self.channel = channel
         self._fences = fences
 
-    def enqueue(self, plan: s.Plan) -> _RemotePlanFuture:
+    def enqueue(self, plan: s.Plan,
+                trace_parent: int = 0) -> _RemotePlanFuture:
+        # No stamps and no parent: the leader's applier is in another
+        # process, on another clock.
         return _RemotePlanFuture(self.channel, plan)
 
     def applied_index_for(self, job_id: str) -> int:

@@ -31,6 +31,7 @@ import logging
 import os
 import queue as _queue
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -178,14 +179,20 @@ class PlanApplier:
         # path pays one load + comparison only).
         tr = tracing.TRACER
         try:
+            # The submitter's worker.submit_plan span (another thread)
+            # is the parent: it caused this work.
             ev_span = tracing.NOOP if tr is None else tr.span(
-                "plan.evaluate", eval_id=plan.eval_id)
+                "plan.evaluate", eval_id=plan.eval_id,
+                parent_id=future.trace_parent)
             with self.metrics.measure("plan.evaluate"), ev_span:
                 result = self.evaluate_plan(snap, plan)
         except Exception as exc:  # pragma: no cover — defensive
             self.logger.exception("plan evaluation failed")
             future.respond(None, exc)
             return
+        # What lies between this stamp and _commit's is the hand-off to
+        # the commit pool (plan.commit_wait, emitted by the submitter).
+        future.t_evaluated = time.perf_counter()
 
         # Staleness + conflict telemetry for the stale-snapshot
         # worker pool: how far behind the log this plan's snapshot
@@ -226,12 +233,21 @@ class PlanApplier:
 
     def _commit(self, plan, result, future, snap,
                 token: Optional[int] = None) -> None:
+        future.t_commit = time.perf_counter()
         tr = tracing.TRACER
         try:
             ap_span = tracing.NOOP if tr is None else tr.span(
-                "plan.apply", eval_id=plan.eval_id)
+                "plan.apply", eval_id=plan.eval_id,
+                parent_id=future.trace_parent)
             with self.metrics.measure("plan.apply"), ap_span:
                 index = self.apply_plan(plan, result, snap)
+            # The program's own count of what it placed (a client can
+            # only count complete evals x their group count).
+            self.metrics.incr_counter(
+                "plan.allocs_committed",
+                sum(len(slab) for slab in result.alloc_slabs)
+                + sum(len(allocs)
+                      for allocs in result.node_allocation.values()))
             result.alloc_index = index
             if result.refresh_index:
                 # Partial commit: ensure the scheduler sees at least

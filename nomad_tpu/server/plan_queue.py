@@ -5,6 +5,7 @@ from __future__ import annotations
 import heapq
 import itertools
 import threading
+import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
@@ -24,15 +25,28 @@ class PlanFuture:
     wait timed out cancels the future, and the applier claims it before
     evaluating — so a plan is either cancelled (never applied; the
     submitter may safely replan without double-committing placements) or
-    claimed (the applier owns it; the submitter must keep waiting)."""
+    claimed (the applier owns it; the submitter must keep waiting).
 
-    def __init__(self):
+    The future also carries the round trip's own clock: five
+    ``perf_counter`` stamps (enqueued, claimed by the applier, evaluate
+    done, commit entered, responded; 0.0 = never reached), read by the
+    submitter once ``wait()`` returns (``WorkerPlanner.submit_plan``
+    turns them into the ``plan.queue_wait`` / ``.commit_wait`` /
+    ``.wake`` samples), and ``trace_parent``, the submitter's
+    ``worker.submit_plan`` span id while the tracer is armed (0
+    otherwise), which the applier's spans take as their parent."""
+
+    def __init__(self, trace_parent: int = 0):
         self._event = threading.Event()
         self._result: Optional[s.PlanResult] = None
         self._error: Optional[Exception] = None
         self._state_l = threading.Lock()
         self._claimed = False
         self._cancelled = False
+        self.trace_parent = trace_parent
+        self.t_enqueued = time.perf_counter()
+        self.t_claimed = self.t_evaluated = 0.0
+        self.t_commit = self.t_responded = 0.0
 
     def claim(self) -> bool:
         """Applier-side: take ownership; False if already cancelled."""
@@ -40,6 +54,7 @@ class PlanFuture:
             if self._cancelled:
                 return False
             self._claimed = True
+            self.t_claimed = time.perf_counter()
             return True
 
     def cancel(self) -> bool:
@@ -54,6 +69,7 @@ class PlanFuture:
     def respond(self, result: Optional[s.PlanResult], error: Optional[Exception]):
         self._result = result
         self._error = error
+        self.t_responded = time.perf_counter()
         self._event.set()
         return self
 
@@ -127,9 +143,9 @@ class PlanQueue:
                 self._heap = []
             self._cond.notify_all()
 
-    def enqueue(self, plan: s.Plan) -> PlanFuture:
+    def enqueue(self, plan: s.Plan, trace_parent: int = 0) -> PlanFuture:
         """(plan_queue.go:95)."""
-        future = PlanFuture()
+        future = PlanFuture(trace_parent)
         with self._l:
             if not self._enabled:
                 raise RuntimeError("plan queue is disabled")
@@ -140,9 +156,7 @@ class PlanQueue:
         return future
 
     def dequeue(self, timeout: Optional[float] = None) -> Optional[Tuple[s.Plan, PlanFuture]]:
-        import time as _time
-
-        deadline = None if timeout is None else _time.monotonic() + timeout
+        deadline = None if timeout is None else time.monotonic() + timeout
         with self._l:
             while True:
                 if not self._enabled:
@@ -150,7 +164,7 @@ class PlanQueue:
                 if self._heap:
                     pending = heapq.heappop(self._heap)
                     return pending.plan, pending.future
-                remaining = None if deadline is None else deadline - _time.monotonic()
+                remaining = None if deadline is None else deadline - time.monotonic()
                 if remaining is not None and remaining <= 0:
                     return None
                 self._cond.wait(remaining if remaining is not None else 1.0)

@@ -1384,6 +1384,23 @@ class Server:
                 and not job.is_parameterized():
             self._check_tenant_admission(job)
 
+        # The two raft applies (job, then its eval), timed as one:
+        # sample ``job.register`` always, a span of the same two stamps
+        # when the tracer is armed (tagged with the eval id, so the
+        # eval's timeline starts here, under the HTTP request).
+        tr = tracing.TRACER
+        t0 = tracing.now()
+        if tr is None:
+            out = self._job_register_apply(job, None)
+            t1 = tracing.now()
+        else:
+            with tr.span("job.register", start=t0, job_id=job.id) as sp:
+                out = self._job_register_apply(job, sp)
+            t1 = sp.end
+        self.metrics.add_sample("job.register", (t1 - t0) * 1000.0)
+        return out
+
+    def _job_register_apply(self, job: s.Job, sp) -> Tuple[int, str]:
         try:
             _, index = self.raft.apply(MessageType.JOB_REGISTER, {"job": job})
         except NotLeaderError:
@@ -1404,10 +1421,11 @@ class Server:
             )
             # Open the eval.e2e umbrella (submit → broker ack) before
             # the eval write so the span covers enqueue + queue wait.
-            tr = tracing.TRACER
-            if tr is not None:
-                tr.mark(ev.id, job_id=job.id, submit="job_register",
-                        priority=job.priority, namespace=job.namespace)
+            if sp is not None:
+                sp.set(eval_id=ev.id)
+                tracing.mark(ev.id, job_id=job.id, submit="job_register",
+                             priority=job.priority,
+                             namespace=job.namespace)
             _, eval_index = self.raft.apply(MessageType.EVAL_UPDATE, {"evals": [ev]})
             eval_id = ev.id
         return index, eval_id

@@ -89,9 +89,14 @@ class WorkerPlanner:
             tr = tracing.TRACER
             submit_span = tracing.NOOP if tr is None else tr.span(
                 "worker.submit_plan", eval_id=self.eval.id)
-            with submit_span:
-                future = w.plan_queue.enqueue(plan)
+            with submit_span as sp:
+                # Armed, the span id rides the future: the applier's
+                # spans (other threads) name it as their parent.
+                future = (w.plan_queue.enqueue(plan) if tr is None
+                          else w.plan_queue.enqueue(
+                              plan, trace_parent=sp.span_id))
                 result = future.wait()
+                self._emit_round_trip(future, tracing.now(), tr)
         finally:
             try:
                 w.broker.resume_nack_timeout(self.eval.id, self.token)
@@ -111,6 +116,30 @@ class WorkerPlanner:
                 w._snap_cache = (idx, state)
             self.snapshot_index = idx
         return result, state
+
+    def _emit_round_trip(self, future, t_woke: float, tr) -> None:
+        """The plan round trip's hand-offs, from the stamps the future
+        collected: queue_wait (enqueued → claimed by the applier),
+        commit_wait (evaluate done → _commit entered; 0 for a plan with
+        nothing to commit) and wake (responded → this thread running
+        again).  With plan.evaluate and plan.apply they sum to the round
+        trip.  A remote future (follower scheduling) carries no stamps
+        and emits nothing."""
+        t_claimed = getattr(future, "t_claimed", 0.0)
+        if not t_claimed or not future.t_responded:
+            return
+        t_commit = future.t_commit or future.t_evaluated
+        m = self.worker.metrics
+        m.add_sample("plan.queue_wait",
+                     (t_claimed - future.t_enqueued) * 1000.0)
+        m.add_sample("plan.commit_wait",
+                     (t_commit - future.t_evaluated) * 1000.0)
+        m.add_sample("plan.wake", (t_woke - future.t_responded) * 1000.0)
+        if tr is not None:
+            # Recorded inside worker.submit_plan: it is their parent.
+            tr.record("plan.queue_wait", future.t_enqueued, t_claimed)
+            tr.record("plan.commit_wait", future.t_evaluated, t_commit)
+            tr.record("plan.wake", future.t_responded, t_woke)
 
     def update_eval(self, ev: s.Evaluation) -> None:
         self.worker.apply_eval_updates([ev])
@@ -601,11 +630,27 @@ class BatchWorker(Worker):
         return kernels.compile_guard(
             functools.partial(self._nack_clocks_held, batch))
 
+    def _snapshot(self):
+        """The batch's state snapshot, timed: sample ``worker.snapshot``
+        always, a span of the same two stamps when the tracer is armed."""
+        tr = tracing.TRACER
+        t0 = tracing.now()
+        if tr is None:
+            snap = self.raft.fsm.state.snapshot()
+            t1 = tracing.now()
+        else:
+            with tr.span("worker.snapshot", annotate=True, start=t0) as sp:
+                snap = self.raft.fsm.state.snapshot()
+            t1 = sp.end
+        self.metrics.add_sample("worker.snapshot", (t1 - t0) * 1000.0)
+        return snap
+
     def _process_batch(self, batch: List[Tuple[s.Evaluation, str]]):
         """Returns the batch's BatchStats, or None when the batch was
         nacked."""
         max_index = max(ev.modify_index for ev, _ in batch)
-        with tracing.span("worker.wait_for_index"):
+        with self.metrics.measure("worker.wait_for_index"), \
+                tracing.span("worker.wait_for_index"):
             self.wait_for_index(max_index, RAFT_SYNC_LIMIT)
         # Always a fresh snapshot on the batch path: the device-resident
         # usage mirror advances by inter-snapshot deltas, and a reused
@@ -614,7 +659,7 @@ class BatchWorker(Worker):
         # the per-eval stale-snapshot pool tolerates, the batched kernel
         # path should not).
         snapshot_index = self.raft.applied_index()
-        snap = self.raft.fsm.state.snapshot()
+        snap = self._snapshot()
 
         # One scheduler instance per batch; per-eval planners for correct
         # token fencing on ack/nack.
@@ -726,7 +771,7 @@ class BatchWorker(Worker):
             max_index = max(ev.modify_index for ev, _ in batch)
             self.wait_for_index(max_index, RAFT_SYNC_LIMIT)
             snapshot_index = self.raft.applied_index()
-            snap = self.raft.fsm.state.snapshot()
+            snap = self._snapshot()
             mux = _MuxPlanner(self, batch, snapshot_index)
             sched = TPUBatchScheduler(self.logger, snap, mux,
                                       mesh=self.mesh, metrics=self.metrics,

@@ -94,9 +94,6 @@ _knob("NOMAD_TPU_QUANT", "bool", True,
 _knob("NOMAD_TPU_RNG_SEED", "int", None,
       "Pin the per-batch tie-break jitter seed for deterministic "
       "placement reproduction")
-_knob("NOMAD_TPU_TIMING", "str", "",
-      "Timing diagnostics: 1 = phase summaries, 2 = staged two-phase "
-      "sync split (diagnostics only)")
 _knob("NOMAD_TPU_PREEMPTION", "bool", False,
       "Default for schedulers constructed without an explicit "
       "preemption flag")

@@ -30,6 +30,8 @@ import time
 import traceback
 from typing import Dict, Optional
 
+from . import tracing
+
 _profile_lock = threading.Lock()
 
 
@@ -135,11 +137,22 @@ def thread_dump() -> str:
     return out.getvalue()
 
 
+#: The host event that joins a capture to the span clock: written at
+#: ``start()`` while its ``tracing.now()`` reading is taken, so
+#: ``trace clock - perf_counter = marker's start in the trace -
+#: marker_perf_counter`` for every span of the interval.
+CLOCK_MARKER = "nomad_tpu_clock_marker"
+HOST_SPANS_FILE = "host_spans.json"
+
+
 class DeviceTracer:
     """Bounded jax.profiler trace sessions (device-side profiling).
 
     One active trace at a time; the trace directory is returned so the
-    operator can pull it into TensorBoard/XProf."""
+    operator can pull it into TensorBoard/XProf.  A capture carries its
+    own join to the eval-lifecycle spans (utils/tracing.py): the clock
+    marker above, and, when the tracer is armed, the interval's spans
+    beside the trace in ``host_spans.json``."""
 
     def __init__(self, base_dir: Optional[str] = None):
         import os
@@ -150,6 +163,7 @@ class DeviceTracer:
         self._lock = threading.Lock()
         self._active_dir: Optional[str] = None
         self._started_at = 0.0
+        self._marker_pc = 0.0
 
     def start(self) -> str:
         import os
@@ -163,21 +177,45 @@ class DeviceTracer:
             d = os.path.join(self.base_dir, time.strftime("%Y%m%d-%H%M%S"))
             os.makedirs(d, exist_ok=True)
             jax.profiler.start_trace(d)
+            with jax.profiler.TraceAnnotation(CLOCK_MARKER):
+                self._marker_pc = tracing.now()
             self._active_dir = d
             self._started_at = time.monotonic()
             return d
 
     def stop(self) -> Dict:
+        import json
+        import os
+
         import jax
 
         with self._lock:
             if self._active_dir is None:
                 raise RuntimeError("no active trace")
             jax.profiler.stop_trace()
+            t_stop = tracing.now()
             d, self._active_dir = self._active_dir, None
-            return {"dir": d,
+            info = {"dir": d,
                     "duration_s": round(time.monotonic() - self._started_at,
-                                        3)}
+                                        3),
+                    "marker": CLOCK_MARKER,
+                    "marker_perf_counter": self._marker_pc}
+            tr = tracing.TRACER
+            if tr is not None:
+                # The interval's spans, on the span clock: add
+                # (marker's start in the trace - marker_perf_counter)
+                # to put them on the trace's.
+                spans = [sp for sp in tr.recent(tr.capacity)
+                         if sp["End"] >= self._marker_pc
+                         and sp["Start"] <= t_stop]
+                path = os.path.join(d, HOST_SPANS_FILE)
+                with open(path, "w") as fh:
+                    json.dump({"marker": CLOCK_MARKER,
+                               "marker_perf_counter": self._marker_pc,
+                               "dropped": tr.dropped, "spans": spans},
+                              fh, default=str)
+                info["host_spans"] = path
+            return info
 
     def capture(self, seconds: float = 1.0) -> Dict:
         """start → sleep → stop in one bounded call (the /trace?seconds=N

@@ -19,8 +19,17 @@ are no locks, no allocations, no timestamps.  Arm process-wide with
 Threading model: spans nest via a thread-local stack (parent linkage
 within a thread); an eval's lifecycle *crosses* threads (RPC handler →
 worker → plan applier), so cross-thread correlation is by ``eval_id``
-attr, not parent pointers.  ``trace_for_eval`` returns every span
-tagged with the eval, sorted by start time — the timeline.
+attr.  Where one span *causes* work on another thread (a submitted plan
+→ the applier's evaluate/apply) the cause's span id travels with the
+work and the far side passes it as ``parent_id=``, so self time (a
+span less its children) is computable across the hand-off.
+``trace_for_eval`` returns every span tagged with the eval, sorted by
+start time — the timeline.
+
+One clock with the device trace: batch-level spans opened with
+``annotate=True`` also enter a ``jax.profiler.TraceAnnotation`` of the
+same name, so a profiler capture's host line carries them on the
+trace's own clock (free while no capture is running).
 
 Correlation with the chaos plane: ``fault.py`` reports every rule fire
 here (``note_fault`` → a ``fault.fire`` span carrying the same
@@ -40,7 +49,8 @@ from typing import Any, Dict, List, Optional
 __all__ = [
     "Span", "Tracer", "TRACER", "NOOP", "now",
     "enable", "disable", "enabled", "span", "event", "record",
-    "trace_for_eval", "recent", "note_fault", "mark", "close_mark",
+    "trace_for_eval", "recent", "dropped", "note_fault", "mark",
+    "close_mark",
 ]
 
 #: The span clock.  ``time.perf_counter()``: monotonic like
@@ -138,26 +148,50 @@ class _NoopSpan:
 NOOP = _NOOP = _NoopSpan()
 
 
+_TRACE_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` (imported on first use: this
+    module must stay importable without touching JAX)."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name)
+
+
 class _ActiveSpan:
     """Context manager pushing/popping one span on the thread-local
-    stack; an exception escaping the block is recorded on the span."""
+    stack; an exception escaping the block is recorded on the span.
+    ``finish(end)`` closes it at a stamp the caller already took, so a
+    sample and its span share their two ``perf_counter`` reads."""
 
-    __slots__ = ("tracer", "sp")
+    __slots__ = ("tracer", "sp", "_ann")
 
-    def __init__(self, tracer: "Tracer", sp: Span):
+    def __init__(self, tracer: "Tracer", sp: Span, annotate: bool):
         self.tracer = tracer
         self.sp = sp
+        self._ann = _annotation(sp.name) if annotate else None
 
     def __enter__(self) -> Span:
         self.tracer._push(self.sp)
+        if self._ann is not None:
+            self._ann.__enter__()
         return self.sp
 
     def __exit__(self, etype, evalue, tb) -> bool:
         if etype is not None:
             self.sp.attrs.setdefault("error", etype.__name__)
             self.sp.attrs.setdefault("error_detail", str(evalue))
-        self.tracer._pop(self.sp)
+        self.finish()
         return False
+
+    def finish(self, end: Optional[float] = None) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self.tracer._pop(self.sp, end)
 
 
 class Tracer:
@@ -170,6 +204,10 @@ class Tracer:
         self._l = threading.Lock()
         self._seq = itertools.count(1)
         self._spans: deque = deque(maxlen=max(16, capacity))
+        # Spans ever stored; what the ring no longer holds is
+        # ``dropped`` (a reader of ``recent()`` must know whether the
+        # interval it wants was evicted).
+        self.recorded = 0
         self._by_eval: "OrderedDict[str, _EvalBucket]" = OrderedDict()
         self.max_evals = max(1, max_evals)
         self._local = threading.local()
@@ -188,13 +226,13 @@ class Tracer:
     def _push(self, sp: Span) -> None:
         self._stack().append(sp)
 
-    def _pop(self, sp: Span) -> None:
+    def _pop(self, sp: Span, end: Optional[float] = None) -> None:
         stk = self._stack()
         if stk and stk[-1] is sp:
             stk.pop()
         elif sp in stk:  # defensive: mis-nested exit
             stk.remove(sp)
-        sp.end = now()
+        sp.end = now() if end is None else end
         self._record(sp)
 
     def current(self) -> Optional[Span]:
@@ -203,13 +241,23 @@ class Tracer:
 
     # -- span creation -----------------------------------------------------
 
-    def _new_span(self, name: str, attrs: Dict[str, Any]) -> Span:
+    def reserve_id(self) -> int:
+        """A span id handed out ahead of its span: children recorded
+        first name it as ``parent_id=``, the parent is ``record``ed
+        afterwards with ``span_id=`` (the batch.device children)."""
+        return next(self._seq)
+
+    def _new_span(self, name: str, attrs: Dict[str, Any],
+                  parent_id: Optional[int] = None,
+                  span_id: Optional[int] = None,
+                  start: Optional[float] = None) -> Span:
         evs = attrs.get("eval_ids")
         if evs is not None and len(evs) > MAX_EVAL_IDS_PER_SPAN:
             attrs["eval_ids"] = list(evs[:MAX_EVAL_IDS_PER_SPAN])
             attrs["eval_ids_elided"] = len(evs) - MAX_EVAL_IDS_PER_SPAN
         parent = self.current()
-        parent_id = parent.span_id if parent is not None else 0
+        if not parent_id:
+            parent_id = parent.span_id if parent is not None else 0
         # Inherit the eval correlation key from the enclosing span so
         # inner spans (wait_for_index, phases) need not repeat it.
         if parent is not None and "eval_id" not in attrs \
@@ -221,10 +269,24 @@ class Tracer:
                 pevs = parent.attrs.get("eval_ids")
                 if pevs is not None:
                     attrs["eval_ids"] = pevs
-        return Span(next(self._seq), parent_id, name, now(), attrs)
+        sp = Span(span_id or next(self._seq), parent_id, name, now(), attrs)
+        if start is not None:
+            # A stamp the caller already took: backdate the wall clock
+            # along with the monotonic start.
+            sp.wall -= sp.start - start
+            sp.start = start
+        return sp
 
-    def span(self, name: str, **attrs: Any) -> _ActiveSpan:
-        return _ActiveSpan(self, self._new_span(name, attrs))
+    def span(self, name: str, *, parent_id: Optional[int] = None,
+             annotate: bool = False, start: Optional[float] = None,
+             **attrs: Any) -> _ActiveSpan:
+        """``parent_id``: the span on ANOTHER thread that caused this one
+        (default: the enclosing span on this thread).  ``annotate``: also
+        enter a profiler TraceAnnotation (batch-level spans only).
+        ``start``: a ``tracing.now()`` stamp the caller already took."""
+        return _ActiveSpan(
+            self, self._new_span(name, attrs, parent_id, start=start),
+            annotate)
 
     def event(self, name: str, **attrs: Any) -> Span:
         """Zero-duration span (a point-in-time lifecycle marker:
@@ -233,16 +295,13 @@ class Tracer:
         self._record(sp)
         return sp
 
-    def record(self, name: str, start: float, end: float,
-               **attrs: Any) -> Span:
+    def record(self, name: str, start: float, end: float, *,
+               parent_id: Optional[int] = None,
+               span_id: Optional[int] = None, **attrs: Any) -> Span:
         """Retroactively record a completed span from already-measured
         ``tracing.now()`` timestamps (the batch scheduler's phase
         timers)."""
-        sp = self._new_span(name, attrs)
-        # Backdate the wall clock along with the monotonic start — it was
-        # stamped at creation (i.e. the phase's END), not at `start`.
-        sp.wall -= sp.start - start
-        sp.start = start
+        sp = self._new_span(name, attrs, parent_id, span_id, start)
         sp.end = end
         self._record(sp)
         return sp
@@ -287,6 +346,7 @@ class Tracer:
             keys.extend(evs)
         with self._l:
             self._spans.append(sp)
+            self.recorded += 1
             for key in keys:
                 bucket = self._by_eval.get(key)
                 if bucket is None:
@@ -325,6 +385,15 @@ class Tracer:
         with self._l:
             spans = list(self._spans)
         return [sp.to_dict() for sp in spans[-n:]]
+
+    @property
+    def capacity(self) -> int:
+        return self._spans.maxlen
+
+    @property
+    def dropped(self) -> int:
+        """Spans the recency ring has evicted."""
+        return max(0, self.recorded - self._spans.maxlen)
 
 
 # -- process-wide arming ------------------------------------------------------
@@ -391,6 +460,11 @@ def trace_for_eval(eval_id: str) -> List[Dict[str, Any]]:
 def recent(n: int = 100) -> List[Dict[str, Any]]:
     tr = TRACER
     return tr.recent(n) if tr is not None else []
+
+
+def dropped() -> int:
+    tr = TRACER
+    return tr.dropped if tr is not None else 0
 
 
 def mark(eval_id: str, **attrs: Any) -> None:

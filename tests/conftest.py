@@ -5,6 +5,7 @@ Force JAX onto a virtual 8-device CPU platform so multi-chip sharding tests
 strategy of simulating multi-node in-process (SURVEY.md §4 item 3).
 Must run before jax is imported anywhere.
 """
+import contextlib
 import os
 import sys
 
@@ -158,3 +159,83 @@ def dev_test_config():
     cfg = AgentConfig.dev()
     cfg.ports.http = 0
     return cfg
+
+
+def wait_for(predicate, timeout=30.0, interval=0.02):
+    import time
+
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if predicate():
+            return True
+        time.sleep(interval)
+    return bool(predicate())
+
+
+def batch_job(count=2):
+    """mock.job() without its network ask (the device path's shape)."""
+    from nomad_tpu import mock
+
+    job = mock.job()
+    job.task_groups[0].count = count
+    for t in job.task_groups[0].tasks:
+        t.resources.networks = []
+    return job
+
+
+def put_job(agent, job):
+    """``PUT /v1/jobs`` over the agent's HTTP port; returns the eval id."""
+    import json
+    import urllib.request
+
+    from nomad_tpu.api.codec import to_wire
+
+    req = urllib.request.Request(
+        agent.http.address + "/v1/jobs", method="PUT",
+        data=json.dumps({"Job": to_wire(job)}).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=30) as resp:
+        return json.loads(resp.read())["EvalID"]
+
+
+@contextlib.contextmanager
+def served_job(data_dir=None, count=2, nodes=3):
+    """One job through the served device path on the CPU backend: an
+    Agent (server only, BatchWorker) takes ``PUT /v1/jobs`` over HTTP and
+    the block yields ``(agent, job, eval_id)`` once the eval is complete,
+    its allocations are in the state store and the broker has acked it.
+    ``data_dir`` makes the server durable (FileLog + WAL: raft.fsync)."""
+    from nomad_tpu import mock
+    from nomad_tpu.agent.agent import Agent
+    from nomad_tpu.structs import structs as s
+
+    cfg = dev_test_config()
+    cfg.client.enabled = False
+    cfg.server.use_tpu_batch_worker = True
+    cfg.server.batch_size = 8
+    if data_dir is not None:
+        cfg.dev_mode = False
+        cfg.data_dir = cfg.server.data_dir = str(data_dir)
+    agent = Agent(cfg)
+    agent.start()
+    try:
+        srv = agent.server
+        for _ in range(nodes):
+            node = mock.node()
+            node.resources.networks = []
+            node.reserved.networks = []
+            srv.node_register(node)
+        job = batch_job(count)
+        eval_id = put_job(agent, job)
+
+        def done():
+            ev = srv.state.eval_by_id(None, eval_id)
+            return (ev is not None and ev.status == s.EVAL_STATUS_COMPLETE
+                    and len(srv.state.allocs_by_job(None, job.id, True))
+                    == count
+                    and srv.eval_broker.stats()["total_unacked"] == 0)
+
+        assert wait_for(done, 60.0), f"eval {eval_id} did not complete"
+        yield agent, job, eval_id
+    finally:
+        agent.shutdown()

@@ -1,0 +1,88 @@
+"""Every sink key a benchmark metric file names is a key the program
+publishes.
+
+A per-layer metric is a data file ``benchmarks/metrics/<name>.json``
+naming a reader and a sink key; a key the program has renamed reads as a
+silent ``null`` (a sample) or a silent 0 (a counter that must read 0) in
+the ledger.  One job is served on the CPU backend through the path the
+benchmark drives (durable Agent, ``PUT /v1/jobs``, BatchWorker), then the
+three events whose counters a healthy job never touches are provoked (an
+operator's snapshot, a rejected kernel result, a plan that does not fit),
+and every ``key`` and ``per`` of a sink reader has to be there."""
+import glob
+import json
+import os
+
+import pytest
+
+import conftest
+
+from nomad_tpu import fault, mock
+from nomad_tpu.structs import structs as s
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SINK_READERS = ("sample_mean", "counter_delta", "counter_per_sample")
+
+
+def _sink_metrics():
+    out = []
+    for path in sorted(glob.glob(
+            os.path.join(ROOT, "benchmarks", "metrics", "*.json"))):
+        with open(path) as fh:
+            spec = json.load(fh)
+        if spec.get("reader") in SINK_READERS:
+            out.append(pytest.param(spec, id=os.path.basename(path)[:-5]))
+    return out
+
+
+METRICS = _sink_metrics()
+
+
+@pytest.fixture(scope="module")
+def sink(tmp_path_factory):
+    data_dir = tmp_path_factory.mktemp("metric-keys")
+    with conftest.served_job(data_dir=data_dir) as (agent, job, _eval_id):
+        srv = agent.server
+        # raft.snapshot: the operator's snapshot the benchmark takes at
+        # the end of set-up.
+        srv.raft.snapshot()
+        # plan.conflict: a plan whose allocation cannot fit its node.
+        node = next(iter(srv.state.nodes(None)))
+        hog = mock.alloc()
+        hog.job_id, hog.node_id = job.id, node.id
+        hog.resources = s.Resources(cpu=node.resources.cpu * 4,
+                                    memory_mb=node.resources.memory_mb * 4)
+        hog.task_resources = {}
+        plan = s.Plan(eval_id=s.generate_uuid(), job=job)
+        plan.append_alloc(hog)
+        result = srv.plan_submit(plan).wait(30.0)
+        assert result.refresh_index > 0
+        # breaker.oracle_routed: one corrupted kernel result, rejected
+        # and served by the oracle.
+        with fault.scenario({"seed": 1, "faults": [
+                {"point": "ops.kernel_result", "action": "corrupt",
+                 "times": 1}]}):
+            second = conftest.batch_job(2)
+            conftest.put_job(agent, second)
+            assert conftest.wait_for(
+                lambda: len(srv.state.allocs_by_job(None, second.id, True))
+                == 2, 60.0)
+        latest = srv.metrics.sink.latest()
+        yield {"samples": set(latest["SampleTotals"]),
+               "counters": set(latest["CounterTotals"])}
+
+
+def test_there_are_sink_metrics():
+    assert len(METRICS) >= 40
+
+
+@pytest.mark.parametrize("spec", METRICS)
+def test_metric_file_names_a_published_key(sink, spec):
+    reader = spec["reader"]
+    if reader == "sample_mean":
+        assert spec["key"] in sink["samples"], spec
+    elif reader == "counter_delta":
+        assert spec["key"] in sink["counters"], spec
+    else:
+        assert spec["key"] in sink["counters"], spec
+        assert spec["per"] in sink["samples"], spec

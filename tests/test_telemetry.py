@@ -2,6 +2,8 @@
 website/source/docs/agent/telemetry.html.md)."""
 import time
 
+import pytest
+
 import conftest
 
 from nomad_tpu import mock
@@ -202,6 +204,69 @@ class TestPrometheusRendering:
                 assert values[f"{base}_count"] >= 1.0
         finally:
             agent.shutdown()
+
+
+class TestServedBatchKeys:
+    """One job over HTTP through the BatchWorker: the always-on samples
+    and the counter of ISSUE 26, under their published names."""
+
+    NEW_SAMPLES = (
+        ["worker.invoke_scheduler.device." + st for st in
+         ("stage", "dispatch", "wait", "fetch", "decode")]
+        + ["worker.invoke_scheduler.prepare",
+           "worker.invoke_scheduler.expand",
+           "worker.invoke_scheduler.batch", "worker.snapshot",
+           "worker.wait_for_index",
+           "worker.invoke_scheduler.finalize.build",
+           "worker.invoke_scheduler.finalize.submit",
+           "worker.invoke_scheduler.finalize.status",
+           "plan.queue_wait", "plan.evaluate", "plan.commit_wait",
+           "plan.apply", "plan.wake", "broker.wait",
+           "http.request.PUT.jobs", "job.register"])
+
+    @pytest.fixture(scope="class")
+    def latest(self):
+        with conftest.served_job(count=2) as (agent, _job, _eval_id):
+            sink = agent.server.metrics.sink
+            # .batch closes just after the ack the helper waited for
+            assert wait_until(
+                lambda: "nomad.worker.invoke_scheduler.batch"
+                in sink.latest()["SampleTotals"], 10.0)
+            yield sink.latest()
+
+    @pytest.mark.parametrize("key", NEW_SAMPLES)
+    def test_sample_is_published_once_per_unit(self, latest, key):
+        count, total = latest["SampleTotals"]["nomad." + key]
+        assert count == 1, (key, count)     # one batch, one plan, one job
+        assert total >= 0.0
+
+    def test_allocs_committed_counts_the_plan(self, latest):
+        assert latest["CounterTotals"]["nomad.plan.allocs_committed"] == 2
+
+    @pytest.mark.parametrize("key", ["worker.invoke_scheduler.commit",
+                                     "worker.invoke_scheduler.fetch"])
+    def test_overlapping_samples_are_gone(self, latest, key):
+        assert "nomad." + key not in latest["SampleTotals"]
+
+    def test_splits_sum_to_their_wholes(self, latest):
+        tot = {k[len("nomad."):]: v[1]
+               for k, v in latest["SampleTotals"].items()}
+        k = "worker.invoke_scheduler"
+        device = sum(tot[f"{k}.device.{st}"] for st in
+                     ("stage", "dispatch", "wait", "fetch", "decode"))
+        assert device == pytest.approx(tot[k + ".device"], rel=0.05)
+        finalize = sum(tot[f"{k}.finalize.{st}"]
+                       for st in ("build", "submit", "status"))
+        assert finalize == pytest.approx(tot[k + ".finalize"], rel=0.05)
+        assert finalize <= tot[k + ".finalize"]
+        trip = sum(tot["plan." + st] for st in
+                   ("queue_wait", "evaluate", "commit_wait", "apply",
+                    "wake"))
+        assert trip <= tot[k + ".finalize.submit"]
+        batch = sum(tot[f"{k}.{st}"] for st in
+                    ("prepare", "encode", "device", "expand", "finalize"))
+        assert batch == pytest.approx(tot[k], rel=0.05)
+        assert tot[k] <= tot[k + ".batch"]
 
 
 class TestServerEmitters:

@@ -112,6 +112,44 @@ class TestTracerMechanics:
         assert tracing.trace_for_eval("e0") == []
         assert tracing.trace_for_eval("e99")
 
+    def test_ring_evictions_are_counted(self):
+        tr = tracing.enable(capacity=32)
+        for i in range(40):
+            tr.event("tick")
+        assert tr.recorded == 40
+        assert tr.dropped == tracing.dropped() == 8
+        assert len(tr.recent(1000)) == 32
+
+    def test_explicit_parent_and_reserved_id(self):
+        tr = tracing.TRACER
+        with tracing.span("cause") as cause:
+            pass
+        # another thread's work names its cause; default = enclosing span
+        with tracing.span("effect", parent_id=cause.span_id):
+            tracing.event("inner")
+        tracing.record("late", 1.0, 2.0, parent_id=cause.span_id)
+        rid = tr.reserve_id()
+        tracing.record("child", 1.0, 1.5, parent_id=rid)
+        tracing.record("parent", 1.0, 2.0, span_id=rid)
+        by = {sp["Name"]: sp for sp in tracing.recent(10)}
+        assert by["effect"]["ParentID"] == by["cause"]["SpanID"]
+        assert by["inner"]["ParentID"] == by["effect"]["SpanID"]
+        assert by["late"]["ParentID"] == by["cause"]["SpanID"]
+        assert by["child"]["ParentID"] == by["parent"]["SpanID"] == rid
+
+    def test_span_takes_and_returns_caller_stamps(self):
+        t0 = tracing.now()
+        with tracing.span("stamped", start=t0, annotate=True) as sp:
+            pass
+        (out,) = [x for x in tracing.recent(5) if x["Name"] == "stamped"]
+        assert out["Start"] == t0 and out["End"] == sp.end >= t0
+        asp = tracing.TRACER.span("finished", start=t0)
+        asp.__enter__()
+        asp.finish(t0 + 1.0)
+        (out,) = [x for x in tracing.recent(5) if x["Name"] == "finished"]
+        assert (out["Start"], out["End"]) == (t0, t0 + 1.0)
+        assert tracing.TRACER.current() is None
+
     def test_fault_fire_correlation(self):
         with fault.scenario({"seed": 3, "faults": [
                 {"point": "heartbeat.deliver", "action": "drop",
@@ -168,12 +206,124 @@ class TestEvalLifecycleTrace:
             assert starts == sorted(starts), list(zip(order, starts))
             for sp in spans:
                 assert sp["End"] >= sp["Start"]
-            # phases are parented under the batch.schedule root
+            # phases are parented under the batch.schedule root, the two
+            # prepare phases through batch.prepare
             root = by_name["batch.schedule"]["SpanID"]
-            assert by_name["batch.phase1"]["ParentID"] == root
+            assert by_name["batch.prepare"]["ParentID"] == root
+            assert by_name["batch.phase1"]["ParentID"] == \
+                by_name["batch.prepare"]["SpanID"]
             assert by_name["batch.finalize"]["ParentID"] == root
         finally:
             srv.shutdown()
+
+
+class TestServedBatchSpanTree:
+    """One job served over HTTP through the BatchWorker: the span tree
+    under the two spans that were opaque (the device call, the plan
+    round trip), broker wait, and the request that started it all."""
+
+    @pytest.fixture(scope="class")
+    def served(self):
+        tracing.enable()
+        try:
+            with conftest.served_job() as (agent, job, eval_id):
+                assert wait_until(
+                    lambda: any(sp["Name"] == "broker.ack" for sp in
+                                tracing.trace_for_eval(eval_id)),
+                    timeout=10.0)
+                yield {"eval_id": eval_id,
+                       "timeline": tracing.trace_for_eval(eval_id),
+                       "all": tracing.recent(4096)}
+        finally:
+            tracing.disable()
+
+    @staticmethod
+    def _one(spans, name):
+        found = [sp for sp in spans if sp["Name"] == name]
+        assert len(found) == 1, (name, [sp["Name"] for sp in spans])
+        return found[0]
+
+    def test_device_stages_tile_the_device_call(self, served):
+        spans = served["timeline"]
+        device = self._one(spans, "batch.device")
+        stages = [self._one(spans, "batch.device." + name) for name in
+                  ("stage", "dispatch", "wait", "fetch", "decode")]
+        # children of batch.device (an id reserved before they ran),
+        # in order, first at its start and last at its end
+        for sp in stages:
+            assert sp["ParentID"] == device["SpanID"], sp
+        assert stages[0]["Start"] == device["Start"]
+        assert stages[-1]["End"] == device["End"]
+        length = device["End"] - device["Start"]
+        assert length > 0
+        gaps = 0.0
+        for a, b in zip(stages, stages[1:]):
+            assert b["Start"] >= a["End"], (a, b)
+            gaps += b["Start"] - a["End"]
+        assert gaps < 0.05 * length, (gaps, length)
+        covered = sum(sp["End"] - sp["Start"] for sp in stages)
+        assert covered == pytest.approx(length - gaps, rel=1e-6)
+        # the existing fetch span sits inside the fetch stage
+        fetch = self._one(spans, "batch.fetch")
+        assert fetch["ParentID"] == stages[3]["SpanID"]
+
+    def test_plan_spans_name_the_submit_span_across_threads(self, served):
+        spans = served["timeline"]
+        submit = self._one(spans, "worker.submit_plan")
+        for name in ("plan.queue_wait", "plan.evaluate",
+                     "plan.commit_wait", "plan.apply", "plan.wake"):
+            sp = self._one([x for x in served["all"]
+                            if x["ParentID"] == submit["SpanID"]], name)
+            assert submit["Start"] <= sp["Start"]
+            assert sp["End"] <= submit["End"]
+        # the raft apply under plan.apply, on the commit thread
+        apply_ = self._one(spans, "plan.apply")
+        assert any(sp["Name"] == "raft.apply"
+                   and sp["ParentID"] == apply_["SpanID"]
+                   for sp in served["all"])
+        # the five stages leave little of the round trip unnamed
+        named = sum(sp["End"] - sp["Start"] for sp in served["all"]
+                    if sp["ParentID"] == submit["SpanID"])
+        assert named <= (submit["End"] - submit["Start"]) * (1 + 1e-9)
+
+    def test_finalize_and_prepare_stages(self, served):
+        spans = served["timeline"]
+        finalize = self._one(spans, "batch.finalize")
+        build, submit, status = (
+            self._one(spans, "batch.finalize." + name)
+            for name in ("build", "submit", "status"))
+        assert finalize["Start"] <= build["Start"]
+        assert build["End"] == submit["Start"]
+        assert submit["End"] == status["Start"]
+        assert status["End"] <= finalize["End"]
+        # worker.submit_plan is what batch.finalize.submit brackets
+        inner = self._one(spans, "worker.submit_plan")
+        assert submit["Start"] <= inner["Start"]
+        assert inner["End"] <= submit["End"]
+        prepare = self._one(spans, "batch.prepare")
+        for name in ("batch.phase1", "batch.phase2"):
+            assert self._one(spans, name)["ParentID"] == prepare["SpanID"]
+        self._one(served["all"], "worker.snapshot")
+
+    def test_broker_dequeue_carries_wait_ms(self, served):
+        spans = served["timeline"]
+        enq = self._one(spans, "broker.enqueue")
+        deq = self._one(spans, "broker.dequeue")
+        wait_ms = deq["Attrs"]["wait_ms"]
+        assert wait_ms >= 0.0
+        # ready → dequeued, so no longer than enqueue → dequeue
+        assert wait_ms <= (deq["Start"] - enq["Start"]) * 1000.0 + 1.0
+
+    def test_http_request_leads_the_timeline(self, served):
+        spans = served["timeline"]
+        first = spans[0]
+        assert first["Name"] == "http.request", [sp["Name"] for sp in spans]
+        assert first["Attrs"]["method"] == "PUT"
+        assert first["Attrs"]["route"] == "jobs"
+        register = self._one(spans, "job.register")
+        assert register["ParentID"] == first["SpanID"]
+        assert first["Start"] <= register["Start"]
+        assert register["End"] <= first["End"]
 
 
 class TestTraceHTTP:
@@ -206,6 +356,7 @@ class TestTraceHTTP:
                     agent.http.address + "/v1/traces?recent=5") as r:
                 body = json.loads(r.read())
             assert body["Enabled"] is True
+            assert body["Dropped"] == 0
             assert 0 < len(body["Spans"]) <= 5
 
             # unknown eval → 404
@@ -228,7 +379,7 @@ class TestTraceHTTP:
             with urllib.request.urlopen(
                     agent.http.address + "/v1/traces") as r:
                 body = json.loads(r.read())
-            assert body == {"Enabled": False, "Spans": []}
+            assert body == {"Enabled": False, "Dropped": 0, "Spans": []}
         finally:
             agent.shutdown()
 
