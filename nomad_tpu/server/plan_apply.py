@@ -27,6 +27,7 @@ reads live alloc rows that an in-flight plan could still change.
 """
 from __future__ import annotations
 
+import itertools
 import logging
 import os
 import queue as _queue
@@ -57,6 +58,61 @@ def _pipeline_depth() -> int:
     return max(1, knobs.get_int("NOMAD_TPU_PLAN_PIPELINE"))
 
 
+def _has_ports(alloc: s.Allocation) -> bool:
+    """Whether the alloc reserves network resources (allocs_fit owns
+    port and bandwidth accounting, so such a node is re-checked by it)."""
+    if alloc.resources is not None and alloc.resources.networks:
+        return True
+    return any(tr.networks for tr in alloc.task_resources.values())
+
+
+def _usage(alloc: s.Allocation) -> np.ndarray:
+    return np.array(s.alloc_usage_vec(alloc), dtype=np.int64)
+
+
+class _InflightPlan:
+    """One in-flight plan's placements, in the forms the fit re-check
+    reads: ``parts`` = (node-id column, usage vector) per slab and per
+    distinct per-object usage, for the array route; ``net_nodes`` = the
+    nodes an entry with network resources lands on (always re-checked by
+    allocs_fit); ``by_node()`` = (alloc, count) lists per node, built on
+    first use by a per-node route."""
+
+    __slots__ = ("result", "parts", "net_nodes", "_by_node")
+
+    def __init__(self, result: s.PlanResult):
+        self.result = result
+        self._by_node: Optional[Dict[str, list]] = None
+        net: set = set()
+        same_usage: Dict[tuple, List[str]] = {}
+        for node_id, allocs in result.node_allocation.items():
+            for alloc in allocs:
+                same_usage.setdefault(
+                    s.alloc_usage_vec(alloc), []).append(node_id)
+                if _has_ports(alloc):
+                    net.add(node_id)
+        self.parts: List[Tuple[List[str], tuple]] = []
+        for slab in result.alloc_slabs:
+            self.parts.append((slab.node_ids, s.alloc_usage_vec(slab.proto)))
+            if _has_ports(slab.proto):
+                net.update(slab.node_ids)
+        self.parts.extend((ids, vec) for vec, ids in same_usage.items())
+        self.net_nodes = net
+
+    def by_node(self) -> Dict[str, List[Tuple[s.Allocation, int]]]:
+        out = self._by_node
+        if out is None:
+            out = {}
+            for node_id, allocs in self.result.node_allocation.items():
+                for alloc in allocs:
+                    out.setdefault(node_id, []).append((alloc, 1))
+            for slab in self.result.alloc_slabs:
+                for node_id, cnt in slab.node_counts().items():
+                    out.setdefault(node_id, []).append((slab.proto, cnt))
+            self._by_node = out
+        return out
+
+
 class _InflightOverlay:
     """Placements of plans whose raft commit is still in flight, keyed
     by plan: the fit re-check adds them to each touched node's proposed
@@ -64,29 +120,58 @@ class _InflightOverlay:
 
     def __init__(self):
         self._l = threading.Lock()
-        self._plans: Dict[int, Dict[str, List[Tuple[s.Allocation, int]]]] = {}
+        self._plans: Dict[int, _InflightPlan] = {}
 
     def add(self, token: int, result: s.PlanResult) -> None:
-        by_node: Dict[str, List[Tuple[s.Allocation, int]]] = {}
-        for node_id, allocs in result.node_allocation.items():
-            for alloc in allocs:
-                by_node.setdefault(node_id, []).append((alloc, 1))
-        for slab in result.alloc_slabs:
-            for node_id, cnt in slab.node_counts().items():
-                by_node.setdefault(node_id, []).append((slab.proto, cnt))
+        entry = _InflightPlan(result)
         with self._l:
-            self._plans[token] = by_node
+            self._plans[token] = entry
 
     def remove(self, token: int) -> None:
         with self._l:
             self._plans.pop(token, None)
 
-    def pending_for(self, node_id: str) -> List[Tuple[s.Allocation, int]]:
+    def snapshot(self) -> List[_InflightPlan]:
+        """Every in-flight plan, read under ONE lock per fit re-check
+        (entries are immutable once added)."""
         with self._l:
-            out: List[Tuple[s.Allocation, int]] = []
-            for by_node in self._plans.values():
-                out.extend(by_node.get(node_id, ()))
-            return out
+            return list(self._plans.values())
+
+
+def _pending_map(inflight: List[_InflightPlan], node_ids
+                 ) -> Dict[str, List[Tuple[s.Allocation, int]]]:
+    """The captured overlay's (alloc, count) entries for ``node_ids``."""
+    if not inflight:
+        return {}
+    by_node = [p.by_node() for p in inflight]
+    return {nid: [e for m in by_node for e in m.get(nid, ())]
+            for nid in node_ids}
+
+
+class _Fits:
+    """One fit re-check's verdicts: ``fit[i]`` for mirror row
+    ``rows[i]`` (the array route) plus a per-node dict (the per-node
+    routes).  The node-id dict callers read is only built on demand."""
+
+    __slots__ = ("cols", "rows", "fit", "scalar")
+
+    def __init__(self, cols=None, scalar: Optional[Dict[str, bool]] = None):
+        self.cols = cols
+        self.rows = np.zeros(0, dtype=np.int64)
+        self.fit = np.zeros(0, dtype=bool)
+        self.scalar: Dict[str, bool] = scalar if scalar is not None else {}
+
+    def all_fit(self) -> bool:
+        return bool(self.fit.all()) and all(self.scalar.values())
+
+    def array_ids(self) -> List[str]:
+        ids = self.cols.node_ids if self.rows.size else ()
+        return [ids[r] for r in self.rows.tolist()]
+
+    def as_dict(self) -> Dict[str, bool]:
+        out = dict(zip(self.array_ids(), self.fit.tolist()))
+        out.update(self.scalar)
+        return out
 
 
 class PlanApplier:
@@ -291,25 +376,28 @@ class PlanApplier:
         Columnar alloc slabs (the TPU batch path) are kept whole on a full
         commit and filtered per node on a partial one."""
         result = s.PlanResult(node_update={}, node_allocation={})
-        touched = {*plan.node_update, *plan.node_allocation,
-                   *plan.node_preemptions}
-        for slab in plan.alloc_slabs:
-            touched.update(slab.node_ids)
-        node_ids = list(touched)
+        fits = self._evaluate_nodes(snap, plan)
+        if fits.all_fit():
+            # Every touched node fits: the plan commits as proposed, and
+            # no per-node verdict has to exist as a Python object.
+            for proposed, kept in (
+                    (plan.node_update, result.node_update),
+                    (plan.node_allocation, result.node_allocation),
+                    (plan.node_preemptions, result.node_preemptions)):
+                kept.update((nid, allocs) for nid, allocs in proposed.items()
+                            if allocs)
+            result.alloc_slabs.extend(plan.alloc_slabs)
+            return result
 
-        slab_adds = self._slab_node_adds(plan)
-        fits = self._evaluate_nodes(snap, plan, node_ids, slab_adds)
-
-        partial = False
         gang_failed = False
         ok_nodes = set()
-        for node_id, fit in fits.items():
+        for node_id, fit in fits.as_dict().items():
             if not fit:
-                partial = True
                 if plan.all_at_once:
                     # gang semantics: all or nothing
                     result.node_update = {}
                     result.node_allocation = {}
+                    result.node_preemptions = {}
                     gang_failed = True
                     break
                 continue
@@ -321,21 +409,14 @@ class PlanApplier:
             if plan.node_preemptions.get(node_id):
                 result.node_preemptions[node_id] = plan.node_preemptions[node_id]
 
-        if gang_failed:
-            result.node_preemptions = {}
-
         if not gang_failed:
             for slab in plan.alloc_slabs:
-                if not partial:
-                    result.alloc_slabs.append(slab)
-                else:
-                    filtered = slab.filter_nodes(ok_nodes)
-                    if len(filtered):
-                        result.alloc_slabs.append(filtered)
+                filtered = slab.filter_nodes(ok_nodes)
+                if len(filtered):
+                    result.alloc_slabs.append(filtered)
 
-        if partial:
-            result.refresh_index = max(
-                snap.table_index("nodes"), snap.table_index("allocs"))
+        result.refresh_index = max(
+            snap.table_index("nodes"), snap.table_index("allocs"))
         return result
 
     @staticmethod
@@ -347,22 +428,58 @@ class PlanApplier:
                 out.setdefault(nid, []).append((slab.proto, cnt))
         return out
 
-    def _evaluate_nodes(self, snap, plan: s.Plan, node_ids: List[str],
-                        slab_adds: Optional[Dict] = None) -> Dict[str, bool]:
-        slab_adds = slab_adds or {}
+    def _evaluate_nodes(self, snap, plan: s.Plan) -> _Fits:
+        """The per-node fit re-check.  Off the columnar mirror each
+        touched node takes one of two routes, chosen from what the plan
+        itself proposes there (_fit_columnar); a store without the
+        mirror is walked.  Differential guard: every
+        NOMAD_TPU_COLUMNAR_GUARD_EVERY evaluations the verdicts are
+        re-derived from the store's own rows and must agree (tests pin
+        the cadence to 1: every tier-1 plan is double-checked)."""
+        from ..state import columnar as colmod
+
         # Overlay FIRST, store second: a pipelined sibling whose commit
         # lands between the two reads is then counted TWICE (its
         # placements in the overlay snapshot AND in the store rows) —
         # conservative — instead of in neither view, which would let
         # two plans jointly over-commit a node.
-        overlay = {nid: self._overlay.pending_for(nid)
-                   for nid in node_ids}
-        out = self._evaluate_nodes_columnar(snap, plan, node_ids,
-                                            slab_adds, overlay)
-        if out is not None:
-            return out
-        return self._evaluate_nodes_walk(snap, plan, node_ids, slab_adds,
-                                         overlay)
+        inflight = self._overlay.snapshot()
+        columns_fn = getattr(snap, "columns", None)
+        cols = (columns_fn() if columns_fn is not None and colmod.enabled()
+                else None)
+        if cols is None:
+            out = _Fits(scalar=self._walk(snap, plan, inflight,
+                                          self._touched(plan)))
+        else:
+            out = self._fit_columnar(snap, plan, cols, inflight)
+        self.metrics.incr_counter("plan.fit.rows_array", len(out.rows))
+        self.metrics.incr_counter("plan.fit.rows_scalar", len(out.scalar))
+        every = colmod.guard_every()
+        if cols is not None and every > 0:
+            self._fit_guard_reads += 1
+            if self._fit_guard_reads % every == 0:
+                start = time.perf_counter()
+                tr = tracing.TRACER
+                with tracing.NOOP if tr is None else tr.span(
+                        "plan.evaluate.guard", eval_id=plan.eval_id,
+                        start=start):
+                    out = self._guard_fit(snap, plan, cols, inflight, out)
+                self.metrics.measure_since("plan.evaluate.guard", start)
+        return out
+
+    @staticmethod
+    def _touched(plan: s.Plan) -> List[str]:
+        touched = {*plan.node_update, *plan.node_allocation,
+                   *plan.node_preemptions}
+        for slab in plan.alloc_slabs:
+            touched.update(slab.node_ids)
+        return list(touched)
+
+    def _walk(self, snap, plan: s.Plan, inflight: List[_InflightPlan],
+              node_ids: List[str]) -> Dict[str, bool]:
+        return self._evaluate_nodes_walk(
+            snap, plan, node_ids, self._slab_node_adds(plan),
+            _pending_map(inflight, node_ids))
 
     def _evaluate_nodes_walk(self, snap, plan: s.Plan,
                              node_ids: List[str], slab_adds: Dict,
@@ -374,53 +491,89 @@ class PlanApplier:
                                               overlay=overlay)
                 for nid in node_ids}
 
-    def _evaluate_nodes_columnar(
-        self, snap, plan: s.Plan, node_ids: List[str], slab_adds: Dict,
-        overlay_map: Dict[str, list], guard: bool = True,
-    ) -> Optional[Dict[str, bool]]:
+    def _fit_columnar(self, snap, plan: s.Plan, cols,
+                      inflight: List[_InflightPlan]) -> _Fits:
         """Fit re-check off the PR 9 columnar mirror: capacity, reserved,
-        eligibility, and LIVE USAGE come straight from the store's numpy
+        eligibility and LIVE USAGE come straight from the store's numpy
         columns (O(changed) fold) instead of walking every touched
-        node's alloc objects — under gang-scale plans the walk was the
-        applier's dominant serial cost.  Per-node plan adds/removals and
-        the in-flight overlay stay host-side Python (small).  Falls back
-        per node for port-reserving allocs (allocs_fit owns port math)
-        and rows the mirror dropped; returns None when the mirror is
-        unavailable so callers run the walk.  Differential guard: every
-        NOMAD_TPU_COLUMNAR_GUARD_EVERY evaluations the walk runs anyway
-        and must agree — a mismatch is logged, counted, and the walk's
-        verdicts win (tests pin the cadence to 1: every tier-1 plan is
-        double-checked)."""
+        node's alloc objects.  A node takes the ARRAY route when all the
+        plan proposes there is slab placements without network
+        resources, no in-flight placement on it carries any, its mirror
+        row exists, and the plan has enough such placements to repay
+        array operations (columnar.ARRAY_MIN_ROWS): all such rows are
+        decided by one gather and one comparison.  Every other node
+        (evictions, preemptions and their staleness fence, per-object
+        allocations, ports, rows the mirror dropped) takes the per-node
+        route (_fit_scalar_rows)."""
         from ..state import columnar as colmod
 
-        columns_fn = getattr(snap, "columns", None)
-        if columns_fn is None or not colmod.enabled():
-            return None
-        cols = columns_fn()
-        if cols is None:
-            return None
         usage = snap.column_usage(cols)
+        # Ordered set: dict keys.
+        scalar: Dict[str, None] = dict.fromkeys(itertools.chain(
+            plan.node_update, plan.node_allocation, plan.node_preemptions))
+        array_slabs = []
+        for slab in plan.alloc_slabs:
+            proto = slab.proto
+            if proto.resources is None or _has_ports(proto):
+                scalar.update(dict.fromkeys(slab.node_ids))
+            elif len(slab.node_ids):
+                array_slabs.append(slab)
+        if sum(len(slab.node_ids) for slab in array_slabs) \
+                < colmod.ARRAY_MIN_ROWS:
+            # Too few rows to repay the array operations' fixed cost.
+            for slab in array_slabs:
+                scalar.update(dict.fromkeys(slab.node_ids))
+            array_slabs = []
+        forced = scalar
+        if any(p.net_nodes for p in inflight):
+            forced = set(scalar).union(*(p.net_nodes for p in inflight))
 
-        def res_vec(r: Optional[s.Resources]) -> np.ndarray:
-            if r is None:
-                return np.zeros(4, dtype=np.int64)
-            return np.array([r.cpu, r.memory_mb, r.disk_mb, r.iops],
-                            dtype=np.int64)
+        parts: List[Tuple[np.ndarray, tuple]] = []
+        for slab in array_slabs:
+            ids = slab.node_ids
+            rows = colmod.gather_index(cols.row_of, ids)
+            bad = (rows < 0) | (rows >= cols.n)
+            if forced:
+                bad |= np.fromiter(map(forced.__contains__, ids),
+                                   bool, len(ids))
+            if bad.any():
+                scalar.update(dict.fromkeys(
+                    ids[i] for i in np.flatnonzero(bad).tolist()))
+                rows = rows[~bad]
+            parts.append((rows, s.alloc_usage_vec(slab.proto)))
 
-        def combined(alloc: s.Allocation) -> np.ndarray:
-            if alloc.resources is not None:
-                return res_vec(alloc.resources)
-            total = res_vec(alloc.shared_resources)
-            for task_res in alloc.task_resources.values():
-                total += res_vec(task_res)
-            return total
+        out = _Fits(cols)
+        all_rows = (np.concatenate([rows for rows, _ in parts])
+                    if parts else out.rows)
+        if all_rows.size:
+            uniq, inv = np.unique(all_rows, return_inverse=True)
+            need = cols.res[uniq] + usage[uniq]
+            off = 0
+            for rows, vec in parts:
+                colmod.add_counts(need, inv[off:off + len(rows)], vec)
+                off += len(rows)
+            for pending in inflight:
+                for ids, vec in pending.parts:
+                    rows = colmod.gather_index(cols.row_of, ids)
+                    pos = np.searchsorted(uniq, rows)
+                    pos[pos == len(uniq)] = 0
+                    colmod.add_counts(need, pos[uniq[pos] == rows], vec)
+            out.rows = uniq
+            out.fit = cols.eligible[uniq] & np.all(need <= cols.cap[uniq],
+                                                   axis=1)
+        if scalar:
+            out.scalar = self._fit_scalar_rows(
+                snap, plan, cols, usage, list(scalar),
+                self._slab_node_adds(plan), _pending_map(inflight, scalar))
+        return out
 
-        def has_ports(alloc: s.Allocation) -> bool:
-            if alloc.resources is not None and alloc.resources.networks:
-                return True
-            return any(tr.networks
-                       for tr in alloc.task_resources.values())
-
+    def _fit_scalar_rows(self, snap, plan: s.Plan, cols, usage: np.ndarray,
+                         node_ids: List[str], slab_adds: Dict,
+                         overlay_map: Dict[str, list]) -> Dict[str, bool]:
+        """The per-node route of the columnar re-check: plan removals,
+        per-object adds and the overlay stay host-side Python (small),
+        with a fallback to allocs_fit for port-reserving allocs and rows
+        the mirror dropped."""
         out: Dict[str, bool] = {}
         for node_id in node_ids:
             if not self._preemptions_fresh(snap, plan, node_id):
@@ -434,11 +587,10 @@ class PlanApplier:
                 continue
             row = cols.row_of.get(node_id)
             if (row is None or row >= cols.n
-                    or any(has_ports(a) for a in adds)
-                    or any(p.resources is not None and p.resources.networks
+                    or any(_has_ports(a) for a in adds)
+                    or any(p.resources is None or _has_ports(p)
                            for p, _ in slab_here)
-                    or any(p.resources is not None and p.resources.networks
-                           for p, _ in overlay)):
+                    or any(_has_ports(p) for p, _ in overlay)):
                 # Port accounting / dropped mirror rows: scalar walk for
                 # this node only.
                 out[node_id] = self._evaluate_node_plan(
@@ -453,43 +605,60 @@ class PlanApplier:
                 live = snap.alloc_by_id(None, removal.id)
                 if (live is not None and not live.terminal_status()
                         and live.node_id == node_id):
-                    need = need - combined(live)
+                    need = need - _usage(live)
             for alloc in adds:
-                need = need + combined(alloc)
+                need = need + _usage(alloc)
             for proto, cnt in slab_here:
-                need = need + cnt * res_vec(proto.resources)
+                need = need + cnt * _usage(proto)
             for proto, cnt in overlay:
-                need = need + cnt * res_vec(proto.resources)
+                need = need + cnt * _usage(proto)
             out[node_id] = bool(np.all(need <= cols.cap[row]))
-
-        every = colmod.guard_every()
-        if guard and every > 0:
-            self._fit_guard_reads += 1
-            if self._fit_guard_reads % every == 0:
-                ref = self._evaluate_nodes_walk(snap, plan, node_ids,
-                                                slab_adds, overlay_map)
-                if ref != out:
-                    # Both passes read LIVE state: a concurrent write
-                    # (pipelined sibling commit, client status) between
-                    # them yields a benign divergence.  Re-run the
-                    # columnar pass — a race will not reproduce against
-                    # the walk's (newer) view; a real mirror bug will.
-                    out2 = self._evaluate_nodes_columnar(
-                        snap, plan, node_ids, slab_adds, overlay_map,
-                        guard=False)
-                    if out2 == ref:
-                        return ref
-                    bad = [nid for nid in node_ids
-                           if ref.get(nid) != out.get(nid)]
-                    colmod.note_guard_mismatch(
-                        "plan_fit", f"{len(bad)} node verdicts",
-                        Nodes=len(bad))
-                    self.logger.error(
-                        "columnar plan-fit guard mismatch on %d nodes "
-                        "(first: %s); using the walk's verdicts",
-                        len(bad), bad[:3])
-                    return ref
         return out
+
+    def _guard_fit(self, snap, plan: s.Plan, cols,
+                   inflight: List[_InflightPlan], out: _Fits) -> _Fits:
+        """The differential guard of one re-check.  Array rows are held
+        against the store's own rows (StateStore.fit_reference_rows:
+        nodes_table and the alloc tables, nothing of the mirror, no
+        Allocation materialized) plus the plan's and the overlay's adds
+        — allocs_fit's arithmetic for rows without ports; per-node rows
+        against the walk.  On a disagreement the full walk arbitrates:
+        both passes read LIVE state, so a concurrent write (pipelined
+        sibling commit, client status) between them yields a benign
+        divergence that a re-run of the columnar pass will not reproduce
+        against the walk's (newer) view; a real mirror bug will, is
+        counted, and the walk's verdicts win."""
+        from ..state import columnar as colmod
+
+        array_ids = out.array_ids()
+        scalar_ids = list(out.scalar)
+        agree = True
+        if array_ids:
+            ready, cap, used = snap.fit_reference_rows(
+                array_ids, out.rows, cols.row_of)
+            pos_of = {nid: i for i, nid in enumerate(array_ids)}
+            for slab in plan.alloc_slabs:
+                colmod.add_node_counts(used, pos_of, slab.node_ids,
+                                       s.alloc_usage_vec(slab.proto))
+            for pending in inflight:
+                for ids, vec in pending.parts:
+                    colmod.add_node_counts(used, pos_of, ids, vec)
+            agree = np.array_equal(
+                ready & np.all(used <= cap, axis=1), out.fit)
+        if agree and scalar_ids:
+            agree = self._walk(snap, plan, inflight, scalar_ids) == out.scalar
+        if agree:
+            return out
+        ref = self._walk(snap, plan, inflight, array_ids + scalar_ids)
+        first = out.as_dict()
+        if self._fit_columnar(snap, plan, cols, inflight).as_dict() != ref:
+            bad = [nid for nid, fit in ref.items() if first.get(nid) != fit]
+            colmod.note_guard_mismatch(
+                "plan_fit", f"{len(bad)} node verdicts", Nodes=len(bad))
+            self.logger.error(
+                "columnar plan-fit guard mismatch on %d nodes "
+                "(first: %s); using the walk's verdicts", len(bad), bad[:3])
+        return _Fits(scalar=ref)
 
     def _preemptions_fresh(self, snap, plan: s.Plan, node_id: str) -> bool:
         """Optimistic-concurrency fence for preemption: every alloc the
@@ -505,9 +674,8 @@ class PlanApplier:
         return True
 
     def _evaluate_node_plan(self, snap, plan: s.Plan, node_id: str,
-                            slab_adds: Optional[Dict] = None,
-                            overlay: Optional[Dict[str, list]] = None,
-                            ) -> bool:
+                            slab_adds: Optional[Dict],
+                            overlay: Dict[str, list]) -> bool:
         """(plan_apply.go:327 evaluateNodePlan).  ``overlay`` is the
         pre-captured in-flight placement snapshot (see _evaluate_nodes:
         it must be read BEFORE the store)."""
@@ -529,9 +697,7 @@ class PlanApplier:
             proposed.extend([proto] * cnt)
         # In-flight overlay: placements committed by pipelined siblings
         # but not yet visible in the store count against this node too.
-        pending = (overlay.get(node_id, ()) if overlay is not None
-                   else self._overlay.pending_for(node_id))
-        for proto, cnt in pending:
+        for proto, cnt in overlay.get(node_id, ()):
             proposed.extend([proto] * cnt)
         try:
             fit, _, _ = allocs_fit(node, proposed)
@@ -541,8 +707,7 @@ class PlanApplier:
 
     def _evaluate_nodes_vectorized(
         self, snap, plan: s.Plan, node_ids: List[str],
-        slab_adds: Optional[Dict] = None,
-        overlay: Optional[Dict[str, list]] = None,
+        slab_adds: Optional[Dict], overlay: Dict[str, list],
     ) -> Dict[str, bool]:
         """Batched re-check: one kernel call replaces the reference's
         NumCPU/2 verification pool (scalar network checks retained
@@ -602,14 +767,13 @@ class PlanApplier:
                 used[i] += cnt * res_vec(proto.resources)
                 has_networks = has_networks or bool(
                     proto.resources is not None and proto.resources.networks)
-            pending = (overlay.get(node_id, ()) if overlay is not None
-                       else self._overlay.pending_for(node_id))
-            for proto, cnt in pending:
-                used[i] += cnt * res_vec(proto.resources)
+            for proto, cnt in overlay.get(node_id, ()):
+                # An in-flight per-object alloc may still carry only its
+                # per-task resources: the canonical usage basis sums them.
+                used[i] += cnt * _usage(proto)
                 # Overlay entries with port reservations route the node
                 # to the scalar fallback, where allocs_fit accounts them.
-                has_networks = has_networks or bool(
-                    proto.resources is not None and proto.resources.networks)
+                has_networks = has_networks or _has_ports(proto)
             if has_networks:
                 # Port/bandwidth accounting stays host-side: full scalar
                 # re-check for nodes with network reservations.

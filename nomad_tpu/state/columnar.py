@@ -47,6 +47,7 @@ Env knobs:
 """
 from __future__ import annotations
 
+import itertools
 import logging
 import os
 import struct
@@ -57,6 +58,13 @@ import numpy as np
 logger = logging.getLogger("nomad_tpu.state.columnar")
 
 RES_DIMS = 4
+
+# Below this many rows a Python loop over them beats array operations:
+# each numpy call has a fixed cost, and on a server whose other threads
+# want the interpreter lock every call that releases it can wait a
+# switch interval to get it back (measured in the served stream path at
+# ten rows per plan, PERF.md PR 27).
+ARRAY_MIN_ROWS = 64
 
 # Guard epoch: bumped on a columnar-guard mismatch (ops/encode); every
 # container built under an older epoch is invalid and rebuilt by its
@@ -128,6 +136,46 @@ def reset_counters() -> None:
     GUARD_RUNS = GUARD_MISMATCHES = 0
     COLUMNAR_ENCODES = WALK_ENCODES = REBUILDS = 0
     USAGE_READS = USAGE_GUARD_RUNS = USAGE_GUARD_MISMATCHES = 0
+
+
+def gather_index(index: Dict[str, int], keys) -> np.ndarray:
+    """``index[key]`` for every key as one int64 array, -1 where the
+    key is absent (one C-level pass: no Python frame per key)."""
+    return np.fromiter(map(index.get, keys, itertools.repeat(-1)),
+                       np.int64, len(keys))
+
+
+def slab_rows(slab, row_of: Dict[str, int]) -> np.ndarray:
+    """``row_of`` row of each of the slab's placements, cached on the
+    slab (an undeclared attr, like ``_id_idx``, so it stays off the wire
+    codec) for as long as the caller keeps reading through the same
+    index: a row index is append-only and a slab's node column immutable
+    post-insert, so a complete answer never changes.  For readers that
+    meet the same slab again and again (the plan applier's guard over
+    still-pending slabs); one-shot readers call gather_index."""
+    cached = getattr(slab, "_rows", None)
+    if cached is not None and cached[0] is row_of:
+        return cached[1]
+    rows = gather_index(row_of, slab.node_ids)
+    if rows.size and rows.min() >= 0:
+        slab._rows = (row_of, rows)
+    return rows
+
+
+def add_counts(out: np.ndarray, pos: np.ndarray, vec) -> None:
+    """``out[p] += vec`` once per occurrence of ``p`` in ``pos``."""
+    if pos.size:
+        out += (np.bincount(pos, minlength=len(out))[:, None]
+                * np.asarray(vec, dtype=np.int64))
+
+
+def add_node_counts(out: np.ndarray, pos_of: Dict[str, int], node_ids,
+                    vec) -> None:
+    """``out[pos_of[nid]] += vec`` once per occurrence of ``nid`` in
+    ``node_ids`` (a slab's node column); ids ``pos_of`` does not hold
+    are skipped."""
+    pos = gather_index(pos_of, node_ids)
+    add_counts(out, pos[pos >= 0], vec)
 
 
 class ClusterColumns:
@@ -356,21 +404,37 @@ class ClusterColumns:
         snap_index = store.table_index("allocs")
         if snap_index <= self.usage_index:
             return True
-        deltas = store.allocs_since(self.usage_index)
-        if deltas is None:
+        entries = store.alloc_log_since(self.usage_index)
+        if entries is None:
             return False
+        from ..structs.structs import alloc_usage_vec
+
         self._own_usage()
         row_of, n, u = self.row_of, self.n, self.usage
-        for nid, vec in deltas:
-            i = row_of.get(nid)
-            if i is None or i >= n:
+        for entry in entries:
+            if len(entry) == 3:     # (index, node_id, delta): one row
+                self._add_row(u, row_of.get(entry[1]), n, entry[2])
                 continue
+            slab = entry[1]         # (index, slab): its node column
+            vec = alloc_usage_vec(slab.proto)
+            if len(slab.node_ids) < ARRAY_MIN_ROWS:
+                for nid in slab.node_ids:
+                    self._add_row(u, row_of.get(nid), n, vec)
+            else:
+                # One scatter-add, no per-allocation Python.
+                rows = gather_index(row_of, slab.node_ids)
+                np.add.at(u, rows[(rows >= 0) & (rows < n)],
+                          np.array(vec, dtype=np.int64))
+        self.usage_index = snap_index
+        return True
+
+    @staticmethod
+    def _add_row(u: np.ndarray, i: Optional[int], n: int, vec) -> None:
+        if i is not None and i < n:
             u[i, 0] += vec[0]
             u[i, 1] += vec[1]
             u[i, 2] += vec[2]
             u[i, 3] += vec[3]
-        self.usage_index = snap_index
-        return True
 
     def rebuild_usage(self, store) -> None:
         """Full usage rebuild from the store's live alloc rows (feed gap

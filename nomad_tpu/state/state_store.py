@@ -34,6 +34,9 @@ from . import columnar
 # Shared immutable empty result for index misses (never mutated).
 _EMPTY_SET: Set[str] = set()
 
+# Stands in for a node the table does not hold: not ready, no capacity.
+_NO_NODE = s.Node()
+
 # Usage-delta log bound (ops/resident.py delta feed): entries beyond the
 # cap are trimmed oldest-first and the floor rises, forcing consumers
 # whose cached index fell off to full re-encode.  Counted in alloc rows
@@ -402,23 +405,80 @@ class StateStore:
                 node_id in self._slab_node_set(slab)
                 for slab in self._pending_slabs):
             self._materialize_pending()
-        ids = self._idx_get(self._allocs_by_node, node_id)
-        if not ids:
-            return
+        if self._idx_get(self._allocs_by_node, node_id):
+            cols.usage[row] = self._indexed_usage(node_id, {})
+
+    def _indexed_usage(self, node_id: str, vec_of: Dict[int, tuple]
+                       ) -> Tuple[int, int, int, int]:
+        """Summed usage of ``node_id``'s non-terminal rows in the by-node
+        index, materializing none: a slab-backed id reads its slab's
+        proto.  ``vec_of`` memoizes the vector per row object across one
+        caller's pass (caller holds the lock, so the rows stay alive and
+        their ``id()`` unique)."""
         c = m = d = io = 0
-        for aid in ids:
-            v = self.allocs_table.get(aid)
+        get = self.allocs_table.get
+        for aid in self._idx_get(self._allocs_by_node, node_id):
+            v = get(aid)
             if v is None:
                 continue
-            r = v.proto if type(v) is s.AllocSlab else v
-            if r.terminal_status():
-                continue
-            vec = self._usage_vec(r)
+            vec = vec_of.get(id(v))
+            if vec is None:
+                r = v.proto if type(v) is s.AllocSlab else v
+                vec = ((0, 0, 0, 0) if r.terminal_status()
+                       else self._usage_vec(r))
+                vec_of[id(v)] = vec
             c += vec[0]
             m += vec[1]
             d += vec[2]
             io += vec[3]
-        cols.usage[row] = (c, m, d, io)
+        return c, m, d, io
+
+    def fit_reference_rows(self, node_ids: List[str], rows: np.ndarray,
+                           row_of: Dict[str, int]
+                           ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(ready, capacity, used)`` per node, read from the tables
+        themselves: status/drain/resources/reserved off ``nodes_table``,
+        and ``used`` = reserved + the usage of every non-terminal alloc
+        row the store holds for the node (by-node index + by-id rows,
+        plus the slabs whose indexing is still deferred).  This is the
+        reference the plan applier's differential guard holds the
+        columnar mirror against, so of the mirror it shares the row
+        INDEX alone (``rows[i]`` = ``row_of[node_ids[i]]``, which finds
+        a pending slab's placements on the asked nodes without a dict
+        probe per placement) and reads none of its columns; it
+        materializes no Allocation, caches nothing back into
+        ``allocs_table`` and leaves pending slabs pending."""
+        vec = columnar.ClusterColumns._vec
+        ready, cap, reserved, live = [], [], [], []
+        with self._lock:
+            vec_of: Dict[int, tuple] = {}
+            for nid in node_ids:
+                node = self.nodes_table.get(nid)
+                if node is None:
+                    node = _NO_NODE
+                ready.append(node.ready())
+                cap.append(vec(node.resources))
+                reserved.append(vec(node.reserved))
+                live.append(self._indexed_usage(nid, vec_of))
+            shape = (len(node_ids), columnar.RES_DIMS)
+            cap = np.array(cap, dtype=np.int64).reshape(shape)
+            used = (np.array(reserved, dtype=np.int64).reshape(shape)
+                    + np.array(live, dtype=np.int64).reshape(shape))
+            pending = [slab for slab in self._pending_slabs
+                       if not slab.proto.terminal_status()]
+            if pending:
+                # Position among node_ids of each mirror row; the extra
+                # last slot is what a placement without a row (-1) reads.
+                pos = np.full(len(row_of) + 1, -1, dtype=np.int64)
+                pos[rows] = np.arange(len(rows))
+                hits: Dict[tuple, List[np.ndarray]] = {}
+                for slab in pending:
+                    hit = pos[columnar.slab_rows(slab, row_of)]
+                    hits.setdefault(self._usage_vec(slab.proto),
+                                    []).append(hit[hit >= 0])
+                for usage, found in hits.items():
+                    columnar.add_counts(used, np.concatenate(found), usage)
+        return np.array(ready, dtype=bool), cap, used
 
     # -- immutable index-set updates ---------------------------------------
     #
@@ -1399,6 +1459,24 @@ class StateStore:
             self._log_usage(index, updated.node_id, v)
             self._ns_fold(updated.namespace, v[0], v[1], v[2], v[3], 1)
 
+    def alloc_log_since(self, index: int) -> Optional[List[tuple]]:
+        """The usage-delta log's raw entries with raft index > ``index``
+        — ``(index, node_id, delta)`` per single row, ``(index, slab)``
+        per bulk insert, unexpanded — or None when the log can no longer
+        answer.  Caller holds the lock."""
+        import bisect
+
+        if index < self._alloc_log_floor:
+            return None
+        # Entries are appended with non-decreasing raft indexes, so the
+        # skip to the first relevant entry is a bisect, not a full
+        # O(log-size) scan.  The slice is bounded by this store's length
+        # cursor: a shared parent list may have grown past it (those
+        # entries belong to a newer world).
+        log, n = self._alloc_log, self._alloc_log_len
+        start = bisect.bisect_right(log, index, 0, n, key=lambda e: e[0])
+        return log[start:n]
+
     def allocs_since(self, index: int
                      ) -> Optional[List[Tuple[str, Tuple[int, int, int, int]]]]:
         """Per-node usage deltas for every alloc write with raft index
@@ -1406,21 +1484,12 @@ class StateStore:
         cache.  Returns None when the log can no longer answer (the
         requested index fell below the trim floor, or predates this
         store's log), which forces the consumer to full re-encode."""
-        import bisect
-
         with self._lock:
-            if index < self._alloc_log_floor:
+            entries = self.alloc_log_since(index)
+            if entries is None:
                 return None
-            # Entries are appended with non-decreasing raft indexes, so
-            # the skip to the first relevant entry is a bisect, not a
-            # full O(log-size) scan.  Iteration is bounded by this
-            # store's length cursor: a shared parent list may have grown
-            # past it (those entries belong to a newer world).
-            log, n = self._alloc_log, self._alloc_log_len
-            start = bisect.bisect_right(log, index, 0, n,
-                                        key=lambda e: e[0])
             out: List[Tuple[str, Tuple[int, int, int, int]]] = []
-            for entry in log[start:n]:
+            for entry in entries:
                 if len(entry) == 2:  # (index, slab): expand per node
                     slab = entry[1]
                     vec = self._usage_vec(slab.proto)

@@ -67,6 +67,10 @@ def sink(tmp_path_factory):
             assert conftest.wait_for(
                 lambda: len(srv.state.allocs_by_job(None, second.id, True))
                 == 2, 60.0)
+            # The batch's stats reach the sink after its plans commit.
+            assert conftest.wait_for(
+                lambda: "nomad.breaker.oracle_routed"
+                in srv.metrics.sink.latest()["CounterTotals"], 30.0)
         latest = srv.metrics.sink.latest()
         yield {"samples": set(latest["SampleTotals"]),
                "counters": set(latest["CounterTotals"])}
@@ -86,3 +90,12 @@ def test_metric_file_names_a_published_key(sink, spec):
     else:
         assert spec["key"] in sink["counters"], spec
         assert spec["per"] in sink["samples"], spec
+
+
+def test_fit_recheck_publishes_its_routes_and_its_guard(sink):
+    """The applier's fit re-check counts the rows each route decided on
+    every plan (the hog's per-object plan took the per-node route) and,
+    at the suite's guard cadence of 1, times every guard run."""
+    assert {"nomad.plan.fit.rows_array",
+            "nomad.plan.fit.rows_scalar"} <= sink["counters"]
+    assert "nomad.plan.evaluate.guard" in sink["samples"]
