@@ -6,11 +6,16 @@ against the plain reference (``reference.py``) and against the
 configuration's guarantees.  Each number compared has a limit of its own;
 the two that are not exact take theirs from the configuration file, where
 ``PERF.md`` gives the readings they were set from.
+
+Nothing here knows what a fleet or a job looks like: the capacity, each
+job's rows and the limits come from the configuration's deployment module
+(``deployments/<name>.py``), which calls ``compare`` and may add names of
+its own to what it returns.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Dict, List, Sequence
+from dataclasses import dataclass, field, replace
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -27,34 +32,22 @@ class Served:
     failed_requests: int = 0
 
 
-def capacity(config: dict) -> np.ndarray:
-    """[N, 3] usable capacity per node (cpu, memory, disk): resources
-    minus reservation."""
-    c = config["cluster"]
-    nd, rv = c["node"], c["node"]["reserved"]
-    row = [nd["cpu"] - rv["cpu"], nd["memory_mb"] - rv["memory_mb"],
-           nd["disk_mb"] - rv["disk_mb"]]
-    return np.tile(np.asarray(row, dtype=np.float64), (c["nodes"], 1))
-
-
-def ask_of(config: dict) -> np.ndarray:
-    t = config["jobs"]["task"]
-    return np.asarray([t["cpu"], t["memory_mb"], t["ephemeral_disk_mb"]],
-                      dtype=np.float64)
-
-
-def compare(served: Served, config: dict) -> Dict[str, Dict[str, float]]:
-    """{name: {"value", "limit"}} for every number compared."""
-    limits = config["limits"]
-    cap = capacity(config)
-    rep = reference.replay(cap, served.jobs)
+def compare(served: Served, cap: np.ndarray, limits: dict,
+            reserved: Optional[np.ndarray] = None
+            ) -> Dict[str, Dict[str, float]]:
+    """{name: {"value", "limit"}} for every number compared, over the
+    capacity ``cap`` ([N, D], rows may differ; with ``reserved`` where
+    they do, see ``reference.score_after``) and each job's own rows."""
+    rep = reference.replay(cap, served.jobs, reserved=reserved)
     twin_nodes = reference.greedy(
-        cap, [j.ask for j in served.jobs], [len(j.nodes) for j in served.jobs])
+        cap, [j.ask for j in served.jobs], [len(j.nodes) for j in served.jobs],
+        feasible=[j.feasible for j in served.jobs],
+        distinct=[j.distinct for j in served.jobs], reserved=reserved)
     twin_used = np.zeros_like(cap)
     for job, nodes in zip(served.jobs, twin_nodes):
         np.add.at(twin_used, nodes, job.ask)
-    ref_sum = reference.scorefit_sum(twin_used, cap)
-    got_sum = reference.scorefit_sum(rep.used, cap)
+    ref_sum = reference.scorefit_sum(twin_used, cap, reserved)
+    got_sum = reference.scorefit_sum(rep.used, cap, reserved)
     over = int((rep.used > cap).any(axis=1).sum())
     out = {
         "score_gap": {"value": rep.widest_gap,
@@ -68,6 +61,9 @@ def compare(served: Served, config: dict) -> Dict[str, Dict[str, float]]:
         "readback_mismatch": {"value": served.readback_mismatch, "limit": 0},
         "failed_requests": {"value": served.failed_requests, "limit": 0},
     }
+    if any(j.distinct is not None for j in served.jobs):
+        out["job_mates_in_one_distinct_group"] = {"value": rep.shared,
+                                                  "limit": 0}
     for name, value in served.device.items():
         out[name] = {"value": value, "limit": 0}
     return out
@@ -77,13 +73,14 @@ def correct(compared: Dict[str, Dict[str, float]]) -> bool:
     return all(v["value"] <= v["limit"] for v in compared.values())
 
 
-def control_jobs(config: dict, served_jobs: Sequence[reference.PlacedJob],
-                 seed: int) -> List[reference.PlacedJob]:
+def control_jobs(cap: np.ndarray, served_jobs: Sequence[reference.PlacedJob],
+                 seed: int, reserved: Optional[np.ndarray] = None
+                 ) -> List[reference.PlacedJob]:
     """The reference put in the program's place with one guarantee broken:
     the same jobs placed over log2(N) sampled candidates, not all nodes."""
-    cap = capacity(config)
     nodes = reference.greedy(
         cap, [j.ask for j in served_jobs], [len(j.nodes) for j in served_jobs],
-        seed=seed, candidates=reference.candidate_limit(cap.shape[0]))
-    return [reference.PlacedJob(j.key, j.ask, n)
-            for j, n in zip(served_jobs, nodes)]
+        seed=seed, candidates=reference.candidate_limit(cap.shape[0]),
+        feasible=[j.feasible for j in served_jobs],
+        distinct=[j.distinct for j in served_jobs], reserved=reserved)
+    return [replace(j, nodes=n) for j, n in zip(served_jobs, nodes)]

@@ -11,7 +11,9 @@ lines from stdout; ``time.monotonic()`` is the machine's clock, so both
 processes read the same one.
 
 One general generator reads a traffic mix's parameters; a new mix of a
-known ``loop`` kind is a data file and no code.
+known ``loop`` kind is a data file and no code.  What a job is called,
+what its body holds and how many allocations it wants come from the
+configuration's deployment module (``manifest.DEPLOYMENT_API``).
 """
 from __future__ import annotations
 
@@ -26,7 +28,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-from benchmarks import shapes
+from benchmarks import manifest
 
 HEARTBEAT_RENEW = 0.7       # renew at this share of the granted TTL
 POLL_S = 0.05
@@ -132,7 +134,8 @@ class Client:
         self.config = init["config"]
         self.http = Http("127.0.0.1", init["http_port"])
         self.rpc_addr = init["rpc_addr"]
-        self.group_count = int(self.config["jobs"]["group_count"])
+        self.seed = int(init["seed"])
+        self.dep = manifest.load_deployment(self.config)
         self.heartbeats: Optional[Heartbeats] = None
 
     # -- pieces ------------------------------------------------------------
@@ -140,7 +143,7 @@ class Client:
     def body(self, jid: str) -> bytes:
         from nomad_tpu.api.codec import to_wire
 
-        job = shapes.make_job(self.config, jid, self.group_count)
+        job = self.dep.make_job(self.config, jid)
         return json.dumps({"Job": to_wire(job)}).encode()
 
     def register(self, jid: str, body: bytes) -> Tuple[str, int]:
@@ -166,14 +169,19 @@ class Client:
                 return False
         return False
 
-    def complete_evals(self) -> Tuple[int, float]:
-        """(evals complete, the instant the state was read): the state is
-        read somewhere inside the request, so the instant is its middle."""
+    def wants(self, jid: str) -> int:
+        return self.dep.wants(self.config, jid)
+
+    def complete_evals(self) -> Tuple[int, int, float]:
+        """(evals complete, the allocations they wanted, the instant the
+        state was read): the state is read somewhere inside the request, so
+        the instant is its middle."""
         t0 = time.monotonic()
         evals, _ = self.http.get_json("/v1/evaluations")
         t1 = time.monotonic()
-        return (sum(1 for e in evals if e["Status"] == "complete"),
-                0.5 * (t0 + t1))
+        done = [self.wants(e["JobID"]) for e in evals
+                if e["Status"] == "complete"]
+        return len(done), sum(done), 0.5 * (t0 + t1)
 
     def broker(self) -> dict:
         return self.http.get_json("/v1/broker/stats")[0]
@@ -222,7 +230,7 @@ class Client:
         deadline = time.monotonic() + float(msg["timeout"])
         bad = []
         for jid, (eval_id, index) in msg["evals"].items():
-            if not self.follow(eval_id, index, self.group_count, deadline):
+            if not self.follow(eval_id, index, self.wants(jid), deadline):
                 bad.append(jid)
         return {"ok": not bad, "bad": bad[:5]}
 
@@ -263,23 +271,27 @@ class Client:
             if st["ready"] <= open_ready:
                 break
             time.sleep(POLL_S)
-        c0, t_open = self.complete_evals()
+        c0, wanted0, t_open = self.complete_evals()
         emit({"event": "open", "t": t_open})
         ready_max = st["ready"]
         drained_at = None
         while time.monotonic() < t_open + seconds:
-            time.sleep(min(0.5, max(0.0, t_open + seconds - time.monotonic())))
+            # Half a second between looks while batches are still to be
+            # taken; once the last one is out, POLL_S, so that the drain is
+            # stamped to that and not to the half second.
+            nap = POLL_S if st["ready"] == 0 else 0.5
+            time.sleep(min(nap, max(0.0, t_open + seconds - time.monotonic())))
             st = self.broker()["ByState"]
             if st["ready"] + st["unacked"] == 0:
                 drained_at = time.monotonic()
                 break
-        c1, t_close = self.complete_evals()
+        c1, wanted1, t_close = self.complete_evals()
         if drained_at is not None:
             t_close = drained_at
         emit({"event": "close", "t": t_close})
         return {"ok": True, "t_open": t_open, "t_close": t_close,
                 "evals_open": c0, "evals_close": c1,
-                "placed": (c1 - c0) * self.group_count,
+                "placed": wanted1 - wanted0,
                 "attempted": c1 - c0, "failed": 0,
                 "drained": drained_at is not None,
                 "broker_ready_open": ready_max}
@@ -294,8 +306,9 @@ class Client:
         # The set of gaps sums to about n / rate; scale so that the last
         # arrival falls just inside the window whatever the order.
         scale = (preroll + seconds) * n / ((n + 1.0) * sum(gaps))
-        prefix = msg["job_prefix"]
-        bodies = [self.body(shapes.job_id(prefix, i)) for i in range(n)]
+        ids = [self.dep.request_id(self.config, msg["job_prefix"], i,
+                                   self.seed) for i in range(n)]
+        bodies = [self.body(jid) for jid in ids]
         rows: List[dict] = [None] * n          # type: ignore[list-item]
         todo: "queue.SimpleQueue" = queue.SimpleQueue()
         straggle = float(mix["straggler_wait_s"])
@@ -313,10 +326,9 @@ class Client:
                 row = {"due": due, "sent": time.monotonic(), "ok": False}
                 rows[i] = row
                 try:
-                    eval_id, index = self.register(
-                        shapes.job_id(prefix, i), bodies[i])
+                    eval_id, index = self.register(ids[i], bodies[i])
                     row["acked"] = time.monotonic()
-                    row["ok"] = self.follow(eval_id, index, self.group_count,
+                    row["ok"] = self.follow(eval_id, index, self.wants(ids[i]),
                                             hard_deadline)
                 except Exception as exc:
                     row["error"] = repr(exc)
@@ -348,17 +360,19 @@ class Client:
             todo.put(None)
         for t in workers:
             t.join(timeout=max(1.0, hard_deadline + 10.0 - time.monotonic()))
-        inwin = [r for r in rows if r is not None
+        inwin = [i for i, r in enumerate(rows) if r is not None
                  and t_open <= r["due"] < t_close]
         worst = (hard_deadline - t_open) * 1000.0
         lat, late, ack = [], [], []
-        failed = 0
-        for r in inwin:
+        failed = placed = 0
+        for i in inwin:
+            r = rows[i]
             late.append((r["sent"] - r["due"]) * 1000.0)
             if "acked" in r:
                 ack.append((r["acked"] - r["sent"]) * 1000.0)
             if r["ok"]:
                 lat.append((r["done"] - r["due"]) * 1000.0)
+                placed += self.wants(ids[i])
             else:
                 failed += 1
                 lat.append(worst)
@@ -367,11 +381,8 @@ class Client:
                 "attempted": len(inwin), "failed": failed,
                 "latency_ms": lat, "generator_late_ms": late,
                 "register_ack_ms": ack, "errors": errors[:3],
-                "placed": (len(inwin) - failed) * self.group_count,
-                "job_ids": [shapes.job_id(prefix, i) for i in range(n)],
-                "jobs_in_window": [shapes.job_id(prefix, i)
-                                   for i, r in enumerate(rows) if r is not None
-                                   and t_open <= r["due"] < t_close]}
+                "placed": placed, "job_ids": ids,
+                "jobs_in_window": [ids[i] for i in inwin]}
 
 
 _OUT_LOCK = threading.Lock()

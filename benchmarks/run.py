@@ -6,7 +6,9 @@ This process holds the chip and is the server: one ``Agent`` (server
 only, ``use_tpu_batch_worker``, shipped defaults, durable ``data_dir``,
 single voter).  It runs no generator, no heartbeat client and no poller:
 those live in the client process (``client.py``), a child that never
-touches a JAX backend.  This process registers the nodes (set-up),
+touches a JAX backend.  What the nodes and the jobs look like and what a
+right answer is comes from the configuration's deployment module
+(``manifest.DEPLOYMENT_API``).  This process registers the nodes (set-up),
 parks and releases the worker for set-up batches, reads the metrics sink
 at the window's two edges, and, once the window has closed, holds what
 the timed path produced against the plain reference.
@@ -36,8 +38,7 @@ sys.path.insert(0, str(ROOT))
 
 from benchmarks import manifest  # noqa: E402
 
-DRY = {"nodes": 400, "jobs": 24, "group_count": 10, "batch_size": 4,
-       "warmup_batches": [1, 2, 4]}
+DRY = {"batch_size": 4, "warmup_batches": [1, 2, 4]}
 SETUP_DEADLINE_S = 900.0
 PARK_DEADLINE_S = 120.0
 K = "nomad.worker.invoke_scheduler"
@@ -169,16 +170,13 @@ def register_nodes(server, nodes):
 # -- reading the timed path's answers back ------------------------------------
 
 
-def collect_served(server, config, job_order):
+def collect_served(server, dep, config, job_ids):
     """What the timed path produced: every complete job's allocations from
-    the state store, in commit order, as plain arrays."""
-    import numpy as np
-
-    from benchmarks import check, reference
+    the state store, in commit order, as the deployment's plain arrays."""
+    from benchmarks import check
     from nomad_tpu.structs import structs as s
 
     snap = server.state.snapshot()
-    n_nodes = config["cluster"]["nodes"]
     served = check.Served(jobs=[])
     keyed = []
     # Commit order: a batch finalizes its evals one after another, plan
@@ -186,24 +184,21 @@ def collect_served(server, config, job_order):
     # index orders the plans.
     complete = {e.job_id: e.modify_index for e in snap.evals(None)
                 if e.status == s.EVAL_STATUS_COMPLETE}
-    for jid, want in job_order:
+    for jid in job_ids:
         if jid not in complete:
             continue
         rows = [(nid, r) for nid, r in snap.alloc_rows_by_job(None, jid)
                 if not r.terminal_status()]
-        if len(rows) != want:
+        if len(rows) != dep.wants(config, jid):
             served.wrong_count += 1
         if not rows:
             continue
-        nodes = np.fromiter((int(nid[5:]) for nid, _ in rows),
-                            dtype=np.int64, count=len(rows))
-        if nodes.min() < 0 or nodes.max() >= n_nodes:
+        nodes = dep.node_indices(config, [nid for nid, _ in rows])
+        if nodes.min() < 0:
             served.wrong_count += 1
             continue
-        res = rows[0][1].resources
-        ask = np.asarray([res.cpu, res.memory_mb, res.disk_mb],
-                         dtype=np.float64)
-        keyed.append((complete[jid], reference.PlacedJob(jid, ask, nodes)))
+        keyed.append((complete[jid], dep.placed_job(
+            config, jid, nodes, [r for _, r in rows])))
     keyed.sort(key=lambda kv: kv[0])
     served.jobs = [job for _, job in keyed]
     return served, snap
@@ -253,12 +248,9 @@ def run(args) -> int:
     dry = args.dry_run_cpu
     if dry:
         os.environ["JAX_PLATFORMS"] = "cpu"
-        config = json.loads(json.dumps(config))
-        config["cluster"]["nodes"] = DRY["nodes"]
-        config["jobs"]["group_count"] = DRY["group_count"]
-        if config["jobs"]["jobs"]:
-            config["jobs"]["jobs"] = DRY["jobs"]
-        config["server"]["batch_size"] = DRY["batch_size"]
+        config = manifest.shrunk(cell)
+        config["server"]["batch_size"] = min(
+            DRY["batch_size"], int(config["server"]["batch_size"]))
         if isinstance(mix.get("warmup_batches"), list):
             mix["warmup_batches"] = DRY["warmup_batches"]
     for item in args.mix:
@@ -324,12 +316,12 @@ def drive(args, cell, config, mix, seed, client, compile_log, device,
           run_dir):
     import jax
 
-    from benchmarks import check, readers, shapes
+    from benchmarks import check, readers
     from nomad_tpu.native import native_wal_available
     from nomad_tpu.server.raft import FileLog
 
+    dep = cell.deployment
     batch_size = int(config["server"]["batch_size"])
-    group_count = int(config["jobs"]["group_count"])
     agent = start_agent(config, str(run_dir / "data"), batch_size)
     server = agent.server
     try:
@@ -338,9 +330,11 @@ def drive(args, cell, config, mix, seed, client, compile_log, device,
         if native_wal_available() and getattr(server.raft, "_nwal",
                                               None) is None:
             raise Abort("FileLog does not run the native group-commit WAL")
-        client.call(cmd="init", config=config, http_port=agent.http.port,
+        client.call(cmd="init", config=config, seed=seed,
+                    http_port=agent.http.port,
                     rpc_addr=server.config.rpc_advertise)
-        nodes = shapes.make_nodes(config)
+        nodes = dep.make_nodes(config)
+        n_nodes = len(nodes)
         t0 = time.monotonic()
         ttls = register_nodes(server, nodes)
         t_reg = time.monotonic()
@@ -348,26 +342,27 @@ def drive(args, cell, config, mix, seed, client, compile_log, device,
         client.call(cmd="heartbeats", nodes=ttls, t_registered=t_reg)
         del nodes
 
-        job_order = []          # (job id, allocations wanted), every job sent
+        job_ids = []            # every job sent, set-up's with the window's
         loop = mix["loop"]
         run_msg = {"cmd": "run", "mix": mix, "seconds": args.seconds,
                    "seed": seed, "batch_size": batch_size}
         if loop == "standing_backlog":
-            n_jobs = int(config["jobs"]["jobs"])
-            ids = [shapes.job_id("job", i) for i in range(n_jobs)]
+            backlog = dep.backlog_ids(config, seed)
+            n_jobs = len(backlog)
             set_workers_paused(server, True)
             wait_parked(server)
-            rep = client.call(cmd="submit", job_ids=ids,
+            rep = client.call(cmd="submit", job_ids=backlog,
                               threads=int(mix["setup_submitters"]))
             say(f"registered {n_jobs} jobs in {rep['seconds']:.1f}s")
-            job_order += [(j, group_count) for j in ids]
+            job_ids += backlog
             run_msg["total_evals"] = n_jobs
         elif loop == "open":
             # Warm-up batches of the window's own shapes: each is
             # registered with the worker parked, so it is one batch.
             k = 0
             for size in mix["warmup_batches"]:
-                ids = [shapes.job_id("warm", k + i) for i in range(size)]
+                ids = [dep.request_id(config, "warm", k + i, seed)
+                       for i in range(size)]
                 k += size
                 set_workers_paused(server, True)
                 wait_parked(server)
@@ -375,7 +370,7 @@ def drive(args, cell, config, mix, seed, client, compile_log, device,
                 set_workers_paused(server, False)
                 client.call(cmd="wait", evals=rep["evals"],
                             timeout=SETUP_DEADLINE_S)
-                job_order += [(j, group_count) for j in ids]
+                job_ids += ids
             run_msg["job_prefix"] = "req"
         else:
             raise Abort(f"unknown loop kind {loop!r}")
@@ -441,13 +436,13 @@ def drive(args, cell, config, mix, seed, client, compile_log, device,
         stats = devices_memory_peak(jax)
         sink_end = server.metrics.sink.latest()
         if loop == "open":
-            job_order += [(j, group_count) for j in cres["job_ids"]]
+            job_ids += cres["job_ids"]
             in_window = cres["jobs_in_window"]
         else:
-            in_window = [j for j, _ in job_order if j.startswith("job-")]
+            in_window = backlog
         t_park = time.monotonic()
         failed_evals = server.broker_stats()["ByState"]["failed"]
-        served, snap = collect_served(server, config, job_order)
+        served, snap = collect_served(server, dep, config, job_ids)
         t_collect = time.monotonic()
         # A sample, drawn from the seed, of the window's jobs that the
         # program called complete, with the last of them in it.
@@ -474,15 +469,15 @@ def drive(args, cell, config, mix, seed, client, compile_log, device,
     del server, agent
 
     t_check = time.monotonic()
-    compared = check.compare(served, config)
+    compared = dep.compare(served, config)
     is_correct = check.correct(compared)
     control = None
     if args.control:
         # The reference in the program's place with one guarantee broken,
         # through the same comparison: it has to come out not correct.
-        served.jobs = check.control_jobs(config, served.jobs, seed)
-        control = check.compare(served, config)
-        for name in ("score_gap", "score_sum_rel"):
+        served.jobs = dep.control_jobs(config, served.jobs, seed)
+        control = dep.compare(served, config)
+        for name in [n for n in ("score_gap", "score_sum_rel") if n in control]:
             say(f"control {args.control} {name}: {control[name]['value']!r} "
                 f"(limit {control[name]['limit']!r})")
         say(f"control {args.control} correct: {check.correct(control)}")
@@ -534,7 +529,7 @@ def drive(args, cell, config, mix, seed, client, compile_log, device,
     ctx = {"sink0": sink0, "sink1": sink1, "client": cres,
            "harness": {"compiles_in_window": compiles1 - compiles0},
            "trace": reduced,
-           "shapes": {"nodes": config["cluster"]["nodes"],
+           "shapes": {"nodes": n_nodes,
                       "device_kind": device["kind"]}}
     layers = {spec["name"]: {"value": v, "unit": spec["unit"]}
               for spec in cell.per_layer

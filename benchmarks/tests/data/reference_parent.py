@@ -12,16 +12,7 @@ lifted), copied in substance from ``bench.py``'s validated numpy twin:
   for every allocation of the same job already on the node, so a job
   whose count is at most the number of feasible nodes lands on distinct
   nodes, the best ``count`` of them;
-- feasibility: every dimension given (cpu, memory, disk) fits, and, where
-  a job brings a feasibility row (one bool per node, computed by its
-  deployment's own evaluator from the configuration file), the row holds;
-- hard distinctness: where a job brings a ``distinct`` row (one group id
-  per node), no two of its allocations share a group, fresh nodes left or
-  not: the node index for ``distinct_hosts``, a property's value for
-  ``distinct_property`` (of nodes that share a value the best is taken).
-
-A job that brings neither row is placed and replayed exactly as before
-there were rows.
+- feasibility: every dimension given (cpu, memory, disk) fits.
 
 Three things are computed from it:
 
@@ -53,32 +44,20 @@ class PlacedJob:
     key: str
     ask: np.ndarray          # [D] float64
     nodes: np.ndarray        # [count] int64
-    feasible: Optional[np.ndarray] = None    # [N] bool; None = every node
-    distinct: Optional[np.ndarray] = None    # [N] int64 group ids; None = soft
 
 
-def score_after(after: np.ndarray, cap: np.ndarray,
-                reserved: Optional[np.ndarray] = None) -> np.ndarray:
-    """ScoreFit of each node once it carries ``after`` ([N, D]).  Upstream
-    counts a node's reservation as used (AllocsFit adds it) while the
-    shares are of ``cap``, the resources less the reservation: a fleet
-    whose nodes differ gives ``reserved`` ([N, D]), since nodes of unlike
-    shape then rank otherwise; on a fleet of one shape the ranking is the
-    same with it or without."""
-    if reserved is not None:
-        after = after + reserved
+def score_after(after: np.ndarray, cap: np.ndarray) -> np.ndarray:
+    """ScoreFit of each node once it carries ``after`` ([N, D])."""
     free_cpu = 1.0 - after[:, CPU] / cap[:, CPU]
     free_mem = 1.0 - after[:, MEM] / cap[:, MEM]
     return np.clip(20.0 - (10.0 ** free_cpu + 10.0 ** free_mem), 0.0, 18.0)
 
 
-def scorefit_sum(used: np.ndarray, cap: np.ndarray,
-                 reserved: Optional[np.ndarray] = None) -> float:
+def scorefit_sum(used: np.ndarray, cap: np.ndarray) -> float:
     """Aggregate ScoreFit over nodes that carry anything (bench.py
     binpack_scores): the order-free basis for comparing two engines."""
     carries = used.any(axis=1)
-    return float(np.where(carries, score_after(used, cap, reserved),
-                          0.0).sum())
+    return float(np.where(carries, score_after(used, cap), 0.0).sum())
 
 
 def _fits(after: np.ndarray, cap: np.ndarray) -> np.ndarray:
@@ -93,80 +72,47 @@ class Replay:
     repeated: int            # allocations sharing a node with a job-mate
                              # while a fresh feasible node was left
     used: np.ndarray         # [N, D] usage after every served job
-    shared: int = 0          # allocations sharing a ``distinct`` group
-                             # with a job-mate
 
 
 def replay(cap: np.ndarray, jobs: Sequence[PlacedJob],
-           used0: Optional[np.ndarray] = None,
-           reserved: Optional[np.ndarray] = None) -> Replay:
+           used0: Optional[np.ndarray] = None) -> Replay:
     used = np.zeros_like(cap) if used0 is None else used0.copy()
-    widest, worst, infeasible, repeated, shared = 0.0, "", 0, 0, 0
+    widest, worst, infeasible, repeated = 0.0, "", 0, 0
     for job in jobs:
         after = used + job.ask
         fits = _fits(after, cap)
-        if job.feasible is not None:
-            fits = fits & job.feasible
-        base = score_after(after, cap, reserved)
+        base = score_after(after, cap)
         counts = np.bincount(job.nodes, minlength=cap.shape[0])
         chosen = counts > 0
         n_feasible = int(fits.sum())
-        if job.distinct is not None or len(job.nodes) <= n_feasible:
+        if len(job.nodes) <= n_feasible:
             infeasible += int(counts[~fits].sum())
             repeated += int((counts[chosen] - 1).sum())
             left = fits & ~chosen
             ok = chosen & fits
-            gap = 0.0
-            if job.distinct is not None:
-                per_group = np.bincount(job.distinct[job.nodes])
-                shared += int((per_group[per_group > 0] - 1).sum())
-                gap = _distinct_gap(base, ok, left, job.distinct)
-            elif left.any() and ok.any():
+            if left.any() and ok.any():
                 gap = float(base[left].max() - base[ok].min())
-            if gap > widest:
-                widest, worst = gap, job.key
+                if gap > widest:
+                    widest, worst = gap, job.key
         else:
             # More allocations than feasible nodes: the rounds wrap and
             # the bound above does not hold; follow it one by one.
-            gap, bad = _replay_one_by_one(cap, used, job, reserved)
+            gap, bad = _replay_one_by_one(cap, used, job)
             infeasible += bad
             if gap > widest:
                 widest, worst = gap, job.key
         np.add.at(used, job.nodes, job.ask)
-    return Replay(widest, worst, infeasible, repeated, used, shared)
+    return Replay(widest, worst, infeasible, repeated, used)
 
 
-def _distinct_gap(base, ok, left, groups) -> float:
-    """The widest gap under hard distinctness.  What a chosen node could
-    have been instead: a node left out whose group no chosen node has, or
-    a node left out of its own group."""
-    if not ok.any():
-        return 0.0
-    taken = np.zeros(int(groups.max()) + 1, dtype=bool)
-    taken[groups[ok]] = True
-    gap = 0.0
-    fresh = left & ~taken[groups]
-    if fresh.any():
-        gap = float(base[fresh].max() - base[ok].min())
-    same = left & taken[groups]
-    if same.any():
-        best = np.full(taken.shape[0], -np.inf)
-        np.maximum.at(best, groups[same], base[same])
-        gap = max(gap, float((best[groups[ok]] - base[ok]).max()))
-    return gap
-
-
-def _replay_one_by_one(cap, used, job, reserved=None):
+def _replay_one_by_one(cap, used, job):
     used = used.copy()
     cnt = np.zeros(cap.shape[0])
     widest, bad = 0.0, 0
     for node in job.nodes:
         after = used + job.ask
         fits = _fits(after, cap)
-        if job.feasible is not None:
-            fits = fits & job.feasible
-        eff = np.where(fits, score_after(after, cap, reserved) - PENALTY * cnt,
-                       -np.inf)
+        eff = np.where(fits, score_after(after, cap) - PENALTY * cnt, -np.inf)
         if not fits[node]:
             bad += 1
         else:
@@ -178,74 +124,51 @@ def _replay_one_by_one(cap, used, job, reserved=None):
 
 def greedy(cap: np.ndarray, asks: Sequence[np.ndarray], counts: Sequence[int],
            used0: Optional[np.ndarray] = None,
-           candidates: Optional[int] = None, seed: int = 0,
-           feasible: Optional[Sequence[Optional[np.ndarray]]] = None,
-           distinct: Optional[Sequence[Optional[np.ndarray]]] = None,
-           reserved: Optional[np.ndarray] = None) -> List[np.ndarray]:
+           candidates: Optional[int] = None, seed: int = 0
+           ) -> List[np.ndarray]:
     """Place the jobs in order; returns each job's node indices.
 
     ``candidates=None`` scores every feasible node (the reference).  With
     a number, each placement scores that many sampled feasible nodes (the
-    control).  ``feasible`` and ``distinct`` give each job's rows, as
-    ``PlacedJob`` carries them; a job under hard distinctness that finds
-    fewer groups than its count is placed as far as they reach."""
+    control)."""
     used = np.zeros_like(cap) if used0 is None else used0.copy()
     rng = np.random.default_rng(seed)
     out = []
-    for j, (ask, count) in enumerate(zip(asks, counts)):
-        row = None if feasible is None else feasible[j]
-        groups = None if distinct is None else distinct[j]
+    for ask, count in zip(asks, counts):
         after = used + ask
         fits = _fits(after, cap)
-        if row is not None:
-            fits = fits & row
-        base = score_after(after, cap, reserved)
-        feasible_idx = np.flatnonzero(fits)
-        if candidates is None and (groups is not None
-                                   or count <= len(feasible_idx)):
+        base = score_after(after, cap)
+        feasible = np.flatnonzero(fits)
+        if candidates is None and count <= len(feasible):
             # Distinct nodes, the best `count`: a stable sort keeps ties
             # in node order, as a first-come argmax would.
-            order = feasible_idx[np.argsort(-base[feasible_idx],
-                                            kind="stable")]
-            if groups is not None:      # of every group its best node only
-                _, first = np.unique(groups[order], return_index=True)
-                order = order[np.sort(first)]
+            order = feasible[np.argsort(-base[feasible], kind="stable")]
             nodes = order[:count]
-        elif candidates is not None and (count <= len(feasible_idx)
-                                         or groups is not None):
+        elif candidates is not None and count <= len(feasible):
             nodes = np.empty(count, dtype=np.int64)
             free = np.ones(cap.shape[0], dtype=bool)
             for k in range(count):
-                pool = feasible_idx[free[feasible_idx]]
-                if not len(pool):
-                    nodes = nodes[:k]
-                    break
+                pool = feasible[free[feasible]]
                 pick = pool[rng.integers(0, len(pool),
                                          size=min(candidates, len(pool)))]
                 best = pick[int(np.argmax(base[pick]))]
                 nodes[k] = best
-                if groups is None:
-                    free[best] = False
-                else:
-                    free &= groups != groups[best]
+                free[best] = False
         else:
-            nodes = _greedy_one_by_one(cap, used, ask, count, row, reserved)
+            nodes = _greedy_one_by_one(cap, used, ask, count)
         np.add.at(used, nodes, ask)
         out.append(nodes)
     return out
 
 
-def _greedy_one_by_one(cap, used, ask, count, row=None, reserved=None):
+def _greedy_one_by_one(cap, used, ask, count):
     used = used.copy()
     cnt = np.zeros(cap.shape[0])
     nodes = []
     for _ in range(count):
         after = used + ask
         fits = _fits(after, cap)
-        if row is not None:
-            fits = fits & row
-        eff = np.where(fits, score_after(after, cap, reserved) - PENALTY * cnt,
-                       -np.inf)
+        eff = np.where(fits, score_after(after, cap) - PENALTY * cnt, -np.inf)
         i = int(np.argmax(eff))
         if not np.isfinite(eff[i]):
             break
