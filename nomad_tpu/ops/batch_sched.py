@@ -73,6 +73,28 @@ from ..utils.lru import LRU
 
 _CLUSTER_CACHE = LRU(4)
 
+# The constraint vocabulary (attr targets + literals) a fleet's batches
+# have used so far, by (store, nodes index, with_networks, pad): every
+# batch encodes against the union.  The vocabulary shapes the static
+# cluster tensors and so selects the program; a batch that happens to
+# lack one of the fleet's usual targets (a drain's 16-evaluation tail
+# with no job of one template, 3 attr columns where every batch before
+# had 4) would otherwise re-encode the fleet, upload it again and compile
+# a program of its own (chip run, PERF.md section 6, PR 29).  A superset
+# encodes exactly what the subset does; a change of the nodes table
+# starts a new entry.
+_VOCABULARY = LRU(4)
+
+
+def _widen_vocabulary(key, attr_targets, literals):
+    targets, lits = _VOCABULARY.get(key, ((), {}))
+    targets = tuple(targets) + tuple(
+        t for t in attr_targets if t not in targets)
+    lits = {t: lits.get(t, frozenset()) | frozenset(literals.get(t, ()))
+            for t in (*lits, *literals)}
+    _VOCABULARY.put(key, (targets, lits))
+    return list(targets), {t: set(vs) for t, vs in lits.items()}
+
 # Device-resident copies of the packed static cluster buffer, keyed by
 # CONTENT digest (not store identity): a rebuilt-but-identical cluster —
 # e.g. bench trials on fresh state stores — skips the multi-MB upload
@@ -443,7 +465,16 @@ class TPUBatchScheduler:
                              * 1000.0)
             m.add_sample("worker.invoke_scheduler.expand",
                          stats.metrics_seconds * 1000.0)
+            m.add_sample("worker.invoke_scheduler.encode.constraint_rows",
+                         stats.constraint_rows_seconds * 1000.0)
             m.add_sample("worker.invoke_scheduler.rounds", stats.rounds)
+            # Published on every batch, 0 where nothing applies, so that
+            # a metric over them reads 0 and not nothing.
+            m.incr_counter("batch.precomp_rows", stats.precomp_rows)
+            m.incr_counter("batch.dp_specs", stats.dp_specs)
+            m.incr_counter("batch.multi_round_specs",
+                           stats.multi_round_specs)
+            m.incr_counter("batch.spec_passes", stats.spec_passes)
             # Bytes are a COUNTER (rate-derivable total), not a sample:
             # the percentile histogram's buckets are ms-calibrated and
             # would quantize MB-scale values into the top bucket.
@@ -815,6 +846,11 @@ class TPUBatchScheduler:
             stats.metrics_seconds = kstats["metrics_seconds"]
             stats.device_stage_seconds = kstats["stage_seconds"]
             stats.rounds = kstats["rounds"]
+            stats.spec_passes = kstats.get("spec_passes", 0)
+            stats.multi_round_specs = kstats.get("multi_round_specs", 0)
+            stats.precomp_rows = kstats["precomp_rows"]
+            stats.constraint_rows_seconds = kstats["constraint_rows_seconds"]
+            stats.dp_specs = kstats["dp_specs"]
             stats.commit_seconds = kstats.get("commit_seconds", 0.0)
             stats.dispatch_seconds = kstats.get("dispatch_seconds", 0.0)
             stats.fetch_seconds = kstats.get("fetch_seconds", 0.0)
@@ -926,6 +962,23 @@ class TPUBatchScheduler:
     def _place_on_device(self, spec_list: List[encode.PlacementSpec]):
         return self._fetch_device(self._dispatch_device(spec_list))
 
+    def _natural_plan(self, spec_list, ct, st, mesh: Optional[bool] = None
+                      ) -> Tuple[int, int, int, int]:
+        """(u_pad, slot_m, max_nnz, host rows or not) of this batch: the
+        canonical bucketing (encode.shape_plan, ISSUE 13 compile-cache
+        audit: one bucketing for the single-chip and the mesh path) at
+        ``st``'s spec pad, for the mesh's slot budget when the batch goes
+        to the mesh, and whether encode built a host-row matrix."""
+        if mesh is None:
+            mesh = self.mesh is not None
+        _, slot_m, max_nnz = encode.shape_plan(
+            st.u_pad, ct.n_pad, ct.n_real,
+            max((sp.count for sp in spec_list), default=1),
+            int(sum(sp.count for sp in spec_list)), mesh=mesh,
+            **({"slot_budget_bytes": MESH_SLOT_BUDGET_BYTES} if mesh
+               else {}))
+        return st.u_pad, slot_m, max_nnz, int(st.precomp.shape != (1, 1))
+
     def _live_allocs_by_node(self) -> Dict[str, List[s.Allocation]]:
         """Full state walk: every live alloc row grouped by node — the
         reference usage basis (and the resident cache's rebuild/guard
@@ -1017,6 +1070,9 @@ class TPUBatchScheduler:
         table_index = getattr(self.state, "table_index", None)
         store_uid = getattr(self.state, "store_uid", None)
         if table_index is not None and store_uid is not None:
+            attr_targets, literals = _widen_vocabulary(
+                (store_uid, table_index("nodes"), with_networks, pad_m),
+                attr_targets, literals)
             lit_key = tuple(sorted(
                 (t, tuple(sorted(vs))) for t, vs in literals.items()))
             # Slot layout (store_uid, nodes_index, ...) is relied on by
@@ -1080,6 +1136,14 @@ class TPUBatchScheduler:
                                              for nid in allocs_by_node)
                                  if i is not None)
         st = encode.encode_specs(spec_list, ct, all_nodes)
+        # The batch's shape plan, or the compiled plan of its shape class
+        # that covers it (kernels.choose_plan: a drain's tail batch runs
+        # the full batches' program instead of compiling its own).
+        with_dp = any(sp.dp_target is not None for sp in spec_list)
+        u_pad, slot_m, max_nnz, host_rows = kernels.choose_plan(
+            (ct.n_pad, with_networks, with_dp, self.mesh is not None),
+            self._natural_plan(spec_list, ct, st))
+        st = encode.pad_specs(st, u_pad, bool(host_rows), ct.n_pad)
 
         # Existing per-(job, node) alloc counts for anti-affinity/distinct,
         # uploaded SPARSE and scattered dense on device: the dense U×N
@@ -1192,7 +1256,6 @@ class TPUBatchScheduler:
             dyn.update(net_active=st.net_active, net_mbits=st.net_mbits,
                        dyn_need=st.dyn_need, resv_words=st.resv_words,
                        u_bw=u_bw, u_dyn=u_dyn, u_ports=u_ports)
-        with_dp = any(sp.dp_target is not None for sp in spec_list)
         if with_dp:
             dyn.update(dp_col=st.dp_col, dp_active=st.dp_active,
                        dp_used=st.dp_used)
@@ -1215,11 +1278,14 @@ class TPUBatchScheduler:
                 quantized=0 if quant is None else 1, t0=t0,
                 resident_info=resident_info, res_key=res_key,
                 snap_index=snap_index, used_host=used
-                if res_key is not None else None, h2d0=h2d0)
+                if res_key is not None else None, h2d0=h2d0,
+                slot_m=slot_m, max_nnz=max_nnz)
             if handle is not None:
                 return handle
             # Slot-record budget exceeded (pathological count skew):
-            # degrade to the single-chip program below.
+            # degrade to the single-chip program below, at its own plan.
+            _, slot_m, max_nnz, _ = self._natural_plan(spec_list, ct, st,
+                                                       mesh=False)
 
         # Donated device-resident usage mirror (ISSUE 13): when the
         # resident slot exactly matches this batch's (key, allocs
@@ -1245,17 +1311,13 @@ class TPUBatchScheduler:
         encode_seconds = time.perf_counter() - t0
         t1 = time.perf_counter()
 
-        # Canonical shape-class plan (ISSUE 13 compile-cache audit): ONE
-        # pow2 bucketing for (U, slot record, COO capacity) shared with
-        # the mesh path — see encode.shape_plan for the slot-mode and
-        # score-carry rules (commit-score side-outputs: [U, M] commit-
-        # aligned slot buffers in slot mode, two [U, N] carries
-        # otherwise; slot mode builds the COO payload with one U×M pass
-        # instead of a nonzero over the U×N matrix).
-        total_asks = int(sum(sp.count for sp in spec_list))
-        max_count = max((sp.count for sp in spec_list), default=1)
-        with_scores, slot_m, max_nnz = encode.shape_plan(
-            st.u_pad, ct.n_pad, ct.n_real, max_count, total_asks)
+        # slot_m and max_nnz are the plan's, chosen above; see
+        # encode.shape_plan for the slot-mode and score-carry rules
+        # (commit-score side-outputs: [U, M] commit-aligned slot buffers
+        # in slot mode, two [U, N] carries otherwise; slot mode builds
+        # the COO payload with one U×M pass instead of a nonzero over
+        # the U×N matrix).
+        with_scores = encode.carries_scores(st.u_pad, ct.n_real)
         fused_buf = fused_meta = fused_overflow = None
         summary_buf = coo_mat = None
         stages = _DeviceStages()
@@ -1467,7 +1529,9 @@ class TPUBatchScheduler:
         commit_seconds = time.perf_counter() - handle["t1"]
         fetch_seconds = time.perf_counter() - t_disp
         dispatch_seconds = max(0.0, commit_seconds - fetch_seconds)
-        rounds = int(summary["scalars"][1])
+        scalars = dict(zip(kernels.SCALARS,
+                           (int(v) for v in summary["scalars"])))
+        rounds = scalars["rounds"]
         unplaced_arr = summary["unplaced"]
         feas_count = summary["feas_count"]
         # Unified COO decode (slot mode arrives as per-alloc COO with
@@ -1486,6 +1550,8 @@ class TPUBatchScheduler:
             coo_rows, coo_cols, coo_counts, coo_scores, coo_coll,
             rounds, with_scores, handle["encode_seconds"], handle["t1"],
             stages)
+        kstats["spec_passes"] = scalars["spec_passes"]
+        kstats["multi_round_specs"] = scalars["multi_round_specs"]
         kstats["commit_seconds"] = commit_seconds
         kstats["dispatch_seconds"] = dispatch_seconds
         kstats["fetch_seconds"] = (fetch_seconds
@@ -1514,7 +1580,7 @@ class TPUBatchScheduler:
     def _dispatch_mesh(self, spec_list, all_nodes, ct, st, static, dyn,
                        *, with_networks, with_dp, quantized, t0,
                        resident_info, res_key=None, snap_index=None,
-                       used_host=None, h2d0=0):
+                       used_host=None, h2d0=0, slot_m=0, max_nnz=0):
         """Node-sharded twin of the fused dispatch: the SAME static/dyn
         tensor dicts, but the static pack is split into per-shard
         buffers placed on their owning device (NamedSharding over the
@@ -1547,25 +1613,24 @@ class TPUBatchScheduler:
         mesh = self.mesh
         d = mesh.devices.size
         n_l = ct.n_pad // d
-        max_count = max((sp.count for sp in spec_list), default=1)
-        total_asks = int(sum(sp.count for sp in spec_list))
-        # Canonical shape-class plan shared with the single-chip path
-        # (ISSUE 13 compile-cache audit).  Slot-mode scores whenever the
+        # ``slot_m`` / ``max_nnz``: the batch's plan as _dispatch_device
+        # chose it (_natural_plan with the mesh's slot budget, or the
+        # compiled plan that covers it).  Slot-mode scores whenever the
         # single-chip path would carry them: the score threshold is
         # taken at the SINGLE-CHIP pad (128), not the mesh's lcm(128, D)
         # pad-up — otherwise a non-power-of-two mesh could cross the
         # 16M boundary and drop scores exactly where the reference
         # path still carries them (encode.shape_plan's n_pad_ref rule).
-        with_scores, slot_m, max_nnz = encode.shape_plan(
-            st.u_pad, ct.n_pad, ct.n_real, max_count, total_asks,
-            mesh=True, slot_budget_bytes=MESH_SLOT_BUDGET_BYTES)
+        with_scores = encode.carries_scores(st.u_pad, ct.n_real)
         if not slot_m:
             self.logger.warning(
                 "mesh slot record for %d specs x %d max count exceeds "
-                "budget; batch takes the single-chip path",
-                st.u_pad, max_count)
+                "budget; batch takes the single-chip path", st.u_pad,
+                max((sp.count for sp in spec_list), default=1))
             return None
-        k_cand = min(n_l, encode.pow2_bucket(max(64, max_count)))
+        # pow2(max(64, max count)), read off the slot bucket so that a
+        # covering plan brings its own candidate width.
+        k_cand = min(n_l, max(64, slot_m))
 
         # Loan the sharded donated mirror (installs it node-sharded on
         # first use).  From here to sharded_fused_pass returning, an
@@ -1871,13 +1936,22 @@ class TPUBatchScheduler:
             "fetch_seconds": kstats_fetch_s,
             "fetch_bytes": kstats_fetch_b,
             "stage_seconds": stages.seconds,
+            # The host-evaluated feasibility rows encode built for this
+            # batch (encode._constraint_row / _driver_row) and the time
+            # in them; the specs that carry a distinct_property.
+            "precomp_rows": len(st.row_stamps),
+            "constraint_rows_seconds": sum(b - a for a, b in st.row_stamps),
+            "dp_specs": int(st.dp_active.sum()),
         }
         kstats.update(preempt_stats)
         tr = tracing.TRACER
         if tr is not None:
             # Phase spans from the timers already taken above: t1 marks
             # the encode→device boundary, t_metrics the device→host one.
-            tr.record("batch.encode", t1 - encode_seconds, t1)
+            enc = tr.record("batch.encode", t1 - encode_seconds, t1)
+            for a, b in st.row_stamps:
+                tr.record("batch.encode.constraint_rows", a, b,
+                          parent_id=enc.span_id)
             tr.record("batch.device", t1, t1 + device_seconds,
                       span_id=stages.parent_id, rounds=rounds)
             tr.record("batch.metrics", t_metrics,
@@ -2460,7 +2534,16 @@ class BatchStats:
         self.finalize_submit_seconds = 0.0
         self.finalize_status_seconds = 0.0
         self.total_seconds = 0.0
+        # Most passes any one spec of the batch took; the passes summed
+        # over its specs; the specs that took more than one.
         self.rounds = 0
+        self.spec_passes = 0
+        self.multi_round_specs = 0
+        # Host-evaluated feasibility rows built in encode, the time in
+        # them (part of encode_seconds), and distinct_property specs.
+        self.precomp_rows = 0
+        self.constraint_rows_seconds = 0.0
+        self.dp_specs = 0
         # Fused score-and-commit path (PR 6): whether this batch ran the
         # single-dispatch/single-fetch program, the wall time of that
         # dispatch (upload → device compute → result transfer), the wall

@@ -16,7 +16,8 @@ compares on device.
 from __future__ import annotations
 
 import logging
-from dataclasses import dataclass, field
+import time
+from dataclasses import dataclass, field, fields, replace
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -218,6 +219,13 @@ def route_shard_deltas(dev_rows, shards: int, n_local: int,
     return rows, vals
 
 
+def carries_scores(u_pad: int, n_real: int) -> bool:
+    """shape_plan's ``with_scores`` rule, for a caller that brings its
+    plan's other two buckets itself (a compiled plan that covers the
+    batch's own, kernels.choose_plan)."""
+    return u_pad * max(128, round_up(n_real, 128)) <= 16_000_000
+
+
 def shape_plan(u_pad: int, n_pad: int, n_real: int, max_count: int,
                total_asks: int, *, mesh: bool = False,
                slot_budget_bytes: int = 64 << 20
@@ -243,8 +251,7 @@ def shape_plan(u_pad: int, n_pad: int, n_real: int, max_count: int,
       committed in two rounds appears twice), per-(spec, node)
       aggregates otherwise.
     """
-    n_pad_ref = max(128, round_up(n_real, 128))
-    with_scores = u_pad * n_pad_ref <= 16_000_000
+    with_scores = carries_scores(u_pad, n_real)
     slot_m = 0
     if mesh or n_pad <= 65536:
         m_b = pow2_bucket(max(8, max_count), minimum=8)
@@ -814,6 +821,9 @@ class SpecTensors:
     dp_col: np.ndarray = None       # [u_pad] int32 — attr column or -1
     dp_active: np.ndarray = None    # [u_pad] bool
     dp_used: np.ndarray = None      # [u_pad, V] bool — value codes in use
+    # (start, end) ``perf_counter`` stamps of each host-evaluated row
+    # (``_constraint_row`` / ``_driver_row``) built for this batch.
+    row_stamps: List[Tuple[float, float]] = field(default_factory=list)
 
 
 def encode_specs(
@@ -845,11 +855,15 @@ def encode_specs(
     # a trivially-true [1,1] broadcast saves a U×N upload to the device.
     precomp = None
 
-    def _precomp():
+    row_stamps: List[Tuple[float, float]] = []
+
+    def _and_host_row(u, build, *args):
         nonlocal precomp
         if precomp is None:
             precomp = np.ones((u_pad, ct.n_pad), dtype=bool)
-        return precomp
+        t_a = time.perf_counter()
+        precomp[u, :ct.n_real] &= build(*args)
+        row_stamps.append((t_a, time.perf_counter()))
 
     job_ids: List[str] = []
     job_row: Dict[str, int] = {}
@@ -910,7 +924,7 @@ def encode_specs(
             target = "${attr.driver." + driver + "}"
             col = ct.attr_index.get(target)
             if col is None:
-                _precomp()[u, :ct.n_real] &= _driver_row(nodes, driver)
+                _and_host_row(u, _driver_row, nodes, driver)
                 continue
             # truthy values per strconv.ParseBool; precompute truth set codes
             truthy = {
@@ -923,7 +937,7 @@ def encode_specs(
                 c_rhs[u, k] = next(iter(truthy))
                 k += 1
             else:
-                _precomp()[u, :ct.n_real] &= _driver_row(nodes, driver)
+                _and_host_row(u, _driver_row, nodes, driver)
 
         for con in sp.constraints:
             if con.operand in (s.CONSTRAINT_DISTINCT_HOSTS,
@@ -941,8 +955,8 @@ def encode_specs(
             else:
                 # Host-evaluated per computed class (or per node if escaped):
                 # the same caching the reference does (feasible.go:597).
-                _precomp()[u, :ct.n_real] &= _constraint_row(
-                    nodes, con, ct, class_cache, eval_ctx)
+                _and_host_row(u, _constraint_row, nodes, con, ct,
+                              class_cache, eval_ctx)
 
     st = SpecTensors(
         specs=specs,
@@ -968,8 +982,31 @@ def encode_specs(
         dp_col=dp_col,
         dp_active=dp_active,
         dp_used=dp_used,
+        row_stamps=row_stamps,
     )
     return st
+
+
+def pad_specs(st: SpecTensors, u_pad: int, precomp: bool, n_pad: int
+              ) -> SpecTensors:
+    """``st`` with its spec axis padded to ``u_pad`` and, with
+    ``precomp``, its host-row matrix materialized: the shapes of a program
+    that is already compiled (kernels.choose_plan).  Padding rows ask for
+    nothing (count 0, no constraint, no distinct_property); an all-true
+    host-row matrix excludes nothing."""
+    if precomp and st.precomp.shape == (1, 1):
+        st = replace(st, precomp=np.ones((st.u_pad, n_pad), dtype=bool))
+    grow = u_pad - st.u_pad
+    if not grow:
+        return st
+    fills = {"precomp": True, "dp_col": -1}
+    grown = {
+        f.name: np.pad(v, [(0, grow)] + [(0, 0)] * (v.ndim - 1),
+                       constant_values=fills.get(f.name, 0))
+        for f in fields(st)
+        if isinstance(v := getattr(st, f.name), np.ndarray) and v.ndim
+        and v.shape[0] == st.u_pad and v.shape != (1, 1)}
+    return replace(st, u_pad=u_pad, **grown)
 
 
 def _driver_row(nodes: Sequence[s.Node], driver: str) -> np.ndarray:

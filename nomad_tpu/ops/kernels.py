@@ -1,5 +1,5 @@
 """TPU batch-scheduling kernels: vectorized feasibility + scoring +
-round-based placement (SURVEY.md §7 steps 2-3).
+spec-major placement passes (SURVEY.md §7 steps 2-3).
 
 Re-derivation of the reference iterator chain (scheduler/stack.go:37) as
 masked tensor ops:
@@ -10,10 +10,13 @@ masked tensor ops:
 - scoring      S[U,N] = score_fit(used+ask) − penalty·collisions
   — BinPackIterator + JobAntiAffinityIterator (rank.go:130,247) as one fused
   elementwise expression over the whole matrix.
-- placement    iterative masked rank-and-commit loop with capacity feedback
-  — the only sequential part (≤count iterations per spec); anti-affinity
-  (20 > max binpack 18) means at most one alloc of a job lands per node per
-  round, so each round places min(count, feasible) allocs per spec.
+- placement    masked rank-and-commit passes with capacity feedback — the
+  only sequential part.  The batch's specs are placed in order, each to
+  its end before the next starts (``spec_major``), as the reference
+  processes one evaluation after another; anti-affinity (20 > max
+  binpack 18) means at most one alloc of a job lands per node per pass,
+  so a pass places min(remaining, feasible) allocs of its spec and most
+  specs take exactly one.
 
 Everything is jittable; no data-dependent Python control flow
 (lax.while_loop / lax.scan / lax.fori_loop only), static shapes from the
@@ -123,6 +126,50 @@ def reset_compile_signatures() -> None:
     global COMPILES
     _COMPILE_SIGS.clear()
     COMPILES = 0
+    with _PLAN_LOCK:
+        _PLANS.clear()
+        _PADDED.clear()
+
+
+# -- shape-plan reuse ---------------------------------------------------------
+#
+# A batch's shape plan is (u_pad, slot_m, max_nnz, host rows): the pow2
+# buckets of its specs, of its largest count and of its asks
+# (encode.shape_plan), and whether it uploads a [U, N] matrix of
+# host-evaluated constraint rows.  Each new plan is a new program, tens of
+# seconds of XLA on a TPU, and a backlog drained in full batches ends in
+# ONE smaller batch: the tail of 16 evaluations behind 29 batches of 64
+# waited 20 s for a program of its own (chip run, PERF.md section 6, PR
+# 29) where the full batch's program places it in 30 ms with 48 padding
+# rows.  So a plan that has not been dispatched yet is served by the
+# smallest dispatched plan of its shape class that is at least as large in
+# every bucket; padding rows, unused slots and an all-true host-row matrix
+# are inert, so placements are unchanged.  A shape that keeps coming earns
+# its own program after ``PLAN_REUSE_LIMIT`` padded batches.
+_PLANS: dict = {}      # shape class -> {plan, ...} dispatched so far
+_PADDED: dict = {}     # (shape class, natural plan) -> batches served padded
+_PLAN_LOCK = threading.Lock()
+PLAN_REUSE_LIMIT = 16
+
+
+def choose_plan(shape_class: tuple, natural: Tuple[int, int, int, int]
+                ) -> Tuple[int, int, int, int]:
+    """The plan to dispatch a batch with, given its own (``natural``)
+    plan and everything else that selects the program (``shape_class``:
+    node pad, networks, distinct_property, mesh).  Records the returned
+    plan as dispatched."""
+    with _PLAN_LOCK:
+        seen = _PLANS.setdefault(shape_class, set())
+        if natural not in seen:
+            fits = [p for p in seen
+                    if all(a >= b for a, b in zip(p, natural))
+                    and bool(p[1]) == bool(natural[1])]
+            served = _PADDED.get((shape_class, natural), 0)
+            if fits and served < PLAN_REUSE_LIMIT:
+                _PADDED[(shape_class, natural)] = served + 1
+                return min(fits)
+            seen.add(natural)
+        return natural
 
 
 def jitter_seed(rng_key: jnp.ndarray) -> jnp.ndarray:
@@ -332,7 +379,8 @@ class PlacementResult(NamedTuple):
     placements: jnp.ndarray   # [U, N] int32 — allocs of spec u committed on node n
     unplaced: jnp.ndarray     # [U] int32 — counts that found no feasible node
     used_after: jnp.ndarray   # [N, 4] int32 — final node usage
-    rounds: jnp.ndarray       # [] int32
+    rounds: jnp.ndarray       # [] int32 — most passes any one spec took
+                              # (``passes.most``)
     # AllocMetric side-outputs (structs.go:4074 contract): the PURE
     # binpack score (rank.go:138 score_node "binpack") and the job
     # collision count at commit time — the host derives the separate
@@ -352,6 +400,55 @@ class PlacementResult(NamedTuple):
     # entirely in this mode.
     slot_scores: jnp.ndarray = None        # [U, M] float32
     slot_coll: jnp.ndarray = None          # [U, M] int32
+    # Pass accounting of the spec-major loop (see ``spec_major``).
+    passes: "PassCounts" = None
+
+
+class PassCounts(NamedTuple):
+    """What ``spec_major`` counted over one batch."""
+
+    most: jnp.ndarray     # [] int32 — most passes any one spec took
+    total: jnp.ndarray    # [] int32 — passes summed over the specs
+    multi: jnp.ndarray    # [] int32 — specs that took more than one pass
+
+
+def spec_major(place_pass, carry, remaining_of, u_pad: int, max_rounds: int):
+    """The placement loop, shared by the single-chip program and the two
+    mesh programs (parallel/sharded.py): the batch's specs in order
+    (host pre-sorts by priority desc — the broker's priority heap,
+    eval_broker.go:43), each placed to its end before the next starts.
+
+    ``place_pass(carry, u) -> (carry, placed, ran)`` is one pass of spec
+    ``u`` over the node table: ``placed`` allocations committed, ``ran``
+    false when the pass did not count (nothing left to place, or no node
+    has room for the ask on capacity alone — the capacity early-exit).
+    A spec is passed over again while its last pass ran and placed
+    something, ``remaining_of(carry)[u]`` is above 0 and it has had fewer
+    than ``max_rounds`` passes; then the next spec starts on the fleet
+    as this one left it, which is what the reference's one evaluation
+    after another does.  One flat ``lax.while_loop``: a spec that is done
+    in one pass costs one iteration.
+
+    Returns ``(carry, PassCounts)``."""
+    zero = jnp.int32(0)
+
+    def cond(state):
+        return state[1] < u_pad
+
+    def body(state):
+        carry, u, passes, most, total, multi = state
+        carry, placed, ran = place_pass(carry, u)
+        passes = passes + ran.astype(jnp.int32)
+        more = (ran & (placed > 0) & (remaining_of(carry)[u] > 0)
+                & (passes < max_rounds))
+        return (carry, jnp.where(more, u, u + 1),
+                jnp.where(more, passes, zero), jnp.maximum(most, passes),
+                total + ran.astype(jnp.int32),
+                multi + (~more & (passes > 1)).astype(jnp.int32))
+
+    carry, _, _, most, total, multi = lax.while_loop(
+        cond, body, (carry, zero, zero, zero, zero, zero))
+    return carry, PassCounts(most=most, total=total, multi=multi)
 
 
 class NetTensors(NamedTuple):
@@ -467,21 +564,23 @@ def _placement_rounds_impl(
 ) -> PlacementResult:
     """The sequential heart of the batch scheduler.
 
-    Each round scans specs in order (host pre-sorts by priority desc — the
-    broker's priority heap, eval_broker.go:43); a spec places at most one
-    alloc per node per round (justified by the anti-affinity penalty: a
-    second same-job alloc on a node scores ≤ −2, below any empty feasible
-    node), committing to its top-k scored nodes under remaining capacity.
-    Loop exits when a round makes no progress (capacity exhausted or all
-    placed).
+    Specs are placed in order, each to its end before the next starts
+    (``spec_major``).  One pass of a spec places at most one alloc per
+    node (justified by the anti-affinity penalty: a second same-job alloc
+    on a node scores ≤ −2, below any empty feasible node), committing to
+    its top-k scored nodes under remaining capacity; the spec is passed
+    over again until its count is met, a pass places nothing (capacity
+    exhausted) or ``max_rounds`` passes are spent.  ``rounds`` in the
+    result is the most passes any one spec took.
 
     Network accounting per (spec, node): bandwidth fit, reserved-port
     bitmap conflict, and dynamic-port-capacity checks, with commit updates
     to all three (rank.go:190-238; concrete dynamic port *values* are
     assigned host-side at finalize, which device-side capacity accounting
     makes safe).  distinct_property: a per-spec used-value bitset masks
-    feasibility; a within-round scatter-min keeps only the best-ranked
-    node per property value (propertyset.go:150).
+    feasibility; a within-pass scatter-min keeps only the best-ranked
+    node per property value (propertyset.go:150), and the nodes it drops
+    wait for the spec's next pass.
     """
     u_pad, n_pad = feas.shape
     v_pad = dp.used0.shape[1]
@@ -490,7 +589,7 @@ def _placement_rounds_impl(
     node_idx = jnp.arange(n_pad, dtype=jnp.int32)
     big_idx = jnp.int32(n_pad + 1)
 
-    def place_one_spec(carry, u):
+    def place_pass(carry, u):
         def try_place(carry):
             (used, job_counts, remaining_count, placements,
              bw_used, port_words, dyn_free, dp_used, commit_scores,
@@ -525,12 +624,17 @@ def _placement_rounds_impl(
                 code_c = None
 
             # Commit the top-k scored nodes (k = remaining count, bounded
-            # by feasible nodes) — one alloc per node this round.
+            # by feasible nodes) — one alloc per node this pass.
             k = jnp.minimum(remaining_count[u],
                             jnp.sum(ok).astype(jnp.int32))
-            return lax.cond(k > 0, lambda c: commit(c, ok, collisions,
-                                                    code_c, k),
-                            skip, carry)
+            carry, placed = lax.cond(
+                k > 0, lambda c: commit(c, ok, collisions, code_c, k),
+                lambda c: (c, jnp.int32(0)), carry)
+            # Capacity early-exit, per spec: a pass over a fleet on which
+            # no node has room for the ask on capacity alone does not
+            # count (net/dp/constraints are stricter, so this is a
+            # necessary condition only and placements are unchanged).
+            return carry, placed, jnp.any(fits)
 
         def commit(carry, ok, collisions, code_c, k):
             (used, job_counts, remaining_count, placements,
@@ -545,7 +649,7 @@ def _placement_rounds_impl(
             # selection, same tie order, ~100x less device work at N≈50k.
             sel = _select_top_k(scored, ok, k)
 
-            # Within-round value dedup for distinct_property: among
+            # Within-pass value dedup for distinct_property: among
             # selected nodes sharing a property value, keep only the
             # best-scored (ties by lowest node index — stable-sort order).
             if use_dp:
@@ -612,62 +716,20 @@ def _placement_rounds_impl(
                     commit_scores, commit_coll, slots, slot_scores,
                     slot_coll), placed
 
-        def skip(carry):
-            return carry, jnp.int32(0)
-
-        # Two-level skip, both REAL branches on TPU (the scan over specs
+        # Two-level skip, both REAL branches on TPU (the loop over specs
         # is sequential, not vmapped, so lax.cond doesn't get batched
         # into a select):
-        #  - outer: remaining_count[u] == 0 (spec fully placed) skips
-        #    even the feasibility/fit prefix — a scalar test, so placed
-        #    specs cost nothing in later rounds;
+        #  - outer: remaining_count[u] == 0 (a padding row, or a spec
+        #    with nothing to place) skips even the feasibility/fit
+        #    prefix — a scalar test;
         #  - inner (in try_place): k == 0 (no feasible node under
         #    remaining capacity) skips the scoring transcendentals and
         #    the top-k select.
         # Neither branch commits anything, so placements stay
         # bit-identical to the unguarded kernel.
-        return lax.cond(carry[2][u] > 0, try_place, skip, carry)
-
-    def round_body(state):
-        (used, job_counts, remaining_count, placements,
-         bw_used, port_words, dyn_free, dp_used, commit_scores,
-         commit_coll, slots, slot_scores, slot_coll, _, rounds) = state
-        carry, placed = lax.scan(
-            place_one_spec,
-            (used, job_counts, remaining_count, placements,
-             bw_used, port_words, dyn_free, dp_used, commit_scores,
-             commit_coll, slots, slot_scores, slot_coll),
-            jnp.arange(u_pad),
-        )
-        (used, job_counts, remaining_count, placements,
-         bw_used, port_words, dyn_free, dp_used, commit_scores,
-         commit_coll, slots, slot_scores, slot_coll) = carry
-        progress = jnp.sum(placed)
-        return (used, job_counts, remaining_count, placements,
-                bw_used, port_words, dyn_free, dp_used, commit_scores,
-                commit_coll, slots, slot_scores, slot_coll, progress,
-                rounds + 1)
-
-    def round_cond(state):
-        used = state[0]
-        remaining_count = state[2]
-        progress = state[13]
-        rounds = state[14]
-        go = ((progress > 0) & (jnp.sum(remaining_count) > 0)
-              & (rounds < max_rounds))
-        # Capacity early-exit: if no node can fit even the SMALLEST
-        # remaining ask (dimension-wise lower bound), no spec can place
-        # anything, so the round would only burn one feasibility prefix
-        # per active spec to discover no progress.  This turns the
-        # always-paid final no-progress round into one [N, 4] pass.
-        # Necessary-condition only (net/dp/constraints are stricter), so
-        # placements are unchanged.
-        active = remaining_count > 0
-        min_ask = jnp.min(jnp.where(active[:, None], ask,
-                                    jnp.int32(2**30)), axis=0)
-        fits_any = jnp.any(jnp.all(min_ask[None, :] <= capacity - used,
-                                   axis=1))
-        return go & fits_any
+        return lax.cond(
+            carry[2][u] > 0, try_place,
+            lambda c: (c, jnp.int32(0), jnp.bool_(False)), carry)
 
     placements0 = jnp.zeros((u_pad, n_pad) if not slot_m else (1, 1),
                             dtype=jnp.int32)
@@ -683,25 +745,36 @@ def _placement_rounds_impl(
     sscore_shape = (u_pad, slot_m) if with_scores and slot_m else (1, 1)
     sscores0 = jnp.zeros(sscore_shape, dtype=jnp.float32)
     scoll0 = jnp.zeros(sscore_shape, dtype=jnp.int32)
-    state = (used0, job_counts0, count, placements0,
+    carry = (used0, job_counts0, count, placements0,
              net.bw_used, net.port_words, net.dyn_free, dp.used0, scores0,
-             coll0, slots0, sscores0, scoll0,
-             jnp.array(1, dtype=jnp.int32), jnp.array(0, dtype=jnp.int32))
+             coll0, slots0, sscores0, scoll0)
     (used, job_counts, remaining, placements,
      _bw, _pw, _df, _dpu, commit_scores, commit_coll, slots, slot_scores,
-     slot_coll, _, rounds) = lax.while_loop(round_cond, round_body, state)
+     slot_coll), passes = spec_major(
+        place_pass, carry, lambda c: c[2], u_pad, max_rounds)
 
     return PlacementResult(
         placements=placements,
         unplaced=remaining,
         used_after=used,
-        rounds=rounds,
+        rounds=passes.most,
         commit_scores=commit_scores,
         commit_collisions=commit_coll,
         slots=slots,
         slot_scores=slot_scores,
         slot_coll=slot_coll,
+        passes=passes,
     )
+
+
+# The result buffer's scalar row, in order (shared by the three programs'
+# packers and the host's decode).
+SCALARS = ("nnz", "rounds", "spec_passes", "multi_round_specs")
+
+
+def pack_scalars(nnz, passes: PassCounts) -> jnp.ndarray:
+    return jnp.stack([nnz, passes.most, passes.total,
+                      passes.multi]).astype(jnp.int32)
 
 
 def summary_layout(u_pad: int, n_pad: int):
@@ -717,7 +790,7 @@ def summary_layout(u_pad: int, n_pad: int):
     return xfer.layout({
         "unplaced": ("i32", (u_pad,)),
         "feas_count": ("i32", (u_pad,)),
-        "scalars": ("i32", (2,)),       # [nnz, rounds]
+        "scalars": ("i32", (len(SCALARS),)),
     })
 
 
@@ -943,7 +1016,7 @@ def _device_compact(result: PlacementResult, feas: jnp.ndarray,
     summary, _ = xfer.pack_device({
         "unplaced": result.unplaced,
         "feas_count": feas_count,
-        "scalars": jnp.stack([nnz, result.rounds]).astype(jnp.int32),
+        "scalars": pack_scalars(nnz, result.passes),
     })
     return summary, coo
 
@@ -1029,7 +1102,7 @@ def fused_window(max_nnz: int, *, with_scores: bool,
 def fused_layout(u_pad: int, *, window_nnz: int, with_scores: bool,
                  compact_u16: bool):
     """Layout of the fused score-and-commit result buffer: summary
-    (unplaced + feas_count + [nnz, rounds]) AND the COO placement
+    (unplaced + feas_count + the ``SCALARS`` row) AND the COO placement
     payload window in ONE packed uint8 buffer, so the whole batch
     result crosses the link in a single transfer (ops/xfer.py layout():
     both sides compute the offsets independently)."""
@@ -1039,7 +1112,7 @@ def fused_layout(u_pad: int, *, window_nnz: int, with_scores: bool,
     return xfer.layout({
         "unplaced": ("i32", (u_pad,)),
         "feas_count": ("i32", (u_pad,)),
-        "scalars": ("i32", (2,)),       # [nnz, rounds]
+        "scalars": ("i32", (len(SCALARS),)),
         "coo": ("u16" if compact_u16 else "i32", (window_nnz, ncols)),
     })
 
@@ -1069,7 +1142,7 @@ def _fused_score_commit(
     use_used_dev: bool = False,
 ):
     """ONE device dispatch for the whole batch: unpack (+ dequantize) →
-    feasibility → lax.scan capacity-feedback placement rounds → COO
+    feasibility → spec-major capacity-feedback placement passes → COO
     compaction (from the commit-aligned slot record when slot_m) →
     single packed result buffer.  The two-dispatch schedule/compact
     split (device_pass) remains the fallback behind NOMAD_TPU_FUSED=0
@@ -1104,7 +1177,7 @@ def _fused_score_commit(
     buf, _ = xfer.pack_device({
         "unplaced": result.unplaced,
         "feas_count": feas_count,
-        "scalars": jnp.stack([nnz, result.rounds]).astype(jnp.int32),
+        "scalars": pack_scalars(nnz, result.passes),
         "coo": coo_win,
     })
     return buf, aux, feas, used_out

@@ -29,6 +29,8 @@ from ..ops.kernels import (
     PlacementResult,
     _score_fit,
     jitter_seed,
+    pack_scalars,
+    spec_major,
     tie_jitter,
 )
 from ..ops.encode import MISSING
@@ -75,23 +77,24 @@ def sharded_placement_rounds(
     """The single-chip `placement_rounds` semantics, node-sharded over the
     mesh: anti-affinity collisions, distinct_hosts, per-(job,node) counts,
     network port/bandwidth accounting, distinct_property and the
-    multi-round capacity-feedback loop all run on sharded state.
+    spec-major capacity-feedback loop (``kernels.spec_major``: each spec
+    placed to its end before the next) all run on sharded state.
 
     Per spec, each shard scores its node shard (binpack − penalty·collisions
     + the same jitter the single-chip kernel uses), takes a local top-k_cand,
     and the k_cand·D candidates are all-gathered over ICI; the global top-k
     selection and shard-local commit follow.  As long as a spec commits
-    ≤ k_cand allocs in a round (one alloc per node per round — the
+    ≤ k_cand allocs in a pass (one alloc per node per pass — the
     anti-affinity bound), the selection is *identical* to the single-chip
     kernel's full-argsort commit, including tie-breaks: gathered candidate
     order is (shard, local index) = global node order, and both paths use
-    stable sorts.  Specs needing more than k_cand·D per round under-commit
-    that round and finish in later rounds (progress loop).
+    stable sorts.  Specs needing more than k_cand·D per pass under-commit
+    that pass and finish in their later passes (progress loop).
 
     ``net`` shards its per-node state (bw_cap/bw_used/dyn_free/port_words)
     over the mesh and replicates the per-spec asks — feasibility and
     commits are shard-local, mirroring ops/kernels.py (rank.go:190-238).
-    ``dp`` replicates the per-spec used-value bitsets; the within-round
+    ``dp`` replicates the per-spec used-value bitsets; the within-pass
     best-per-value dedup runs as pmax/pmin all-reduces over the value
     axis so every shard keeps the same winner the single-chip
     scatter-max/min picks (propertyset.go:150).
@@ -155,7 +158,7 @@ def sharded_placement_rounds(
         big_idx = jnp.int32(n_pad + 1)
         gidx = shard * n_l + jnp.arange(n_l, dtype=jnp.int32)
 
-        def place_one_spec(carry, u):
+        def place_pass(carry, u):
             (used, jc, remaining, placements,
              bw_used, port_words, dyn_free, dp_used) = carry
             cap_left = cap_l - used
@@ -189,8 +192,14 @@ def sharded_placement_rounds(
             loc_scores, loc_idx = lax.top_k(scored, k_cand)
             all_scores = lax.all_gather(
                 loc_scores, NODE_AXIS, tiled=True)     # [D*k_cand]
-            n_ok = lax.psum(jnp.sum(ok.astype(jnp.int32)), NODE_AXIS)
+            n_ok, n_fits = lax.psum(
+                jnp.stack([jnp.sum(ok.astype(jnp.int32)),
+                           jnp.sum(fits.astype(jnp.int32))]), NODE_AXIS)
             k = jnp.minimum(remaining[u], n_ok)
+            # The single-chip pass's two skips, as what they count: a
+            # pass with nothing left to place, or over a fleet with no
+            # room for the ask on capacity alone, did not run.
+            ran = (remaining[u] > 0) & (n_fits > 0)
 
             order = jnp.argsort(-all_scores)
             ranks = jnp.zeros(c_total, dtype=jnp.int32).at[order].set(
@@ -200,7 +209,7 @@ def sharded_placement_rounds(
             sel = jnp.zeros(n_l, dtype=bool).at[loc_idx].set(my_sel) & ok
 
             if use_dp:
-                # Cross-shard within-round value dedup: the best-scored
+                # Cross-shard within-pass value dedup: the best-scored
                 # selected node per property value wins globally (ties by
                 # lowest GLOBAL node index), via pmax/pmin over the value
                 # axis — bit-identical to the single-chip scatter-max/min.
@@ -240,36 +249,15 @@ def sharded_placement_rounds(
                 dp_used = dp_used.at[u].set(dp_used[u] | dp_upd)
 
             return (used, jc, remaining, placements,
-                    bw_used, port_words, dyn_free, dp_used), placed
-
-        def round_body(state):
-            (used, jc, remaining, placements, bw_used, port_words,
-             dyn_free, dp_used, _, rounds) = state
-            carry, placed = lax.scan(
-                place_one_spec,
-                (used, jc, remaining, placements, bw_used, port_words,
-                 dyn_free, dp_used),
-                jnp.arange(u_pad))
-            (used, jc, remaining, placements, bw_used, port_words,
-             dyn_free, dp_used) = carry
-            return (used, jc, remaining, placements, bw_used, port_words,
-                    dyn_free, dp_used, jnp.sum(placed), rounds + 1)
-
-        def round_cond(state):
-            remaining = state[2]
-            progress = state[8]
-            rounds = state[9]
-            return ((progress > 0) & (jnp.sum(remaining) > 0)
-                    & (rounds < max_rounds))
+                    bw_used, port_words, dyn_free, dp_used), placed, ran
 
         placements0 = _mark_varying(
             jnp.zeros((u_pad, n_l), dtype=jnp.int32))
-        state = (used_l, jc_l, count_r, placements0,
-                 bw_used_l0, port_words_l0, dyn_free_l0, dp_used0_r,
-                 jnp.array(1, dtype=jnp.int32), jnp.array(0, dtype=jnp.int32))
-        (used, jc, remaining, placements, _bw, _pw, _df, _dpu, _,
-         rounds) = lax.while_loop(round_cond, round_body, state)
-        return placements, remaining, used, rounds
+        carry = (used_l, jc_l, count_r, placements0,
+                 bw_used_l0, port_words_l0, dyn_free_l0, dp_used0_r)
+        (used, jc, remaining, placements, _bw, _pw, _df, _dpu), passes = \
+            spec_major(place_pass, carry, lambda c: c[2], u_pad, max_rounds)
+        return placements, remaining, used, passes.most
 
     placements, unplaced, used_after, rounds = _run(
         feas, used0, capacity, denom, ask, count, penalty, distinct_hosts,
@@ -288,12 +276,13 @@ def sharded_placement_rounds(
 # over node-sharded packed static buffers + a replicated dynamic buffer
 # runs unpack (+ dequantize) → per-shard usage-delta scatter-adds →
 # per-shard feasibility → the local-top-k + ICI-all-gather capacity-
-# feedback commit loop → a commit-ordered slot record → slot→COO gather
+# feedback commit loop (spec-major, ops/kernels.spec_major: the one loop
+# all three programs share) → a commit-ordered slot record → slot→COO gather
 # → ONE packed result buffer (replicated, fetched from one device).
 #
-# Exactness: per round a spec commits at most ``remaining ≤ count``
+# Exactness: per pass a spec commits at most ``remaining ≤ count``
 # allocs, so with ``k_cand ≥ max(count)`` (or k_cand == the whole shard)
-# the global top-``remaining`` of any round lies inside the gathered
+# the global top-``remaining`` of any pass lies inside the gathered
 # local top-k_cand candidates — the selection, tie-jitter (keyed on
 # GLOBAL node index) and commit order are bit-identical to the
 # single-chip kernel.  batch_sched sizes k_cand that way, so the mesh
@@ -497,7 +486,7 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
         jit_seed_r = jitter_seed(key)
         d_arange = jnp.arange(d, dtype=jnp.int32)
 
-        def place_one_spec(carry, u):
+        def place_pass(carry, u):
             (used, jc, remaining, bw_used, port_words, dyn_free, dp_used,
              slots, sscores, scoll) = carry
             cap_left = cap_l - used
@@ -532,8 +521,11 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
             # k ≤ remaining ≤ count ≤ k_cand).
             loc_scores, loc_idx = lax.top_k(scored, k_cand)
             all_scores = lax.all_gather(loc_scores, NODE_AXIS, tiled=True)
-            n_ok = lax.psum(jnp.sum(ok.astype(jnp.int32)), NODE_AXIS)
+            n_ok, n_fits = lax.psum(
+                jnp.stack([jnp.sum(ok.astype(jnp.int32)),
+                           jnp.sum(fits.astype(jnp.int32))]), NODE_AXIS)
             k = jnp.minimum(remaining[u], n_ok)
+            ran = (remaining[u] > 0) & (n_fits > 0)
             order = jnp.argsort(-all_scores)
             ranks = jnp.zeros(c_total, dtype=jnp.int32).at[order].set(
                 jnp.arange(c_total, dtype=jnp.int32))
@@ -595,38 +587,17 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
                     dp_upd_l.astype(jnp.int32), NODE_AXIS) > 0
                 dp_used = dp_used.at[u].set(dp_used[u] | dp_upd)
             return (used, jc, remaining, bw_used, port_words, dyn_free,
-                    dp_used, slots, sscores, scoll), placed
-
-        def round_body(state):
-            (used, jc, remaining, bw_used, port_words, dyn_free, dp_used,
-             slots, sscores, scoll, _, rounds) = state
-            carry, placed = lax.scan(
-                place_one_spec,
-                (used, jc, remaining, bw_used, port_words, dyn_free,
-                 dp_used, slots, sscores, scoll),
-                jnp.arange(u_pad))
-            (used, jc, remaining, bw_used, port_words, dyn_free, dp_used,
-             slots, sscores, scoll) = carry
-            return (used, jc, remaining, bw_used, port_words, dyn_free,
-                    dp_used, slots, sscores, scoll, jnp.sum(placed),
-                    rounds + 1)
-
-        def round_cond(state):
-            remaining = state[2]
-            progress = state[10]
-            rounds = state[11]
-            return ((progress > 0) & (jnp.sum(remaining) > 0)
-                    & (rounds < max_rounds))
+                    dp_used, slots, sscores, scoll), placed, ran
 
         sscore_shape = (u_pad, slot_m) if with_scores else (1, 1)
-        state = (used0, jc0, count_r,
+        carry = (used0, jc0, count_r,
                  bw_used0, port_words0, dyn_free0, dp_used_init,
                  _mark_varying(jnp.zeros((u_pad, slot_m), dtype=jnp.int32)),
                  _mark_varying(jnp.zeros(sscore_shape, dtype=jnp.float32)),
-                 _mark_varying(jnp.zeros(sscore_shape, dtype=jnp.int32)),
-                 jnp.array(1, dtype=jnp.int32), jnp.array(0, dtype=jnp.int32))
+                 _mark_varying(jnp.zeros(sscore_shape, dtype=jnp.int32)))
         (used, jc, remaining, _bw, _pw, _df, _dpu, slots_p, sscores_p,
-         scoll_p, _, rounds) = lax.while_loop(round_cond, round_body, state)
+         scoll_p), passes = spec_major(
+            place_pass, carry, lambda c: c[2], u_pad, max_rounds)
 
         # Disjoint per-shard partials → ONE psum yields the replicated
         # commit-ordered record; +1/-1 encoding keeps empty slots at -1.
@@ -641,7 +612,7 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
         buf, _ = xfer.pack_device({
             "unplaced": remaining,
             "feas_count": feas_count,
-            "scalars": jnp.stack([nnz, rounds]).astype(jnp.int32),
+            "scalars": pack_scalars(nnz, passes),
             "coo": coo_win,
         })
         return buf, slots_full, sscores_full, scoll_full, feas_l, used_dev_l

@@ -509,6 +509,11 @@ class TestCompileAudit:
         bench --check guards at 200 batches rides this counter."""
         from nomad_tpu.ops import kernels
 
+        # A clean audit: with bigger plans on record from this process's
+        # earlier batches, the first PLAN_REUSE_LIMIT of these would run
+        # a bigger compiled program and the next would earn its own
+        # (kernels.choose_plan) — one signature, but not at a fixed batch.
+        kernels.reset_compile_signatures()
         h = Harness()
         for _ in range(8):
             h.state.upsert_node(h.next_index(), make_node())
