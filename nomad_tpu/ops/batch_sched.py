@@ -467,10 +467,14 @@ class TPUBatchScheduler:
                          stats.metrics_seconds * 1000.0)
             m.add_sample("worker.invoke_scheduler.encode.constraint_rows",
                          stats.constraint_rows_seconds * 1000.0)
+            m.add_sample("worker.invoke_scheduler.encode.resident",
+                         stats.resident_seconds * 1000.0)
             m.add_sample("worker.invoke_scheduler.rounds", stats.rounds)
             # Published on every batch, 0 where nothing applies, so that
             # a metric over them reads 0 and not nothing.
             m.incr_counter("batch.precomp_rows", stats.precomp_rows)
+            m.incr_counter("batch.constraint_row_reuse",
+                           stats.constraint_row_reuse)
             m.incr_counter("batch.dp_specs", stats.dp_specs)
             m.incr_counter("batch.multi_round_specs",
                            stats.multi_round_specs)
@@ -850,6 +854,7 @@ class TPUBatchScheduler:
             stats.multi_round_specs = kstats.get("multi_round_specs", 0)
             stats.precomp_rows = kstats["precomp_rows"]
             stats.constraint_rows_seconds = kstats["constraint_rows_seconds"]
+            stats.constraint_row_reuse = kstats["constraint_row_reuse"]
             stats.dp_specs = kstats["dp_specs"]
             stats.commit_seconds = kstats.get("commit_seconds", 0.0)
             stats.dispatch_seconds = kstats.get("dispatch_seconds", 0.0)
@@ -887,6 +892,8 @@ class TPUBatchScheduler:
         stats.full_reencodes = 1 if res_info.get("full_reencode") else 0
         stats.staleness_fences = 1 if res_info.get("fence") else 0
         stats.delta_apply_seconds = res_info.get("delta_apply_s", 0.0)
+        t_a, t_b = res_info.get("stamps", (0.0, 0.0))
+        stats.resident_seconds = t_b - t_a
 
     def _route_through_oracle(self, scheds) -> None:
         """Degraded path: process each eval with the CPU GenericScheduler
@@ -1099,7 +1106,7 @@ class TPUBatchScheduler:
         resident_info: Dict = {}
         use_resident = (resident.enabled() and not with_networks
                         and cache_key is not None
-                        and getattr(self.state, "allocs_since", None)
+                        and getattr(self.state, "alloc_log_since", None)
                         is not None)
         if use_resident:
             # The usage mirror depends only on the node set, not the
@@ -1107,12 +1114,15 @@ class TPUBatchScheduler:
             # nodes index, pad geometry) so residency survives
             # vocabulary changes; ``shards`` lets the differential
             # guard attribute a mismatch to the owning mesh shard.
+            t_res = time.perf_counter()
             used, touched, resident_info = resident.acquire(
                 self.state, cache_key[:2] + (base.n_pad,), base,
                 self._live_allocs_by_node, breaker=self.breaker,
                 shards=(self.mesh.devices.size
                         if self.mesh is not None else 0),
                 usage_fn=lambda: self._columnar_usage(base))
+            # Feed read, fold and device delta apply, inside encode.
+            resident_info["stamps"] = (t_res, time.perf_counter())
             ct = encode.with_usage(base, used)
             # The preemption pass only needs WHICH nodes may carry live
             # allocs (it re-materializes candidate rows from state);
@@ -1549,7 +1559,7 @@ class TPUBatchScheduler:
             spec_list, all_nodes, ct, st, feas, unplaced_arr, feas_count,
             coo_rows, coo_cols, coo_counts, coo_scores, coo_coll,
             rounds, with_scores, handle["encode_seconds"], handle["t1"],
-            stages)
+            stages, (handle.get("resident") or {}).get("stamps"))
         kstats["spec_passes"] = scalars["spec_passes"]
         kstats["multi_round_specs"] = scalars["multi_round_specs"]
         kstats["commit_seconds"] = commit_seconds
@@ -1702,12 +1712,13 @@ class TPUBatchScheduler:
                                  unplaced_arr, feas_count, coo_rows,
                                  coo_cols, coo_counts, coo_scores, coo_coll,
                                  rounds, with_scores, encode_seconds, t1,
-                                 stages):
+                                 stages, resident_stamps=None):
         """Shared device→host post-processing for the single-chip and
         mesh placement paths: lazy failure-forensics row fetch, COO →
         per-spec slots, AllocMetric assembly.  ``stages`` arrives with
         its ``decode`` stage running; the device_seconds stamp below
-        closes it."""
+        closes it.  ``resident_stamps``: when encode took the resident
+        path, the two stamps around ``resident.acquire``."""
         # Chaos hook: corrupt the fetched kernel outputs (the damage a
         # flaky accelerator / bad HBM would do), THEN validate — the
         # validation below is exactly what protects production from the
@@ -1936,11 +1947,13 @@ class TPUBatchScheduler:
             "fetch_seconds": kstats_fetch_s,
             "fetch_bytes": kstats_fetch_b,
             "stage_seconds": stages.seconds,
-            # The host-evaluated feasibility rows encode built for this
-            # batch (encode._constraint_row / _driver_row) and the time
-            # in them; the specs that carry a distinct_property.
+            # The host-evaluated feasibility rows encode supplied for
+            # this batch (encode._host_row), the time in them, and how
+            # many came from a kept row; the specs that carry a
+            # distinct_property.
             "precomp_rows": len(st.row_stamps),
             "constraint_rows_seconds": sum(b - a for a, b in st.row_stamps),
+            "constraint_row_reuse": st.rows_reused,
             "dp_specs": int(st.dp_active.sum()),
         }
         kstats.update(preempt_stats)
@@ -1951,6 +1964,9 @@ class TPUBatchScheduler:
             enc = tr.record("batch.encode", t1 - encode_seconds, t1)
             for a, b in st.row_stamps:
                 tr.record("batch.encode.constraint_rows", a, b,
+                          parent_id=enc.span_id)
+            if resident_stamps is not None:
+                tr.record("batch.encode.resident", *resident_stamps,
                           parent_id=enc.span_id)
             tr.record("batch.device", t1, t1 + device_seconds,
                       span_id=stages.parent_id, rounds=rounds)
@@ -2539,10 +2555,15 @@ class BatchStats:
         self.rounds = 0
         self.spec_passes = 0
         self.multi_round_specs = 0
-        # Host-evaluated feasibility rows built in encode, the time in
-        # them (part of encode_seconds), and distinct_property specs.
+        # Host-evaluated feasibility rows encode supplied, the time in
+        # them (part of encode_seconds), how many were served from a
+        # kept row, and distinct_property specs.
         self.precomp_rows = 0
         self.constraint_rows_seconds = 0.0
+        self.constraint_row_reuse = 0
+        # Time inside resident.acquire (part of encode_seconds): feed
+        # read, fold and device delta apply; 0 off the resident path.
+        self.resident_seconds = 0.0
         self.dp_specs = 0
         # Fused score-and-commit path (PR 6): whether this batch ran the
         # single-dispatch/single-fetch program, the wall time of that

@@ -190,33 +190,35 @@ def pow2_bucket(x: int, minimum: int = 8) -> int:
     return v
 
 
-def route_shard_deltas(dev_rows, shards: int, n_local: int,
-                       dims: int = 4):
-    """Split a global usage-delta run into per-shard (local_row, vals)
-    runs for the donated per-shard scatter-add (ops/resident.py mesh
-    mirror): one numpy pass over the changed rows — O(changed), never
-    O(cluster) — emitting ``rows [D, k_b] int32`` (-1 padding) and
-    ``vals [D, k_b, dims] int32`` whose leading axis shards over the
+def route_shard_deltas(rows: np.ndarray, vals: np.ndarray, shards: int,
+                       n_local: int):
+    """Split a global usage-delta run ``(rows int[k], vals int[k, dims])``
+    into per-shard (local_row, vals) runs for the donated per-shard
+    scatter-add (ops/resident.py mesh mirror): one stable sort by
+    owning shard over the changed rows — O(changed), never O(cluster),
+    no Python per row — emitting ``rows [D, k_b] int32`` (-1 padding)
+    and ``vals [D, k_b, dims] int32`` whose leading axis shards over the
     node mesh (``NamedSharding(mesh, P(NODE_AXIS))`` hands each device
-    exactly its run).  ``k_b`` is the pow2 bucket of the LARGEST
-    per-shard run so the donated apply jit holds a fixed handful of
-    shapes regardless of how deltas skew across shards."""
-    per_rows = [[] for _ in range(shards)]
-    per_vals = [[] for _ in range(shards)]
-    for i, vec in dev_rows:
-        s_i = i // n_local
-        if 0 <= s_i < shards:
-            per_rows[s_i].append(i - s_i * n_local)
-            per_vals[s_i].append(vec)
-    k_b = pow2_bucket(max(1, max(len(r) for r in per_rows)))
-    rows = np.full((shards, k_b), -1, dtype=np.int32)
-    vals = np.zeros((shards, k_b, dims), dtype=np.int32)
-    for s_i in range(shards):
-        k = len(per_rows[s_i])
-        if k:
-            rows[s_i, :k] = per_rows[s_i]
-            vals[s_i, :k] = per_vals[s_i]
-    return rows, vals
+    exactly its run, in the order the rows came).  ``k_b`` is the pow2
+    bucket of the LARGEST per-shard run so the donated apply jit holds a
+    fixed handful of shapes regardless of how deltas skew across
+    shards."""
+    rows = np.asarray(rows, dtype=np.int64)
+    shard = rows // n_local
+    owned = (shard >= 0) & (shard < shards)
+    if not owned.all():
+        rows, vals, shard = rows[owned], vals[owned], shard[owned]
+    order = np.argsort(shard, kind="stable")
+    shard = shard[order]
+    counts = np.bincount(shard, minlength=shards)
+    k_b = pow2_bucket(max(1, int(counts.max(initial=0))))
+    # Position of each row inside its shard's run.
+    pos = np.arange(len(shard)) - (np.cumsum(counts) - counts)[shard]
+    out_rows = np.full((shards, k_b), -1, dtype=np.int32)
+    out_vals = np.zeros((shards, k_b, vals.shape[1]), dtype=np.int32)
+    out_rows[shard, pos] = rows[order] - shard * n_local
+    out_vals[shard, pos] = vals[order]
+    return out_rows, out_vals
 
 
 def carries_scores(u_pad: int, n_real: int) -> bool:
@@ -451,6 +453,7 @@ def encode_cluster_static(
     ct._nodes = list(nodes)            # type: ignore[attr-defined]
     ct._with_networks = with_networks  # type: ignore[attr-defined]
     ct._node_index = {nid: i for i, nid in enumerate(node_ids)}  # type: ignore[attr-defined]
+    ct._host_rows = _HostRows()        # type: ignore[attr-defined]
     return ct
 
 
@@ -515,6 +518,7 @@ def encode_cluster_static_columnar(
     ct._nodes = nodes if type(nodes) is list else list(nodes)  # type: ignore[attr-defined]
     ct._with_networks = False          # type: ignore[attr-defined]
     ct._node_index = {nid: i for i, nid in enumerate(node_ids)}  # type: ignore[attr-defined]
+    ct._host_rows = _HostRows()        # type: ignore[attr-defined]
     ct._columnar = True                # type: ignore[attr-defined]
     return ct
 
@@ -602,6 +606,17 @@ def build_cluster_static(
     return ct
 
 
+def _carry_host_attrs(ct: ClusterTensors, new: ClusterTensors) -> None:
+    """Host-only attributes of the static tensors (undeclared, so they
+    stay off the dataclass) that a per-batch clone shares with them:
+    none depends on usage, and ``_host_rows`` must be the SAME object so
+    a row built under one batch's clone is found by the next."""
+    for attr in ("_raw_rows", "_value_sets", "_class_codebook", "_nodes",
+                 "_with_networks", "_node_index", "_host_rows"):
+        if hasattr(ct, attr):
+            setattr(new, attr, getattr(ct, attr))
+
+
 def apply_alloc_usage(
     ct: ClusterTensors,
     allocs_by_node: Dict[str, List[s.Allocation]],
@@ -625,10 +640,7 @@ def apply_alloc_usage(
         port_words=(ct.port_words.copy()
                     if getattr(ct, "_with_networks", False) else ct.port_words),
     )
-    for attr in ("_raw_rows", "_value_sets", "_class_codebook", "_nodes",
-                 "_with_networks", "_node_index"):
-        if hasattr(ct, attr):
-            setattr(new, attr, getattr(ct, attr))
+    _carry_host_attrs(ct, new)
 
     node_index = new._node_index
     nodes = new._nodes
@@ -681,10 +693,7 @@ def with_usage(ct: ClusterTensors, used) -> ClusterTensors:
     import dataclasses as _dc
 
     new = _dc.replace(ct, used=used)
-    for attr in ("_raw_rows", "_value_sets", "_class_codebook", "_nodes",
-                 "_with_networks", "_node_index"):
-        if hasattr(ct, attr):
-            setattr(new, attr, getattr(ct, attr))
+    _carry_host_attrs(ct, new)
     return new
 
 
@@ -822,8 +831,10 @@ class SpecTensors:
     dp_active: np.ndarray = None    # [u_pad] bool
     dp_used: np.ndarray = None      # [u_pad, V] bool — value codes in use
     # (start, end) ``perf_counter`` stamps of each host-evaluated row
-    # (``_constraint_row`` / ``_driver_row``) built for this batch.
+    # (``_host_row``) the batch's specs took, and how many of them were
+    # served from a kept row with no node and no class evaluated.
     row_stamps: List[Tuple[float, float]] = field(default_factory=list)
+    rows_reused: int = 0
 
 
 def encode_specs(
@@ -856,14 +867,27 @@ def encode_specs(
     precomp = None
 
     row_stamps: List[Tuple[float, float]] = []
+    rows_reused = 0
+    eval_ctx = EvalContext(state=None, plan=s.Plan())  # caches only
 
-    def _and_host_row(u, build, *args):
-        nonlocal precomp
+    def _and_host_row(u, key, check, by_class=True):
+        nonlocal precomp, rows_reused
         if precomp is None:
             precomp = np.ones((u_pad, ct.n_pad), dtype=bool)
         t_a = time.perf_counter()
-        precomp[u, :ct.n_real] &= build(*args)
+        row, reused = _host_row(ct, nodes, key, check, by_class)
+        precomp[u, :ct.n_real] &= row
+        rows_reused += reused
         row_stamps.append((t_a, time.perf_counter()))
+
+    def _and_driver_row(u, driver):
+        key = f"driver.{driver}"
+
+        def check(node):
+            val = node.attributes.get(key)
+            return bool(val is not None and _parse_bool(val))
+
+        _and_host_row(u, ("driver", driver), check)
 
     job_ids: List[str] = []
     job_row: Dict[str, int] = {}
@@ -882,10 +906,6 @@ def encode_specs(
             v_max = max(v_max, len(ct.value_codebooks[sp.dp_target]) + 1)
     v_pad = pow2_bucket(v_max, minimum=2) if v_max > 1 else 1
     dp_used = np.zeros((u_pad, v_pad), dtype=bool)
-
-    # Class-level cache for non-vectorizable checks: (constraint-key, class)
-    class_cache: Dict[Tuple[str, str, str, int], bool] = {}
-    eval_ctx = EvalContext(state=None, plan=s.Plan())  # caches only
 
     for u, sp in enumerate(specs):
         ask[u] = sp.ask
@@ -924,7 +944,7 @@ def encode_specs(
             target = "${attr.driver." + driver + "}"
             col = ct.attr_index.get(target)
             if col is None:
-                _and_host_row(u, _driver_row, nodes, driver)
+                _and_driver_row(u, driver)
                 continue
             # truthy values per strconv.ParseBool; precompute truth set codes
             truthy = {
@@ -937,7 +957,7 @@ def encode_specs(
                 c_rhs[u, k] = next(iter(truthy))
                 k += 1
             else:
-                _and_host_row(u, _driver_row, nodes, driver)
+                _and_driver_row(u, driver)
 
         for con in sp.constraints:
             if con.operand in (s.CONSTRAINT_DISTINCT_HOSTS,
@@ -955,8 +975,11 @@ def encode_specs(
             else:
                 # Host-evaluated per computed class (or per node if escaped):
                 # the same caching the reference does (feasible.go:597).
-                _and_host_row(u, _constraint_row, nodes, con, ct,
-                              class_cache, eval_ctx)
+                _and_host_row(
+                    u, (con.ltarget, con.operand, con.rtarget),
+                    lambda node, con=con: _check_on_node(eval_ctx, con,
+                                                         node),
+                    by_class=not _escapes_class(con))
 
     st = SpecTensors(
         specs=specs,
@@ -983,6 +1006,7 @@ def encode_specs(
         dp_active=dp_active,
         dp_used=dp_used,
         row_stamps=row_stamps,
+        rows_reused=rows_reused,
     )
     return st
 
@@ -1009,43 +1033,71 @@ def pad_specs(st: SpecTensors, u_pad: int, precomp: bool, n_pad: int
     return replace(st, u_pad=u_pad, **grown)
 
 
-def _driver_row(nodes: Sequence[s.Node], driver: str) -> np.ndarray:
-    out = np.zeros(len(nodes), dtype=bool)
-    key = f"driver.{driver}"
-    for i, node in enumerate(nodes):
-        val = node.attributes.get(key)
-        out[i] = bool(val is not None and _parse_bool(val))
-    return out
-
-
 def _escapes_class(constraint: s.Constraint) -> bool:
     from ..structs.node_class import _target_escapes
 
     return _target_escapes(constraint.ltarget) or _target_escapes(constraint.rtarget)
 
 
-def _constraint_row(
-    nodes: Sequence[s.Node],
-    con: s.Constraint,
-    ct: ClusterTensors,
-    class_cache: Dict,
-    eval_ctx: EvalContext,
-) -> np.ndarray:
-    """Evaluate one non-vectorizable constraint host-side, caching per
-    computed class unless the constraint escapes class semantics."""
-    out = np.zeros(len(nodes), dtype=bool)
-    escaped = _escapes_class(con)
-    for i, node in enumerate(nodes):
-        if not escaped and node.computed_class:
-            key = (con.ltarget, con.operand, con.rtarget, ct.class_code[i].item())
-            if key in class_cache:
-                out[i] = class_cache[key]
-                continue
-        ok = _check_on_node(eval_ctx, con, node)
-        out[i] = ok
-        if not escaped and node.computed_class:
-            class_cache[key] = ok
-    return out
+# Host rows kept per static encode before the oldest goes: a row is one
+# bool per node, and a fleet's jobs share a small vocabulary of version,
+# regexp and set constraints.
+HOST_ROWS_KEPT = 256
+
+
+class _HostRows:
+    """The host-evaluated feasibility rows of one static encode, and the
+    fleet's computed classes as ``(first, inverse, loners)``: a
+    representative node of each class code, every node's position among
+    them, and the nodes that have no computed class."""
+
+    __slots__ = ("rows", "groups")
+
+    def __init__(self) -> None:
+        self.rows: Dict[Tuple, np.ndarray] = {}
+        self.groups = None
+
+
+def _host_row(ct: ClusterTensors, nodes: Sequence[s.Node], key: Tuple,
+              check, by_class: bool) -> Tuple[np.ndarray, bool]:
+    """One host-evaluated feasibility row, ``check(node)`` for every
+    node, and whether it was served from a kept row.
+
+    A row depends on node attributes alone, so it is kept under ``key``
+    with the static cluster tensors it was decided on (``_host_rows``,
+    shared by every per-batch clone): the tensors' cache key holds the
+    node table's index, so a node that registers, drains or changes an
+    attribute drops the rows with the tensors (batch_sched
+    ``_CLUSTER_CACHE``).  With ``by_class`` the check runs once per
+    computed class present in the fleet and is gathered by class code —
+    upstream's FeasibilityWrapper / EvalCache unit of caching
+    (feasible.go:597) — and per node only for nodes without a computed
+    class; a check that escapes class semantics runs on every node."""
+    kept: _HostRows = ct._host_rows  # type: ignore[attr-defined]
+    row = kept.rows.get(key)
+    if row is not None:
+        return row, True
+    if by_class:
+        if kept.groups is None:
+            _, first, inverse = np.unique(
+                ct.class_code[:ct.n_real], return_index=True,
+                return_inverse=True)
+            loners = np.flatnonzero(np.fromiter(
+                (not nodes[i].computed_class for i in first),
+                bool, len(first))[inverse])
+            kept.groups = (first.tolist(), inverse, loners.tolist())
+        first, inverse, loners = kept.groups
+        row = np.fromiter((check(nodes[i]) for i in first),
+                          bool, len(first))[inverse]
+        for i in loners:
+            row[i] = check(nodes[i])
+    else:
+        row = np.fromiter(map(check, nodes), bool, len(nodes))
+    row.flags.writeable = False
+    if len(kept.rows) >= HOST_ROWS_KEPT:
+        del kept.rows[next(iter(kept.rows))]
+    kept.rows[key] = row
+    return row, False
 
 
 def _check_on_node(eval_ctx: EvalContext, con: s.Constraint, node: s.Node) -> bool:

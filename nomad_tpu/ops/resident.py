@@ -7,9 +7,10 @@ This module keeps the usage matrix RESIDENT between batches, keyed by
 the same static-cluster cache key batch_sched already maintains
 (store lineage + nodes-table raft index + constraint vocabulary), and
 catches it up with the state store's usage-delta feed
-(``StateStore.allocs_since``) — O(changed allocs) per batch, the
-Megatron/Pathways persistent-device-state trick applied to the
-scheduler's cluster mirror.
+(``StateStore.alloc_log_since``, folded as arrays: one index gather and
+one scatter-add per batch, no Python per allocation) — O(changed allocs)
+per batch, the Megatron/Pathways persistent-device-state trick applied
+to the scheduler's cluster mirror.
 
 Correctness machinery:
 
@@ -17,7 +18,7 @@ Correctness machinery:
   than the resident state (its allocs index is behind the cached one —
   e.g. a replayed eval or a harness snapshot) full re-encodes from its
   own snapshot and leaves the resident state untouched.
-- **Feed gap**: when ``allocs_since`` cannot answer (the cached index
+- **Feed gap**: when ``alloc_log_since`` cannot answer (the cached index
   fell off the bounded log, or a restore reset the feed) the cache is
   rebuilt from a full walk and the event stream gets a
   ``NodeStateDelta`` summary so operators see residency churn.
@@ -363,10 +364,11 @@ def check_quant_roundtrip(exact: np.ndarray, quantized: np.ndarray,
     return False
 
 
-def _apply_device_deltas(used_dev, dev_rows, mesh=None):
-    """Catch the device mirror up with one donated scatter-add (no-op
-    when the mirror is absent or nothing changed).  Rows are bucketed to
-    powers of two so the jit cache stays a fixed handful of shapes.
+def _apply_device_deltas(used_dev, rows, vals, mesh=None):
+    """Catch the device mirror up with one donated scatter-add of the
+    usage rows ``(rows int[k], vals int[k, 4])`` (no-op when the mirror
+    is absent or nothing changed).  Rows are bucketed to powers of two
+    so the jit cache stays a fixed handful of shapes.
 
     With ``mesh`` the mirror is node-sharded: the global delta stream is
     routed into per-shard (local_row, vals) runs host-side
@@ -374,7 +376,7 @@ def _apply_device_deltas(used_dev, dev_rows, mesh=None):
     by the per-shard donated scatter-add, so every shard touches only
     the rows it owns."""
     global DEV_APPLIES, DEV_H2D_BYTES
-    if used_dev is None or not dev_rows:
+    if used_dev is None or not len(rows):
         return used_dev
     from .encode import pow2_bucket, route_shard_deltas
     from .kernels import program_call
@@ -388,8 +390,7 @@ def _apply_device_deltas(used_dev, dev_rows, mesh=None):
 
             d = mesh.devices.size
             n_l = used_dev.shape[0] // d
-            rows, vals = route_shard_deltas(dev_rows, d, n_l,
-                                            dims=RES_DIMS)
+            rows, vals = route_shard_deltas(rows, vals, d, n_l)
             DEV_APPLIES += 1
             DEV_H2D_BYTES += rows.nbytes + vals.nbytes
             spec = NamedSharding(mesh, P(shmod.NODE_AXIS))
@@ -398,19 +399,16 @@ def _apply_device_deltas(used_dev, dev_rows, mesh=None):
                 return _delta_apply_mesh_fn(mesh)(
                     used_dev, jax.device_put(rows, spec),
                     jax.device_put(vals, spec))
-        k_b = pow2_bucket(len(dev_rows))
-        rows = np.full(k_b, -1, dtype=np.int32)
-        vals = np.zeros((k_b, RES_DIMS), dtype=np.int32)
-        for j, (i, vec) in enumerate(dev_rows):
-            rows[j] = i
-            vals[j, 0] = vec[0]
-            vals[j, 1] = vec[1]
-            vals[j, 2] = vec[2]
-            vals[j, 3] = vec[3]
+        k = len(rows)
+        k_b = pow2_bucket(k)
+        rows_b = np.full(k_b, -1, dtype=np.int32)
+        rows_b[:k] = rows
+        vals_b = np.zeros((k_b, RES_DIMS), dtype=np.int32)
+        vals_b[:k] = vals
         DEV_APPLIES += 1
-        DEV_H2D_BYTES += rows.nbytes + vals.nbytes
+        DEV_H2D_BYTES += rows_b.nbytes + vals_b.nbytes
         with program_call("resident_delta", (used_dev.shape, k_b)):
-            return _delta_apply_fn()(used_dev, rows, vals)
+            return _delta_apply_fn()(used_dev, rows_b, vals_b)
     except Exception:
         # The donated input is consumed even on failure — a dead handle
         # must not linger in the slot (the next take reinstalls from
@@ -418,6 +416,40 @@ def _apply_device_deltas(used_dev, dev_rows, mesh=None):
         logger.exception("donated delta apply failed; dropping the "
                          "device mirror")
         return None
+
+
+def _feed_rows(entries, node_index: Dict[str, int]
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """The usage-delta feed's raw entries (StateStore.alloc_log_since)
+    as ``(rows int64[k], vals int64[k, 4])``: one usage row per
+    allocation write, in feed order; writes on nodes the fleet does not
+    hold are dropped.  Every entry's node ids go through ONE index
+    gather and a slab's usage vector is repeated over its node column —
+    no Python per allocation, and at a stream batch's one or two ten-row
+    slabs no slower than a lookup per row (PERF.md PR 30)."""
+    from ..state.columnar import gather_index
+    from ..structs.structs import alloc_usage_vec
+
+    node_ids: List[str] = []
+    vecs: List[Tuple] = []
+    counts: List[int] = []
+    for entry in entries:
+        if len(entry) == 3:     # (index, node_id, delta): one row
+            node_ids.append(entry[1])
+            vecs.append(entry[2])
+            counts.append(1)
+        else:                   # (index, slab): its node column
+            slab = entry[1]
+            node_ids.extend(slab.node_ids)
+            vecs.append(alloc_usage_vec(slab.proto))
+            counts.append(len(slab.node_ids))
+    rows = gather_index(node_index, node_ids)
+    vals = np.repeat(np.array(vecs, dtype=np.int64).reshape(-1, RES_DIMS),
+                     counts, axis=0)
+    known = rows >= 0
+    if not known.all():
+        rows, vals = rows[known], vals[known]
+    return rows, vals
 
 
 def _publish(etype_reason: str, **payload) -> None:
@@ -536,31 +568,22 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                          CachedIndex=st.alloc_index)
                 return used, sorted(touched), info
 
-            deltas = (state.allocs_since(st.alloc_index)
-                      if snap_index > st.alloc_index else [])
-            if deltas is not None:
-                node_index = base._node_index  # type: ignore[attr-defined]
+            entries = (state.alloc_log_since(st.alloc_index)
+                       if snap_index > st.alloc_index else [])
+            if entries is not None:
                 used = st.used
-                dev_rows: List[Tuple[int, Tuple]] = []
+                rows, vals = _feed_rows(
+                    entries, base._node_index)  # type: ignore[attr-defined]
+                np.add.at(used, rows, vals)
+                st.touched.update(np.unique(rows).tolist())
                 track_dev = st.used_dev is not None
-                for nid, vec in deltas:
-                    i = node_index.get(nid)
-                    if i is None:
-                        continue
-                    used[i, 0] += vec[0]
-                    used[i, 1] += vec[1]
-                    used[i, 2] += vec[2]
-                    used[i, 3] += vec[3]
-                    st.touched.add(i)
-                    if track_dev:
-                        dev_rows.append((i, vec))
                 st.alloc_index = snap_index
                 st.hits += 1
-                st.delta_rows += len(deltas)
+                st.delta_rows += len(rows)
                 st.since_guard += 1
                 HITS += 1
                 info["resident_hit"] = True
-                info["delta_rows"] = len(deltas)
+                info["delta_rows"] = len(rows)
 
                 act = fault.faultpoint("ops.resident_state")
                 if act is not None and act.kind == "corrupt":
@@ -576,16 +599,17 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                         # DEVICE copy identically, so host and device
                         # stay consistent with each other and the
                         # host-vs-walk guard below catches both.
-                        vec = [0] * RES_DIMS
-                        vec[dim] = bump
-                        dev_rows.append((row, tuple(vec)))
+                        vec = np.zeros((1, RES_DIMS), dtype=np.int64)
+                        vec[0, dim] = bump
+                        rows = np.append(rows, row)
+                        vals = np.concatenate([vals, vec])
 
                 if track_dev:
                     import time as _time
 
                     t_da = _time.monotonic()
                     st.used_dev = _apply_device_deltas(
-                        st.used_dev, dev_rows, mesh=st.dev_mesh)
+                        st.used_dev, rows, vals, mesh=st.dev_mesh)
                     info["delta_apply_s"] = _time.monotonic() - t_da
 
                 every = guard_every()

@@ -1463,27 +1463,32 @@ class StateStore:
         """The usage-delta log's raw entries with raft index > ``index``
         — ``(index, node_id, delta)`` per single row, ``(index, slab)``
         per bulk insert, unexpanded — or None when the log can no longer
-        answer.  Caller holds the lock."""
+        answer.  The array readers' feed (columnar.fold_usage, the
+        resident mirror): a slab stays one entry, so nothing is paid per
+        allocation here."""
         import bisect
 
-        if index < self._alloc_log_floor:
-            return None
-        # Entries are appended with non-decreasing raft indexes, so the
-        # skip to the first relevant entry is a bisect, not a full
-        # O(log-size) scan.  The slice is bounded by this store's length
-        # cursor: a shared parent list may have grown past it (those
-        # entries belong to a newer world).
-        log, n = self._alloc_log, self._alloc_log_len
-        start = bisect.bisect_right(log, index, 0, n, key=lambda e: e[0])
-        return log[start:n]
+        with self._lock:
+            if index < self._alloc_log_floor:
+                return None
+            # Entries are appended with non-decreasing raft indexes, so
+            # the skip to the first relevant entry is a bisect, not a
+            # full O(log-size) scan.  The slice is bounded by this
+            # store's length cursor: a shared parent list may have grown
+            # past it (those entries belong to a newer world).
+            log, n = self._alloc_log, self._alloc_log_len
+            start = bisect.bisect_right(log, index, 0, n,
+                                        key=lambda e: e[0])
+            return log[start:n]
 
     def allocs_since(self, index: int
                      ) -> Optional[List[Tuple[str, Tuple[int, int, int, int]]]]:
         """Per-node usage deltas for every alloc write with raft index
-        > ``index`` — the delta feed behind the device-resident node-state
-        cache.  Returns None when the log can no longer answer (the
-        requested index fell below the trim floor, or predates this
-        store's log), which forces the consumer to full re-encode."""
+        > ``index``: ``alloc_log_since`` expanded to one ``(node_id,
+        delta)`` tuple per (entry, node).  Returns None when the log can
+        no longer answer (the requested index fell below the trim floor,
+        or predates this store's log), which forces the consumer to full
+        re-encode."""
         with self._lock:
             entries = self.alloc_log_since(index)
             if entries is None:
