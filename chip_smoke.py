@@ -317,6 +317,103 @@ def live_placements(state, jobs):
         if not a.terminal_status()) for job in jobs}
 
 
+def fleet_arrays(nodes):
+    """The reference's view of the fleet: each node's usable cpu and
+    memory (resources less the reservation) and its row."""
+    import numpy as np
+
+    cap = np.array([[n.resources.cpu - n.reserved.cpu,
+                     n.resources.memory_mb - n.reserved.memory_mb]
+                    for n in nodes], dtype=np.float64)
+    return cap, {n.id: i for i, n in enumerate(nodes)}
+
+
+def scorefit_sum(state, nodes) -> float:
+    """Aggregate final-state ScoreFit over the nodes that carry a live
+    allocation in ``state``: the order-free basis for comparing two
+    engines' bin-packing on the same fleet."""
+    import numpy as np
+
+    from benchmarks import reference
+
+    cap, row_of = fleet_arrays(nodes)
+    used = np.zeros_like(cap)
+    for nid, row in state.alloc_rows(None):
+        if row.terminal_status():
+            continue
+        res = row.resources
+        if res is None:
+            # Oracle-path allocs carry per-task resources only (the
+            # combined total is normally filled at plan apply).
+            tasks = row.task_resources.values()
+            used[row_of[nid]] += (sum(t.cpu for t in tasks),
+                                  sum(t.memory_mb for t in tasks))
+        else:
+            used[row_of[nid]] += (res.cpu, res.memory_mb)
+    return reference.scorefit_sum(used, cap)
+
+
+def exact_reference(nodes, jobs):
+    """The unlimited-candidate oracle — the kernel's exact objective —
+    as ``benchmarks/reference.py``'s numpy twin places it: (ScoreFit
+    sum, allocations placed)."""
+    import numpy as np
+
+    from benchmarks import reference
+
+    cap, _ = fleet_arrays(nodes)
+    groups = [tg for job in jobs for tg in job.task_groups]
+    asks = [np.array([sum(t.resources.cpu for t in tg.tasks),
+                      sum(t.resources.memory_mb for t in tg.tasks)],
+                     dtype=np.float64) for tg in groups]
+    used = np.zeros_like(cap)
+    placed = 0
+    for ask, rows in zip(asks, reference.greedy(
+            cap, asks, [tg.count for tg in groups])):
+        np.add.at(used, rows, ask)
+        placed += len(rows)
+    return reference.scorefit_sum(used, cap), placed
+
+
+def run_oracle(nodes, jobs, unlimited=False):
+    """GenericScheduler on a fresh copy of the nodes and jobs, one eval
+    after another; returns its state store.  ``unlimited`` lifts the
+    LimitIterator candidate cap (select.go:5-44, stack.go:124-137): true
+    greedy best-fit through the full iterator stack, O(N x placements)
+    in Python, so only at a small size."""
+    from nomad_tpu.scheduler import Harness, new_service_scheduler
+    from nomad_tpu.scheduler import select as select_mod
+    from nomad_tpu.structs import structs as s
+
+    h = Harness()
+    for node in nodes:
+        h.state.upsert_node(h.next_index(), node.copy())
+    set_limit = select_mod.LimitIterator.set_limit
+    lifted = []
+
+    def no_limit(self, limit):
+        lifted.append(limit)
+        set_limit(self, 10**9)
+
+    if unlimited:
+        select_mod.LimitIterator.set_limit = no_limit
+    try:
+        for job in jobs:
+            h.state.upsert_job(h.next_index(), job.copy())
+            h.process(new_service_scheduler, s.Evaluation(
+                id=s.generate_uuid(), priority=job.priority, type=job.type,
+                triggered_by=s.EVAL_TRIGGER_JOB_REGISTER, job_id=job.id,
+                status=s.EVAL_STATUS_PENDING))
+    finally:
+        select_mod.LimitIterator.set_limit = set_limit
+    if unlimited and not lifted:
+        # The stack no longer routes through set_limit: the "unlimited"
+        # chain would silently be the sampled one.
+        raise RuntimeError("LimitIterator.set_limit never called; "
+                           "lifting the candidate cap had no effect")
+    return h.state
+
+
 def oracle_reference(nodes, jobs):
     """The plain reference on a fresh copy of the same nodes and jobs.
 
@@ -324,39 +421,30 @@ def oracle_reference(nodes, jobs):
     scheduler.testing.Harness + GenericScheduler.  Its ScoreFit sum is
     NOT the 0.5% contract's basis — it scores log2(N) sampled candidates
     per placement, and the accidental spreading inflates a sum of the
-    convex 10^freeFrac (bench.py bench_score_delta).  The contract's
-    basis is the unlimited-candidate oracle — the kernel's exact
-    objective — through bench.py's numpy twin of that chain, which the
-    caller validates against the real chain at a small size.
+    convex 10^freeFrac.  The contract's basis is the unlimited-candidate
+    oracle — the kernel's exact objective — through the numpy twin of
+    that chain, which the caller validates against the real chain at a
+    small size.
 
     Returns ({job id: placed}, sampled ScoreFit sum, exact ScoreFit sum).
     """
-    from bench import binpack_scores, numpy_unlimited_oracle, reg_eval
-    from nomad_tpu.scheduler import Harness, new_service_scheduler
-
-    h = Harness()
-    for node in nodes:
-        h.state.upsert_node(h.next_index(), node.copy())
-    exact_sum, _, _ = numpy_unlimited_oracle(h, jobs)
-    for job in jobs:
-        h.state.upsert_job(h.next_index(), job.copy())
-        h.process(new_service_scheduler, reg_eval(job))
+    state = run_oracle(nodes, jobs)
     placed = {jid: len(ids)
-              for jid, ids in live_placements(h.state, jobs).items()}
-    return placed, binpack_scores(h)[0], exact_sum
+              for jid, ids in live_placements(state, jobs).items()}
+    exact_sum, _ = exact_reference(nodes, jobs)
+    return placed, scorefit_sum(state, nodes), exact_sum
 
 
 def validate_exact_reference(ck) -> None:
-    """bench.py's two-link chain, first link: the numpy twin equals the
-    REAL GenericScheduler chain with the candidate limit lifted, at a
-    size where that chain can run."""
-    from bench import (_run_real_unlimited_oracle, build_problem,
-                       numpy_unlimited_oracle)
-
+    """The numpy twin equals the REAL GenericScheduler chain with the
+    candidate limit lifted, at a size where that chain can run."""
     n, j, c = 200, 2, 50
-    real_sum, _, real_placed = _run_real_unlimited_oracle(n, j, c)
-    h, jobs, _ = build_problem(n, j, c)
-    twin_sum, _, twin_placed = numpy_unlimited_oracle(h, jobs)
+    nodes, jobs = make_nodes(n), make_jobs(j, c)
+    state = run_oracle(nodes, jobs, unlimited=True)
+    real_sum = scorefit_sum(state, nodes)
+    real_placed = sum(
+        len(ids) for ids in live_placements(state, jobs).values())
+    twin_sum, twin_placed = exact_reference(nodes, jobs)
     ck.check(real_placed == twin_placed
              and abs(real_sum - twin_sum) <= 1e-6 * real_sum,
              f"exact reference: numpy twin equals the real unlimited "
@@ -367,7 +455,6 @@ def validate_exact_reference(ck) -> None:
 def drive(server, api, size, nodes, jobs, compile_log, ck):
     """The load and the answers: two bursts, the oracle comparison in
     between, then the reads.  Returns the final placements."""
-    from bench import binpack_scores
     from nomad_tpu.loadgen.auditor import integrity_sweep
 
     sample, rest = jobs[:size.sample_jobs], jobs[size.sample_jobs:]
@@ -381,7 +468,7 @@ def drive(server, api, size, nodes, jobs, compile_log, ck):
     served_state = server.state.snapshot()
     served = {jid: len(ids) for jid, ids in
               live_placements(served_state, sample).items()}
-    served_score = binpack_scores(SimpleNamespace(state=served_state))[0]
+    served_score = scorefit_sum(served_state, nodes)
     t0 = time.monotonic()
     oracle, sampled_score, exact_score = oracle_reference(nodes, sample)
     delta_pct = abs(served_score - exact_score) / exact_score * 100.0

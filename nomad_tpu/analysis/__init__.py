@@ -2,7 +2,7 @@
 
 ``python -m nomad_tpu.analysis --check`` runs four rule families over
 the whole non-vendor tree (the ``nomad_tpu`` package plus the root
-``bench.py`` / ``__graft_entry__.py`` drivers; tests are exempt — they
+``__graft_entry__.py`` driver; tests are exempt — they
 deliberately arm knobs and hold locks in shapes production code must
 not):
 
@@ -163,9 +163,8 @@ def iter_source_files(root: Optional[str] = None) -> List[str]:
             if fn.endswith(".py"):
                 out.append(os.path.relpath(
                     os.path.join(dirpath, fn), root).replace(os.sep, "/"))
-    for fn in ("bench.py", "__graft_entry__.py"):
-        if os.path.exists(os.path.join(root, fn)):
-            out.append(fn)
+    if os.path.exists(os.path.join(root, "__graft_entry__.py")):
+        out.append("__graft_entry__.py")
     return out
 
 

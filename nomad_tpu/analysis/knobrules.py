@@ -8,7 +8,7 @@ Every ``NOMAD_TPU_*`` environment variable is declared once in
   ``utils/knobs.py`` (writes — arming a drill, spawning a child with a
   knob set — are fine; interpreting a knob's value ad hoc is not).
   Names are resolved through module-level string constants
-  (``CHILD_ENV = "NOMAD_TPU_BENCH_CHILD"``) so indirection cannot
+  (``TRACE_ENV = "NOMAD_TPU_TRACE"``) so indirection cannot
   launder a read.
 - **knob-unregistered** — any ``NOMAD_TPU_*`` token appearing in a
   Python source (string, comment, knobs accessor argument) that is not

@@ -1,9 +1,9 @@
 """Zero-reflection struct codec: ONE generated wire format for RPC, the
 raft log, and FSM snapshots (ROADMAP item 1).
 
-LOADGEN_r03 named the residual honestly: reflection-msgpack codec +
-replication cost per log entry roughly cancels one follower's entire
-scheduling gain.  This package removes the reflection: per-type
+An early follower-scale run named the residual: reflection-msgpack
+codec + replication cost per log entry roughly cancels one follower's
+entire scheduling gain.  This package removes the reflection: per-type
 encoders/decoders are GENERATED from the dataclass schemas once
 (codec/gen.py), emit flat length-prefixed binary layouts, and serve as
 the one codec for

@@ -1,6 +1,6 @@
 """CLI entry: ``python -m nomad_tpu.loadgen``.
 
-Prints ONE JSON line to stdout (the machine contract, like bench.py) and
+Prints ONE JSON line to stdout (the machine contract) and
 a human summary to stderr.  ``--smoke`` is the tier-1 fast path;
 ``--compare-workers 1,4`` runs the same offered load at each worker
 count and reports the sustained-throughput speedup.

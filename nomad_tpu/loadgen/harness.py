@@ -57,7 +57,7 @@ def _apply_switch_interval():
     """Set the GIL switch interval from the env; returns the PRIOR
     value so in-process callers (the harness leader — unlike follower
     subprocesses, it shares the interpreter with whatever ran the
-    scenario, e.g. bench --check phases) can restore it."""
+    scenario, e.g. a test) can restore it."""
     import os
     import sys
 

@@ -1,9 +1,7 @@
 """Report rendering + persistence for load-harness runs.
 
-The JSON report is the machine contract (bench.py --check and the tier-1
-smoke test read it); ``render_report`` is the human summary printed to
-stderr, deliberately shaped like the bench's phase detail so the two
-read side by side.
+The JSON report is the machine contract (the tier-1 scenario tests read
+it); ``render_report`` is the human summary printed to stderr.
 """
 from __future__ import annotations
 

@@ -273,7 +273,7 @@ MULTI_SERVER = Scenario(
 #: ZERO violations — no double placement, no dup names, no overcommit,
 #: no lost acked eval, no FSM-prefix divergence — with recovery-time
 #: percentiles (placed/s back to ≥80% of pre-fault inside the bound)
-#: recorded in LOADGEN_r05.json.  Job mix stays small (count 1-2) so
+#: in the report.  Job mix stays small (count 1-2) so
 #: the auditor's fingerprint sweeps stay cheap against the state size.
 CHAOS_SOAK = Scenario(
     name="chaos_soak",

@@ -552,9 +552,7 @@ def fused_drill(seed: int = 0, log=print) -> bool:
             log(f"fused drill: FAIL — {msg}")
         return cond
 
-    saved = {k: os.environ.get(k)
-             for k in ("NOMAD_TPU_FUSED", "NOMAD_TPU_QUANT")}
-    os.environ["NOMAD_TPU_FUSED"] = "1"
+    saved = {"NOMAD_TPU_QUANT": knobs.raw("NOMAD_TPU_QUANT")}
     os.environ["NOMAD_TPU_QUANT"] = "1"
     brk = KernelCircuitBreaker(threshold=0.9, window=8, min_checks=1,
                                cooldown=3600.0)
@@ -1459,7 +1457,7 @@ def analysis_drill(seed: int = 0, log=print) -> bool:
     """Invariant-analysis drill (ISSUE 15), three legs:
 
     1. the static pass is CLEAN on the tree (zero unsuppressed
-       violations — the same gate bench --check enforces);
+       violations — the gate tests/test_analysis.py enforces);
     2. the runtime lock-order sanitizer catches a seeded inversion
        (A→B in one thread, B→A in another ⇒ cycle + witness) and is
        acyclic-silent on the well-ordered control;

@@ -43,7 +43,6 @@ from . import breaker as breaker_mod
 from . import encode, kernels, xfer
 from . import resident
 from .breaker import HALF_OPEN, KernelIntegrityError
-from .kernels import device_pass, summary_layout
 
 logger = logging.getLogger("nomad_tpu.ops.batch_sched")
 
@@ -97,19 +96,9 @@ def _widen_vocabulary(key, attr_targets, literals):
 
 # Device-resident copies of the packed static cluster buffer, keyed by
 # CONTENT digest (not store identity): a rebuilt-but-identical cluster —
-# e.g. bench trials on fresh state stores — skips the multi-MB upload
+# e.g. repeated runs on fresh state stores — skips the multi-MB upload
 # entirely (link cost per upload: not measured on the current chip).
 _DEVICE_STATIC_CACHE = LRU(4)
-
-
-def fused_enabled() -> bool:
-    """NOMAD_TPU_FUSED (default ON): score + capacity-feedback commit +
-    result compaction run as ONE device dispatch whose whole output —
-    summary, placements, AllocMetric scores — crosses the link in a
-    single transfer (kernels.fused_pass).  0/false keeps the two-phase
-    schedule/compact split as the fallback; both paths are bit-identical
-    by construction (same scan, same compaction expression)."""
-    return knobs.get_bool("NOMAD_TPU_FUSED")
 
 
 def validate_device_outputs(spec_list, ct, unplaced_arr, coo_rows,
@@ -485,7 +474,7 @@ class TPUBatchScheduler:
             m.incr_counter("batch.fetch_bytes", stats.fetch_bytes)
             # Host→device transfer accounting (ISSUE 14): split
             # single-chip vs mesh so the sharded-mirror win is
-            # observable in /v1/metrics, not just the bench headline.
+            # observable in /v1/metrics.
             m.incr_counter("batch.mesh_h2d_bytes" if stats.mesh_shards
                            else "batch.h2d_bytes", stats.h2d_bytes)
             if stats.delta_apply_seconds:
@@ -530,8 +519,8 @@ class TPUBatchScheduler:
         if resident.DEV_APPLIES:
             m.set_gauge("batch.resident_dev_applies", resident.DEV_APPLIES)
         # Compile-cache audit (ISSUE 13): distinct placement-program
-        # signatures seen process-wide — an upper bound on XLA compiles;
-        # bench --check asserts a ceiling over the config_steady stream.
+        # signatures seen process-wide — an upper bound on XLA compiles
+        # (tests/test_fused.py asserts a ceiling over a steady stream).
         m.set_gauge("batch.compiles", kernels.compile_signatures())
         # Compiled-program / device-buffer cache recycling (ISSUE 14
         # satellite): nonzero churn at steady state means the LRU caps
@@ -588,8 +577,8 @@ class TPUBatchScheduler:
         neighbor batches' host phases interleaved on this thread — the
         per-batch samples measure what an eval experiences, and their
         sum exceeds the stream's wall time by design.  Throughput claims
-        come from the stream's own elapsed time (bench config_steady's
-        sustained placed/s), never from summing batch totals."""
+        come from the stream's own elapsed time, never from summing
+        batch totals."""
         out: List[BatchStats] = []
         pending = None
         try:
@@ -1179,7 +1168,7 @@ class TPUBatchScheduler:
         for i, ((j, n), v) in enumerate(jc_entries.items()):
             jc_rows[i], jc_cols[i], jc_vals[i] = j, n, v
 
-        # Upload split (ops/kernels.py device_pass): the multi-MB static
+        # Upload split (ops/kernels.py _device_schedule): the multi-MB static
         # cluster tensors ship once and live on device keyed by content
         # digest; the per-batch dynamic buffer carries only the U-sized
         # spec tensors plus sparse alloc-usage deltas.
@@ -1195,7 +1184,7 @@ class TPUBatchScheduler:
         # (resident.check_quant_roundtrip) runs once per static encode
         # and on mismatch the batch falls back to exact int32 rows.
         # quant_enabled() is re-read EVERY batch (the runtime kill-switch
-        # convention fused_enabled()/resident.enabled() follow); only the
+        # convention resident.enabled() follows); only the
         # computed rows are memoized on the cached static tensors.
         quant = None
         if encode.quant_enabled():
@@ -1242,8 +1231,8 @@ class TPUBatchScheduler:
             "u_rows": u_rows, "u_vals": u_vals,
             # Tie-break jitter seed: random per batch, overridable with
             # NOMAD_TPU_RNG_SEED for deterministic placement reproduction
-            # (the fused-vs-two-phase differential tests pin placements
-            # bit-identical under a fixed seed).
+            # (the differential tests pin placements bit-identical
+            # under a fixed seed).
             # raw + explicit int(): a malformed pin must fail LOUDLY
             # at dispatch, not silently fall through to a random seed
             # the operator believes is deterministic.
@@ -1328,8 +1317,6 @@ class TPUBatchScheduler:
         # the COO payload with one U×M pass instead of a nonzero over
         # the U×N matrix).
         with_scores = encode.carries_scores(st.u_pad, ct.n_real)
-        fused_buf = fused_meta = fused_overflow = None
-        summary_buf = coo_mat = None
         stages = _DeviceStages()
         with stages:
             # stage: content digest of the static pack, the device-side
@@ -1349,27 +1336,18 @@ class TPUBatchScheduler:
             # dispatch: the program call until it returns (asynchronous:
             # the host's cost only) and the mirror loan handed back.
             stages.begin("dispatch")
-            if fused_enabled():
-                # Tentpole path: score + commit + compaction as ONE
-                # device dispatch emitting ONE packed result buffer,
-                # fetched in a single transfer by _fetch_device (the aux
-                # overflow source stays device-resident, touched only on
-                # window overflow).
-                fused_buf, fused_aux, feas, fused_meta, used_out = \
-                    kernels.fused_pass(
-                        static_dev, dyn_dev, used_dev,
-                        meta_s=meta_s, meta_d=meta_d, u_pad=st.u_pad,
-                        n_pad=ct.n_pad, with_networks=with_networks,
-                        with_dp=with_dp, with_scores=with_scores,
-                        max_nnz=max_nnz, slot_m=slot_m)
-                fused_overflow = ("slots" if slot_m else "coo", fused_aux)
-            else:
-                summary_buf, coo_mat, feas, used_out = device_pass(
+            # Score + commit + compaction as ONE device dispatch
+            # emitting ONE packed result buffer, fetched in a single
+            # transfer by _fetch_device (the aux overflow source stays
+            # device-resident, touched only on window overflow).
+            fused_buf, fused_aux, feas, fused_meta, used_out = \
+                kernels.fused_pass(
                     static_dev, dyn_dev, used_dev,
                     meta_s=meta_s, meta_d=meta_d, u_pad=st.u_pad,
                     n_pad=ct.n_pad, with_networks=with_networks,
                     with_dp=with_dp, with_scores=with_scores,
                     max_nnz=max_nnz, slot_m=slot_m)
+            fused_overflow = ("slots" if slot_m else "coo", fused_aux)
             if used_out is not None:
                 # The kernel aliased the donated mirror back out — return
                 # the loan so the next batch's delta apply lands in place.
@@ -1378,8 +1356,7 @@ class TPUBatchScheduler:
         # in _fetch_device so a pipelining caller can overlap host work.
         return {
             "spec_list": spec_list, "all_nodes": all_nodes, "ct": ct,
-            "st": st, "feas": feas, "summary_buf": summary_buf,
-            "coo_mat": coo_mat, "slot_m": slot_m,
+            "st": st, "feas": feas,
             "fused_buf": fused_buf, "fused_meta": fused_meta,
             "fused_overflow": fused_overflow,
             "quantized": 0 if quant is None else 1,
@@ -1429,7 +1406,6 @@ class TPUBatchScheduler:
         all_nodes = handle["all_nodes"]
         ct, st = handle["ct"], handle["st"]
         feas = handle["feas"]
-        summary_buf, coo_mat = handle["summary_buf"], handle["coo_mat"]
         with_scores = handle["with_scores"]
         max_nnz = handle["max_nnz"]
 
@@ -1439,93 +1415,44 @@ class TPUBatchScheduler:
         # the compute costs the transfer no host round trip.  fetch: the
         # rest of the transfer and the unpack.
         t_disp = stages.begin("wait")
-        result_buf = (handle["fused_buf"]
-                      if handle.get("fused_buf") is not None
-                      else summary_buf)
+        result_buf = handle["fused_buf"]
         result_buf.copy_to_host_async()
         result_buf.block_until_ready()
         stages.begin("fetch")
-        fetch_bytes = 0
-        if handle.get("fused_buf") is not None:
-            # Fused path: the WHOLE batch result — summary + COO
-            # placement payload + score side-outputs — in ONE device
-            # transfer (the tentpole contract; the "exactly one
-            # batch.fetch span per batch" tracing assertion pins it).
-            # Only when nnz overflows the payload window (>8MB of
-            # placements) does a second fetch of the overflow source
-            # run, inside the same span.
-            with tracing.span("batch.fetch", annotate=True, fused=1):
-                raw = np.asarray(jax.device_get(handle["fused_buf"]))
-                fetch_bytes = raw.nbytes
-                summary = xfer.unpack_host(raw, handle["fused_meta"])
-                nnz = int(summary["scalars"][0])
-                coo_win = summary["coo"]
-                if nnz <= coo_win.shape[0]:
-                    coo = coo_win[:nnz]
-                else:
-                    kind, aux = handle["fused_overflow"]
-                    logger.info(
-                        "fused fetch overflow: nnz %d > window %d; one "
-                        "extra %s fetch", nnz, coo_win.shape[0], kind)
-                    if kind == "coo":
-                        nnz_b = min(max_nnz,
-                                    encode.pow2_bucket(nnz, minimum=8))
-                        coo = np.asarray(
-                            jax.device_get(aux[:nnz_b]))[:nnz]
-                        fetch_bytes += (nnz_b * coo.shape[1]
-                                        * coo.dtype.itemsize)
-                    else:
-                        # Slot mode: dispatch a right-sized slot→COO
-                        # gather over the device-resident record and
-                        # prefix-fetch it — bytes proportional to the
-                        # actual placements, not the [U, M] record.
-                        nnz_b = min(max_nnz,
-                                    encode.pow2_bucket(nnz, minimum=8))
-                        slots_d, sscores_d, scoll_d = aux
-                        ov_coo, _ = kernels.slots_to_coo(
-                            slots_d, sscores_d, scoll_d, out_rows=nnz_b,
-                            with_scores=with_scores,
-                            compact_u16=coo_win.dtype == np.uint16)
-                        coo = np.asarray(jax.device_get(ov_coo))[:nnz]
-                        fetch_bytes += (nnz_b * coo.shape[1]
-                                        * coo.dtype.itemsize)
-        else:
-            ncols = 5 if with_scores else 3
-            # dtype truth comes from the device array itself (uint16 when
-            # the kernel compacted small, int32 otherwise).
-            isz = coo_mat.dtype.itemsize
-            # Small COO bucket: fetch summary + full bucket concurrently
-            # (one blocking round).  Big bucket: summary first, then a
-            # power-of-two bucketed [nnz_b, C] prefix — the bucket keeps
-            # the slice shape stable across batches (a raw [:nnz] slice
-            # would trace+compile a fresh program per distinct nnz).
-            # Both rounds live under ONE batch.fetch span: this is the
-            # non-fused fallback's one logical batched fetch.
-            if max_nnz * ncols * isz <= (4 << 20):
-                with tracing.span("batch.fetch", annotate=True):
-                    sraw, coo_full = jax.device_get((summary_buf, coo_mat))
-                summary = xfer.unpack_host(
-                    np.asarray(sraw), summary_layout(st.u_pad, ct.n_pad))
-                nnz = int(summary["scalars"][0])
-                coo = np.asarray(coo_full[:nnz])
-                fetch_bytes = (np.asarray(sraw).nbytes
-                               + np.asarray(coo_full).nbytes)
+        # The WHOLE batch result — summary + COO placement payload +
+        # score side-outputs — in ONE device transfer (the "exactly
+        # one batch.fetch span per batch" tracing assertion pins it).
+        # Only when nnz overflows the payload window (>8MB of
+        # placements) does a second fetch of the overflow source
+        # run, inside the same span.
+        with tracing.span("batch.fetch", annotate=True, fused=1):
+            raw = np.asarray(jax.device_get(result_buf))
+            fetch_bytes = raw.nbytes
+            summary = xfer.unpack_host(raw, handle["fused_meta"])
+            nnz = int(summary["scalars"][0])
+            coo_win = summary["coo"]
+            if nnz <= coo_win.shape[0]:
+                coo = coo_win[:nnz]
             else:
-                with tracing.span("batch.fetch", annotate=True):
-                    sraw = np.asarray(jax.device_get(summary_buf))
-                    summary = xfer.unpack_host(
-                        sraw, summary_layout(st.u_pad, ct.n_pad))
-                    nnz = int(summary["scalars"][0])
-                    if nnz:
-                        nnz_b = min(max_nnz,
-                                    encode.pow2_bucket(nnz, minimum=8))
-                        coo = np.asarray(
-                            jax.device_get(coo_mat[:nnz_b]))[:nnz]
-                        fetch_bytes = sraw.nbytes + nnz_b * ncols * isz
-                    else:
-                        coo = np.zeros((0, ncols),
-                                       dtype=np.dtype(coo_mat.dtype))
-                        fetch_bytes = sraw.nbytes
+                kind, aux = handle["fused_overflow"]
+                logger.info(
+                    "fused fetch overflow: nnz %d > window %d; one "
+                    "extra %s fetch", nnz, coo_win.shape[0], kind)
+                nnz_b = min(max_nnz, encode.pow2_bucket(nnz, minimum=8))
+                if kind == "coo":
+                    ov_coo = aux[:nnz_b]
+                else:
+                    # Slot mode: dispatch a right-sized slot→COO
+                    # gather over the device-resident record and
+                    # prefix-fetch it — bytes proportional to the
+                    # actual placements, not the [U, M] record.
+                    slots_d, sscores_d, scoll_d = aux
+                    ov_coo, _ = kernels.slots_to_coo(
+                        slots_d, sscores_d, scoll_d, out_rows=nnz_b,
+                        with_scores=with_scores,
+                        compact_u16=coo_win.dtype == np.uint16)
+                coo = np.asarray(jax.device_get(ov_coo))[:nnz]
+                fetch_bytes += nnz_b * coo.shape[1] * coo.dtype.itemsize
         # decode: from here to the device_seconds stamp in
         # _finalize_device_outputs (COO split, validation, expansion,
         # the forensics fetch).
@@ -1567,7 +1494,7 @@ class TPUBatchScheduler:
         kstats["fetch_seconds"] = (fetch_seconds
                                    + kstats.get("fetch_seconds", 0.0))
         kstats["fetch_bytes"] = fetch_bytes + kstats.get("fetch_bytes", 0)
-        kstats["fused"] = 1 if handle.get("fused_buf") is not None else 0
+        kstats["fused"] = 1
         kstats["quantized"] = handle.get("quantized", 0)
         kstats["mesh_shards"] = handle.get("mesh_shards", 0)
         kstats["h2d_bytes"] = handle.get("h2d_bytes", 0)
@@ -1696,8 +1623,7 @@ class TPUBatchScheduler:
         MESH_PASSES += 1
         return {
             "spec_list": spec_list, "all_nodes": all_nodes, "ct": ct,
-            "st": st, "feas": feas, "summary_buf": None, "coo_mat": None,
-            "slot_m": slot_m, "fused_buf": fused_buf,
+            "st": st, "feas": feas, "fused_buf": fused_buf,
             "fused_meta": fused_meta,
             "fused_overflow": ("slots", aux),
             "quantized": quantized, "mesh_shards": d,
@@ -2051,8 +1977,9 @@ class TPUBatchScheduler:
         batch (a second eviction on the same node would need the
         post-first-eviction state the kernel did not see).  Every commit
         is cross-checked against the scalar oracle on identical inputs;
-        the agreement counters surface in BatchStats (the bench's
-        kernel-vs-oracle eviction-set agreement metric)."""
+        the agreement counters surface in BatchStats (the
+        kernel-vs-oracle eviction-set agreement tests/test_preempt.py
+        holds)."""
         from ..scheduler import preempt as preempt_oracle
 
         pu = ctx["pu"]

@@ -55,8 +55,8 @@ NEG_INF = -1e30
 # placement programs are invoked with is recorded here — a new signature
 # is (at most) one fresh XLA compile, an old one is a guaranteed cache
 # hit — so `compile_signatures()` is an upper bound on placement-program
-# compiles that bench `--check` can assert a ceiling on (config_steady's
-# 200-batch stream must stay within a fixed handful of shapes).
+# compiles that a test can assert a ceiling on (tests/test_fused.py: a
+# steady stream must stay within a fixed handful of shapes).
 _COMPILE_SIGS = set()
 COMPILES = 0
 
@@ -113,8 +113,8 @@ def program_call(kind: str, sig: tuple):
 
 def signature_kinds() -> dict:
     """Distinct recorded signatures per program kind — the debugging
-    view behind the `batch.compiles` gauge: when a bench compile
-    ceiling trips, this names WHICH program family leaked shapes."""
+    view behind the `batch.compiles` gauge: when a compile ceiling
+    trips, this names WHICH program family leaked shapes."""
     out: dict = {}
     for kind, _sig in _COMPILE_SIGS:
         out[kind] = out.get(kind, 0) + 1
@@ -122,7 +122,7 @@ def signature_kinds() -> dict:
 
 
 def reset_compile_signatures() -> None:
-    """Test/bench helper: zero the audit (does NOT clear jit caches)."""
+    """Test helper: zero the audit (does NOT clear jit caches)."""
     global COMPILES
     _COMPILE_SIGS.clear()
     COMPILES = 0
@@ -777,23 +777,6 @@ def pack_scalars(nnz, passes: PassCounts) -> jnp.ndarray:
                       passes.multi]).astype(jnp.int32)
 
 
-def summary_layout(u_pad: int, n_pad: int):
-    """Layout of the packed device→host summary buffer (shared contract
-    between device_pass and its caller; see ops/xfer.py layout()).
-
-    used_after is deliberately NOT shipped: [n_pad, 4] int32 is ~1MB at
-    50k nodes — the host reconstructs it exactly from used0 + the COO
-    placements × asks (see batch_sched._place_on_device), so the
-    summary stays a few KB."""
-    from . import xfer
-
-    return xfer.layout({
-        "unplaced": ("i32", (u_pad,)),
-        "feas_count": ("i32", (u_pad,)),
-        "scalars": ("i32", (len(SCALARS),)),
-    })
-
-
 @functools.partial(jax.jit, static_argnames=(
     "meta_s", "meta_d", "u_pad", "n_pad", "with_networks", "with_dp",
     "with_scores", "max_rounds", "slot_m", "use_used_dev"),
@@ -814,7 +797,7 @@ def _device_schedule(
     slot_m: int = 0,
     use_used_dev: bool = False,
 ):
-    """Dispatch 1: unpack + feasibility + placement rounds.
+    """Unpack + feasibility + placement rounds.
 
     The upload is split so the link carries only what changed: the
     static cluster buffer (attr/elig/dc/cap/denom + network baselines —
@@ -968,9 +951,9 @@ def _compact_from_slots(result: PlacementResult, *, out_rows: int,
 
 def _compact_coo(result: PlacementResult, *, u_pad: int, n_pad: int,
                  with_scores: bool, max_nnz: int, compact_u16: bool):
-    """Shared COO compaction expression (the two-phase _device_compact
-    and the fused single-buffer program must emit byte-identical
-    triplets).  Returns (coo [max_nnz, C], nnz scalar)."""
+    """COO compaction by a nonzero over the [U, N] placement matrix
+    (the fused program's matrix mode, slot_m == 0).  Returns
+    (coo [max_nnz, C], nnz scalar)."""
     rows, cols = jnp.nonzero(result.placements, size=max_nnz, fill_value=-1)
     valid = rows >= 0
     nnz = jnp.sum(valid.astype(jnp.int32))
@@ -984,103 +967,6 @@ def _compact_coo(result: PlacementResult, *, u_pad: int, n_pad: int,
         co = jnp.where(valid, result.commit_collisions[r, c], 0)
         coo_cols += [lax.bitcast_convert_type(sc, jnp.int32), co]
     return jnp.stack(coo_cols, axis=1), nnz
-
-
-@functools.partial(jax.jit, static_argnames=("with_scores", "max_nnz",
-                                             "compact_u16", "slot_m"))
-def _device_compact(result: PlacementResult, feas: jnp.ndarray,
-                    *, with_scores: bool, max_nnz: int,
-                    compact_u16: bool = False, slot_m: int = 0):
-    """Dispatch 2: COO compaction + packed summary (device-resident
-    inputs, so the extra dispatch costs no link traffic — and keeping it
-    out of the scheduling program keeps XLA compile time sane).
-
-    With slot_m the COO comes from the commit-aligned slot record (one
-    U×M pass, per-alloc entries); otherwise from a nonzero over the
-    [U, N] matrix.  compact_u16 halves the COO bytes on the link
-    (row/col/count as uint16) — valid only without scores and when U/N
-    fit in 16 bits; safe because the host only ever reads the valid
-    [:nnz] prefix (the -1 fill would wrap)."""
-    from . import xfer
-
-    u_pad, n_pad = feas.shape
-    if slot_m:
-        coo, nnz = _compact_from_slots(
-            result, out_rows=max_nnz, with_scores=with_scores,
-            compact_u16=compact_u16)
-    else:
-        coo, nnz = _compact_coo(result, u_pad=u_pad, n_pad=n_pad,
-                                with_scores=with_scores, max_nnz=max_nnz,
-                                compact_u16=compact_u16)
-    feas_count = jnp.sum(feas, axis=1).astype(jnp.int32)
-    summary, _ = xfer.pack_device({
-        "unplaced": result.unplaced,
-        "feas_count": feas_count,
-        "scalars": pack_scalars(nnz, result.passes),
-    })
-    return summary, coo
-
-
-def device_pass(
-    static_buf: jnp.ndarray,
-    dyn_buf: jnp.ndarray,
-    used_dev: jnp.ndarray = None,
-    *,
-    meta_s,
-    meta_d,
-    u_pad: int,
-    n_pad: int,
-    with_networks: bool,
-    with_dp: bool,
-    with_scores: bool,
-    max_nnz: int,
-    max_rounds: int = 256,
-    slot_m: int = 0,
-):
-    """The whole batch-scheduling device program over a cached static
-    buffer + ONE per-batch dynamic upload, returning ONE packed summary
-    + a COO matrix the host fetches as a [nnz, C] prefix, so the dense
-    [U, N] outputs never cross the link (VERDICT r1 weak #1; link cost
-    on the current chip: not measured).
-
-    Two dispatches (schedule, compact) rather than one fused program:
-    both stay on device so the split is free at the link, and it keeps
-    the XLA optimization time of the big scheduling program from
-    compounding with the compaction graph.
-
-    Returns (summary_buf uint8, coo [max_nnz, C], feas, used_out);
-    C = 5 with scores (int32: row, col, count, score-bits, collisions),
-    else 3 (row, col, count — uint16 when U/N/rounds all fit 16 bits,
-    int32 otherwise; read the dtype off the array).  With slot_m > 0 the
-    COO is built from the scan's commit-aligned slot record (per-alloc
-    entries, counts ≡ 1) instead of a [U, N] nonzero.  feas stays on
-    device for the rare lazy failure-forensics row fetch.  ``used_dev``
-    (optional): the donated device-resident usage mirror; ``used_out``
-    is the aliased buffer to hand back to the resident slot (None when
-    no mirror was passed).
-    """
-    use_used_dev = used_dev is not None
-    if used_dev is None:
-        used_dev = jnp.zeros((1, 4), dtype=jnp.int32)
-    # <= 65536: u16 stores values 0..65535 and row/col/count are all
-    # strictly below their pad bound (a 65536-node bucket still has max
-    # col 65535 — `< 65536` wrongly fell back to int32 exactly at the
-    # 50k-node bench shape, tripling the COO bytes on the link).
-    compact_u16 = (not with_scores and u_pad <= 65536 and n_pad <= 65536
-                   and max_rounds < 65536)
-    with program_call("device_pass", (
-            meta_s, meta_d, u_pad, n_pad, with_networks, with_dp,
-            with_scores, max_nnz, max_rounds, slot_m, use_used_dev)):
-        result, feas, used_out = _device_schedule(
-            static_buf, dyn_buf, used_dev, meta_s=meta_s, meta_d=meta_d,
-            u_pad=u_pad, n_pad=n_pad,
-            with_networks=with_networks, with_dp=with_dp,
-            with_scores=with_scores, max_rounds=max_rounds, slot_m=slot_m,
-            use_used_dev=use_used_dev)
-        summary, coo = _device_compact(
-            result, feas, with_scores=with_scores, max_nnz=max_nnz,
-            compact_u16=compact_u16, slot_m=slot_m)
-    return summary, coo, feas, (used_out if use_used_dev else None)
 
 
 # Fused result-buffer COO window: the single transfer carries at most
@@ -1144,13 +1030,9 @@ def _fused_score_commit(
     """ONE device dispatch for the whole batch: unpack (+ dequantize) →
     feasibility → spec-major capacity-feedback placement passes → COO
     compaction (from the commit-aligned slot record when slot_m) →
-    single packed result buffer.  The two-dispatch schedule/compact
-    split (device_pass) remains the fallback behind NOMAD_TPU_FUSED=0
-    and the diagnostics paths; placements are bit-identical between the
-    two by construction (same _device_schedule, same compaction
-    expressions).  ``used_dev`` is the DONATED device-resident usage
-    mirror (a [1, 4] dummy when use_used_dev is off), returned aliased
-    as the last output."""
+    single packed result buffer.  ``used_dev`` is the DONATED
+    device-resident usage mirror (a [1, 4] dummy when use_used_dev is
+    off), returned aliased as the last output."""
     result, feas, used_out = _device_schedule(
         static_buf, dyn_buf, used_dev, meta_s=meta_s, meta_d=meta_d,
         u_pad=u_pad, n_pad=n_pad, with_networks=with_networks,
@@ -1232,34 +1114,6 @@ def fused_pass(
     return buf, aux, feas, meta, (used_out if use_used_dev else None)
 
 
-@functools.partial(jax.jit, static_argnames=("max_nnz",))
-def compact_placements(
-    feas: jnp.ndarray,          # [U, N] bool
-    placements: jnp.ndarray,    # [U, N] int32
-    commit_scores: jnp.ndarray,  # [U, N] f32 (or [1,1] when disabled)
-    commit_coll: jnp.ndarray,    # [U, N] int32 (or [1,1])
-    max_nnz: int,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Device-side compaction of the placement matrix to COO — the
-    dense [U, N] outputs never leave the device:
-
-      rows/cols int32[max_nnz] (-1 padding), counts int32[max_nnz],
-      scores f32[max_nnz], feas_count int32[U]
-
-    max_nnz is bounded by the batch's total asks (static per bucket)."""
-    rows, cols = jnp.nonzero(placements, size=max_nnz, fill_value=-1)
-    valid = rows >= 0
-    r = jnp.clip(rows, 0, placements.shape[0] - 1)
-    c = jnp.clip(cols, 0, placements.shape[1] - 1)
-    counts = jnp.where(valid, placements[r, c], 0)
-    sr = jnp.clip(r, 0, commit_scores.shape[0] - 1)
-    sc = jnp.clip(c, 0, commit_scores.shape[1] - 1)
-    scores = jnp.where(valid, commit_scores[sr, sc], 0.0)
-    coll = jnp.where(valid, commit_coll[sr, sc], 0)
-    feas_count = jnp.sum(feas, axis=1).astype(jnp.int32)
-    return rows, cols, counts, scores, coll, feas_count
-
-
 @functools.partial(jax.jit, static_argnames=("u_pad", "n_pad"))
 def scatter_job_counts(
     rows: jnp.ndarray,   # [K] int32, -1 padding
@@ -1289,24 +1143,3 @@ def batch_allocs_fit(
     fit = ~jnp.any(over, axis=1)
     first_dim = jnp.argmax(over, axis=1).astype(jnp.int32)
     return fit, jnp.where(fit, -1, first_dim)
-
-
-def aggregate_binpack_score(
-    placements: jnp.ndarray,  # [U, N] int32
-    used0: jnp.ndarray,
-    denom: jnp.ndarray,
-    ask: jnp.ndarray,
-) -> jnp.ndarray:
-    """Recompute the sum of marginal ScoreFit values in commit order
-    (approximated by recomputing each spec's score against final state minus
-    its own ask) — used for differential scoring against the oracle."""
-    # For score parity checks we use final utilization per node.
-    total_ask = jnp.einsum("un,ud->nd", placements.astype(jnp.int32), ask)
-    final_used = used0 + total_ask
-    after = final_used[:, :2].astype(jnp.float32)
-    safe_denom = jnp.where(denom == 0.0, 1.0, denom)
-    frac = 1.0 - after / safe_denom
-    total = _pow10(frac[:, 0]) + _pow10(frac[:, 1])
-    score = jnp.clip(20.0 - total, 0.0, 18.0)
-    n_placed = jnp.sum(placements, axis=0)
-    return jnp.sum(score * (n_placed > 0))

@@ -37,7 +37,8 @@ batches keep the full-encode path.
 Env knobs:
 
 - ``NOMAD_TPU_RESIDENT``              — 0 disables residency (full
-  re-encode every batch; the bench's residency-off baseline)
+  re-encode every batch: the reference the differential guard
+  compares against)
 - ``NOMAD_TPU_RESIDENT_GUARD_EVERY``  — differential-guard cadence in
   delta hits (0 disables the guard)
 - ``NOMAD_TPU_ALLOC_LOG_CAP``         — state-store feed bound (see
@@ -217,8 +218,8 @@ DEV_INSTALLS = 0
 DEV_GUARD_MISMATCHES = 0
 # Host→device bytes the mirror machinery moved (installs + routed delta
 # uploads): batch_sched samples this around each dispatch so BatchStats
-# h2d_bytes — and the bench time_split — can show the transfer the
-# donated protocol removes from the steady state.
+# h2d_bytes can show the transfer the donated protocol removes from the
+# steady state.
 DEV_H2D_BYTES = 0
 # Quantization round-trip guard (PR 6): every quantized static upload is
 # dequantized host-side and bit-compared against the exact rows before

@@ -6,7 +6,8 @@ nomad/structs/structs.go:4637-4665 codec handles).  Since ISSUE 11 the
 default encoding is the generated struct codec (nomad_tpu/codec): flat
 per-type layouts, no reflection walk per entry — the leader's entry
 encode and every follower's apply decode are the two biggest per-plan
-costs LOADGEN_r03 charged to this module's msgpack path.
+costs an early follower-scale run charged to this module's msgpack
+path.
 
 Compatibility is per frame: codec blobs carry the 0xC1 magic (a byte
 msgpack never emits), so ``decode_payload`` sniffs and accepts BOTH

@@ -350,7 +350,7 @@ class RPCServer:
                 # satellite): the residual reflection traffic must be
                 # provably Status/Serf control chatter, never a hot
                 # scheduling method — codec.msgpack_methods() is the
-                # profile `bench --check` and the soak report read.
+                # profile the soak report reads.
                 codec.note_msgpack_method(method)
             fn = self.methods.get(method)
             if fn is None:

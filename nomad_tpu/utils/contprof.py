@@ -1,6 +1,6 @@
 """Continuous host-attribution profiler (``NOMAD_TPU_CONTPROF=1``).
 
-The control plane's scaling story is host-bound (BENCH_r08: the M=4
+The control plane's scaling story is host-bound (a CPU run's M=4
 multi-worker speedup collapsed to ~1x under a GIL-saturated host), but
 nothing in the repo could say *where* host time goes.  This module is
 the measurement plane: a background sampler at low Hz walks
@@ -15,8 +15,8 @@ paths, maintaining rolling per-subsystem CPU-share gauges
 - ``/v1/profile/continuous`` serves a bounded recent window
   (:func:`window`);
 - the loadgen harness snapshots a per-leg ``host_attribution`` report
-  section (:func:`host_attribution`), which ``bench --check`` gates on
-  (≥80% of non-idle samples attributed, <3% armed overhead).
+  section (:func:`host_attribution`); tests/test_contprof.py holds the
+  classifier to ≥80% of non-idle samples attributed.
 
 Two riders share the plane's arming story:
 

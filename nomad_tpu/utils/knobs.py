@@ -26,8 +26,8 @@ Parsing semantics (the one place that decides):
 - int/float: unset, empty, or unparseable ⇒ default (a malformed knob
   must degrade to the default, not crash a server mid-flight) — but an
   unparseable value warns ONCE per name on stderr so an operator typo
-  (``NOMAD_TPU_BENCH_MESH_NODES=50k``) cannot silently benchmark the
-  wrong shape;
+  (``NOMAD_TPU_ALLOC_LOG_CAP=256k``) cannot silently run with the
+  default;
 - save/restore sites (arm a knob for a drill, restore after) use
   :func:`raw`, which returns the verbatim env value or ``None``.
 """
@@ -85,9 +85,6 @@ def _knob(name: str, kind: str, default, doc: str,
 # ---------------------------------------------------------------------------
 
 # -- device hot path --------------------------------------------------------
-_knob("NOMAD_TPU_FUSED", "bool", True,
-      "Fused score-and-commit: ONE device dispatch + ONE fetch per "
-      "batch; 0 keeps the bit-identical two-phase split")
 _knob("NOMAD_TPU_QUANT", "bool", True,
       "Quantized int8/int16 static resource rows (exact-or-absent "
       "round-trip, guarded)")
@@ -273,44 +270,12 @@ _knob("NOMAD_TPU_REGION_PROBE_TIMEOUT", "float", 1.0,
       "Timeout for best-effort cross-region leader probes in the "
       "/v1/regions detail surface (seconds)")
 
-# -- loadgen / bench --------------------------------------------------------
+# -- loadgen ----------------------------------------------------------------
 _knob("NOMAD_TPU_SWITCH_INTERVAL", "float", None,
       "sys.setswitchinterval override applied for loadgen "
       "measurement runs")
 _knob("NOMAD_TPU_LG_PROFILE", "bool", False,
       "Start the sampling profiler in loadgen follower children")
-_knob("NOMAD_TPU_BENCH_BUDGET_S", "float", None,
-      "Bench trajectory wall-clock budget override (seconds)")
-_knob("NOMAD_TPU_BENCH_CHECK_THRESHOLD", "float", None,
-      "bench --check regression tolerance override",
-      default_label="1.5")
-_knob("NOMAD_TPU_BENCH_PARTIAL", "str", None,
-      "Bench child: path receiving partial trajectory JSON after "
-      "every phase")
-_knob("NOMAD_TPU_BENCH_CHILD", "str", None,
-      "Internal: marks a bench trajectory child process")
-_knob("NOMAD_TPU_BENCH_MESH_CHILD", "str", None,
-      "Internal: marks the forced-8-device config_mesh child")
-_knob("NOMAD_TPU_BENCH_MESH_STEADY_CHILD", "str", None,
-      "Internal: marks the config_mesh_steady child")
-_knob("NOMAD_TPU_BENCH_MESH10M", "bool", False,
-      "Opt into the ~10min 10M-node config_mesh_10m bench point")
-_knob("NOMAD_TPU_BENCH_MESH_NODES", "int", None,
-      "config_mesh cluster size override", default_label="1000000")
-_knob("NOMAD_TPU_BENCH_MESH_JOBS", "int", None,
-      "config_mesh job count override", default_label="100")
-_knob("NOMAD_TPU_BENCH_MESH_COUNT", "int", None,
-      "config_mesh per-job taskgroup count override",
-      default_label="100000")
-_knob("NOMAD_TPU_BENCH_MESH_STEADY_NODES", "int", None,
-      "config_mesh_steady warm cluster size override",
-      default_label="1000000")
-_knob("NOMAD_TPU_BENCH_MESH_STEADY_BATCHES", "int", None,
-      "config_mesh_steady stream length override", default_label="200")
-_knob("NOMAD_TPU_BENCH_SNAP_NODES", "int", 50000,
-      "config_snapshot node count")
-_knob("NOMAD_TPU_BENCH_SNAP_ALLOCS", "int", 250000,
-      "config_snapshot alloc count")
 
 
 # ---------------------------------------------------------------------------

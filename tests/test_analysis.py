@@ -4,7 +4,7 @@
   including regression fixtures reproducing the PR 9 fsync-under-lock
   and PR 10 drain-under-lock shapes.
 - The tree itself ships green: ``run_checks()`` returns zero
-  unsuppressed violations (the acceptance gate bench --check enforces).
+  unsuppressed violations.
 - Runtime lockcheck units: a seeded inversion is caught with a witness
   cycle, the Condition protocol tracks manual release windows, and the
   disarmed state costs one module-global load (nothing patched).
@@ -234,7 +234,7 @@ class TestGuardAndKnobRules:
         src = (
             "import os\n"
             "def enabled():\n"
-            "    return os.environ.get('NOMAD_TPU_FUSED') == '1'\n"
+            "    return os.environ.get('NOMAD_TPU_QUANT') == '1'\n"
         )
         out = knobrules.check(ROOT, [_sf("nomad_tpu/fake_knob.py", src)])
         mine = [v for v in out if v.path == "nomad_tpu/fake_knob.py"]
@@ -243,7 +243,7 @@ class TestGuardAndKnobRules:
     def test_env_read_through_module_constant_fires(self):
         src = (
             "import os\n"
-            "CHILD = 'NOMAD_TPU_BENCH_CHILD'\n"
+            "CHILD = 'NOMAD_TPU_LG_PROFILE'\n"
             "def is_child():\n"
             "    return os.environ.get(CHILD) == '1'\n"
         )
@@ -263,8 +263,8 @@ class TestGuardAndKnobRules:
         src = (
             "import os\n"
             "def arm():\n"
-            "    os.environ['NOMAD_TPU_FUSED'] = '0'\n"
-            "    os.environ.pop('NOMAD_TPU_QUANT', None)\n"
+            "    os.environ['NOMAD_TPU_QUANT'] = '0'\n"
+            "    os.environ.pop('NOMAD_TPU_RESIDENT', None)\n"
         )
         out = knobrules.check(ROOT, [_sf("nomad_tpu/fake_knob4.py",
                                          src)])
@@ -276,10 +276,10 @@ class TestGuardAndKnobRules:
     def test_knob_accessors(self, monkeypatch):
         with pytest.raises(knobs.UnknownKnobError):
             knobs.get_bool("NOMAD_TPU_NOT_A_KNOB")
-        monkeypatch.setenv("NOMAD_TPU_FUSED", "off")
-        assert knobs.get_bool("NOMAD_TPU_FUSED") is False
-        monkeypatch.setenv("NOMAD_TPU_FUSED", "")
-        assert knobs.get_bool("NOMAD_TPU_FUSED") is True  # default
+        monkeypatch.setenv("NOMAD_TPU_QUANT", "off")
+        assert knobs.get_bool("NOMAD_TPU_QUANT") is False
+        monkeypatch.setenv("NOMAD_TPU_QUANT", "")
+        assert knobs.get_bool("NOMAD_TPU_QUANT") is True  # default
         monkeypatch.setenv("NOMAD_TPU_PLAN_PIPELINE", "garbage")
         assert knobs.get_int("NOMAD_TPU_PLAN_PIPELINE") == 8  # default
         monkeypatch.setenv("NOMAD_TPU_RNG_SEED", "123")
@@ -332,7 +332,7 @@ class TestTreeShipsGreen:
     def test_every_source_file_scanned(self):
         paths = iter_source_files(ROOT)
         assert "nomad_tpu/server/raft.py" in paths
-        assert "bench.py" in paths
+        assert "__graft_entry__.py" in paths
         assert not any(p.startswith("tests/") for p in paths)
 
 

@@ -3,8 +3,7 @@ partitions, crash-restart recovery, and the continuous safety auditor.
 
 Fast fixed-seed scenarios run in tier-1 under the ``chaos`` marker
 (including the subprocess kill+restart smoke soak); the full 3-server
-soak is additionally marked ``slow`` — its recorded evidence lives in
-LOADGEN_r05.json.
+soak is additionally marked ``slow``.
 """
 import os
 import time
@@ -500,7 +499,7 @@ class TestChaosSoakSmoke:
 
     @pytest.mark.slow
     def test_full_soak_three_servers(self):
-        """The recorded chaos_soak shape (LOADGEN_r05.json): 3 servers,
+        """The chaos_soak scenario: 3 servers,
         kills + repeated partitions, zero violations."""
         from nomad_tpu.loadgen.harness import run_scenario
         from nomad_tpu.loadgen.scenario import get_scenario

@@ -452,3 +452,30 @@ class TestRegionEventAggregator:
             pool.close()
             for sub in subs:
                 sub.close()
+
+
+@pytest.mark.federation
+@pytest.mark.chaos
+class TestMultiRegionSoak:
+    def test_blackout_and_heal_hold_the_partition_contract(self):
+        """The ``multi_region`` scenario: two WAN-joined regions, a
+        quarter of the submits cross-region, one region dark for 3 s
+        mid-run and healed.  No job places in two regions, no acked eval
+        is lost, the dark region places again inside the bound, and the
+        blackout shows as retryable NoPathToRegion NACKs that drop
+        nothing."""
+        from nomad_tpu.loadgen.federation import run_multi_region
+        from nomad_tpu.loadgen.scenario import get_scenario
+
+        rep = run_multi_region(get_scenario("multi_region"))
+        aud = rep.get("auditor") or {}
+        assert aud.get("violation_count") == 0, aud.get("violations")
+        assert (aud.get("final_sweep") or {}).get(
+            "cross_region_double_placed", 0) == 0
+        assert aud.get("lost_acked", 0) == 0
+        blackout = (rep.get("federation") or {}).get("blackout") or {}
+        assert blackout.get("recovered"), blackout
+        offered = rep["offered"]
+        assert offered["no_path_events"] > 0
+        assert offered["dropped_after_retries"] == 0
+        assert rep["sustained"]["stragglers_after_drain"] == 0

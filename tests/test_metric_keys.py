@@ -59,6 +59,7 @@ def sink(tmp_path_factory):
         assert result.refresh_index > 0
         # breaker.oracle_routed: one corrupted kernel result, rejected
         # and served by the oracle.
+        before_reject = srv.metrics.sink.latest()
         with fault.scenario({"seed": 1, "faults": [
                 {"point": "ops.kernel_result", "action": "corrupt",
                  "times": 1}]}):
@@ -73,7 +74,8 @@ def sink(tmp_path_factory):
                 in srv.metrics.sink.latest()["CounterTotals"], 30.0)
         latest = srv.metrics.sink.latest()
         yield {"samples": set(latest["SampleTotals"]),
-               "counters": set(latest["CounterTotals"])}
+               "counters": set(latest["CounterTotals"]),
+               "before_reject": before_reject, "latest": latest}
 
 
 def test_there_are_sink_metrics():
@@ -99,3 +101,23 @@ def test_fit_recheck_publishes_its_routes_and_its_guard(sink):
     assert {"nomad.plan.fit.rows_array",
             "nomad.plan.fit.rows_scalar"} <= sink["counters"]
     assert "nomad.plan.evaluate.guard" in sink["samples"]
+
+
+def test_fused_counter_counts_the_batches_the_device_answered(sink):
+    """``nomad.batch.fused`` is what tells a batch the device program
+    answered from an oracle-routed one (the benchmark's
+    ``batches_not_fused``, chip_smoke.py): one per device batch, none
+    for the batch whose kernel result was rejected."""
+    K = "nomad.worker.invoke_scheduler"
+
+    def read(snap):
+        samples, counters = snap["SampleTotals"], snap["CounterTotals"]
+        return (samples[K][0], samples[K + ".device"][0],
+                counters.get("nomad.batch.fused", 0),
+                counters.get("nomad.breaker.oracle_routed", 0))
+
+    calls0, device0, fused0, routed0 = read(sink["before_reject"])
+    assert device0 >= 1 and fused0 == device0 == calls0
+    calls1, device1, fused1, routed1 = read(sink["latest"])
+    assert calls1 == calls0 + 1 and routed1 == routed0 + 1
+    assert (device1, fused1) == (device0, fused0)
