@@ -859,12 +859,10 @@ class TPUBatchScheduler:
             stats.preempt_agree = kstats.get("preempt_agree", 0)
             self._apply_resident_stats(stats, kstats.get("resident") or {})
 
-        # Phase 3: materialize allocs into each eval's plan and submit.
+        # Phase 3: materialize allocs into each eval's plan, submit the
+        # batch's plans together, settle each eval from its own result.
         t_final = time.perf_counter()
-        net_index_cache: Dict[str, "NetworkIndex"] = {}
-        for ev, sched in scheds:
-            self._finalize(ev, sched, prep.specs, expanded, unplaced,
-                           per_spec_metrics, net_index_cache, stats)
+        self._finalize(scheds, prep.specs, expanded, per_spec_metrics, stats)
         stats.finalize_seconds = time.perf_counter() - t_final
         if tr is not None:
             tr.record("batch.finalize", t_final,
@@ -2230,34 +2228,62 @@ class TPUBatchScheduler:
             cache[node_id] = idx
         return idx
 
-    def _finalize(self, ev, sched, specs, expanded, unplaced,
-                  per_spec_metrics, net_index_cache, stats) -> None:
-        """Materialize this eval's assigned slots into its plan, then submit
-        + set status, mirroring generic_sched.go:104 Process.  Its three
-        stages (build the plan, the submit_plan round trip, the status
-        write) are timed into ``stats`` and, armed, recorded as
-        batch.finalize.* spans of this eval."""
-        t_in = time.perf_counter()
-        t_sub = t_st = self._finalize_build(
-            ev, sched, specs, expanded, per_spec_metrics, net_index_cache)
-        if sched.plan.is_no_op() and not ev.annotate_plan:
-            set_status(self.logger, self.planner, ev, sched.next_eval,
-                       sched.blocked, sched.failed_tg_allocs,
-                       s.EVAL_STATUS_COMPLETE, "", sched.queued_allocs)
-        else:
-            settled = self._finalize_submit(ev, sched)
-            t_st = time.perf_counter()
-            if not settled:
-                self._finalize_status(ev, sched)
-        t_out = time.perf_counter()
-        stats.finalize_build_seconds += t_sub - t_in
-        stats.finalize_submit_seconds += t_st - t_sub
-        stats.finalize_status_seconds += t_out - t_st
+    def _finalize(self, scheds, specs, expanded, per_spec_metrics,
+                  stats) -> None:
+        """Materialize every eval's assigned slots into its plan, hand
+        the batch's plans to the planner as ONE submission, then settle
+        each eval from its own result and write the statuses of all that
+        completed in ONE write (``planner.update_evals``: one fsync) —
+        generic_sched.go:104 Process, a batch at a time.  The three
+        passes (build the plans, the submission's round trip with any
+        oracle retry after it, the status writes) are timed into
+        ``stats`` and, armed, recorded as batch.finalize.* spans: build
+        per eval, submit and status per batch."""
         tr = tracing.TRACER
+        net_index_cache: Dict[str, "NetworkIndex"] = {}
+        t_in = t_sub = time.perf_counter()
+        for ev, sched in scheds:
+            t_built = self._finalize_build(
+                ev, sched, specs, expanded, per_spec_metrics, net_index_cache)
+            if tr is not None:
+                tr.record("batch.finalize.build", t_sub, t_built,
+                          eval_id=ev.id)
+            t_sub = t_built
+
+        # A plan that proposes nothing is not submitted; its eval
+        # completes with the others.
+        submitting = [(ev, sched) for ev, sched in scheds
+                      if not sched.plan.is_no_op() or ev.annotate_plan]
+        retried = set()
+        if submitting:
+            plans = [sched.plan for _, sched in submitting]
+            submit = getattr(self.planner, "submit_plans", None)
+            outcomes = (submit(plans) if submit is not None else
+                        [self.planner.submit_plan(plan) for plan in plans])
+            for (ev, sched), (result, new_state) in zip(submitting, outcomes):
+                if self._finalize_settle(ev, sched, result, new_state):
+                    retried.add(ev.id)
+        t_st = time.perf_counter()
+
+        completed = _EvalUpdates()
+        for ev, sched in scheds:
+            if ev.id not in retried:
+                self._finalize_status(ev, sched, completed)
+        if completed.evals:
+            update = getattr(self.planner, "update_evals", None)
+            if update is not None:
+                update(completed.evals)
+            else:
+                for new_eval in completed.evals:
+                    self.planner.update_eval(new_eval)
+        t_out = time.perf_counter()
+        stats.finalize_build_seconds = t_sub - t_in
+        stats.finalize_submit_seconds = t_st - t_sub
+        stats.finalize_status_seconds = t_out - t_st
         if tr is not None:
-            tr.record("batch.finalize.build", t_in, t_sub, eval_id=ev.id)
-            tr.record("batch.finalize.submit", t_sub, t_st, eval_id=ev.id)
-            tr.record("batch.finalize.status", t_st, t_out, eval_id=ev.id)
+            ids = tracing.eval_id_attrs((ev for ev, _ in scheds), len(scheds))
+            tr.record("batch.finalize.submit", t_sub, t_st, **ids)
+            tr.record("batch.finalize.status", t_st, t_out, **ids)
 
     def _finalize_build(self, ev, sched, specs, expanded, per_spec_metrics,
                         net_index_cache) -> float:
@@ -2414,11 +2440,10 @@ class TPUBatchScheduler:
 
         return time.perf_counter()
 
-    def _finalize_submit(self, ev, sched) -> bool:
-        """The plan round trip as the worker sees it.  True when a
-        conflict sent the eval through the oracle, which then wrote the
-        eval's status itself."""
-        result, new_state = self.planner.submit_plan(sched.plan)
+    def _finalize_settle(self, ev, sched, result, new_state) -> bool:
+        """An eval's own result of the submission.  True when a conflict
+        sent the eval through the oracle, alone and after the batch's
+        plans, which then wrote the eval's status itself."""
         from ..scheduler.util import adjust_queued_allocations
 
         adjust_queued_allocations(self.logger, result, sched.queued_allocs)
@@ -2437,8 +2462,9 @@ class TPUBatchScheduler:
             return True
         return False
 
-    def _finalize_status(self, ev, sched) -> None:
-        """The eval's own raft write: reblock, or complete."""
+    def _finalize_status(self, ev, sched, completed: "_EvalUpdates") -> None:
+        """The eval's status: a reblock is its own raft write; an eval
+        that completes joins ``completed``, the batch's one write."""
         if ev.status == s.EVAL_STATUS_BLOCKED and sched.failed_tg_allocs:
             e = sched.ctx.eligibility()
             new_eval = ev.copy()
@@ -2447,9 +2473,20 @@ class TPUBatchScheduler:
             self.planner.reblock_eval(new_eval)
             return
 
-        set_status(self.logger, self.planner, ev, sched.next_eval, sched.blocked,
+        set_status(self.logger, completed, ev, sched.next_eval, sched.blocked,
                    sched.failed_tg_allocs, s.EVAL_STATUS_COMPLETE, "",
                    sched.queued_allocs)
+
+
+class _EvalUpdates:
+    """Stands in for the planner in ``set_status``: keeps the evals it
+    would have written, for one write."""
+
+    def __init__(self) -> None:
+        self.evals: List[s.Evaluation] = []
+
+    def update_eval(self, ev: s.Evaluation) -> None:
+        self.evals.append(ev)
 
 
 class BatchStats:
@@ -2470,9 +2507,9 @@ class BatchStats:
         self.device_stage_seconds: Dict[str, float] = {}
         self.metrics_seconds = 0.0
         self.finalize_seconds = 0.0
-        # finalize split, each summed over the batch's evals: building
-        # the plan, the submit_plan round trip (an oracle retry on
-        # conflict with it), and the eval's status write.
+        # finalize split, one pass over the batch's evals each: building
+        # the plans, their one submission's round trip (oracle retries
+        # on conflict with it), and the status writes.
         self.finalize_build_seconds = 0.0
         self.finalize_submit_seconds = 0.0
         self.finalize_status_seconds = 0.0

@@ -24,6 +24,17 @@ through the pipeline.  The overlay is conservative (pending REMOVALS are
 ignored), so the re-check can only be stricter than the truth.  Plans
 carrying preemptions keep the strict serial path: their staleness fence
 reads live alloc rows that an in-flight plan could still change.
+
+Group submission (ISSUE 32): a queue item is one SUBMISSION, one plan or
+a batch's plans in spec order (PlanQueue.enqueue_group).  Consecutive
+plans that propose nothing but slab placements without network are
+decided by ONE fit re-check over all their rows (usage only grows along
+the run, so if every touched node fits with all of the run's rows added,
+each plan fits in sequence and commits whole; if not, the pass decides
+nothing and the plans take the single-plan route one by one), committed
+as one raft entry PER PLAN written back to back under one fsync
+(RaftLog.apply_many), and answered each with its own result.  The
+single-plan route is the run of one.
 """
 from __future__ import annotations
 
@@ -235,31 +246,74 @@ class PlanApplier:
         snapshot taken at dequeue time.  Commit waits run on the waiter
         pool so evaluation of plan N+1 overlaps the (multi-voter,
         round-trip-priced) commit of plan N — the reference's async
-        overlap, plan_apply.go:55-120."""
+        overlap, plan_apply.go:55-120.
+
+        A queue item is one submission: one plan, or a batch's plans in
+        spec order.  Its consecutive groupable plans are decided
+        together (_process_plans); every other plan ends the run before
+        it and is decided alone.  The runs are taken in the submission's
+        order, each after the one before it has committed."""
         while not self._stop.is_set():
             item = self.plan_queue.dequeue(timeout=0.2)
             if item is None:
                 continue
-            plan, future = item
-            if not future.claim():
-                # Submitter gave up (RPC deadline) before we started:
-                # skipping here is what makes its replan safe.
-                self.logger.warning("plan for eval %s was cancelled before "
-                                    "apply; dropping", plan.eval_id)
-                continue
-            if plan.node_preemptions:
-                # The preemption staleness fence reads live alloc rows
-                # (modify_index equality): an in-flight plan could still
-                # change them, so preemption plans run strictly serial
-                # against a quiesced pipeline.
-                self._drain_inflight()
-                self._process_plan(plan, future, pipelined=False)
-            else:
-                self._process_plan(plan, future, pipelined=True)
+            claimed = []
+            for plan, future in item:
+                if future.claim():
+                    claimed.append((plan, future))
+                else:
+                    # Submitter gave up (RPC deadline) before we started:
+                    # skipping here is what makes its replan safe.
+                    self.logger.warning("plan for eval %s was cancelled "
+                                        "before apply; dropping", plan.eval_id)
+            for n, run in enumerate(self._runs(claimed)):
+                serial = bool(run[0][0].node_preemptions)
+                if n or serial:
+                    # A submission's entries reach the log in its own
+                    # order: a run starts when the run before it has
+                    # committed.  And the preemption staleness fence
+                    # reads live alloc rows (modify_index equality): an
+                    # in-flight plan could still change them, so
+                    # preemption plans run strictly serial against a
+                    # quiesced pipeline.
+                    self._drain_inflight()
+                self._process_plans(run, pipelined=not serial)
 
-    def _process_plan(self, plan: s.Plan, future: PlanFuture,
-                      pipelined: bool) -> None:
+    @staticmethod
+    def _groupable(plan: s.Plan) -> bool:
+        """Whether all the plan proposes is slab placements that the
+        array route of the fit re-check decides (_fit_columnar's own
+        test): then it can be decided together with its neighbours."""
+        return not (plan.node_update or plan.node_allocation
+                    or plan.node_preemptions or plan.all_at_once) and all(
+            slab.proto.resources is not None and not _has_ports(slab.proto)
+            for slab in plan.alloc_slabs)
+
+    @classmethod
+    def _runs(cls, pairs: List[Tuple[s.Plan, PlanFuture]]):
+        """A submission's (plan, future) pairs cut into runs, order kept:
+        consecutive groupable plans together, any other plan alone."""
+        run: List[Tuple[s.Plan, PlanFuture]] = []
+        for pair in pairs:
+            if cls._groupable(pair[0]):
+                run.append(pair)
+                continue
+            if run:
+                yield run
+                run = []
+            yield [pair]
+        if run:
+            yield run
+
+    def _process_plans(self, pairs: List[Tuple[s.Plan, PlanFuture]],
+                       pipelined: bool) -> None:
+        """Decide and commit one run of a submission: one plan, or
+        several groupable plans by one fit re-check, one raft write
+        phase and one fsync.  The run's samples and spans (plan.evaluate,
+        plan.apply and, by its submitter, the hand-offs) are emitted
+        once, whatever its length."""
         snap = self.raft.fsm.state
+        plans = [plan for plan, _ in pairs]
         # Branch before building span attrs (the disarmed per-plan
         # path pays one load + comparison only).
         tr = tracing.TRACER
@@ -267,65 +321,104 @@ class PlanApplier:
             # The submitter's worker.submit_plan span (another thread)
             # is the parent: it caused this work.
             ev_span = tracing.NOOP if tr is None else tr.span(
-                "plan.evaluate", eval_id=plan.eval_id,
-                parent_id=future.trace_parent)
+                "plan.evaluate", parent_id=pairs[0][1].trace_parent,
+                **tracing.plan_attrs(plans))
             with self.metrics.measure("plan.evaluate"), ev_span:
-                result = self.evaluate_plan(snap, plan)
+                results = self._evaluate_plans(snap, plans)
         except Exception as exc:  # pragma: no cover — defensive
             self.logger.exception("plan evaluation failed")
-            future.respond(None, exc)
+            for _, future in pairs:
+                future.respond(None, exc)
+            return
+        if results is None:
+            # Some row of the group does not fit with all of the group's
+            # rows added: the pass decided nothing.  One by one, each
+            # when its predecessor has committed (so in the store, and
+            # the log in the submission's order).
+            for pair in pairs:
+                self._drain_inflight()
+                self._process_plans([pair], pipelined)
             return
         # What lies between this stamp and _commit's is the hand-off to
         # the commit pool (plan.commit_wait, emitted by the submitter).
-        future.t_evaluated = time.perf_counter()
-
-        # Staleness + conflict telemetry for the stale-snapshot
-        # worker pool: how far behind the log this plan's snapshot
-        # was, and whether the optimistic-concurrency re-check had
-        # to reject part of it (the submitter replans the rejected
-        # remainder off refreshed state — the requeue path).
-        if plan.snapshot_index:
-            self.metrics.add_sample(
-                "plan.staleness",
-                max(0, self.raft.applied_index() - plan.snapshot_index))
-        if result.refresh_index:
-            self.metrics.incr_counter("plan.conflict")
-            if tr is not None:
-                tr.event("plan.conflict", eval_id=plan.eval_id,
-                         snapshot_index=plan.snapshot_index,
-                         refresh_index=result.refresh_index)
-
-        if not (result.node_update or result.node_allocation
-                or result.alloc_slabs):
-            future.respond(result, None)
+        t_evaluated = time.perf_counter()
+        applied = (self.raft.applied_index()
+                   if any(plan.snapshot_index for plan in plans) else 0)
+        commits = []
+        for (plan, future), result in zip(pairs, results):
+            future.t_evaluated = t_evaluated
+            # Staleness + conflict telemetry for the stale-snapshot
+            # worker pool: how far behind the log this plan's snapshot
+            # was, and whether the optimistic-concurrency re-check had
+            # to reject part of it (the submitter replans the rejected
+            # remainder off refreshed state — the requeue path).
+            if plan.snapshot_index:
+                self.metrics.add_sample(
+                    "plan.staleness",
+                    max(0, applied - plan.snapshot_index))
+            if result.refresh_index:
+                self.metrics.incr_counter("plan.conflict")
+                if tr is not None:
+                    tr.event("plan.conflict", eval_id=plan.eval_id,
+                             snapshot_index=plan.snapshot_index,
+                             refresh_index=result.refresh_index)
+            if result.node_update or result.node_allocation \
+                    or result.alloc_slabs:
+                commits.append((plan, result, future))
+            else:
+                future.respond(result, None)
+        if not commits:
             return
         if not pipelined or self.pipeline_depth <= 1 \
                 or not self._commit_threads:
-            self._commit(plan, result, future, snap)
+            self._commit(commits, snap)
             return
-        # Hand the commit wait to the pool: the overlay entry makes the
+        # Hand the commit wait to the pool: the overlay entries make the
         # not-yet-visible placements count against every later fit
-        # re-check until the raft apply lands.
+        # re-check until the raft applies land.
         with self._inflight_cv:
             while self._inflight >= self.pipeline_depth \
                     and not self._stop.is_set():
                 self._inflight_cv.wait(0.2)
             self._inflight += 1
-            self._token_seq += 1
-            token = self._token_seq
-        self._overlay.add(token, result)
-        self._commit_q.put((token, plan, result, future, snap))
+            tokens = range(self._token_seq + 1,
+                           self._token_seq + 1 + len(commits))
+            self._token_seq = tokens[-1]
+        for token, (_, result, _) in zip(tokens, commits):
+            self._overlay.add(token, result)
+        self._commit_q.put((commits, snap, tokens))
 
-    def _commit(self, plan, result, future, snap,
-                token: Optional[int] = None) -> None:
-        future.t_commit = time.perf_counter()
+    def _commit(self, commits: List[Tuple[s.Plan, s.PlanResult, PlanFuture]],
+                snap, tokens=()) -> None:
+        """Apply the run's results through the log and answer each
+        plan's future with its own outcome, none before the fsync that
+        covers the run has returned (RaftLog.apply_many)."""
+        t_commit = time.perf_counter()
+        for _, _, future in commits:
+            future.t_commit = t_commit
+        plans = [plan for plan, _, _ in commits]
         tr = tracing.TRACER
         try:
             ap_span = tracing.NOOP if tr is None else tr.span(
-                "plan.apply", eval_id=plan.eval_id,
-                parent_id=future.trace_parent)
+                "plan.apply", parent_id=commits[0][2].trace_parent,
+                **tracing.plan_attrs(plans))
             with self.metrics.measure("plan.apply"), ap_span:
-                index = self.apply_plan(plan, result, snap)
+                outcomes = self.apply_plans(
+                    [(plan, result) for plan, result, _ in commits], snap)
+        except Exception as exc:  # pragma: no cover — defensive
+            outcomes = [exc] * len(commits)
+        finally:
+            # Remove only now: the FSM applies are visible in the live
+            # store (or the plans failed and never will be) — there is
+            # no window where a placement is in neither.
+            for token in tokens:
+                self._overlay.remove(token)
+        for (plan, result, future), index in zip(commits, outcomes):
+            if isinstance(index, Exception):
+                self.logger.error("failed to apply plan for eval %s",
+                                  plan.eval_id, exc_info=index)
+                future.respond(None, index)
+                continue
             # The program's own count of what it placed (a client can
             # only count complete evals x their group count).
             self.metrics.incr_counter(
@@ -338,26 +431,16 @@ class PlanApplier:
                 # Partial commit: ensure the scheduler sees at least
                 # its own placements (plan_apply.go:187-193).
                 result.refresh_index = max(result.refresh_index, index)
-        except Exception as exc:
-            self.logger.exception("failed to apply plan")
-            future.respond(None, exc)
-            return
-        finally:
-            if token is not None:
-                # Remove only now: the FSM apply is visible in the live
-                # store (or the plan failed and never will be) — there
-                # is no window where a placement is in neither.
-                self._overlay.remove(token)
-        future.respond(result, None)
+            future.respond(result, None)
 
     def _commit_loop(self) -> None:
         while True:
             item = self._commit_q.get()
             if item is None:
                 return
-            token, plan, result, future, snap = item
+            commits, snap, tokens = item
             try:
-                self._commit(plan, result, future, snap, token=token)
+                self._commit(commits, snap, tokens)
             finally:
                 with self._inflight_cv:
                     self._inflight -= 1
@@ -370,25 +453,52 @@ class PlanApplier:
 
     # -- evaluation --------------------------------------------------------
 
+    def _evaluate_plans(self, snap, plans: List[s.Plan]
+                        ) -> Optional[List[s.PlanResult]]:
+        """One fit re-check for a run of plans.  One plan: evaluate_plan.
+        Several (all groupable: slab placements only): their slabs are
+        re-checked as ONE plan's, by the routes that are there, against
+        one read of the overlay and of the mirror.  Usage only grows
+        along the run, so if every touched node fits with all of the
+        run's rows added, every plan fits in sequence and each commits
+        whole; if any does not, the pass decides nothing (None) and the
+        caller takes the plans one by one.  ``nomad.plan.submitted``
+        counts the plans each pass decided."""
+        if len(plans) == 1:
+            results = [self.evaluate_plan(snap, plans[0])]
+        else:
+            together = s.Plan(alloc_slabs=[
+                slab for plan in plans for slab in plan.alloc_slabs])
+            fits = self._evaluate_nodes(snap, together, len(plans))
+            results = ([self._whole(plan) for plan in plans]
+                       if fits.all_fit() else None)
+        self.metrics.incr_counter("plan.submitted", len(results or ()))
+        return results
+
+    @staticmethod
+    def _whole(plan: s.Plan) -> s.PlanResult:
+        """Every touched node fits: the plan commits as proposed, and
+        no per-node verdict has to exist as a Python object."""
+        result = s.PlanResult(node_update={}, node_allocation={})
+        for proposed, kept in (
+                (plan.node_update, result.node_update),
+                (plan.node_allocation, result.node_allocation),
+                (plan.node_preemptions, result.node_preemptions)):
+            kept.update((nid, allocs) for nid, allocs in proposed.items()
+                        if allocs)
+        result.alloc_slabs.extend(plan.alloc_slabs)
+        return result
+
     def evaluate_plan(self, snap, plan: s.Plan) -> s.PlanResult:
         """Determine the committable subset (plan_apply.go:202
         evaluatePlan): per-node fit re-check, partial or gang commit.
         Columnar alloc slabs (the TPU batch path) are kept whole on a full
         commit and filtered per node on a partial one."""
-        result = s.PlanResult(node_update={}, node_allocation={})
         fits = self._evaluate_nodes(snap, plan)
         if fits.all_fit():
-            # Every touched node fits: the plan commits as proposed, and
-            # no per-node verdict has to exist as a Python object.
-            for proposed, kept in (
-                    (plan.node_update, result.node_update),
-                    (plan.node_allocation, result.node_allocation),
-                    (plan.node_preemptions, result.node_preemptions)):
-                kept.update((nid, allocs) for nid, allocs in proposed.items()
-                            if allocs)
-            result.alloc_slabs.extend(plan.alloc_slabs)
-            return result
+            return self._whole(plan)
 
+        result = s.PlanResult(node_update={}, node_allocation={})
         gang_failed = False
         ok_nodes = set()
         for node_id, fit in fits.as_dict().items():
@@ -428,14 +538,19 @@ class PlanApplier:
                 out.setdefault(nid, []).append((slab.proto, cnt))
         return out
 
-    def _evaluate_nodes(self, snap, plan: s.Plan) -> _Fits:
-        """The per-node fit re-check.  Off the columnar mirror each
-        touched node takes one of two routes, chosen from what the plan
-        itself proposes there (_fit_columnar); a store without the
-        mirror is walked.  Differential guard: every
-        NOMAD_TPU_COLUMNAR_GUARD_EVERY evaluations the verdicts are
-        re-derived from the store's own rows and must agree (tests pin
-        the cadence to 1: every tier-1 plan is double-checked)."""
+    def _evaluate_nodes(self, snap, plan: s.Plan, n_plans: int = 1) -> _Fits:
+        """The per-node fit re-check of ``plan``, which stands for
+        ``n_plans`` submitted plans (a run's slabs taken together,
+        _evaluate_plans).  Off the columnar mirror each touched node
+        takes one of two routes, chosen from what the plan itself
+        proposes there (_fit_columnar); a store without the mirror is
+        walked.  Differential guard: its cadence
+        (NOMAD_TPU_COLUMNAR_GUARD_EVERY) counts PLANS DECIDED; a pass in
+        which the count crosses a multiple of it has ALL of its verdicts
+        re-derived from the store's own rows, and they must agree (tests
+        pin the cadence to 1: every tier-1 pass is double-checked).  A
+        run's pass that found a row unfit decides nothing and is not
+        counted: its plans are re-checked, and counted, one by one."""
         from ..state import columnar as colmod
 
         # Overlay FIRST, store second: a pipelined sibling whose commit
@@ -455,14 +570,16 @@ class PlanApplier:
         self.metrics.incr_counter("plan.fit.rows_array", len(out.rows))
         self.metrics.incr_counter("plan.fit.rows_scalar", len(out.scalar))
         every = colmod.guard_every()
-        if cols is not None and every > 0:
-            self._fit_guard_reads += 1
-            if self._fit_guard_reads % every == 0:
+        if cols is not None and every > 0 \
+                and (n_plans == 1 or out.all_fit()):
+            before = self._fit_guard_reads
+            self._fit_guard_reads += n_plans
+            if self._fit_guard_reads // every != before // every:
                 start = time.perf_counter()
                 tr = tracing.TRACER
+                # Under plan.evaluate on this thread: its eval ids.
                 with tracing.NOOP if tr is None else tr.span(
-                        "plan.evaluate.guard", eval_id=plan.eval_id,
-                        start=start):
+                        "plan.evaluate.guard", start=start):
                     out = self._guard_fit(snap, plan, cols, inflight, out)
                 self.metrics.measure_since("plan.evaluate.guard", start)
         return out
@@ -809,7 +926,43 @@ class PlanApplier:
 
     def apply_plan(self, plan: s.Plan, result: s.PlanResult, snap) -> int:
         """Commit the result through the log (plan_apply.go:123-175
-        applyPlan)."""
+        applyPlan): a run of one."""
+        index, = self.apply_plans([(plan, result)], snap)
+        if isinstance(index, Exception):
+            raise index
+        return index
+
+    def apply_plans(self, items: List[Tuple[s.Plan, s.PlanResult]],
+                    snap) -> list:
+        """Commit a run's results through the log, ONE entry per plan,
+        written back to back under one fsync (RaftLog.apply_many).
+        Returns per plan, in order, its apply index or the exception
+        that failed it: a plan whose entry could not be built or whose
+        FSM apply raised fails alone."""
+        outcomes: list = [None] * len(items)
+        staged = []
+        for pos, (plan, result) in enumerate(items):
+            try:
+                staged.append((pos, *self._plan_entry(plan, result, snap)))
+            except Exception as exc:
+                outcomes[pos] = exc
+        applied = self.raft.apply_many(
+            [(MessageType.APPLY_PLAN_RESULTS, payload)
+             for _, payload, _, _ in staged])
+        for (pos, _, preempted, preemption_evals), outcome in zip(
+                staged, applied):
+            if isinstance(outcome, Exception):
+                outcomes[pos] = outcome
+                continue
+            plan, result = items[pos]
+            outcomes[pos] = index = outcome[1]
+            self._plan_applied(plan, result, index, preempted,
+                               preemption_evals)
+        return outcomes
+
+    def _plan_entry(self, plan: s.Plan, result: s.PlanResult, snap):
+        """The plan's APPLY_PLAN_RESULTS payload, the allocs it preempts
+        and their jobs' follow-up evals."""
         import time as _time
 
         # Fault point BEFORE the raft commit: an injected crash here is a
@@ -869,7 +1022,12 @@ class PlanApplier:
                 preempted, snap.latest_index(),
                 job_lookup=lambda jid: snap.job_by_id(None, jid))
             payload["preemption_evals"] = preemption_evals
-        _, index = self.raft.apply(MessageType.APPLY_PLAN_RESULTS, payload)
+        return payload, preempted, preemption_evals
+
+    def _plan_applied(self, plan: s.Plan, result: s.PlanResult, index: int,
+                      preempted: List[s.Allocation],
+                      preemption_evals: List[s.Evaluation]) -> None:
+        """What follows a plan's raft apply at ``index``."""
         # Stale-snapshot fence bookkeeping: workers may not reuse a
         # cached snapshot for this job below this index (worker.py
         # _snapshot_covering).
@@ -909,4 +1067,3 @@ class PlanApplier:
                 ev.snapshot_index = index
             if self.blocked_evals is not None:
                 self.blocked_evals.block_preempted(preemption_evals)
-        return index

@@ -82,10 +82,12 @@ class PlanFuture:
 
 
 @dataclass(order=True)
-class _PendingPlan:
+class _PendingPlans:
+    """One queue item: the plans one submission handed over, in its
+    order, each with its own future (a single plan is a list of one)."""
+
     sort_key: Tuple[int, int, int]
-    plan: s.Plan = field(compare=False)
-    future: PlanFuture = field(compare=False)
+    plans: List[Tuple[s.Plan, PlanFuture]] = field(compare=False)
 
 
 class PlanQueue:
@@ -93,7 +95,7 @@ class PlanQueue:
         self._l = threading.Lock()
         self._cond = threading.Condition(self._l)
         self._enabled = False
-        self._heap: List[_PendingPlan] = []
+        self._heap: List[_PendingPlans] = []
         self._seq = itertools.count()
         # Per-job last plan-apply index (stale-snapshot fence): a worker
         # may reuse a cached snapshot for job J only if it covers J's
@@ -138,32 +140,42 @@ class PlanQueue:
                 # silent drop would hang their future.wait() forever
                 # (plan_queue.go Flush responds with an error).
                 for item in self._heap:
-                    item.future.respond(None, RuntimeError(
-                        "plan queue is disabled (leadership lost)"))
+                    for _, future in item.plans:
+                        future.respond(None, RuntimeError(
+                            "plan queue is disabled (leadership lost)"))
                 self._heap = []
             self._cond.notify_all()
 
     def enqueue(self, plan: s.Plan, trace_parent: int = 0) -> PlanFuture:
         """(plan_queue.go:95)."""
-        future = PlanFuture(trace_parent)
+        return self.enqueue_group([plan], trace_parent)[0]
+
+    def enqueue_group(self, plans: List[s.Plan],
+                      trace_parent: int = 0) -> List[PlanFuture]:
+        """One queue item for the plans of one submission (a batch's
+        plans, in spec order): the applier dequeues them together and
+        decides them in that order.  The item's priority is the highest
+        of its plans'; each plan has its own future."""
+        futures = [PlanFuture(trace_parent) for _ in plans]
         with self._l:
             if not self._enabled:
                 raise RuntimeError("plan queue is disabled")
-            heapq.heappush(
-                self._heap,
-                _PendingPlan((-plan.priority, 0, next(self._seq)), plan, future))
+            heapq.heappush(self._heap, _PendingPlans(
+                (-max(plan.priority for plan in plans), 0, next(self._seq)),
+                list(zip(plans, futures))))
             self._cond.notify_all()
-        return future
+        return futures
 
-    def dequeue(self, timeout: Optional[float] = None) -> Optional[Tuple[s.Plan, PlanFuture]]:
+    def dequeue(self, timeout: Optional[float] = None
+                ) -> Optional[List[Tuple[s.Plan, PlanFuture]]]:
+        """The next submission's (plan, future) pairs, in its order."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._l:
             while True:
                 if not self._enabled:
                     return None
                 if self._heap:
-                    pending = heapq.heappop(self._heap)
-                    return pending.plan, pending.future
+                    return heapq.heappop(self._heap).plans
                 remaining = None if deadline is None else deadline - time.monotonic()
                 if remaining is not None and remaining <= 0:
                     return None

@@ -159,96 +159,152 @@ class RaftLog:
 
     def apply(self, msg_type: MessageType, payload: dict):
         """Append + commit + apply one entry; returns (result, index)
-        (the raftApply path, nomad/rpc.go raftApply → fsm.Apply).
+        (the raftApply path, nomad/rpc.go raftApply → fsm.Apply): a
+        group of one through :meth:`apply_many`."""
+        outcome, = self.apply_many([(msg_type, payload)])
+        if isinstance(outcome, Exception):
+            raise outcome
+        return outcome
+
+    def apply_many(self, entries: List[Tuple[MessageType, dict]]) -> list:
+        """Append + commit + apply ``entries`` back to back, one log
+        entry each, under ONE durability wait; returns per entry, in
+        order, ``(result, index)`` or the exception that failed it.
 
         Three phases, preserving durability-before-visibility while
         letting concurrent appliers share one fsync:
 
-        1. Under the log lock: assign the index and WRITE the entry
-           (file order == index order, so the durable prefix is always
-           gap-free).  No fsync here — holding the lock across the
-           fsync made group commit structurally impossible (appends
-           were never concurrent) and serialized one fsync per apply.
-        2. Outside the lock: wait for durability (_sync_persist);
-           concurrent waiters coalesce into one group-commit fsync.
+        1. Under the log lock: assign each entry the next index and
+           WRITE it (file order == index order, so the durable prefix is
+           always gap-free; a group's indexes are consecutive).  No
+           fsync here — holding the lock across the fsync made group
+           commit structurally impossible (appends were never
+           concurrent) and serialized one fsync per apply.  An entry
+           whose write fails releases its index and fails alone, unless
+           the failure poisoned the log (below).
+        2. Outside the lock: ONE wait for durability, on the last
+           written entry (_sync_persist): a sync covers the whole
+           written prefix, and concurrent waiters coalesce into one
+           group-commit fsync.  Nothing of the group is applied or
+           answered before it returns.
         3. Apply sequencer: FSM applies run in strict index order,
            AFTER durability — nothing external (event stream, blocking
            queries, applied_index readers) can observe state a crash
-           would erase.  A sync covers the whole written prefix, so
-           waiting for entry N-1's APPLY never waits on another fsync.
+           would erase.  Waiting for entry N-1's APPLY never waits on
+           another fsync.  An FSM apply that raises fails its own entry
+           and the sequencer moves on.
 
         A durability failure poisons the log (fsync failure is fatal —
-        the reference panics): the entry was never applied, no retry
-        can double-apply, and every queued/later apply fails too."""
+        the reference panics): no entry of the group was applied, no
+        retry can double-apply, every entry of the group fails (those
+        already written stay in the file as a prefix of whole entries
+        nobody was told about, which recovery replays — the
+        entry-durable, ack-lost case) and every queued/later apply fails
+        too."""
         t0 = time.perf_counter()
+        outcomes: list = [None] * len(entries)
+        written: List[Tuple[int, int]] = []     # (position, index)
+        token = poisoned = None
         with self._l:
-            if not self._leader:
-                raise NotLeaderError("not the leader")
-            if getattr(self, "_wal_failed", False):
-                # A durability failure already poisoned this log: the
-                # durable prefix is unknown, so NO further applies are
-                # accepted — restart to recover from it.
-                raise NotLeaderError("write-ahead log failed; restart "
-                                     "to recover from the durable prefix")
-            # Fault point BEFORE append: an injected crash here models the
-            # leader dying before the entry commits — nothing persists,
-            # nothing applies, and the caller's retry path must cope.
-            if _fire_apply_fault(self._last_index + 1, msg_type) is not None:
-                raise NotLeaderError("injected step-down")
-            self._last_index += 1
-            index = self._last_index
+            for pos, (msg_type, payload) in enumerate(entries):
+                try:
+                    index, token = self._append(msg_type, payload)
+                except Exception as exc:
+                    outcomes[pos] = exc
+                    if getattr(self, "_wal_failed", False):
+                        poisoned = exc
+                        break
+                else:
+                    written.append((pos, index))
+        if written and token is not None:
             try:
-                token = self._persist(index, msg_type, payload)
-            except Exception:
-                # Nothing reached the log (writes roll back torn
-                # frames): release the index so the apply sequencer
-                # never waits on a permanently-missing entry.
-                self._last_index -= 1
-                raise
-        if token is not None:
-            try:
-                self._sync_persist(token, msg_type)
-            except Exception:
+                if poisoned is None:
+                    self._sync_persist(token, entries[written[-1][0]][0],
+                                       len(written))
+                else:
+                    self._release_persist(len(written))
+            except Exception as exc:
+                poisoned = exc
+            if poisoned is not None:
                 with self._l:
                     self._wal_failed = True
                 with self._apply_cv:
-                    # The written entry will never apply: every later
-                    # (higher-index) applier queued behind it must fail
+                    # The written entries will never apply: every later
+                    # (higher-index) applier queued behind them must fail
                     # rather than wait forever.
                     self._apply_failed = True
                     self._apply_cv.notify_all()
-                raise
-        with self._apply_cv:
-            while self._apply_next != index:
-                if self._apply_failed:
-                    raise NotLeaderError(
+        if poisoned is not None:
+            return [poisoned if out is None else out for out in outcomes]
+        tr = tracing.TRACER
+        for pos, index in written:
+            msg_type, payload = entries[pos]
+            with self._apply_cv:
+                while self._apply_next != index:
+                    if self._apply_failed:
+                        break
+                    self._apply_cv.wait()
+                if self._apply_next != index:
+                    outcomes[pos] = NotLeaderError(
                         "write-ahead log failed; restart to recover "
                         "from the durable prefix")
-                self._apply_cv.wait()
-            try:
-                result = self.fsm.apply(index, msg_type, payload)
-            finally:
-                # ALWAYS advance: an FSM apply that raises (e.g. a
-                # deregister of an unknown node) propagates to its one
-                # caller exactly as before, but the sequencer must not
-                # wedge every later apply behind the dead index.
-                self._applied = index  # visible only now: post-durability
-                self._apply_next = index + 1
-                self._apply_cv.notify_all()
-        self.metrics.measure_since("raft.apply", t0)
-        # Branch before building attrs: the disarmed commit path pays
-        # one load + comparison, no getattr/dict/timestamp.
-        tr = tracing.TRACER
-        if tr is not None:
-            tr.record("raft.apply", t0, time.perf_counter(), index=index,
-                      msg_type=getattr(msg_type, "name", str(msg_type)))
-        return result, index
+                    continue
+                try:
+                    outcomes[pos] = (
+                        self.fsm.apply(index, msg_type, payload), index)
+                except Exception as exc:
+                    # Propagates to this entry's one caller exactly as
+                    # before; ALWAYS advance: the sequencer must not
+                    # wedge every later apply behind the dead index.
+                    outcomes[pos] = exc
+                finally:
+                    self._applied = index  # visible only now: post-durability
+                    self._apply_next = index + 1
+                    self._apply_cv.notify_all()
+            self.metrics.measure_since("raft.apply", t0)
+            # Branch before building attrs: the disarmed commit path pays
+            # one load + comparison, no getattr/dict/timestamp.
+            if tr is not None:
+                tr.record("raft.apply", t0, time.perf_counter(), index=index,
+                          msg_type=getattr(msg_type, "name", str(msg_type)))
+            t0 = time.perf_counter()
+        return outcomes
+
+    def _append(self, msg_type: MessageType, payload: dict):
+        """Assign the next index and write the entry (caller holds the
+        log lock); returns (index, durability token)."""
+        if not self._leader:
+            raise NotLeaderError("not the leader")
+        if getattr(self, "_wal_failed", False):
+            # A durability failure already poisoned this log: the
+            # durable prefix is unknown, so NO further applies are
+            # accepted — restart to recover from it.
+            raise NotLeaderError("write-ahead log failed; restart "
+                                 "to recover from the durable prefix")
+        # Fault point BEFORE append: an injected crash here models the
+        # leader dying before the entry commits — nothing persists,
+        # nothing applies, and the caller's retry path must cope.
+        if _fire_apply_fault(self._last_index + 1, msg_type) is not None:
+            raise NotLeaderError("injected step-down")
+        self._last_index += 1
+        try:
+            return self._last_index, self._persist(
+                self._last_index, msg_type, payload)
+        except Exception:
+            # Nothing reached the log (writes roll back torn frames):
+            # release the index so the apply sequencer never waits on a
+            # permanently-missing entry.
+            self._last_index -= 1
+            raise
 
     def _persist(self, index: int, msg_type: MessageType, payload: dict):
         return None  # in-memory: nothing to do
 
-    def _sync_persist(self, token, msg_type) -> None:
+    def _sync_persist(self, token, msg_type, entries: int = 1) -> None:
         pass  # in-memory: nothing to wait for
+
+    def _release_persist(self, entries: int) -> None:
+        pass  # in-memory: no durability tokens
 
     def snapshot(self) -> None:
         pass
@@ -595,23 +651,29 @@ class FileLog(RaftLog):
             self._sync_inflight += 1
         return token
 
-    def _sync_persist(self, seq: int, msg_type) -> None:
+    def _sync_persist(self, seq: int, msg_type, entries: int = 1) -> None:
         """Wait (outside the raft lock) until the entry written as
-        ``seq`` is durable.  Concurrent callers coalesce into one fsync
-        — natively via wal.cc's group commit, in the fallback via the
-        same written/synced-seq single-syncer dance in Python."""
+        ``seq`` — the last of ``entries`` written back to back — is
+        durable.  Concurrent callers coalesce into one fsync — natively
+        via wal.cc's group commit, in the fallback via the same
+        written/synced-seq single-syncer dance in Python."""
         t0 = time.perf_counter()
         try:
             self._do_sync_persist(seq)
         finally:
-            with self._py_cv:
-                self._sync_inflight -= 1
-                self._py_cv.notify_all()
+            self._release_persist(entries)
         self.metrics.measure_since("raft.fsync", t0)
         if msg_type == MessageType.APPLY_PLAN_RESULTS:
             # The loadgen report's plan_apply_fsync percentiles: the
             # durability wait specifically on the plan-apply path.
             self.metrics.measure_since("raft.fsync.plan", t0)
+
+    def _release_persist(self, entries: int) -> None:
+        """Hand back the durability tokens of ``entries`` written
+        entries (the WAL roll waits for none to be out)."""
+        with self._py_cv:
+            self._sync_inflight -= entries
+            self._py_cv.notify_all()
 
     def _do_sync_persist(self, seq: int) -> None:
         if self._nwal is not None:
@@ -1819,6 +1881,17 @@ class MultiRaft(RaftLog):
             self._compact()
 
     # -- the apply path ----------------------------------------------------
+
+    def apply_many(self, entries: List[Tuple[MessageType, dict]]) -> list:
+        """Each entry through :meth:`apply`, in order: on the multi-voter
+        log every entry waits its own replication round trip."""
+        outcomes: list = []
+        for msg_type, payload in entries:
+            try:
+                outcomes.append(self.apply(msg_type, payload))
+            except Exception as exc:
+                outcomes.append(exc)
+        return outcomes
 
     def apply(self, msg_type: MessageType, payload: dict):
         from .log_codec import encode_payload

@@ -57,6 +57,71 @@ def _stale_snapshot_max_lag() -> int:
     return knobs.get_int("NOMAD_TPU_STALE_SNAPSHOT_LAG")
 
 
+def submit_plans(worker: "Worker",
+                 items: List[Tuple["WorkerPlanner", s.Plan]]) -> list:
+    """One submission to the plan queue: each plan stamped with its own
+    eval's token and snapshot index, every eval's nack clock held for
+    the wait (the queue is unbounded), ONE queue item (one plan:
+    ``enqueue``; a batch's plans: ``enqueue_group``, which the applier
+    decides together), one wait.  Returns ``(result, refreshed state)``
+    per plan, in order; a plan that failed raises, once every future of
+    the submission has been answered."""
+    plans = [plan for _, plan in items]
+    for planner, plan in items:
+        plan.eval_token = planner.token
+        if planner.snapshot_index is not None:
+            plan.snapshot_index = planner.snapshot_index
+    with worker._nack_clocks_held(
+            [(planner.eval, planner.token) for planner, _ in items]):
+        tr = tracing.TRACER
+        submit_span = tracing.NOOP if tr is None else tr.span(
+            "worker.submit_plan", **tracing.plan_attrs(plans))
+        with submit_span as sp:
+            # Armed, the span id rides the futures: the applier's
+            # spans (other threads) name it as their parent.
+            parent = 0 if tr is None else sp.span_id
+            queue = worker.plan_queue
+            futures = ([queue.enqueue(plans[0], trace_parent=parent)]
+                       if len(plans) == 1
+                       else queue.enqueue_group(plans, trace_parent=parent))
+            results, failed = [], None
+            for future in futures:
+                try:
+                    results.append(future.wait())
+                except Exception as exc:
+                    results.append(None)
+                    failed = failed or exc
+            _emit_round_trip(worker.metrics, futures[-1], tracing.now(), tr)
+    if failed is not None:
+        raise failed
+    return [(result, planner._refreshed(result))
+            for (planner, _), result in zip(items, results)]
+
+
+def _emit_round_trip(metrics, future, t_woke: float, tr) -> None:
+    """A submission's hand-offs, from the stamps its last future
+    collected: queue_wait (enqueued → claimed by the applier),
+    commit_wait (evaluate done → _commit entered; 0 for a plan with
+    nothing to commit) and wake (responded → this thread running
+    again).  With plan.evaluate and plan.apply they sum to the round
+    trip.  A remote future (follower scheduling) carries no stamps
+    and emits nothing."""
+    t_claimed = getattr(future, "t_claimed", 0.0)
+    if not t_claimed or not future.t_responded:
+        return
+    t_commit = future.t_commit or future.t_evaluated
+    metrics.add_sample("plan.queue_wait",
+                       (t_claimed - future.t_enqueued) * 1000.0)
+    metrics.add_sample("plan.commit_wait",
+                       (t_commit - future.t_evaluated) * 1000.0)
+    metrics.add_sample("plan.wake", (t_woke - future.t_responded) * 1000.0)
+    if tr is not None:
+        # Recorded inside worker.submit_plan: it is their parent.
+        tr.record("plan.queue_wait", future.t_enqueued, t_claimed)
+        tr.record("plan.commit_wait", future.t_evaluated, t_commit)
+        tr.record("plan.wake", future.t_responded, t_woke)
+
+
 class WorkerPlanner:
     """The scheduler.Planner implementation workers hand to schedulers
     (worker.go:300-499)."""
@@ -77,69 +142,24 @@ class WorkerPlanner:
     def submit_plan(self, plan: s.Plan):
         """(worker.go:300 SubmitPlan) — pause the nack timer while in the
         unbounded plan queue, attach the eval token for fencing."""
+        return submit_plans(self.worker, [(self, plan)])[0]
+
+    def _refreshed(self, result: Optional[s.PlanResult]):
+        """The state a scheduler retries on after a partial commit."""
+        if result is None or not result.refresh_index:
+            return None
+        # Wait for our state to catch up, then hand a refreshed
+        # snapshot to the scheduler (worker.go:335-350).  The
+        # refresh also replaces the worker's stale-snapshot cache —
+        # a conflict means the cached view lost its bet.
         w = self.worker
-        plan.eval_token = self.token
-        if self.snapshot_index is not None:
-            plan.snapshot_index = self.snapshot_index
-        try:
-            w.broker.pause_nack_timeout(self.eval.id, self.token)
-        except EvalBrokerError:
-            pass
-        try:
-            tr = tracing.TRACER
-            submit_span = tracing.NOOP if tr is None else tr.span(
-                "worker.submit_plan", eval_id=self.eval.id)
-            with submit_span as sp:
-                # Armed, the span id rides the future: the applier's
-                # spans (other threads) name it as their parent.
-                future = (w.plan_queue.enqueue(plan) if tr is None
-                          else w.plan_queue.enqueue(
-                              plan, trace_parent=sp.span_id))
-                result = future.wait()
-                self._emit_round_trip(future, tracing.now(), tr)
-        finally:
-            try:
-                w.broker.resume_nack_timeout(self.eval.id, self.token)
-            except EvalBrokerError:
-                pass
-
-        state = None
-        if result is not None and result.refresh_index:
-            # Wait for our state to catch up, then hand a refreshed
-            # snapshot to the scheduler (worker.go:335-350).  The
-            # refresh also replaces the worker's stale-snapshot cache —
-            # a conflict means the cached view lost its bet.
-            w.wait_for_index(result.refresh_index, RAFT_SYNC_LIMIT)
-            idx = w.raft.applied_index()
-            state = w.raft.fsm.state.snapshot()
-            if w._stale_ok:
-                w._snap_cache = (idx, state)
-            self.snapshot_index = idx
-        return result, state
-
-    def _emit_round_trip(self, future, t_woke: float, tr) -> None:
-        """The plan round trip's hand-offs, from the stamps the future
-        collected: queue_wait (enqueued → claimed by the applier),
-        commit_wait (evaluate done → _commit entered; 0 for a plan with
-        nothing to commit) and wake (responded → this thread running
-        again).  With plan.evaluate and plan.apply they sum to the round
-        trip.  A remote future (follower scheduling) carries no stamps
-        and emits nothing."""
-        t_claimed = getattr(future, "t_claimed", 0.0)
-        if not t_claimed or not future.t_responded:
-            return
-        t_commit = future.t_commit or future.t_evaluated
-        m = self.worker.metrics
-        m.add_sample("plan.queue_wait",
-                     (t_claimed - future.t_enqueued) * 1000.0)
-        m.add_sample("plan.commit_wait",
-                     (t_commit - future.t_evaluated) * 1000.0)
-        m.add_sample("plan.wake", (t_woke - future.t_responded) * 1000.0)
-        if tr is not None:
-            # Recorded inside worker.submit_plan: it is their parent.
-            tr.record("plan.queue_wait", future.t_enqueued, t_claimed)
-            tr.record("plan.commit_wait", future.t_evaluated, t_commit)
-            tr.record("plan.wake", future.t_responded, t_woke)
+        w.wait_for_index(result.refresh_index, RAFT_SYNC_LIMIT)
+        idx = w.raft.applied_index()
+        state = w.raft.fsm.state.snapshot()
+        if w._stale_ok:
+            w._snap_cache = (idx, state)
+        self.snapshot_index = idx
+        return state
 
     def update_eval(self, ev: s.Evaluation) -> None:
         self.worker.apply_eval_updates([ev])
@@ -348,6 +368,26 @@ class Worker:
             self.logger.debug("could not record failure reason for %d "
                               "evals", len(failed), exc_info=True)
 
+    @contextlib.contextmanager
+    def _nack_clocks_held(self, batch):
+        """Hold the batch's nack clocks; resuming restarts each eval's
+        full timeout (the WorkerPlanner.submit_plan discipline)."""
+        held = []
+        for ev, token in batch:
+            try:
+                self.broker.pause_nack_timeout(ev.id, token)
+                held.append((ev.id, token))
+            except EvalBrokerError:
+                pass
+        try:
+            yield
+        finally:
+            for eval_id, token in held:
+                try:
+                    self.broker.resume_nack_timeout(eval_id, token)
+                except EvalBrokerError:
+                    pass
+
     # -- leader-write hooks ------------------------------------------------
     # The two write surfaces workers/planners touch beyond plan
     # submission.  On a leader-local worker they go straight through the
@@ -357,6 +397,22 @@ class Worker:
 
     def apply_eval_updates(self, evals: List[s.Evaluation]) -> None:
         self.raft.apply(MessageType.EVAL_UPDATE, {"evals": evals})
+
+    def apply_eval_statuses(self, evals: List[s.Evaluation]) -> None:
+        """A batch's eval statuses: one EVAL_UPDATE entry per eval, in
+        the order given (the batch's plan order), written back to back
+        under ONE fsync.  An entry each, not one entry for the list: an
+        eval's ``modify_index`` then still says where its completion
+        fell among the batch's, which is how a reader of the store
+        (the benchmark's replay in commit order) orders a batch's
+        plans."""
+        failed = None
+        for outcome in self.raft.apply_many(
+                [(MessageType.EVAL_UPDATE, {"evals": [ev]}) for ev in evals]):
+            if isinstance(outcome, Exception):
+                failed = failed or outcome
+        if failed is not None:
+            raise failed
 
     def reblock_eval_update(self, ev: s.Evaluation, token: str) -> None:
         self.apply_eval_updates([ev])
@@ -460,18 +516,47 @@ class _MuxPlanner:
     """Routes planner calls to the owning eval's WorkerPlanner."""
 
     def __init__(self, worker: "Worker", batch, snapshot_index: int):
+        self.worker = worker
+        # The plans of the submission being gathered (submit_plans).
+        self._group: Optional[list] = None
         self.planners = {
             ev.id: WorkerPlanner(worker, ev, token,
                                  snapshot_index=snapshot_index)
             for ev, token in batch}
-        self._by_plan_eval = self.planners
 
     def submit_plan(self, plan):
-        return self.planners[plan.eval_id].submit_plan(plan)
+        """One eval's plan on its way to the plan queue: the one place a
+        plan is handed over, whichever way the batch submits (what wraps
+        it, as the benchmark's fault harness does, meets every plan).
+        Alone, the plan is submitted and waited for; inside
+        ``submit_plans`` it joins the batch's one submission and its
+        answer comes with the group's."""
+        item = (self.planners[plan.eval_id], plan)
+        if self._group is None:
+            return submit_plans(self.worker, [item])[0]
+        self._group.append(item)
+        return None
+
+    def submit_plans(self, plans):
+        """The batch's plans as one submission, each handed over through
+        ``submit_plan`` (a list of one is ``submit_plan``)."""
+        if len(plans) == 1:
+            return [self.submit_plan(plans[0])]
+        self._group = items = []
+        try:
+            for plan in plans:
+                self.submit_plan(plan)
+        finally:
+            self._group = None
+        return submit_plans(self.worker, items)
 
     def update_eval(self, ev):
         p = self.planners.get(ev.id) or next(iter(self.planners.values()))
         p.update_eval(ev)
+
+    def update_evals(self, evs):
+        """The batch's eval statuses in one write (one fsync)."""
+        self.worker.apply_eval_statuses(evs)
 
     def create_eval(self, ev):
         p = self.planners.get(ev.previous_eval) or next(iter(self.planners.values()))
@@ -597,26 +682,6 @@ class BatchWorker(Worker):
                 sp.set(fused=stats.fused, quantized=stats.quantized,
                        fetch_bytes=stats.fetch_bytes,
                        commit_s=round(stats.commit_seconds, 4))
-
-    @contextlib.contextmanager
-    def _nack_clocks_held(self, batch):
-        """Hold the batch's nack clocks; resuming restarts each eval's
-        full timeout (the WorkerPlanner.submit_plan discipline)."""
-        held = []
-        for ev, token in batch:
-            try:
-                self.broker.pause_nack_timeout(ev.id, token)
-                held.append((ev.id, token))
-            except EvalBrokerError:
-                pass
-        try:
-            yield
-        finally:
-            for eval_id, token in held:
-                try:
-                    self.broker.resume_nack_timeout(eval_id, token)
-                except EvalBrokerError:
-                    pass
 
     def _compile_guard(self, batch):
         """XLA compilation runs outside the nack clock: a cold shape

@@ -440,6 +440,15 @@ def eval_id_attrs(evals, total: int) -> Dict[str, Any]:
     return out
 
 
+def plan_attrs(plans) -> Dict[str, Any]:
+    """Correlation attrs for the spans of one plan submission:
+    ``eval_id`` for one plan, ``eval_ids`` for a batch's plans (the
+    tracer keeps the first ``MAX_EVAL_IDS_PER_SPAN``)."""
+    if len(plans) == 1:
+        return {"eval_id": plans[0].eval_id}
+    return {"eval_ids": [plan.eval_id for plan in plans]}
+
+
 def event(name: str, **attrs: Any) -> None:
     tr = TRACER
     if tr is not None:

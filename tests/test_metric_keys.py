@@ -79,7 +79,7 @@ def sink(tmp_path_factory):
 
 
 def test_there_are_sink_metrics():
-    assert len(METRICS) >= 40
+    assert len(METRICS) >= 42
 
 
 @pytest.mark.parametrize("spec", METRICS)
@@ -101,6 +101,29 @@ def test_fit_recheck_publishes_its_routes_and_its_guard(sink):
     assert {"nomad.plan.fit.rows_array",
             "nomad.plan.fit.rows_scalar"} <= sink["counters"]
     assert "nomad.plan.evaluate.guard" in sink["samples"]
+
+
+@pytest.mark.parametrize("name", ["plans_per_submission.tput",
+                                  "plans_per_submission.lat"])
+def test_plans_per_submission_reads_what_every_plan_path_publishes(
+        sink, name):
+    """``nomad.plan.submitted`` counts the plans each pass of the
+    applier's fit re-check decided, one pass to a ``nomad.plan.evaluate``
+    sample: the served jobs' batches held one plan each, and the hog's
+    plan went through ``plan_submit``, so every pass decided one."""
+    with open(os.path.join(ROOT, "benchmarks", "metrics",
+                           name + ".json")) as fh:
+        spec = json.load(fh)
+    assert (spec["reader"], spec["key"], spec["per"]) == (
+        "counter_per_sample", "nomad.plan.submitted", "nomad.plan.evaluate")
+    latest = sink["latest"]
+    passes = latest["SampleTotals"][spec["per"]][0]
+    assert passes >= 2
+    assert latest["CounterTotals"][spec["key"]] == passes
+    # the samples of one submission: one of each to a pass
+    for key in ("nomad.plan.queue_wait", "nomad.plan.commit_wait",
+                "nomad.plan.wake"):
+        assert latest["SampleTotals"][key][0] <= passes
 
 
 def test_fused_counter_counts_the_batches_the_device_answered(sink):
