@@ -22,7 +22,10 @@ Layout (little-endian throughout):
               packed varint-prefixed strings or the 3-field generator
               spec — AllocSlab's formulaic columns stay ~40 bytes on the
               wire and in the replicated log (the PR 9/10 compaction,
-              preserved by construction)
+              preserved by construction).  A subtag-0 column may be
+              written from an indexed column (structs.NodeColumn: rows
+              of a table of pre-packed ids, gathered): the same bytes as
+              from the list of its strings, decoded as a plain list
 - Dict[str,X] varint count + (str, X) pairs
 - Any         tagged value tree (see ``_val``), which also carries whole
               raft log payloads: dicts/lists/scalars plus any registered
@@ -40,7 +43,7 @@ import struct
 import typing
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from ..structs.structs import LazyNames, LazyUuids, _LazyStrs
+from ..structs.structs import LazyNames, LazyUuids, NodeColumn, _LazyStrs
 from . import native
 from .schema import FINGERPRINT, MAGIC, TYPE_IDS, TYPES_BY_ID, VERSION
 
@@ -150,10 +153,13 @@ def _strs(w: bytearray, col) -> None:
         w += pb
         _uv(w, col.n)
         return
-    if isinstance(col, _LazyStrs):  # unknown lazy subclass: materialize
-        col = list(col)
     w.append(0)
     _uv(w, len(col))
+    if type(col) is NodeColumn:     # the same bytes, from its integers
+        w += native.pack_column(col)
+        return
+    if isinstance(col, _LazyStrs):  # unknown lazy subclass: materialize
+        col = list(col)
     w += native.pack_strs(col)
 
 

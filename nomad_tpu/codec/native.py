@@ -13,6 +13,10 @@ Python twin and bit-compared.  A mismatch disables the native path for
 the process, feeds the PR 2 kernel circuit breaker
 (``ops.breaker.BREAKER``), and logs — wrong bytes must never reach a
 peer quietly.  ``NOMAD_TPU_NO_NATIVE=1`` forces the twin.
+
+A slab committed by the device path carries its node column as integers
+(structs.NodeColumn); ``pack_column`` writes the same bytes for it by
+one gather of the fleet's pre-packed ids, under the same guard.
 """
 from __future__ import annotations
 
@@ -27,6 +31,7 @@ GUARD_RUNS = 0
 GUARD_MISMATCHES = 0
 NATIVE_PACKS = 0
 NATIVE_UNPACKS = 0
+COLUMN_PACKS = 0
 
 _guard_counter = 0
 _native_disabled = False
@@ -42,9 +47,9 @@ def guard_every() -> int:
 
 def reset_counters() -> None:
     global GUARD_RUNS, GUARD_MISMATCHES, NATIVE_PACKS, NATIVE_UNPACKS
-    global _guard_counter, _native_disabled
+    global COLUMN_PACKS, _guard_counter, _native_disabled
     GUARD_RUNS = GUARD_MISMATCHES = 0
-    NATIVE_PACKS = NATIVE_UNPACKS = 0
+    NATIVE_PACKS = NATIVE_UNPACKS = COLUMN_PACKS = 0
     _guard_counter = 0
     _native_disabled = False
 
@@ -141,7 +146,7 @@ def _py_split_strs(b: bytes, p: int, n: int) -> Tuple[List[str], int]:
 def pack_strs(strs) -> bytes:
     """Pack a string column (varint len + utf8 per item); caller writes
     the count.  Native when available, differential-guarded."""
-    global NATIVE_PACKS, GUARD_RUNS, _guard_counter
+    global NATIVE_PACKS
     encoded = [s.encode("utf-8") for s in strs]
     lib = None if _native_disabled else _get_lib()
     if lib is None or not encoded:
@@ -157,22 +162,73 @@ def pack_strs(strs) -> bytes:
         return _py_pack_strs(encoded)
     NATIVE_PACKS += 1
     result = out.raw
+    if _guard_due() and result != _py_pack_strs(encoded):
+        _note_mismatch("pack_strs")
+        return _py_pack_strs(encoded)
+    return result
+
+
+def _guard_due() -> bool:
+    """Count one guarded call; True on every ``guard_every()``-th."""
+    global GUARD_RUNS, _guard_counter
     every = guard_every()
-    if every > 0:
-        _guard_counter += 1
-        if _guard_counter >= every:
-            _guard_counter = 0
-            GUARD_RUNS += 1
-            if result != _py_pack_strs(encoded):
-                _note_mismatch("pack_strs")
-                return _py_pack_strs(encoded)
+    if every <= 0:
+        return False
+    _guard_counter += 1
+    if _guard_counter < every:
+        return False
+    _guard_counter = 0
+    GUARD_RUNS += 1
+    return True
+
+
+def _packed_table(table):
+    """Each id of ``table`` (structs.NodeTable) as ``pack_strs`` writes
+    it, made once per table: one fixed-width ``S`` array when every
+    packed id has the same length (a uuid fleet, ``node-00001``), else a
+    list of ``bytes``."""
+    packed = table.packed
+    if packed is None:
+        import numpy as np
+
+        packed = [_py_pack_strs([s.encode("utf-8")]) for s in table.ids]
+        widths = set(map(len, packed))
+        if len(widths) == 1:
+            packed = np.frombuffer(b"".join(packed),
+                                   dtype=f"S{widths.pop()}")
+        table.packed = packed
+    return packed
+
+
+def pack_column(col) -> bytes:
+    """``pack_strs(list(col))`` for an indexed node column
+    (structs.NodeColumn), byte for byte, as one gather of the table's
+    pre-packed ids by the column's integers: no string is made or
+    encoded.  Guarded like the native pack: every
+    ``NOMAD_TPU_CODEC_GUARD_EVERY``-th call is compared with the Python
+    twin over the materialized strings, and a mismatch turns this route
+    off for the process with the native one."""
+    global COLUMN_PACKS
+    if _native_disabled or not len(col):
+        return pack_strs(list(col))
+    packed = _packed_table(col.table)
+    if type(packed) is list:
+        result = b"".join(map(packed.__getitem__, col.idx.tolist()))
+    else:
+        result = packed[col.idx].tobytes()
+    COLUMN_PACKS += 1
+    if _guard_due():
+        twin = _py_pack_strs([s.encode("utf-8") for s in col])
+        if result != twin:
+            _note_mismatch("pack_column")
+            return twin
     return result
 
 
 def unpack_strs(b: bytes, p: int, n: int) -> Tuple[List[str], int]:
     """Parse ``n`` packed strings from ``b`` at ``p``; returns
     (strings, new position).  Native length scan when available."""
-    global NATIVE_UNPACKS, GUARD_RUNS, _guard_counter
+    global NATIVE_UNPACKS
     from .gen import CodecError
 
     if n > len(b) - p:  # each string costs >= 1 byte
@@ -191,14 +247,9 @@ def unpack_strs(b: bytes, p: int, n: int) -> Tuple[List[str], int]:
     NATIVE_UNPACKS += 1
     out = [b[offs[i]:offs[i] + lens[i]].decode("utf-8")
            for i in range(n)]
-    every = guard_every()
-    if every > 0:
-        _guard_counter += 1
-        if _guard_counter >= every:
-            _guard_counter = 0
-            GUARD_RUNS += 1
-            twin, twin_end = _py_split_strs(b, p, n)
-            if twin != out or twin_end != end:
-                _note_mismatch("unpack_strs")
-                return twin, twin_end
+    if _guard_due():
+        twin, twin_end = _py_split_strs(b, p, n)
+        if twin != out or twin_end != end:
+            _note_mismatch("unpack_strs")
+            return twin, twin_end
     return out, end

@@ -1669,7 +1669,13 @@ class TPUBatchScheduler:
         vcnt = coo_counts[valid]
         u_lo = np.searchsorted(vr, np.arange(len(spec_list)), side="left")
         u_hi = np.searchsorted(vr, np.arange(len(spec_list)), side="right")
-        node_id_arr = np.array(ct.node_ids, dtype=object)
+        # The fleet's id table rides the static tensors (one per
+        # encoding, encode._carry_host_attrs); tensors built elsewhere
+        # get one for the batch.
+        node_table = getattr(ct, "_node_table", None)
+        if node_table is None:
+            node_table = s.NodeTable(ct.node_ids)
+        node_id_arr = node_table.ids
         total_asks = int(sum(sp.count for sp in spec_list))
         exp_off, exp_idx = decode_mod.expand_coo(
             coo_rows, coo_cols, coo_counts, len(spec_list), ct.n_real,
@@ -1799,8 +1805,10 @@ class TPUBatchScheduler:
         for u, sp in enumerate(spec_list):
             key = (sp.job.id, sp.tg.name)
             lo, hi = int(u_lo[u]), int(u_hi[u])
-            expanded[key] = node_id_arr[
-                exp_idx[int(exp_off[u]):int(exp_off[u + 1])]].tolist()
+            # The spec's slots stay the integers the device returned
+            # (a sequence of node ids to whoever reads strings).
+            expanded[key] = s.NodeColumn(
+                node_table, exp_idx[int(exp_off[u]):int(exp_off[u + 1])])
             unplaced[key] = int(unplaced_arr[u])
 
             n_unplaced = unplaced[key]

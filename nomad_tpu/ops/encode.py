@@ -453,6 +453,7 @@ def encode_cluster_static(
     ct._nodes = list(nodes)            # type: ignore[attr-defined]
     ct._with_networks = with_networks  # type: ignore[attr-defined]
     ct._node_index = {nid: i for i, nid in enumerate(node_ids)}  # type: ignore[attr-defined]
+    ct._node_table = s.NodeTable(node_ids)  # type: ignore[attr-defined]
     ct._host_rows = _HostRows()        # type: ignore[attr-defined]
     return ct
 
@@ -518,6 +519,7 @@ def encode_cluster_static_columnar(
     ct._nodes = nodes if type(nodes) is list else list(nodes)  # type: ignore[attr-defined]
     ct._with_networks = False          # type: ignore[attr-defined]
     ct._node_index = {nid: i for i, nid in enumerate(node_ids)}  # type: ignore[attr-defined]
+    ct._node_table = s.NodeTable(node_ids)  # type: ignore[attr-defined]
     ct._host_rows = _HostRows()        # type: ignore[attr-defined]
     ct._columnar = True                # type: ignore[attr-defined]
     return ct
@@ -612,7 +614,8 @@ def _carry_host_attrs(ct: ClusterTensors, new: ClusterTensors) -> None:
     none depends on usage, and ``_host_rows`` must be the SAME object so
     a row built under one batch's clone is found by the next."""
     for attr in ("_raw_rows", "_value_sets", "_class_codebook", "_nodes",
-                 "_with_networks", "_node_index", "_host_rows"):
+                 "_with_networks", "_node_index", "_node_table",
+                 "_host_rows"):
         if hasattr(ct, attr):
             setattr(new, attr, getattr(ct, attr))
 

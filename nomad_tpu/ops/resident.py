@@ -424,27 +424,36 @@ def _feed_rows(entries, node_index: Dict[str, int]
     """The usage-delta feed's raw entries (StateStore.alloc_log_since)
     as ``(rows int64[k], vals int64[k, 4])``: one usage row per
     allocation write, in feed order; writes on nodes the fleet does not
-    hold are dropped.  Every entry's node ids go through ONE index
-    gather and a slab's usage vector is repeated over its node column —
-    no Python per allocation, and at a stream batch's one or two ten-row
-    slabs no slower than a lookup per row (PERF.md PR 30)."""
+    hold are dropped.  Each entry's node ids go through one index
+    gather (an indexed column's is ``perm[idx]``, no string handled;
+    runs of single-row entries share one) and a slab's usage vector is
+    repeated over its node column — no Python per allocation, and at a
+    stream batch's one or two ten-row slabs no slower than a lookup per
+    row (PERF.md PR 30)."""
     from ..state.columnar import gather_index
     from ..structs.structs import alloc_usage_vec
 
-    node_ids: List[str] = []
+    parts: List[np.ndarray] = []
+    singles: List[str] = []
     vecs: List[Tuple] = []
     counts: List[int] = []
     for entry in entries:
         if len(entry) == 3:     # (index, node_id, delta): one row
-            node_ids.append(entry[1])
+            singles.append(entry[1])
             vecs.append(entry[2])
             counts.append(1)
-        else:                   # (index, slab): its node column
-            slab = entry[1]
-            node_ids.extend(slab.node_ids)
-            vecs.append(alloc_usage_vec(slab.proto))
-            counts.append(len(slab.node_ids))
-    rows = gather_index(node_index, node_ids)
+            continue
+        if singles:
+            parts.append(gather_index(node_index, singles))
+            singles = []
+        slab = entry[1]         # (index, slab): its node column
+        parts.append(gather_index(node_index, slab.node_ids))
+        vecs.append(alloc_usage_vec(slab.proto))
+        counts.append(len(slab.node_ids))
+    if singles:
+        parts.append(gather_index(node_index, singles))
+    rows = (np.concatenate(parts) if parts
+            else np.zeros(0, dtype=np.int64))
     vals = np.repeat(np.array(vecs, dtype=np.int64).reshape(-1, RES_DIMS),
                      counts, axis=0)
     known = rows >= 0

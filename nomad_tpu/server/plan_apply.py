@@ -164,10 +164,13 @@ class _Fits:
     ``rows[i]`` (the array route) plus a per-node dict (the per-node
     routes).  The node-id dict callers read is only built on demand."""
 
-    __slots__ = ("cols", "rows", "fit", "scalar")
+    __slots__ = ("cols", "rows", "fit", "scalar", "indexed")
 
     def __init__(self, cols=None, scalar: Optional[Dict[str, bool]] = None):
         self.cols = cols
+        # Slab rows whose mirror rows came from an indexed node column's
+        # integers (no string handled).
+        self.indexed = 0
         self.rows = np.zeros(0, dtype=np.int64)
         self.fit = np.zeros(0, dtype=bool)
         self.scalar: Dict[str, bool] = scalar if scalar is not None else {}
@@ -569,6 +572,7 @@ class PlanApplier:
             out = self._fit_columnar(snap, plan, cols, inflight)
         self.metrics.incr_counter("plan.fit.rows_array", len(out.rows))
         self.metrics.incr_counter("plan.fit.rows_scalar", len(out.scalar))
+        indexed = out.indexed
         every = colmod.guard_every()
         if cols is not None and every > 0 \
                 and (n_plans == 1 or out.all_fit()):
@@ -582,6 +586,10 @@ class PlanApplier:
                         "plan.evaluate.guard", start=start):
                     out = self._guard_fit(snap, plan, cols, inflight, out)
                 self.metrics.measure_since("plan.evaluate.guard", start)
+        # After the guard's run, so right before the pass's
+        # plan.evaluate sample: a window's edge (benchmarks read the
+        # counter per such sample) then falls between them rarely.
+        self.metrics.incr_counter("plan.fit.rows_indexed", indexed)
         return out
 
     @staticmethod
@@ -646,9 +654,12 @@ class PlanApplier:
             forced = set(scalar).union(*(p.net_nodes for p in inflight))
 
         parts: List[Tuple[np.ndarray, tuple]] = []
+        indexed = 0
         for slab in array_slabs:
             ids = slab.node_ids
             rows = colmod.gather_index(cols.row_of, ids)
+            if type(ids) is s.NodeColumn:   # rows from its integers
+                indexed += len(ids)
             bad = (rows < 0) | (rows >= cols.n)
             if forced:
                 bad |= np.fromiter(map(forced.__contains__, ids),
@@ -660,6 +671,7 @@ class PlanApplier:
             parts.append((rows, s.alloc_usage_vec(slab.proto)))
 
         out = _Fits(cols)
+        out.indexed = indexed
         all_rows = (np.concatenate([rows for rows, _ in parts])
                     if parts else out.rows)
         if all_rows.size:

@@ -55,6 +55,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..structs.structs import NodeColumn
+
 logger = logging.getLogger("nomad_tpu.state.columnar")
 
 RES_DIMS = 4
@@ -140,9 +142,22 @@ def reset_counters() -> None:
 
 def gather_index(index: Dict[str, int], keys) -> np.ndarray:
     """``index[key]`` for every key as one int64 array, -1 where the
-    key is absent (one C-level pass: no Python frame per key)."""
+    key is absent (one C-level pass: no Python frame per key).  An
+    indexed node column (structs.NodeColumn) is answered from its
+    integers, ``table.rows_in(index)[idx]``: no string handled, and
+    ``index`` must then be an append-only row index (NodeTable.rows_in
+    keeps the table's rows per index object)."""
+    if type(keys) is NodeColumn:
+        return keys.table.rows_in(index)[keys.idx]
     return np.fromiter(map(index.get, keys, itertools.repeat(-1)),
                        np.int64, len(keys))
+
+
+def _strings(keys):
+    """``keys`` as strings.  The guards' references read an indexed
+    column's strings, never its integers: they are the route a wrong
+    table or permutation must not share."""
+    return keys.strings() if type(keys) is NodeColumn else keys
 
 
 def slab_rows(slab, row_of: Dict[str, int]) -> np.ndarray:
@@ -152,11 +167,12 @@ def slab_rows(slab, row_of: Dict[str, int]) -> np.ndarray:
     index: a row index is append-only and a slab's node column immutable
     post-insert, so a complete answer never changes.  For readers that
     meet the same slab again and again (the plan applier's guard over
-    still-pending slabs); one-shot readers call gather_index."""
+    still-pending slabs, which is why this reads the column's strings);
+    one-shot readers call gather_index."""
     cached = getattr(slab, "_rows", None)
     if cached is not None and cached[0] is row_of:
         return cached[1]
-    rows = gather_index(row_of, slab.node_ids)
+    rows = gather_index(row_of, _strings(slab.node_ids))
     if rows.size and rows.min() >= 0:
         slab._rows = (row_of, rows)
     return rows
@@ -173,8 +189,8 @@ def add_node_counts(out: np.ndarray, pos_of: Dict[str, int], node_ids,
                     vec) -> None:
     """``out[pos_of[nid]] += vec`` once per occurrence of ``nid`` in
     ``node_ids`` (a slab's node column); ids ``pos_of`` does not hold
-    are skipped."""
-    pos = gather_index(pos_of, node_ids)
+    are skipped.  The plan-fit guard's reference."""
+    pos = gather_index(pos_of, _strings(node_ids))
     add_counts(out, pos[pos >= 0], vec)
 
 

@@ -1390,6 +1390,93 @@ class LazyNames(_LazyStrs):
         return f"{self.prefix}[{i}]"
 
 
+class NodeTable:
+    """The node ids of one encoded fleet, in the row order of its
+    tensors: what every NodeColumn cut from that encoding shares.  Made
+    once with the fleet's static tensors (ops/encode), not per batch; a
+    slab that outlives the encoding keeps it alive."""
+
+    __slots__ = ("ids", "packed", "_perms")
+
+    # Row permutations kept, one per index dict asked about (the store's
+    # mirror, the resident mirror, a snapshot's private copy).
+    PERMS_KEPT = 4
+
+    def __init__(self, node_ids) -> None:
+        import numpy as np
+
+        self.ids = np.array(node_ids, dtype=object)
+        # Each id as the struct codec writes it; filled by the codec the
+        # first time it packs a column of this table (codec/native.py).
+        self.packed = None
+        self._perms: tuple = ()
+
+    def rows_in(self, index: Dict[str, int]):
+        """``index[id]`` of every id of the table as one int64 array, -1
+        where the index lacks it, kept per index OBJECT.  ``index`` must
+        be append-only (a row index: a key never moves or leaves), so an
+        answer without a -1 stays right, and one with a -1 is computed
+        again once the index has grown."""
+        n = len(index)
+        for kept, perm, complete, size in self._perms:
+            if kept is index and (complete or size == n):
+                return perm
+        # At call time, not import time: state/ stands on this module.
+        from ..state.columnar import gather_index
+
+        perm = gather_index(index, self.ids)
+        entry = (index, perm, bool(perm.size == 0 or perm.min() >= 0), n)
+        self._perms = (entry,) + tuple(
+            e for e in self._perms if e[0] is not index
+        )[:self.PERMS_KEPT - 1]
+        return perm
+
+
+class NodeColumn(_LazyStrs):
+    """A slab's node column as the device returned it: rows ``idx`` of
+    the encoded fleet ``table``.  A sequence of node-id strings to
+    whatever reads it as one; readers that want mirror rows or log bytes
+    take them from the integers (state/columnar.gather_index,
+    codec/native.pack_column) and handle no string."""
+
+    __slots__ = ("table", "idx", "_strs")
+
+    def __init__(self, table: NodeTable, idx) -> None:
+        super().__init__(len(idx))
+        self.table = table
+        self.idx = idx
+        self._strs: Optional[List[str]] = None
+
+    def strings(self) -> List[str]:
+        """The column as the list of its strings, made by one gather
+        (no Python call per item) on first use and kept: a slab is read
+        as strings again and again by the same few readers (the
+        plan-fit guard's reference, by-node indexing).  Read-only."""
+        strs = self._strs
+        if strs is None:
+            strs = self._strs = self.table.ids[self.idx].tolist()
+        return strs
+
+    def _make(self, i: int) -> str:
+        strs = self._strs
+        return strs[i] if strs is not None else self.table.ids[self.idx[i]]
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return NodeColumn(self.table, self.idx[i])
+        return super().__getitem__(i)
+
+    def __iter__(self):
+        return iter(self.strings())
+
+    def __eq__(self, other):
+        if isinstance(other, (list, _LazyStrs)):
+            return list(self) == list(other)
+        return NotImplemented
+
+    __hash__ = None
+
+
 @dataclass
 class AllocSlab:
     """Columnar batch of placements sharing one prototype allocation.

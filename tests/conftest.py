@@ -216,6 +216,9 @@ def served_job(data_dir=None, count=2, nodes=3):
     if data_dir is not None:
         cfg.dev_mode = False
         cfg.data_dir = cfg.server.data_dir = str(data_dir)
+        # Outside dev mode the server listens on ports.rpc (4647):
+        # ephemeral, for durable agents of test files run side by side.
+        cfg.ports.rpc = 0
     agent = Agent(cfg)
     agent.start()
     try:

@@ -79,7 +79,7 @@ def sink(tmp_path_factory):
 
 
 def test_there_are_sink_metrics():
-    assert len(METRICS) >= 42
+    assert len(METRICS) >= 44
 
 
 @pytest.mark.parametrize("spec", METRICS)
@@ -124,6 +124,25 @@ def test_plans_per_submission_reads_what_every_plan_path_publishes(
     for key in ("nomad.plan.queue_wait", "nomad.plan.commit_wait",
                 "nomad.plan.wake"):
         assert latest["SampleTotals"][key][0] <= passes
+
+
+@pytest.mark.parametrize("name", [
+    "plan_fit_indexed_rows_per_submission.tput",
+    "plan_fit_indexed_rows_per_submission.lat"])
+def test_indexed_rows_counter_is_published_by_every_columnar_pass(
+        sink, name):
+    """``nomad.plan.fit.rows_indexed`` counts, per pass of the columnar
+    fit re-check, the slab rows whose mirror rows came from an indexed
+    node column's integers: published by every such pass, 0 for the
+    served jobs' two-row plans (under ``ARRAY_MIN_ROWS``: the per-node
+    route reads strings) and for the hog's per-object plan."""
+    with open(os.path.join(ROOT, "benchmarks", "metrics",
+                           name + ".json")) as fh:
+        spec = json.load(fh)
+    assert (spec["reader"], spec["key"], spec["per"]) == (
+        "counter_per_sample", "nomad.plan.fit.rows_indexed",
+        "nomad.plan.evaluate")
+    assert sink["latest"]["CounterTotals"][spec["key"]] == 0
 
 
 def test_fused_counter_counts_the_batches_the_device_answered(sink):
