@@ -465,6 +465,7 @@ class TPUBatchScheduler:
             m.incr_counter("batch.constraint_row_reuse",
                            stats.constraint_row_reuse)
             m.incr_counter("batch.dp_specs", stats.dp_specs)
+            m.incr_counter("batch.dp_dense_specs", stats.dp_dense_specs)
             m.incr_counter("batch.multi_round_specs",
                            stats.multi_round_specs)
             m.incr_counter("batch.spec_passes", stats.spec_passes)
@@ -845,6 +846,7 @@ class TPUBatchScheduler:
             stats.constraint_rows_seconds = kstats["constraint_rows_seconds"]
             stats.constraint_row_reuse = kstats["constraint_row_reuse"]
             stats.dp_specs = kstats["dp_specs"]
+            stats.dp_dense_specs = kstats["dp_dense_specs"]
             stats.commit_seconds = kstats.get("commit_seconds", 0.0)
             stats.dispatch_seconds = kstats.get("dispatch_seconds", 0.0)
             stats.fetch_seconds = kstats.get("fetch_seconds", 0.0)
@@ -1871,6 +1873,7 @@ class TPUBatchScheduler:
                     fail_cache[sig] = m
             metrics[key] = m
 
+        dp_specs = int(st.dp_active.sum())
         kstats = {
             "device_seconds": device_seconds,
             "encode_seconds": encode_seconds,
@@ -1886,7 +1889,11 @@ class TPUBatchScheduler:
             "precomp_rows": len(st.row_stamps),
             "constraint_rows_seconds": sum(b - a for a, b in st.row_stamps),
             "constraint_row_reuse": st.rows_reused,
-            "dp_specs": int(st.dp_active.sum()),
+            "dp_specs": dp_specs,
+            # Of those, the ones whose program read its per-value tables
+            # in the dense form: all or none, by the dispatched v_pad.
+            "dp_dense_specs": (dp_specs if kernels.dp_dense(
+                st.dp_used.shape[1]) else 0),
         }
         kstats.update(preempt_stats)
         tr = tracing.TRACER
@@ -2537,6 +2544,7 @@ class BatchStats:
         # read, fold and device delta apply; 0 off the resident path.
         self.resident_seconds = 0.0
         self.dp_specs = 0
+        self.dp_dense_specs = 0
         # Fused score-and-commit path (PR 6): whether this batch ran the
         # single-dispatch/single-fetch program, the wall time of that
         # dispatch (upload → device compute → result transfer), the wall

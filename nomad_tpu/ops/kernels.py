@@ -500,6 +500,106 @@ def _disabled_dp(u_pad: int, n_pad: int) -> DPTensors:
     )
 
 
+# The most value codes (``v_pad``, a static shape) up to which a pass reads
+# and writes its per-property-value tables densely (``ValueCodes``).
+# From one reading on the chip (TPU v5 lite, PERF.md section 6, PR 38):
+# the seven accesses of a pass alone in a 2,000-iteration loop at n_pad
+# 5,120, u_pad 64 took 247-278 us a pass in the scatter/gather form at
+# every v_pad from 128 to 8,192 (7.5 ns an element, one at a time) and
+# 6.5 / 8.9 / 14.1 / 26.7 / 51.6 us in the dense form at v_pad 128 / 256 /
+# 512 / 1,024 / 2,048: linear at 0.025 us a value, so the forms cross
+# near v_pad 10,000 whatever n_pad is (both are linear in it).  1,024 is
+# the largest bucket read at a ninefold margin; the bound stays a tenth
+# of the crossing because [v_pad, n_pad] is also what the compiler may
+# have to hold if a reduction does not fuse (268 MB as f32 at the
+# 65,536-node bucket).  A property with a value per node
+# (``${node.unique.name}``: v_pad 8,192 on 5,000 nodes) keeps the
+# scatter/gather form.
+DP_DENSE_MAX_V = 1024
+
+
+def dp_dense(v_pad: int) -> bool:
+    """Whether a program with ``v_pad`` value codes takes the dense form
+    of the four accesses below: decided at trace time from that static
+    shape alone, the same on every backend."""
+    return v_pad <= DP_DENSE_MAX_V
+
+
+class ValueCodes(NamedTuple):
+    """One pass's view of a spec's property column: every node's value
+    code, and how the per-value tables (``[v_pad]``) are reached from it.
+
+    ``hot[v, n] = (code[n] == v)``, ``[v_pad, n_pad]`` with ``n_pad``
+    minor, makes every access below a dense compare-and-reduce on the
+    VPU, the shape ``_byte_histogram_dense`` uses and for its reason: the
+    TPU backend runs a scatter or a gather of ``n_pad`` elements through
+    a ``[v_pad]`` table one element at a time.  ``hot`` None keeps the
+    scatter/gather form, which costs ``n_pad`` steps whatever ``v_pad``
+    is (a property with as many values as nodes would make the dense
+    form quadratic) and is the reference the tests hold the dense form
+    to.  ``max``, ``min`` and ``any`` are exact and exactly one ``v`` is
+    hot per node, so both forms give the same bits, ``fill`` included."""
+
+    code: jnp.ndarray     # [N] int32 in [0, v_pad)
+    hot: jnp.ndarray      # [v_pad, N] bool, or None
+    v_pad: int
+
+
+def value_codes(codes: jnp.ndarray, v_pad: int) -> ValueCodes:
+    """``codes`` ([N] int32, MISSING = -1) clipped into the tables' range,
+    in the form ``dp_dense`` gives this ``v_pad``."""
+    code = jnp.clip(codes, 0, v_pad - 1)
+    hot = None
+    if dp_dense(v_pad):
+        hot = code[None, :] == jnp.arange(v_pad, dtype=jnp.int32)[:, None]
+    return ValueCodes(code=code, hot=hot, v_pad=v_pad)
+
+
+def dp_used_lookup(vc: ValueCodes, used_row: jnp.ndarray) -> jnp.ndarray:
+    """[N] bool: is the node's value in the spec's used set
+    (``used_row[code]``)."""
+    if vc.hot is None:
+        return used_row[vc.code]
+    return jnp.any(vc.hot & used_row[:, None], axis=0)
+
+
+def dp_best_per_value(vc: ValueCodes, vals: jnp.ndarray, fill, *,
+                      largest: bool) -> jnp.ndarray:
+    """[v_pad]: the largest (or smallest) of ``vals`` over the nodes of
+    each value, ``fill`` for a value no node has (``fill`` is the
+    reduction's identity for every ``vals`` the callers pass)."""
+    if vc.hot is None:
+        table = jnp.full(vc.v_pad, fill, dtype=vals.dtype).at[vc.code]
+        return table.max(vals) if largest else table.min(vals)
+    spread = jnp.where(vc.hot, vals[None, :], fill)
+    return (jnp.max if largest else jnp.min)(spread, axis=1)
+
+
+def dp_read_back(vc: ValueCodes, table: jnp.ndarray, fill, *,
+                 largest: bool) -> jnp.ndarray:
+    """[N]: each node's own value's entry of a per-value table
+    (``table[code]``); ``fill`` and ``largest`` as the table was made."""
+    if vc.hot is None:
+        return table[vc.code]
+    spread = jnp.where(vc.hot, table[:, None], fill)
+    return (jnp.max if largest else jnp.min)(spread, axis=0)
+
+
+def dp_used_update(vc: ValueCodes, hit: jnp.ndarray) -> jnp.ndarray:
+    """[v_pad] bool: the values of the nodes in ``hit``."""
+    if vc.hot is None:
+        return jnp.zeros(vc.v_pad, dtype=bool).at[vc.code].max(hit)
+    return jnp.any(vc.hot & hit[None, :], axis=1)
+
+
+def dp_columns(attr_values: jnp.ndarray, col: jnp.ndarray) -> jnp.ndarray:
+    """[U, N]: every spec's property column, taken once a batch (a gather
+    of U contiguous rows) so that a pass reads its own as a row slice
+    instead of a strided column of ``attr_values`` ([N, K])."""
+    with jax.named_scope("property_columns"):
+        return attr_values.T[jnp.clip(col, 0, attr_values.shape[1] - 1)]
+
+
 def placement_rounds(
     feas: jnp.ndarray,         # [U, N] bool — static feasibility
     used0: jnp.ndarray,        # [N, 4] int32 — usage incl. reserved
@@ -578,9 +678,10 @@ def _placement_rounds_impl(
     to all three (rank.go:190-238; concrete dynamic port *values* are
     assigned host-side at finalize, which device-side capacity accounting
     makes safe).  distinct_property: a per-spec used-value bitset masks
-    feasibility; a within-pass scatter-min keeps only the best-ranked
-    node per property value (propertyset.go:150), and the nodes it drops
-    wait for the spec's next pass.
+    feasibility; a within-pass per-value best (``ValueCodes``: dense up
+    to ``DP_DENSE_MAX_V`` value codes, scatter/gather above) keeps only
+    the best-ranked node per property value (propertyset.go:150), and
+    the nodes it drops wait for the spec's next pass.
     """
     u_pad, n_pad = feas.shape
     v_pad = dp.used0.shape[1]
@@ -588,6 +689,7 @@ def _placement_rounds_impl(
     jit_seed = jitter_seed(rng_key)
     node_idx = jnp.arange(n_pad, dtype=jnp.int32)
     big_idx = jnp.int32(n_pad + 1)
+    dp_codes = dp_columns(dp.attr_values, dp.col) if use_dp else None
 
     def place_pass(carry, u):
         def try_place(carry):
@@ -595,40 +697,44 @@ def _placement_rounds_impl(
              bw_used, port_words, dyn_free, dp_used, commit_scores,
              commit_coll, slots, slot_scores, slot_coll) = carry
 
-            cap_left = capacity - used                       # [N, 4]
-            fits = jnp.all(ask[u][None, :] <= cap_left, axis=1)
-            collisions = job_counts[job_index[u]]            # [N] int32
-            ok = feas[u] & fits
-            ok = ok & jnp.where(distinct_hosts[u], collisions == 0, True)
+            with jax.named_scope("prefix"):
+                cap_left = capacity - used                   # [N, 4]
+                fits = jnp.all(ask[u][None, :] <= cap_left, axis=1)
+                collisions = job_counts[job_index[u]]        # [N] int32
+                ok = feas[u] & fits
+                ok = ok & jnp.where(distinct_hosts[u], collisions == 0,
+                                    True)
 
-            # Network feasibility (bandwidth + reserved conflicts +
-            # dynamic capacity); statically absent when the batch has no
-            # network asks.
-            if use_net:
-                bw_ok = bw_used + net.mbits[u] <= net.bw_cap
-                resv_hit = jnp.any(
-                    (port_words & net.resv_words[u][None, :]) != 0, axis=1)
-                dyn_ok = dyn_free >= net.dyn_need[u]
-                ok = ok & jnp.where(net.active[u],
-                                    bw_ok & ~resv_hit & dyn_ok, True)
+                # Network feasibility (bandwidth + reserved conflicts +
+                # dynamic capacity); statically absent when the batch has
+                # no network asks.
+                if use_net:
+                    bw_ok = bw_used + net.mbits[u] <= net.bw_cap
+                    resv_hit = jnp.any(
+                        (port_words & net.resv_words[u][None, :]) != 0,
+                        axis=1)
+                    dyn_ok = dyn_free >= net.dyn_need[u]
+                    ok = ok & jnp.where(net.active[u],
+                                        bw_ok & ~resv_hit & dyn_ok, True)
 
             # distinct_property feasibility: node must have the property
             # and its value must be unused (propertyset.go:150).
             if use_dp:
-                col = jnp.clip(dp.col[u], 0, dp.attr_values.shape[1] - 1)
-                codes = dp.attr_values[:, col]                    # [N]
-                code_c = jnp.clip(codes, 0, v_pad - 1)
-                dp_ok = (codes != MISSING) & ~dp_used[u, code_c]
-                ok = ok & jnp.where(dp.active[u], dp_ok, True)
+                with jax.named_scope("dp_feasible"):
+                    codes = dp_codes[u]                           # [N]
+                    vc = value_codes(codes, v_pad)
+                    dp_ok = ((codes != MISSING)
+                             & ~dp_used_lookup(vc, dp_used[u]))
+                    ok = ok & jnp.where(dp.active[u], dp_ok, True)
             else:
-                code_c = None
+                codes = None
 
             # Commit the top-k scored nodes (k = remaining count, bounded
             # by feasible nodes) — one alloc per node this pass.
             k = jnp.minimum(remaining_count[u],
                             jnp.sum(ok).astype(jnp.int32))
             carry, placed = lax.cond(
-                k > 0, lambda c: commit(c, ok, collisions, code_c, k),
+                k > 0, lambda c: commit(c, ok, collisions, codes, k),
                 lambda c: (c, jnp.int32(0)), carry)
             # Capacity early-exit, per spec: a pass over a fleet on which
             # no node has room for the ask on capacity alone does not
@@ -636,81 +742,99 @@ def _placement_rounds_impl(
             # necessary condition only and placements are unchanged).
             return carry, placed, jnp.any(fits)
 
-        def commit(carry, ok, collisions, code_c, k):
+        def commit(carry, ok, collisions, codes, k):
             (used, job_counts, remaining_count, placements,
              bw_used, port_words, dyn_free, dp_used, commit_scores,
              commit_coll, slots, slot_scores, slot_coll) = carry
-            base_score = _score_fit(used, ask[u], denom)
-            score = base_score - penalty[u] * collisions.astype(jnp.float32)
-            score = score + tie_jitter(jit_seed, u, node_idx)
-            scored = jnp.where(ok, score, NEG_INF)
+            with jax.named_scope("score"):
+                base_score = _score_fit(used, ask[u], denom)
+                score = (base_score
+                         - penalty[u] * collisions.astype(jnp.float32))
+                score = score + tie_jitter(jit_seed, u, node_idx)
+                scored = jnp.where(ok, score, NEG_INF)
 
             # Threshold bisection instead of a full argsort: same
             # selection, same tie order, ~100x less device work at N≈50k.
-            sel = _select_top_k(scored, ok, k)
+            with jax.named_scope("select"):
+                sel = _select_top_k(scored, ok, k)
 
             # Within-pass value dedup for distinct_property: among
             # selected nodes sharing a property value, keep only the
             # best-scored (ties by lowest node index — stable-sort order).
             if use_dp:
-                sel_score = jnp.where(sel, scored, jnp.float32(NEG_INF))
-                best_per_code = jnp.full(v_pad, NEG_INF, dtype=jnp.float32
-                                         ).at[code_c].max(sel_score)
-                cand_dp = sel & (sel_score >= best_per_code[code_c])
-                best_idx = jnp.full(v_pad, big_idx, dtype=jnp.int32
-                                    ).at[code_c].min(
-                    jnp.where(cand_dp, node_idx, big_idx))
-                keep_dp = cand_dp & (node_idx == best_idx[code_c])
-                sel = jnp.where(dp.active[u], keep_dp, sel)
+                with jax.named_scope("dp_dedup"):
+                    # Made anew from the codes, not handed over from
+                    # try_place: a [v_pad, N] operand of the cond would
+                    # be written out and read back once a pass.
+                    vc = value_codes(codes, v_pad)
+                    neg = jnp.float32(NEG_INF)
+                    sel_score = jnp.where(sel, scored, neg)
+                    best_per_code = dp_best_per_value(
+                        vc, sel_score, neg, largest=True)
+                    cand_dp = sel & (sel_score >= dp_read_back(
+                        vc, best_per_code, neg, largest=True))
+                    best_idx = dp_best_per_value(
+                        vc, jnp.where(cand_dp, node_idx, big_idx), big_idx,
+                        largest=False)
+                    keep_dp = cand_dp & (node_idx == dp_read_back(
+                        vc, best_idx, big_idx, largest=False))
+                    sel = jnp.where(dp.active[u], keep_dp, sel)
 
-            sel_i = sel.astype(jnp.int32)
-            placed = jnp.sum(sel_i)
-            used = used + sel_i[:, None] * ask[u][None, :]
-            job_counts = job_counts.at[job_index[u]].add(sel_i)
-            if not slot_m:
-                # The dense [U, N] placement matrix only feeds the
-                # matrix-form compaction; in slot mode the slot record
-                # IS the placement output, so the carry compiles away.
-                placements = placements.at[u].add(sel_i)
+            with jax.named_scope("commit_usage"):
+                sel_i = sel.astype(jnp.int32)
+                placed = jnp.sum(sel_i)
+                used = used + sel_i[:, None] * ask[u][None, :]
+                job_counts = job_counts.at[job_index[u]].add(sel_i)
+                if not slot_m:
+                    # The dense [U, N] placement matrix only feeds the
+                    # matrix-form compaction; in slot mode the slot record
+                    # IS the placement output, so the carry compiles away.
+                    placements = placements.at[u].add(sel_i)
 
             if slot_m:
                 # Compact slot record: append this commit's node indices
                 # to spec u's slot row in ascending-node order — the COO
                 # payload is built from this, no nonzero pass later.
-                pos = jnp.cumsum(sel.astype(jnp.int32))
-                offset = count[u] - remaining_count[u]  # placed so far
-                dest = jnp.where(sel, offset + pos - 1, jnp.int32(slot_m))
-                slots = slots.at[u, dest].set(node_idx, mode="drop")
-                if with_scores:
-                    # Commit-aligned score record: same dest scatter, so
-                    # the [U, N] score carries below compile away.
-                    slot_scores = slot_scores.at[u, dest].set(
-                        base_score, mode="drop")
-                    slot_coll = slot_coll.at[u, dest].set(
-                        collisions, mode="drop")
+                with jax.named_scope("slots"):
+                    pos = jnp.cumsum(sel.astype(jnp.int32))
+                    offset = count[u] - remaining_count[u]  # placed so far
+                    dest = jnp.where(sel, offset + pos - 1,
+                                     jnp.int32(slot_m))
+                    slots = slots.at[u, dest].set(node_idx, mode="drop")
+                    if with_scores:
+                        # Commit-aligned score record: same dest scatter,
+                        # so the [U, N] score carries below compile away.
+                        slot_scores = slot_scores.at[u, dest].set(
+                            base_score, mode="drop")
+                        slot_coll = slot_coll.at[u, dest].set(
+                            collisions, mode="drop")
 
-            remaining_count = remaining_count.at[u].add(-placed)
+            with jax.named_scope("commit_usage"):
+                remaining_count = remaining_count.at[u].add(-placed)
 
-            if use_net:
-                commit_net = net.active[u]
-                bw_used = bw_used + jnp.where(commit_net,
-                                              sel_i * net.mbits[u], 0)
-                port_words = jnp.where(
-                    (commit_net & sel)[:, None],
-                    port_words | net.resv_words[u][None, :], port_words)
-                dyn_free = dyn_free - jnp.where(commit_net,
-                                                sel_i * net.dyn_need[u], 0)
+                if use_net:
+                    commit_net = net.active[u]
+                    bw_used = bw_used + jnp.where(commit_net,
+                                                  sel_i * net.mbits[u], 0)
+                    port_words = jnp.where(
+                        (commit_net & sel)[:, None],
+                        port_words | net.resv_words[u][None, :],
+                        port_words)
+                    dyn_free = dyn_free - jnp.where(
+                        commit_net, sel_i * net.dyn_need[u], 0)
             if use_dp:
-                dp_upd = jnp.zeros(v_pad, dtype=bool).at[code_c].max(
-                    sel & dp.active[u])
-                dp_used = dp_used.at[u].set(dp_used[u] | dp_upd)
+                with jax.named_scope("dp_update"):
+                    dp_upd = dp_used_update(vc, sel & dp.active[u])
+                    dp_used = lax.dynamic_update_index_in_dim(
+                        dp_used, dp_used[u] | dp_upd, u, axis=0)
             # Commit-time AllocMetric side-outputs: pure binpack score and
             # the collision count behind any anti-affinity penalty.
             if with_scores and not slot_m:
-                commit_scores = commit_scores.at[u].set(jnp.where(
-                    sel, base_score, commit_scores[u]))
-                commit_coll = commit_coll.at[u].set(jnp.where(
-                    sel, collisions, commit_coll[u]))
+                with jax.named_scope("commit_usage"):
+                    commit_scores = commit_scores.at[u].set(jnp.where(
+                        sel, base_score, commit_scores[u]))
+                    commit_coll = commit_coll.at[u].set(jnp.where(
+                        sel, collisions, commit_coll[u]))
             return (used, job_counts, remaining_count, placements,
                     bw_used, port_words, dyn_free, dp_used,
                     commit_scores, commit_coll, slots, slot_scores,

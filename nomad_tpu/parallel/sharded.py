@@ -28,10 +28,16 @@ from ..ops.kernels import (
     NetTensors,
     PlacementResult,
     _score_fit,
+    dp_best_per_value,
+    dp_columns,
+    dp_read_back,
+    dp_used_lookup,
+    dp_used_update,
     jitter_seed,
     pack_scalars,
     spec_major,
     tie_jitter,
+    value_codes,
 )
 from ..ops.encode import MISSING
 
@@ -96,8 +102,9 @@ def sharded_placement_rounds(
     commits are shard-local, mirroring ops/kernels.py (rank.go:190-238).
     ``dp`` replicates the per-spec used-value bitsets; the within-pass
     best-per-value dedup runs as pmax/pmin all-reduces over the value
-    axis so every shard keeps the same winner the single-chip
-    scatter-max/min picks (propertyset.go:150).
+    axis, between the single-chip kernel's own per-value table and its
+    read-back (``kernels.ValueCodes``), so every shard keeps the winner
+    the single-chip pass picks (propertyset.go:150).
 
     Ref: scheduler/rank.go:247 (anti-affinity), feasible.go:148
     (distinct_hosts), SURVEY.md §2.9 node-axis sharding.
@@ -157,6 +164,7 @@ def sharded_placement_rounds(
         c_total = k_cand * d
         big_idx = jnp.int32(n_pad + 1)
         gidx = shard * n_l + jnp.arange(n_l, dtype=jnp.int32)
+        dp_codes_l = dp_columns(dp_attr_l, dp_col_r) if use_dp else None
 
         def place_pass(carry, u):
             (used, jc, remaining, placements,
@@ -176,10 +184,10 @@ def sharded_placement_rounds(
                                     bw_ok & ~resv_hit & dyn_ok, True)
 
             if use_dp:
-                col = jnp.clip(dp_col_r[u], 0, dp_attr_l.shape[1] - 1)
-                codes = dp_attr_l[:, col]              # [N_l]
-                code_c = jnp.clip(codes, 0, v_pad - 1)
-                dp_ok = (codes != MISSING) & ~dp_used[u, code_c]
+                codes = dp_codes_l[u]                  # [N_l]
+                vc = value_codes(codes, v_pad)
+                dp_ok = ((codes != MISSING)
+                         & ~dp_used_lookup(vc, dp_used[u]))
                 ok = ok & jnp.where(dp_active_r[u], dp_ok, True)
 
             score = _score_fit(used, ask_r[u], denom_l)
@@ -212,17 +220,21 @@ def sharded_placement_rounds(
                 # Cross-shard within-pass value dedup: the best-scored
                 # selected node per property value wins globally (ties by
                 # lowest GLOBAL node index), via pmax/pmin over the value
-                # axis — bit-identical to the single-chip scatter-max/min.
-                sel_score = jnp.where(sel, scored, jnp.float32(NEG_INF))
-                best_l = jnp.full(v_pad, NEG_INF, dtype=jnp.float32
-                                  ).at[code_c].max(sel_score)
-                best_g = lax.pmax(best_l, NODE_AXIS)
-                cand_dp = sel & (sel_score >= best_g[code_c])
-                idx_l = jnp.full(v_pad, big_idx, dtype=jnp.int32
-                                 ).at[code_c].min(
-                    jnp.where(cand_dp, gidx, big_idx))
-                idx_g = lax.pmin(idx_l, NODE_AXIS)
-                keep = cand_dp & (gidx == idx_g[code_c])
+                # axis — bit-identical to the single-chip per-value best.
+                neg = jnp.float32(NEG_INF)
+                sel_score = jnp.where(sel, scored, neg)
+                best_g = lax.pmax(
+                    dp_best_per_value(vc, sel_score, neg, largest=True),
+                    NODE_AXIS)
+                cand_dp = sel & (sel_score >= dp_read_back(
+                    vc, best_g, neg, largest=True))
+                idx_g = lax.pmin(
+                    dp_best_per_value(
+                        vc, jnp.where(cand_dp, gidx, big_idx), big_idx,
+                        largest=False),
+                    NODE_AXIS)
+                keep = cand_dp & (gidx == dp_read_back(
+                    vc, idx_g, big_idx, largest=False))
                 sel = jnp.where(dp_active_r[u], keep, sel)
 
             sel_i = sel.astype(jnp.int32)
@@ -242,11 +254,11 @@ def sharded_placement_rounds(
                 dyn_free = dyn_free - jnp.where(
                     commit_net, sel_i * dyn_need_r[u], 0)
             if use_dp:
-                dp_upd_l = jnp.zeros(v_pad, dtype=bool).at[code_c].max(
-                    sel & dp_active_r[u])
                 dp_upd = lax.psum(
-                    dp_upd_l.astype(jnp.int32), NODE_AXIS) > 0
-                dp_used = dp_used.at[u].set(dp_used[u] | dp_upd)
+                    dp_used_update(vc, sel & dp_active_r[u]).astype(
+                        jnp.int32), NODE_AXIS) > 0
+                dp_used = lax.dynamic_update_index_in_dim(
+                    dp_used, dp_used[u] | dp_upd, u, axis=0)
 
             return (used, jc, remaining, placements,
                     bw_used, port_words, dyn_free, dp_used), placed, ran
@@ -474,6 +486,7 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
         if with_dp:
             dp_used_init = dd["dp_used"]
             v_pad = dp_used_init.shape[1]
+            dp_codes_l = dp_columns(ds["attr"], dd["dp_col"])
         else:
             dp_used_init = jnp.zeros((1, 1), dtype=bool)
             v_pad = 1
@@ -504,10 +517,10 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
                 ok = ok & jnp.where(dd["net_active"][u],
                                     bw_ok & ~resv_hit & dyn_ok, True)
             if with_dp:
-                col = jnp.clip(dd["dp_col"][u], 0, ds["attr"].shape[1] - 1)
-                codes = ds["attr"][:, col]
-                code_c = jnp.clip(codes, 0, v_pad - 1)
-                dp_ok = (codes != MISSING) & ~dp_used[u, code_c]
+                codes = dp_codes_l[u]
+                vc = value_codes(codes, v_pad)
+                dp_ok = ((codes != MISSING)
+                         & ~dp_used_lookup(vc, dp_used[u]))
                 ok = ok & jnp.where(dd["dp_active"][u], dp_ok, True)
 
             base_score = score_fit(used, ask_r[u], denom_l)
@@ -535,16 +548,20 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
             sel = jnp.zeros(n_l, dtype=bool).at[loc_idx].set(my_sel) & ok
 
             if with_dp:
-                sel_score = jnp.where(sel, scored, jnp.float32(NEG_INF))
-                best_l = jnp.full(v_pad, NEG_INF, dtype=jnp.float32
-                                  ).at[code_c].max(sel_score)
-                best_g = lax.pmax(best_l, NODE_AXIS)
-                cand_dp = sel & (sel_score >= best_g[code_c])
-                idx_l = jnp.full(v_pad, big_idx, dtype=jnp.int32
-                                 ).at[code_c].min(
-                    jnp.where(cand_dp, gidx, big_idx))
-                idx_g = lax.pmin(idx_l, NODE_AXIS)
-                keep = cand_dp & (gidx == idx_g[code_c])
+                neg = jnp.float32(NEG_INF)
+                sel_score = jnp.where(sel, scored, neg)
+                best_g = lax.pmax(
+                    dp_best_per_value(vc, sel_score, neg, largest=True),
+                    NODE_AXIS)
+                cand_dp = sel & (sel_score >= dp_read_back(
+                    vc, best_g, neg, largest=True))
+                idx_g = lax.pmin(
+                    dp_best_per_value(
+                        vc, jnp.where(cand_dp, gidx, big_idx), big_idx,
+                        largest=False),
+                    NODE_AXIS)
+                keep = cand_dp & (gidx == dp_read_back(
+                    vc, idx_g, big_idx, largest=False))
                 sel = jnp.where(dd["dp_active"][u], keep, sel)
 
             sel_i = sel.astype(jnp.int32)
@@ -581,11 +598,11 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
                 dyn_free = dyn_free - jnp.where(
                     commit_net, sel_i * dd["dyn_need"][u], 0)
             if with_dp:
-                dp_upd_l = jnp.zeros(v_pad, dtype=bool).at[code_c].max(
-                    sel & dd["dp_active"][u])
                 dp_upd = lax.psum(
-                    dp_upd_l.astype(jnp.int32), NODE_AXIS) > 0
-                dp_used = dp_used.at[u].set(dp_used[u] | dp_upd)
+                    dp_used_update(vc, sel & dd["dp_active"][u]).astype(
+                        jnp.int32), NODE_AXIS) > 0
+                dp_used = lax.dynamic_update_index_in_dim(
+                    dp_used, dp_used[u] | dp_upd, u, axis=0)
             return (used, jc, remaining, bw_used, port_words, dyn_free,
                     dp_used, slots, sscores, scoll), placed, ran
 
