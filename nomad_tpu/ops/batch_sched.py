@@ -33,7 +33,7 @@ import numpy as np
 
 from .. import fault
 from ..scheduler.generic import GenericScheduler
-from ..utils import knobs, tracing
+from ..utils import knobs, telemetry, tracing
 from ..utils.platform import ensure_compile_cache
 from ..utils.telemetry import NULL_TELEMETRY
 from ..scheduler.scheduler import register_scheduler
@@ -262,69 +262,26 @@ class _CollectingScheduler(GenericScheduler):
             for tg, names, prevs in order]
 
 
+# The contiguous stages of three of a batch's phases, each timed by a
+# tracing.Stages: samples ``worker.invoke_scheduler.<phase>.<stage>``
+# that sum to the phase's own, and spans ``batch.<phase>.<stage>``.
+#
+# The device call (live spans, children of ``batch.device``): stage +
+# dispatch run in ``_dispatch_device`` / ``_dispatch_mesh``, the other
+# three in ``_fetch_device`` (one thread, possibly with another batch's
+# prepare in between when the drain is pipelined).
 DEVICE_STAGES = ("stage", "dispatch", "wait", "fetch", "decode")
-
-
-class _DeviceStages:
-    """The device call's five contiguous stages (DEVICE_STAGES), timed
-    for the sink always and for the tracer when it is armed.
-
-    A boundary is ONE ``perf_counter`` stamp that closes a stage and
-    opens the next, so the stages tile ``t1 → t1 + device_seconds`` and
-    a stage's sample and span share their stamps.  Armed, each stage is
-    a live ``batch.device.<stage>`` span (entered as a profiler
-    TraceAnnotation too) whose parent is the ``batch.device`` span that
-    ``_finalize_device_outputs`` records afterwards under the id
-    reserved here.  Disarmed, a boundary costs its stamp and a dict
-    store.  ``with stages:`` closes whatever stage an exception left
-    open.  stage + dispatch run in ``_dispatch_device`` /
-    ``_dispatch_mesh``, the other three in ``_fetch_device`` (one
-    thread, possibly with another batch's prepare in between when the
-    drain is pipelined)."""
-
-    __slots__ = ("seconds", "parent_id", "_tr", "_open", "_name", "_t")
-
-    def __init__(self) -> None:
-        self.seconds: Dict[str, float] = {}
-        self._tr = tracing.TRACER
-        self.parent_id = (self._tr.reserve_id()
-                          if self._tr is not None else 0)
-        self._open = None
-        self._name = ""
-        self._t = 0.0
-
-    def begin(self, name: str, t: Optional[float] = None) -> float:
-        """Open ``name`` at ``t`` (now when not given), closing the
-        stage that was running at the same stamp."""
-        if t is None:
-            t = time.perf_counter()
-        if self._name:
-            self.end(t)
-        self._name, self._t = name, t
-        if self._tr is not None:
-            self._open = self._tr.span("batch.device." + name,
-                                       parent_id=self.parent_id,
-                                       annotate=True, start=t)
-            self._open.__enter__()
-        return t
-
-    def end(self, t: Optional[float] = None) -> float:
-        if t is None:
-            t = time.perf_counter()
-        if self._name:
-            self.seconds[self._name] = t - self._t
-            self._name = ""
-            if self._open is not None:
-                self._open.finish(t)
-                self._open = None
-        return t
-
-    def __enter__(self) -> "_DeviceStages":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        self.end()
-        return False
+# Encode, up to the dispatch point: the fleet list, the vocabulary and
+# the cluster tensors (cache lookup or static encode); the usage rows
+# (the resident mirror's acquire, else the usage walk); the specs'
+# tensors, the plan and the padding; the sparse job-count,
+# distinct_property and dynamic entries; the mirror loan and the two
+# host packs.
+ENCODE_STAGES = ("nodes", "resident", "specs", "sparse", "pack")
+# Expand (span ``batch.metrics``), accumulated over the spec loop: the
+# preemption commit; a spec's NodeColumn and unplaced count; its
+# ``scores`` dictionary; the failure forensics, their memo, the tail.
+EXPAND_STAGES = ("preempt", "slots", "scores", "failures")
 
 
 class _PreparedBatch:
@@ -333,7 +290,7 @@ class _PreparedBatch:
     at most one of these between dispatch and complete)."""
 
     __slots__ = ("evals", "scheds", "specs", "spec_list", "stats", "t0",
-                 "handle", "probe", "routed")
+                 "c0", "handle", "probe", "routed")
 
     def __init__(self, evals):
         self.evals = evals
@@ -342,9 +299,18 @@ class _PreparedBatch:
         self.spec_list = []
         self.stats = BatchStats()
         self.t0 = time.perf_counter()
+        self.c0 = time.thread_time()    # this thread's CPU clock at t0
         self.handle = None      # _dispatch_device output (device in flight)
         self.probe = False      # this batch is the breaker's half-open probe
         self.routed = False     # breaker-open: already oracle-processed
+
+    def close(self) -> "BatchStats":
+        """The batch's last stamp: its wall time and, at the same two
+        points, the CPU time of this thread inside it."""
+        self.stats.total_seconds = time.perf_counter() - self.t0
+        self.stats.cpu_seconds = time.thread_time() - self.c0
+        self.stats.num_evals = len(self.evals)
+        return self.stats
 
 
 class TPUBatchScheduler:
@@ -420,7 +386,8 @@ class TPUBatchScheduler:
                        resident_hits=stats.resident_hits,
                        delta_rows=stats.delta_rows,
                        h2d_bytes=stats.h2d_bytes,
-                       delta_apply_s=round(stats.delta_apply_seconds, 6))
+                       delta_apply_s=round(stats.delta_apply_seconds, 6),
+                       cpu_ms=round(stats.cpu_seconds * 1000.0, 4))
                 if self.snapshot_index is not None:
                     sp.set(snapshot_index=self.snapshot_index)
         self._emit_batch_stats(stats)
@@ -432,12 +399,13 @@ class TPUBatchScheduler:
         # sibling in the family (DEFAULT_BUCKETS is ms-calibrated).
         m.add_sample("worker.invoke_scheduler",
                      stats.total_seconds * 1000.0)
+        m.add_sample("worker.invoke_scheduler.cpu",
+                     stats.cpu_seconds * 1000.0)
         m.add_sample("worker.invoke_scheduler.prepare",
                      stats.prepare_seconds * 1000.0)
-        m.add_sample("worker.invoke_scheduler.phase1",
-                     stats.phase1_seconds * 1000.0)
-        m.add_sample("worker.invoke_scheduler.phase2",
-                     stats.phase2_seconds * 1000.0)
+        # The collector's pauses since the worker's last batch, on
+        # whichever thread they fell (0.0 when nothing was collected).
+        telemetry.publish_gc_pauses(m)
         # Device-path phases only when the kernel actually ran: oracle-
         # routed or ask-less batches would otherwise flood the percentile
         # windows with zeros exactly when the device path is degraded.
@@ -446,18 +414,22 @@ class TPUBatchScheduler:
                          stats.encode_seconds * 1000.0)
             m.add_sample("worker.invoke_scheduler.device",
                          stats.device_seconds * 1000.0)
-            # The device call split into its five contiguous stages
-            # (they sum to .device) and what follows it before finalize.
-            for name in DEVICE_STAGES:
-                m.add_sample("worker.invoke_scheduler.device." + name,
-                             stats.device_stage_seconds.get(name, 0.0)
-                             * 1000.0)
             m.add_sample("worker.invoke_scheduler.expand",
                          stats.metrics_seconds * 1000.0)
+            # Each of the three split into its contiguous stages, which
+            # sum to it; every stage on every batch, 0 where it did not
+            # run.
+            for phase, names, seconds in (
+                    ("encode", ENCODE_STAGES, stats.encode_stage_seconds),
+                    ("device", DEVICE_STAGES, stats.device_stage_seconds),
+                    ("expand", EXPAND_STAGES, stats.expand_stage_seconds)):
+                for name in names:
+                    m.add_sample(
+                        f"worker.invoke_scheduler.{phase}.{name}",
+                        seconds.get(name, 0.0) * 1000.0)
+            # Inside encode.specs: the host-evaluated rows.
             m.add_sample("worker.invoke_scheduler.encode.constraint_rows",
                          stats.constraint_rows_seconds * 1000.0)
-            m.add_sample("worker.invoke_scheduler.encode.resident",
-                         stats.resident_seconds * 1000.0)
             m.add_sample("worker.invoke_scheduler.rounds", stats.rounds)
             # Published on every batch, 0 where nothing applies, so that
             # a metric over them reads 0 and not nothing.
@@ -622,6 +594,7 @@ class TPUBatchScheduler:
                       num_evals=stats.num_evals, num_specs=stats.num_specs,
                       resident_hits=stats.resident_hits,
                       pipeline_overlap_s=round(stats.pipeline_overlap_s, 4),
+                      cpu_ms=round(stats.cpu_seconds * 1000.0, 4),
                       **tracing.eval_id_attrs(prep.evals, len(prep.evals)))
         self._emit_batch_stats(stats)
         return stats
@@ -768,13 +741,11 @@ class TPUBatchScheduler:
         """Stage 3: blocking fetch of the device results, breaker
         bookkeeping, and per-eval plan finalize/submit."""
         stats = prep.stats
-        evals, scheds = prep.evals, prep.scheds
+        scheds = prep.scheds
         tr = tracing.TRACER
 
         if prep.routed:
-            stats.total_seconds = time.perf_counter() - prep.t0
-            stats.num_evals = len(evals)
-            return stats
+            return prep.close()
 
         # Per-spec flat slot lists (node id per placement), expanded on
         # the numpy side in _fetch_device.
@@ -809,9 +780,7 @@ class TPUBatchScheduler:
                               breaker_state=stats.breaker_state,
                               num_evals=len(scheds), detail=str(e))
                 self._route_through_oracle(scheds)
-                stats.total_seconds = time.perf_counter() - prep.t0
-                stats.num_evals = len(evals)
-                return stats
+                return prep.close()
             except Exception:
                 # A raw device error (OOM, XLA failure — what a genuinely
                 # flaky accelerator throws) keeps its existing propagate-
@@ -839,6 +808,8 @@ class TPUBatchScheduler:
             stats.encode_seconds = kstats["encode_seconds"]
             stats.metrics_seconds = kstats["metrics_seconds"]
             stats.device_stage_seconds = kstats["stage_seconds"]
+            stats.encode_stage_seconds = kstats["encode_stage_seconds"]
+            stats.expand_stage_seconds = kstats["expand_stage_seconds"]
             stats.rounds = kstats["rounds"]
             stats.spec_passes = kstats.get("spec_passes", 0)
             stats.multi_round_specs = kstats.get("multi_round_specs", 0)
@@ -870,9 +841,7 @@ class TPUBatchScheduler:
             tr.record("batch.finalize", t_final,
                       t_final + stats.finalize_seconds)
 
-        stats.total_seconds = time.perf_counter() - prep.t0
-        stats.num_evals = len(evals)
-        return stats
+        return prep.close()
 
     @staticmethod
     def _apply_resident_stats(stats: "BatchStats", res_info: Dict) -> None:
@@ -881,8 +850,6 @@ class TPUBatchScheduler:
         stats.full_reencodes = 1 if res_info.get("full_reencode") else 0
         stats.staleness_fences = 1 if res_info.get("fence") else 0
         stats.delta_apply_seconds = res_info.get("delta_apply_s", 0.0)
-        t_a, t_b = res_info.get("stamps", (0.0, 0.0))
-        stats.resident_seconds = t_b - t_a
 
     def _route_through_oracle(self, scheds) -> None:
         """Degraded path: process each eval with the CPU GenericScheduler
@@ -1039,7 +1006,8 @@ class TPUBatchScheduler:
         not including) the blocking fetch.  Returns the in-flight handle
         _fetch_device consumes — the split point the double-buffered
         pipeline overlaps across batches."""
-        t0 = time.perf_counter()
+        enc = tracing.Stages("batch.encode.")     # ENCODE_STAGES
+        enc.begin("nodes")
         # Host→device transfer accounting (ISSUE 14 satellite): the
         # resident mirror's own uploads (installs + routed delta
         # applies) happen inside acquire/take below; sample the module
@@ -1103,21 +1071,22 @@ class TPUBatchScheduler:
             # nodes index, pad geometry) so residency survives
             # vocabulary changes; ``shards`` lets the differential
             # guard attribute a mismatch to the owning mesh shard.
-            t_res = time.perf_counter()
+            # Feed read, fold and device delta apply, inside encode.
+            enc.begin("resident")
             used, touched, resident_info = resident.acquire(
                 self.state, cache_key[:2] + (base.n_pad,), base,
                 self._live_allocs_by_node, breaker=self.breaker,
                 shards=(self.mesh.devices.size
                         if self.mesh is not None else 0),
                 usage_fn=lambda: self._columnar_usage(base))
-            # Feed read, fold and device delta apply, inside encode.
-            resident_info["stamps"] = (t_res, time.perf_counter())
+            enc.begin("specs")
             ct = encode.with_usage(base, used)
             # The preemption pass only needs WHICH nodes may carry live
             # allocs (it re-materializes candidate rows from state);
             # avoid the full row walk the resident path just saved.
             self._allocs_by_node = _TouchedNodeIds(base.node_ids, touched)
         else:
+            enc.begin("resident")       # off the mirror: the usage walk
             cu = (self._columnar_usage(base)
                   if not with_networks else None)
             if cu is not None:
@@ -1134,6 +1103,7 @@ class TPUBatchScheduler:
                 touched = sorted(i for i in (node_index.get(nid)
                                              for nid in allocs_by_node)
                                  if i is not None)
+            enc.begin("specs")
         st = encode.encode_specs(spec_list, ct, all_nodes)
         # The batch's shape plan, or the compiled plan of its shape class
         # that covers it (kernels.choose_plan: a drain's tail batch runs
@@ -1144,6 +1114,7 @@ class TPUBatchScheduler:
             self._natural_plan(spec_list, ct, st))
         st = encode.pad_specs(st, u_pad, bool(host_rows), ct.n_pad)
 
+        enc.begin("sparse")
         # Existing per-(job, node) alloc counts for anti-affinity/distinct,
         # uploaded SPARSE and scattered dense on device: the dense U×N
         # matrix is mostly zeros.
@@ -1259,6 +1230,7 @@ class TPUBatchScheduler:
             dyn.update(dp_col=st.dp_col, dp_active=st.dp_active,
                        dp_used=st.dp_used)
 
+        enc.begin("pack")
         if self.mesh is not None:
             # Sharded donated-mirror eligibility (ISSUE 14): when the
             # resident slot matches this batch exactly, _dispatch_mesh
@@ -1274,7 +1246,7 @@ class TPUBatchScheduler:
             handle = self._dispatch_mesh(
                 spec_list, all_nodes, ct, st, static, dyn,
                 with_networks=with_networks, with_dp=with_dp,
-                quantized=0 if quant is None else 1, t0=t0,
+                quantized=0 if quant is None else 1, enc=enc,
                 resident_info=resident_info, res_key=res_key,
                 snap_index=snap_index, used_host=used
                 if res_key is not None else None, h2d0=h2d0,
@@ -1307,8 +1279,7 @@ class TPUBatchScheduler:
 
         sbuf, meta_s = xfer.pack_host(static)
         dbuf, meta_d = xfer.pack_host(dyn)
-        encode_seconds = time.perf_counter() - t0
-        t1 = time.perf_counter()
+        t1 = enc.end()      # encode ends where the device call begins
 
         # slot_m and max_nnz are the plan's, chosen above; see
         # encode.shape_plan for the slot-mode and score-carry rules
@@ -1317,7 +1288,7 @@ class TPUBatchScheduler:
         # the COO payload with one U×M pass instead of a nonzero over
         # the U×N matrix).
         with_scores = encode.carries_scores(st.u_pad, ct.n_real)
-        stages = _DeviceStages()
+        stages = tracing.Stages("batch.device.", live=True)
         with stages:
             # stage: content digest of the static pack, the device-side
             # cache of it, and the uploads (static on a miss, dynamic
@@ -1361,7 +1332,7 @@ class TPUBatchScheduler:
             "fused_overflow": fused_overflow,
             "quantized": 0 if quant is None else 1,
             "with_scores": with_scores, "max_nnz": max_nnz,
-            "encode_seconds": encode_seconds, "t1": t1, "stages": stages,
+            "enc": enc, "t1": t1, "stages": stages,
             "resident": resident_info,
             "h2d_bytes": (dbuf.nbytes + static_h2d
                           + (resident.DEV_H2D_BYTES - h2d0)),
@@ -1485,8 +1456,7 @@ class TPUBatchScheduler:
         expanded, unplaced, metrics, kstats = self._finalize_device_outputs(
             spec_list, all_nodes, ct, st, feas, unplaced_arr, feas_count,
             coo_rows, coo_cols, coo_counts, coo_scores, coo_coll,
-            rounds, with_scores, handle["encode_seconds"], handle["t1"],
-            stages, (handle.get("resident") or {}).get("stamps"))
+            rounds, with_scores, handle["enc"], handle["t1"], stages)
         kstats["spec_passes"] = scalars["spec_passes"]
         kstats["multi_round_specs"] = scalars["multi_round_specs"]
         kstats["commit_seconds"] = commit_seconds
@@ -1515,7 +1485,7 @@ class TPUBatchScheduler:
         return 128 * d // math.gcd(128, d)
 
     def _dispatch_mesh(self, spec_list, all_nodes, ct, st, static, dyn,
-                       *, with_networks, with_dp, quantized, t0,
+                       *, with_networks, with_dp, quantized, enc,
                        resident_info, res_key=None, snap_index=None,
                        used_host=None, h2d0=0, slot_m=0, max_nnz=0):
         """Node-sharded twin of the fused dispatch: the SAME static/dyn
@@ -1589,10 +1559,9 @@ class TPUBatchScheduler:
         sbuf, meta_s = xfer.pack_host_sharded(
             static, d, replicate=("res_scale",))         # [D, B]
         dbuf, meta_d = xfer.pack_host(dyn)
-        encode_seconds = time.perf_counter() - t0
-        t1 = time.perf_counter()
+        t1 = enc.end()
 
-        stages = _DeviceStages()
+        stages = tracing.Stages("batch.device.", live=True)
         with stages:
             stages.begin("stage", t1)
             digest = (hashlib.blake2b(sbuf.tobytes(),
@@ -1628,7 +1597,7 @@ class TPUBatchScheduler:
             "fused_overflow": ("slots", aux),
             "quantized": quantized, "mesh_shards": d,
             "with_scores": with_scores, "max_nnz": max_nnz,
-            "encode_seconds": encode_seconds, "t1": t1, "stages": stages,
+            "enc": enc, "t1": t1, "stages": stages,
             "resident": resident_info,
             "h2d_bytes": (dbuf.nbytes + static_h2d
                           + (resident.DEV_H2D_BYTES - h2d0)),
@@ -1637,14 +1606,12 @@ class TPUBatchScheduler:
     def _finalize_device_outputs(self, spec_list, all_nodes, ct, st, feas,
                                  unplaced_arr, feas_count, coo_rows,
                                  coo_cols, coo_counts, coo_scores, coo_coll,
-                                 rounds, with_scores, encode_seconds, t1,
-                                 stages, resident_stamps=None):
+                                 rounds, with_scores, enc, t1, stages):
         """Shared device→host post-processing for the single-chip and
         mesh placement paths: lazy failure-forensics row fetch, COO →
         per-spec slots, AllocMetric assembly.  ``stages`` arrives with
         its ``decode`` stage running; the device_seconds stamp below
-        closes it.  ``resident_stamps``: when encode took the resident
-        path, the two stamps around ``resident.acquire``."""
+        closes it.  ``enc``: encode's stages, closed at ``t1``."""
         # Chaos hook: corrupt the fetched kernel outputs (the damage a
         # flaky accelerator / bad HBM would do), THEN validate — the
         # validation below is exactly what protects production from the
@@ -1784,7 +1751,8 @@ class TPUBatchScheduler:
                     np.asarray(a).nbytes
                     for a in jax.tree_util.tree_leaves(fetched["preempt"]))
         device_seconds = stages.end() - t1
-        t_metrics = time.perf_counter()
+        exp = tracing.Stages("batch.metrics.")    # EXPAND_STAGES
+        t_metrics = exp.begin("preempt")
 
         # Preemption commit (host greedy pass over the fetched eviction
         # sets; mutates unplaced_arr/used_after so the failure forensics
@@ -1804,7 +1772,10 @@ class TPUBatchScheduler:
         # uniform fleets fail by the hundreds with identical signatures,
         # so the vectorized-but-per-spec forensics run once per shape.
         fail_cache: Dict[Tuple, s.AllocMetric] = {}
+        # Stamps per spec, none per allocation: two for a spec placed
+        # whole, four for one that left asks unplaced.
         for u, sp in enumerate(spec_list):
+            exp.begin("slots")
             key = (sp.job.id, sp.tg.name)
             lo, hi = int(u_lo[u]), int(u_hi[u])
             # The spec's slots stay the integers the device returned
@@ -1815,6 +1786,8 @@ class TPUBatchScheduler:
 
             n_unplaced = unplaced[key]
             sig = None
+            if n_unplaced > 0:
+                exp.begin("failures")       # the memo
             if n_unplaced > 0 and lo == hi and feas_rows.get(u) is None:
                 sig = (sp.ask.tobytes(), tuple(sp.datacenters),
                        tuple((c.ltarget, c.operand, c.rtarget)
@@ -1834,6 +1807,7 @@ class TPUBatchScheduler:
 
             # AllocMetric parity from kernel side-outputs
             # (structs.go:4074-4172 contract; VERDICT r1 weak #7).
+            exp.begin("scores")
             m = s.AllocMetric()
             m.nodes_evaluated = ct.n_real
             m.nodes_filtered = ct.n_real - int(feas_count[u])
@@ -1863,6 +1837,7 @@ class TPUBatchScheduler:
                             m.scores[ids[j] + ".job-anti-affinity"] = \
                                 -pen * int(co_seg[j])
             if n_unplaced > 0:
+                exp.begin("failures")
                 placed_row = np.zeros(ct.n_real, dtype=np.int32)
                 placed_row[vc[lo:hi]] = vcnt[lo:hi]
                 self._fill_failure_metrics(
@@ -1873,15 +1848,20 @@ class TPUBatchScheduler:
                     fail_cache[sig] = m
             metrics[key] = m
 
+        # The tail, up to the stamp that ends expand.
+        exp.begin("failures")
         dp_specs = int(st.dp_active.sum())
+        metrics_seconds = exp.end() - t_metrics
         kstats = {
             "device_seconds": device_seconds,
-            "encode_seconds": encode_seconds,
-            "metrics_seconds": time.perf_counter() - t_metrics,
+            "encode_seconds": sum(enc.seconds.values()),
+            "metrics_seconds": metrics_seconds,
             "rounds": rounds,
             "fetch_seconds": kstats_fetch_s,
             "fetch_bytes": kstats_fetch_b,
             "stage_seconds": stages.seconds,
+            "encode_stage_seconds": enc.seconds,
+            "expand_stage_seconds": exp.seconds,
             # The host-evaluated feasibility rows encode supplied for
             # this batch (encode._host_row), the time in them, and how
             # many came from a kept row; the specs that carry a
@@ -1900,18 +1880,18 @@ class TPUBatchScheduler:
         if tr is not None:
             # Phase spans from the timers already taken above: t1 marks
             # the encode→device boundary, t_metrics the device→host one.
-            enc = tr.record("batch.encode", t1 - encode_seconds, t1)
+            t0 = t1 - kstats["encode_seconds"]
+            parent = tr.record("batch.encode", t0, t1).span_id
+            enc.lay(t0, ENCODE_STAGES, parent)
             for a, b in st.row_stamps:
                 tr.record("batch.encode.constraint_rows", a, b,
-                          parent_id=enc.span_id)
-            if resident_stamps is not None:
-                tr.record("batch.encode.resident", *resident_stamps,
-                          parent_id=enc.span_id)
+                          parent_id=parent)
             tr.record("batch.device", t1, t1 + device_seconds,
                       span_id=stages.parent_id, rounds=rounds)
-            tr.record("batch.metrics", t_metrics,
-                      t_metrics + kstats["metrics_seconds"],
-                      preempt_placed=kstats.get("preempt_placed", 0))
+            parent = tr.record(
+                "batch.metrics", t_metrics, t_metrics + metrics_seconds,
+                preempt_placed=kstats.get("preempt_placed", 0)).span_id
+            exp.lay(t_metrics, EXPAND_STAGES, parent)
         return expanded, unplaced, metrics, kstats
 
     # -- preemption pass ----------------------------------------------------
@@ -2518,8 +2498,11 @@ class BatchStats:
         self.prepare_seconds = 0.0
         self.phase1_seconds = 0.0
         self.phase2_seconds = 0.0
-        # device_seconds split into DEVICE_STAGES (name → seconds).
+        # encode_seconds, device_seconds and metrics_seconds split into
+        # ENCODE_STAGES, DEVICE_STAGES, EXPAND_STAGES (name → seconds).
+        self.encode_stage_seconds: Dict[str, float] = {}
         self.device_stage_seconds: Dict[str, float] = {}
+        self.expand_stage_seconds: Dict[str, float] = {}
         self.metrics_seconds = 0.0
         self.finalize_seconds = 0.0
         # finalize split, one pass over the batch's evals each: building
@@ -2529,6 +2512,10 @@ class BatchStats:
         self.finalize_submit_seconds = 0.0
         self.finalize_status_seconds = 0.0
         self.total_seconds = 0.0
+        # CPU time of the worker's thread between total_seconds' two
+        # stamps (time.thread_time: waits for the interpreter lock, the
+        # device, the applier excluded).
+        self.cpu_seconds = 0.0
         # Most passes any one spec of the batch took; the passes summed
         # over its specs; the specs that took more than one.
         self.rounds = 0
@@ -2540,9 +2527,6 @@ class BatchStats:
         self.precomp_rows = 0
         self.constraint_rows_seconds = 0.0
         self.constraint_row_reuse = 0
-        # Time inside resident.acquire (part of encode_seconds): feed
-        # read, fold and device delta apply; 0 off the resident path.
-        self.resident_seconds = 0.0
         self.dp_specs = 0
         self.dp_dense_specs = 0
         # Fused score-and-commit path (PR 6): whether this batch ran the

@@ -188,6 +188,15 @@ class _Fits:
         return out
 
 
+# plan.apply's contiguous stages, in order: the payloads built, the
+# entries through the codec, the rest of the log's write phase (its
+# lock, the WAL writes), the one durability wait, the sequencer wait
+# and FSM applies, and what follows the applies.  Samples
+# ``plan.apply.<stage>`` and, armed, spans of the same names; they sum
+# to ``plan.apply``.
+APPLY_STAGES = ("entry", "encode", "write", "sync", "fsm", "post")
+
+
 class PlanApplier:
     def __init__(self, plan_queue: PlanQueue, raft: RaftLog,
                  logger: Optional[logging.Logger] = None,
@@ -323,10 +332,9 @@ class PlanApplier:
         try:
             # The submitter's worker.submit_plan span (another thread)
             # is the parent: it caused this work.
-            ev_span = tracing.NOOP if tr is None else tr.span(
-                "plan.evaluate", parent_id=pairs[0][1].trace_parent,
-                **tracing.plan_attrs(plans))
-            with self.metrics.measure("plan.evaluate"), ev_span:
+            with tracing.timed(self.metrics, "plan.evaluate", cpu=True,
+                               attrs=lambda: tracing.plan_attrs(plans),
+                               parent_id=pairs[0][1].trace_parent):
                 results = self._evaluate_plans(snap, plans)
         except Exception as exc:  # pragma: no cover — defensive
             self.logger.exception("plan evaluation failed")
@@ -400,14 +408,18 @@ class PlanApplier:
         for _, _, future in commits:
             future.t_commit = t_commit
         plans = [plan for plan, _, _ in commits]
-        tr = tracing.TRACER
+        # plan.apply and its six stages, from one set of stamps: the
+        # stages tile it (APPLY_STAGES).
+        stages = tracing.Stages("plan.apply.")
+        ap = tracing.timed(self.metrics, "plan.apply", cpu=True,
+                           attrs=lambda: tracing.plan_attrs(plans),
+                           parent_id=commits[0][2].trace_parent)
         try:
-            ap_span = tracing.NOOP if tr is None else tr.span(
-                "plan.apply", parent_id=commits[0][2].trace_parent,
-                **tracing.plan_attrs(plans))
-            with self.metrics.measure("plan.apply"), ap_span:
+            with ap:
+                stages.begin("entry", ap.start)
                 outcomes = self.apply_plans(
-                    [(plan, result) for plan, result, _ in commits], snap)
+                    [(plan, result) for plan, result, _ in commits], snap,
+                    stages)
         except Exception as exc:  # pragma: no cover — defensive
             outcomes = [exc] * len(commits)
         finally:
@@ -416,7 +428,15 @@ class PlanApplier:
             # no window where a placement is in neither.
             for token in tokens:
                 self._overlay.remove(token)
+        stages.end(ap.end)
+        for name in APPLY_STAGES:
+            self.metrics.add_sample("plan.apply." + name,
+                                    stages.seconds.get(name, 0.0) * 1000.0)
+        stages.lay(ap.start, APPLY_STAGES, ap.span_id)
         for (plan, result, future), index in zip(commits, outcomes):
+            # plan.respond (the submitter's sample) runs from the end of
+            # plan.apply to the run's last answer.
+            future.t_applied = ap.end
             if isinstance(index, Exception):
                 self.logger.error("failed to apply plan for eval %s",
                                   plan.eval_id, exc_info=index)
@@ -945,12 +965,15 @@ class PlanApplier:
         return index
 
     def apply_plans(self, items: List[Tuple[s.Plan, s.PlanResult]],
-                    snap) -> list:
+                    snap, stages: Optional[tracing.Stages] = None) -> list:
         """Commit a run's results through the log, ONE entry per plan,
         written back to back under one fsync (RaftLog.apply_many).
         Returns per plan, in order, its apply index or the exception
         that failed it: a plan whose entry could not be built or whose
-        FSM apply raised fails alone."""
+        FSM apply raised fails alone.  ``stages``: the caller's stamper
+        with ``entry`` running; left with ``post`` running."""
+        if stages is None:
+            stages = tracing.Stages("plan.apply.")
         outcomes: list = [None] * len(items)
         staged = []
         for pos, (plan, result) in enumerate(items):
@@ -958,9 +981,17 @@ class PlanApplier:
                 staged.append((pos, *self._plan_entry(plan, result, snap)))
             except Exception as exc:
                 outcomes[pos] = exc
+        stages.begin("write")
         applied = self.raft.apply_many(
             [(MessageType.APPLY_PLAN_RESULTS, payload)
              for _, payload, _, _ in staged])
+        stages.begin("post")
+        # The log's own split of the call (one set of stamps, its
+        # own); what it does not name stays ``write``, which on a log
+        # that splits nothing (multi-voter) is the whole call.
+        timing = getattr(applied, "timing", None) or {}
+        for name in ("encode", "sync", "fsm"):
+            stages.carve(name, timing.get(name, 0.0), "write")
         for (pos, _, preempted, preemption_evals), outcome in zip(
                 staged, applied):
             if isinstance(outcome, Exception):

@@ -27,12 +27,12 @@ class PlanFuture:
     submitter may safely replan without double-committing placements) or
     claimed (the applier owns it; the submitter must keep waiting).
 
-    The future also carries the round trip's own clock: five
+    The future also carries the round trip's own clock: six
     ``perf_counter`` stamps (enqueued, claimed by the applier, evaluate
-    done, commit entered, responded; 0.0 = never reached), read by the
-    submitter once ``wait()`` returns (``WorkerPlanner.submit_plan``
+    done, commit entered, applied, responded; 0.0 = never reached), read
+    by the submitter once ``wait()`` returns (``WorkerPlanner.submit_plan``
     turns them into the ``plan.queue_wait`` / ``.commit_wait`` /
-    ``.wake`` samples), and ``trace_parent``, the submitter's
+    ``.respond`` / ``.wake`` samples), and ``trace_parent``, the submitter's
     ``worker.submit_plan`` span id while the tracer is armed (0
     otherwise), which the applier's spans take as their parent."""
 
@@ -46,7 +46,7 @@ class PlanFuture:
         self.trace_parent = trace_parent
         self.t_enqueued = time.perf_counter()
         self.t_claimed = self.t_evaluated = 0.0
-        self.t_commit = self.t_responded = 0.0
+        self.t_commit = self.t_applied = self.t_responded = 0.0
 
     def claim(self) -> bool:
         """Applier-side: take ownership; False if already cancelled."""

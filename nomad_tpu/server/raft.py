@@ -112,6 +112,9 @@ class RaftLog:
         self._apply_cv = threading.Condition()
         self._apply_next = 1
         self._apply_failed = False
+        # Codec time of the entries written under the current hold of
+        # the log lock (a durable log's _persist adds to it).
+        self._encode_seconds = 0.0
 
     # -- leadership --------------------------------------------------------
 
@@ -200,12 +203,19 @@ class RaftLog:
         already written stay in the file as a prefix of whole entries
         nobody was told about, which recovery replays — the
         entry-durable, ack-lost case) and every queued/later apply fails
-        too."""
+        too.
+
+        The call's four stages ride the returned list as ``timing``
+        (seconds: ``encode``, the group's entries through the codec;
+        ``write``, the rest of phase 1; ``sync``, phase 2; ``fsm``,
+        phase 3), from one set of stamps, two an entry; they sum to the
+        ``raft.apply`` sample, one a call."""
         t0 = time.perf_counter()
-        outcomes: list = [None] * len(entries)
+        outcomes = _Outcomes([None] * len(entries))
         written: List[Tuple[int, int]] = []     # (position, index)
         token = poisoned = None
         with self._l:
+            self._encode_seconds = 0.0
             for pos, (msg_type, payload) in enumerate(entries):
                 try:
                     index, token = self._append(msg_type, payload)
@@ -216,6 +226,8 @@ class RaftLog:
                         break
                 else:
                     written.append((pos, index))
+            encode = self._encode_seconds
+        t_written = time.perf_counter()
         if written and token is not None:
             try:
                 if poisoned is None:
@@ -237,6 +249,7 @@ class RaftLog:
         if poisoned is not None:
             return [poisoned if out is None else out for out in outcomes]
         tr = tracing.TRACER
+        t_synced = t_entry = time.perf_counter()
         for pos, index in written:
             msg_type, payload = entries[pos]
             with self._apply_cv:
@@ -261,13 +274,19 @@ class RaftLog:
                     self._applied = index  # visible only now: post-durability
                     self._apply_next = index + 1
                     self._apply_cv.notify_all()
-            self.metrics.measure_since("raft.apply", t0)
             # Branch before building attrs: the disarmed commit path pays
-            # one load + comparison, no getattr/dict/timestamp.
+            # one load + comparison, no getattr/dict/timestamp.  Armed,
+            # a span an entry: its sequencer wait and FSM apply alone.
             if tr is not None:
-                tr.record("raft.apply", t0, time.perf_counter(), index=index,
+                t_prev, t_entry = t_entry, time.perf_counter()
+                tr.record("raft.apply", t_prev, t_entry, index=index,
                           msg_type=getattr(msg_type, "name", str(msg_type)))
-            t0 = time.perf_counter()
+        t_end = time.perf_counter()
+        self.metrics.add_sample("raft.apply", (t_end - t0) * 1000.0)
+        outcomes.timing = {"encode": encode,
+                           "write": t_written - t0 - encode,
+                           "sync": t_synced - t_written,
+                           "fsm": t_end - t_synced}
         return outcomes
 
     def _append(self, msg_type: MessageType, payload: dict):
@@ -315,6 +334,13 @@ class RaftLog:
 
 class NotLeaderError(Exception):
     pass
+
+
+class _Outcomes(list):
+    """What ``apply_many`` returns: the per-entry outcomes, and the
+    call's stage totals as ``timing`` (None where nothing was timed)."""
+
+    timing = None
 
 
 class InmemLog(RaftLog):
@@ -600,7 +626,9 @@ class FileLog(RaftLog):
         """WRITE one entry (buffered, index order — caller holds the
         raft lock) and return the durability token _sync_persist waits
         on outside the lock."""
+        t_enc = time.perf_counter()
         blob = _encode_entry(index, msg_type, payload)
+        self._encode_seconds += time.perf_counter() - t_enc
         # Fault point ``wal.fsync``: a crash here models the process
         # dying mid-frame — a torn partial record is left on disk (the
         # recovery path must truncate it) and the entry never applies.

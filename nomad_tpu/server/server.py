@@ -18,7 +18,7 @@ from typing import Dict, List, Optional, Tuple
 from .. import fault
 from ..structs import structs as s
 from ..tenancy import QuotaLedger, RateLimiter
-from ..utils import blackbox, contprof, knobs, tracing
+from ..utils import blackbox, contprof, knobs, telemetry, tracing
 from ..utils.telemetry import Telemetry
 from . import event_broker as event_stream
 from .blocked_evals import BlockedEvals
@@ -161,6 +161,7 @@ class Server:
         contprof.maybe_arm_from_env()
         blackbox.maybe_arm_from_env()
         blackbox.register_server(self)
+        telemetry.watch_gc(self)
         # Vault client (nomad/vault.go:234); vault_api injects the fake
         # in tests (vault_testing.go role).
         self.vault = ServerVaultClient(self.config.vault or VaultConfig(),
@@ -436,6 +437,7 @@ class Server:
         self._shutdown.set()
         self._leader = False
         blackbox.unregister_server(self)
+        telemetry.unwatch_gc(self)
         event_stream.unregister(self.event_broker)
         self.event_broker.close()
         for worker in self.workers:

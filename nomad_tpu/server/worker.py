@@ -102,7 +102,8 @@ def _emit_round_trip(metrics, future, t_woke: float, tr) -> None:
     """A submission's hand-offs, from the stamps its last future
     collected: queue_wait (enqueued → claimed by the applier),
     commit_wait (evaluate done → _commit entered; 0 for a plan with
-    nothing to commit) and wake (responded → this thread running
+    nothing to commit), respond (plan.apply done → the submission's
+    last future answered) and wake (answered → this thread running
     again).  With plan.evaluate and plan.apply they sum to the round
     trip.  A remote future (follower scheduling) carries no stamps
     and emits nothing."""
@@ -110,15 +111,19 @@ def _emit_round_trip(metrics, future, t_woke: float, tr) -> None:
     if not t_claimed or not future.t_responded:
         return
     t_commit = future.t_commit or future.t_evaluated
+    t_applied = future.t_applied or t_commit
     metrics.add_sample("plan.queue_wait",
                        (t_claimed - future.t_enqueued) * 1000.0)
     metrics.add_sample("plan.commit_wait",
                        (t_commit - future.t_evaluated) * 1000.0)
+    metrics.add_sample("plan.respond",
+                       (future.t_responded - t_applied) * 1000.0)
     metrics.add_sample("plan.wake", (t_woke - future.t_responded) * 1000.0)
     if tr is not None:
         # Recorded inside worker.submit_plan: it is their parent.
         tr.record("plan.queue_wait", future.t_enqueued, t_claimed)
         tr.record("plan.commit_wait", future.t_evaluated, t_commit)
+        tr.record("plan.respond", t_applied, future.t_responded)
         tr.record("plan.wake", future.t_responded, t_woke)
 
 
@@ -614,6 +619,9 @@ class BatchWorker(Worker):
         # Optional device mesh: placement passes run node-sharded over it
         # (each federated region schedules on its own slice).
         self.mesh = mesh
+        # Seconds of the running cycle that its named parts have taken
+        # so far (_cycle; this thread's alone).
+        self._cycle_named = 0.0
         # Before this process compiles anything: JAX latches the
         # persistent cache off at the first compile without a directory.
         from ..utils.platform import ensure_compile_cache
@@ -633,27 +641,50 @@ class BatchWorker(Worker):
         pipelined = pipeline_enabled()
         while not self._stop.is_set():
             self._check_paused()
-            try:
-                batch = self.broker.dequeue_batch(
-                    [s.JOB_TYPE_SERVICE, s.JOB_TYPE_BATCH],
-                    self.max_batch, DEQUEUE_TIMEOUT)
-            except EvalBrokerError:
-                time.sleep(self._idle_backoff.next_delay())
-                continue
-            self._idle_backoff.reset()
-            if batch:
-                if pipelined:
-                    # Per-batch latency samples are taken at each batch's
-                    # finish (one drain spans many batches — a single
-                    # measure() here would corrupt the histogram).
-                    self._process_batches_pipelined(batch)
-                else:
-                    with self.metrics.measure(
-                            "worker.invoke_scheduler.batch"):
-                        self.process_batch(batch)
-            # Always also poll system/core (zero timeout) so a sustained
-            # service/batch stream cannot starve them.
-            self._poll_system_core()
+            self._cycle(pipelined)
+
+    def _cycle(self, pipelined: bool) -> None:
+        """One turn of the loop: dequeue a batch, process it, poll
+        system/core.  A serial turn that took a batch is one
+        ``worker.cycle`` sample (entry of the dequeue → return of the
+        poll), tiled by ``worker.dequeue``, ``.wait_for_index``,
+        ``.snapshot``, ``.invoke_scheduler``, ``.release`` and ``.ack``;
+        what they leave is ``worker.cycle.unnamed``.  In a standing
+        backlog the cycles abut."""
+        t0 = tracing.now()
+        try:
+            batch = self.broker.dequeue_batch(
+                [s.JOB_TYPE_SERVICE, s.JOB_TYPE_BATCH],
+                self.max_batch, DEQUEUE_TIMEOUT)
+        except EvalBrokerError:
+            time.sleep(self._idle_backoff.next_delay())
+            return
+        t_got = tracing.now()
+        self._idle_backoff.reset()
+        serial = bool(batch) and not pipelined
+        if serial:
+            self.metrics.add_sample("worker.dequeue", (t_got - t0) * 1000.0)
+            self._cycle_named = t_got - t0
+            with self.metrics.measure("worker.invoke_scheduler.batch"):
+                self.process_batch(batch)
+        elif batch:
+            # Per-batch latency samples are taken at each batch's
+            # finish (one drain spans many batches — a single
+            # measure() here would corrupt the histogram).
+            self._process_batches_pipelined(batch)
+        # Always also poll system/core (zero timeout) so a sustained
+        # service/batch stream cannot starve them.
+        self._poll_system_core()
+        if serial:
+            t_end = tracing.now()
+            self.metrics.add_sample("worker.cycle", (t_end - t0) * 1000.0)
+            self.metrics.add_sample(
+                "worker.cycle.unnamed",
+                max(0.0, t_end - t0 - self._cycle_named) * 1000.0)
+            # No eval ids: the dequeue that returned a batch began
+            # before its evals existed (the wait for work is in it).
+            tracing.record("worker.dequeue", t0, t_got, num_evals=len(batch))
+            tracing.record("worker.cycle", t0, t_end, num_evals=len(batch))
 
     def _poll_system_core(self) -> None:
         try:
@@ -698,25 +729,19 @@ class BatchWorker(Worker):
     def _snapshot(self):
         """The batch's state snapshot, timed: sample ``worker.snapshot``
         always, a span of the same two stamps when the tracer is armed."""
-        tr = tracing.TRACER
-        t0 = tracing.now()
-        if tr is None:
+        with tracing.timed(self.metrics, "worker.snapshot",
+                           annotate=True) as t:
             snap = self.raft.fsm.state.snapshot()
-            t1 = tracing.now()
-        else:
-            with tr.span("worker.snapshot", annotate=True, start=t0) as sp:
-                snap = self.raft.fsm.state.snapshot()
-            t1 = sp.end
-        self.metrics.add_sample("worker.snapshot", (t1 - t0) * 1000.0)
+        self._cycle_named += t.end - t.start
         return snap
 
     def _process_batch(self, batch: List[Tuple[s.Evaluation, str]]):
         """Returns the batch's BatchStats, or None when the batch was
         nacked."""
         max_index = max(ev.modify_index for ev, _ in batch)
-        with self.metrics.measure("worker.wait_for_index"), \
-                tracing.span("worker.wait_for_index"):
+        with tracing.timed(self.metrics, "worker.wait_for_index") as t:
             self.wait_for_index(max_index, RAFT_SYNC_LIMIT)
+        self._cycle_named += t.end - t.start
         # Always a fresh snapshot on the batch path: the device-resident
         # usage mirror advances by inter-snapshot deltas, and a reused
         # pre-apply snapshot would hide the previous batch's own
@@ -742,6 +767,7 @@ class BatchWorker(Worker):
         attempts = {} if tr is None else {
             ev.id: self.broker.delivery_attempts(ev.id)
             for ev, _ in batch}
+        t_call = tracing.now()
         try:
             with self._compile_guard(batch):
                 stats = sched.schedule_batch([ev for ev, _ in batch])
@@ -761,6 +787,25 @@ class BatchWorker(Worker):
                 except EvalBrokerError:
                     pass
             return None
+        # What the scheduler call took beside the batch itself: its
+        # stats published (~90 sink calls) and, mostly, its frames
+        # released (the batch's AllocMetrics, tensors and plans freed).
+        t_ret = tracing.now()
+        release = max(0.0, t_ret - t_call - stats.total_seconds)
+        self.metrics.add_sample("worker.release", release * 1000.0)
+        tracing.record("worker.release", t_ret - release, t_ret)
+        self._cycle_named += stats.total_seconds + release
+        with tracing.timed(self.metrics, "worker.ack",
+                           num_evals=len(batch)) as t:
+            self._ack_batch(batch, attempts)
+        self._cycle_named += t.end - t.start
+        return stats
+
+    def _ack_batch(self, batch, attempts) -> None:
+        """Ack every eval of a scheduled batch, with its per-delivery
+        ``worker.attempt`` marker when the tracer was armed as the
+        batch's attempt numbers were taken."""
+        tr = tracing.TRACER if attempts else None
         for ev, token in batch:
             try:
                 self.broker.ack(ev.id, token)
@@ -778,7 +823,6 @@ class BatchWorker(Worker):
                     # per-eval Worker's span.
                     tr.event("worker.attempt", eval_id=ev.id,
                              attempt=attempts[ev.id])
-        return stats
 
     # -- pipelined drain (NOMAD_TPU_PIPELINE=1) ----------------------------
     #
@@ -882,18 +926,7 @@ class BatchWorker(Worker):
                       fused=stats.fused, fetch_bytes=stats.fetch_bytes,
                       **tracing.eval_id_attrs(
                           (ev for ev, _ in ctx.batch), len(ctx.batch)))
-        for ev, token in ctx.batch:
-            try:
-                self.broker.ack(ev.id, token)
-            except EvalBrokerError as exc:
-                if tr is not None:
-                    tr.event("worker.attempt", eval_id=ev.id,
-                             attempt=ctx.attempts[ev.id],
-                             nack_reason=f"ack failed: {exc}")
-            else:
-                if tr is not None:
-                    tr.event("worker.attempt", eval_id=ev.id,
-                             attempt=ctx.attempts[ev.id])
+        self._ack_batch(ctx.batch, ctx.attempts)
 
     def _nack_batch(self, batch, attempts, exc: Exception) -> None:
         tr = tracing.TRACER

@@ -7,11 +7,14 @@ website/source/docs/agent/telemetry.html.md)."""
 from __future__ import annotations
 
 import bisect
+import gc
 import re
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
+
+from . import tracing
 
 
 class MetricsSink:
@@ -269,6 +272,80 @@ class Telemetry:
 
 
 NULL_TELEMETRY = Telemetry(sink=BlackholeSink())
+
+
+# ---------------------------------------------------------------------------
+# The collector's pauses (the reference's nomad.runtime.total_gc_pause_ns)
+# ---------------------------------------------------------------------------
+
+# Process-wide monotone totals, milliseconds: every collection, and
+# those of generation 2 (a full collection walks every live container).
+# Written by the gc callback alone, which takes no lock and touches no
+# sink: a collection can begin inside any allocation, a sink's or the
+# tracer's locked sections included.
+GC_PAUSE_MS = 0.0
+GC_FULL_PAUSE_MS = 0.0
+_gc_t0 = 0.0
+# Full collections not yet recorded as spans: (start, end, collected,
+# thread name), tracing.now() clock; bounded, drained by the publisher.
+_gc_full = deque(maxlen=256)
+_gc_published = [0.0, 0.0]
+_gc_watchers: set = set()
+_gc_l = threading.Lock()
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    global GC_PAUSE_MS, GC_FULL_PAUSE_MS, _gc_t0
+    if phase == "start":
+        _gc_t0 = tracing.now()
+        return
+    end = tracing.now()
+    pause = (end - _gc_t0) * 1000.0
+    GC_PAUSE_MS += pause
+    if info["generation"] == 2:
+        GC_FULL_PAUSE_MS += pause
+        if tracing.TRACER is not None:
+            _gc_full.append((_gc_t0, end, info["collected"],
+                             threading.current_thread().name))
+
+
+def watch_gc(owner) -> None:
+    """Count the collector's pauses for ``owner`` (a server): ONE
+    ``gc.callbacks`` entry however many owners a process holds."""
+    with _gc_l:
+        if not _gc_watchers:
+            gc.callbacks.append(_on_gc)
+        _gc_watchers.add(id(owner))
+
+
+def unwatch_gc(owner) -> None:
+    """The last owner to leave takes the callback with it."""
+    with _gc_l:
+        _gc_watchers.discard(id(owner))
+        if not _gc_watchers and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+
+
+def publish_gc_pauses(metrics: "Telemetry") -> None:
+    """Counters ``runtime.gc_pause_ms`` and ``runtime.gc_full_pause_ms``
+    by what the totals gained since the last call (the batch worker's,
+    once a batch; 0.0 when nothing was collected, so the keys are always
+    there).  Armed, each full collection since then is also a span
+    ``runtime.gc`` (``generation``, ``collected``, ``thread``) on the
+    trace clock, recorded here and not in the callback, which may run
+    while its thread holds the tracer's lock."""
+    with _gc_l:
+        total, full = GC_PAUSE_MS, GC_FULL_PAUSE_MS
+        gained = total - _gc_published[0], full - _gc_published[1]
+        _gc_published[:] = total, full
+        pending = [_gc_full.popleft() for _ in range(len(_gc_full))]
+    metrics.incr_counter("runtime.gc_pause_ms", gained[0])
+    metrics.incr_counter("runtime.gc_full_pause_ms", gained[1])
+    tr = tracing.TRACER
+    if tr is not None:
+        for start, end, collected, thread in pending:
+            tr.record("runtime.gc", start, end, generation=2,
+                      collected=collected, thread=thread)
 
 
 # ---------------------------------------------------------------------------

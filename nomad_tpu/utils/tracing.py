@@ -50,7 +50,7 @@ __all__ = [
     "Span", "Tracer", "TRACER", "NOOP", "now",
     "enable", "disable", "enabled", "span", "event", "record",
     "trace_for_eval", "recent", "dropped", "note_fault", "mark",
-    "close_mark",
+    "close_mark", "Stages", "timed",
 ]
 
 #: The span clock.  ``time.perf_counter()``: monotonic like
@@ -447,6 +447,147 @@ def plan_attrs(plans) -> Dict[str, Any]:
     if len(plans) == 1:
         return {"eval_id": plans[0].eval_id}
     return {"eval_ids": [plan.eval_id for plan in plans]}
+
+
+class Stages:
+    """The contiguous stages of one parent, timed for the sink always
+    and for the tracer when it is armed, from one set of stamps.
+
+    A boundary is ONE ``perf_counter`` stamp that closes the running
+    stage and opens the next (``begin``), so the stages tile their parent
+    and a stage's sample and span share their stamps.  A stage begun
+    again (a loop over specs) accumulates: ``seconds`` maps a stage to
+    its total.  Disarmed, a boundary costs its stamp and a dict store.
+
+    Spans, armed, are ``<prefix><stage>``.  ``live``: every run of a
+    stage is a live span, entered as a profiler TraceAnnotation too (the
+    device call's stages, which a device capture shows on its own
+    clock), child of the parent span the caller records afterwards under
+    ``parent_id``, reserved here.  Otherwise ``lay`` records one span a
+    stage once the parent is known, its length the stage's total, back
+    to back from the parent's start: the true intervals where each stage
+    ran once, in order.  ``with stages:`` closes whatever stage an
+    exception left open."""
+
+    __slots__ = ("prefix", "seconds", "parent_id", "_tr", "_open", "_name",
+                 "_t")
+
+    def __init__(self, prefix: str, live: bool = False) -> None:
+        self.prefix = prefix
+        self.seconds: Dict[str, float] = {}
+        self._tr = TRACER if live else None
+        self.parent_id = (self._tr.reserve_id()
+                          if self._tr is not None else 0)
+        self._open = None
+        self._name = ""
+        self._t = 0.0
+
+    def begin(self, name: str, t: Optional[float] = None) -> float:
+        """Open ``name`` at ``t`` (now when not given), closing the
+        stage that was running at the same stamp."""
+        if t is None:
+            t = now()
+        if self._name:
+            self.end(t)
+        self._name, self._t = name, t
+        if self._tr is not None:
+            self._open = self._tr.span(self.prefix + name,
+                                       parent_id=self.parent_id,
+                                       annotate=True, start=t)
+            self._open.__enter__()
+        return t
+
+    def end(self, t: Optional[float] = None) -> float:
+        if t is None:
+            t = now()
+        if self._name:
+            self.seconds[self._name] = (self.seconds.get(self._name, 0.0)
+                                        + t - self._t)
+            self._name = ""
+            if self._open is not None:
+                self._open.finish(t)
+                self._open = None
+        return t
+
+    def carve(self, name: str, seconds: float, out_of: str) -> None:
+        """Give ``name`` the ``seconds`` that a callee stamped inside
+        the stage ``out_of`` (no more than it holds): the sum stays."""
+        seconds = min(seconds, self.seconds.get(out_of, 0.0))
+        self.seconds[out_of] = self.seconds.get(out_of, 0.0) - seconds
+        self.seconds[name] = self.seconds.get(name, 0.0) + seconds
+
+    def lay(self, start: float, names, parent_id: int) -> None:
+        """Armed: one span a stage of ``names``, in that order, back to
+        back from ``start``, children of ``parent_id``."""
+        tr = TRACER
+        if tr is None:
+            return
+        for name in names:
+            end = start + self.seconds.get(name, 0.0)
+            tr.record(self.prefix + name, start, end, parent_id=parent_id)
+            start = end
+
+    def __enter__(self) -> "Stages":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
+
+
+class timed:
+    """``with tracing.timed(metrics, "plan.apply", cpu=True, ...) as t:``
+    one stage as a sink sample ``key`` (ms) always and, armed, a span of
+    the same name from the same two stamps (``t.start``, ``t.end``;
+    ``t.span`` is the open span, NOOP disarmed, ``t.span_id`` its id,
+    0 disarmed).  ``attrs`` is called for
+    the span's attrs only when the tracer is armed.  ``cpu``: also read
+    ``time.thread_time()`` at the two stamps: sample ``<key>.cpu`` and
+    attr ``cpu_ms``, the CPU time of THIS thread inside the stage.  Wall
+    less CPU is the time the thread did not run: the interpreter lock,
+    the disk, the device, a condition."""
+
+    __slots__ = ("metrics", "key", "cpu", "attrs", "kw", "start", "end",
+                 "span", "span_id", "_active", "_c0")
+
+    def __init__(self, metrics, key: str, *, cpu: bool = False,
+                 attrs=None, **kw: Any) -> None:
+        self.metrics = metrics
+        self.key = key
+        self.cpu = cpu
+        self.attrs = attrs
+        self.kw = kw
+        self.span = _NOOP
+        self.span_id = 0
+        self._active = None
+
+    def __enter__(self) -> "timed":
+        tr = TRACER
+        self.start = now()
+        if self.cpu:
+            self._c0 = time.thread_time()
+        if tr is not None:
+            attrs = self.attrs() if self.attrs is not None else {}
+            self._active = tr.span(self.key, start=self.start, **self.kw,
+                                   **attrs)
+            self.span = self._active.__enter__()
+            self.span_id = self.span.span_id
+        return self
+
+    def __exit__(self, etype, evalue, tb) -> bool:
+        cpu_ms = ((time.thread_time() - self._c0) * 1000.0
+                  if self.cpu else 0.0)
+        if self._active is None:
+            self.end = now()
+        else:
+            if self.cpu:
+                self.span.attrs["cpu_ms"] = round(cpu_ms, 4)
+            self._active.__exit__(etype, evalue, tb)
+            self.end = self.span.end
+        self.metrics.add_sample(self.key, (self.end - self.start) * 1000.0)
+        if self.cpu:
+            self.metrics.add_sample(self.key + ".cpu", cpu_ms)
+        return False
 
 
 def event(name: str, **attrs: Any) -> None:

@@ -1,5 +1,6 @@
 """Telemetry tests (reference: armon/go-metrics usage; metric names per
 website/source/docs/agent/telemetry.html.md)."""
+import gc
 import time
 
 import pytest
@@ -7,8 +8,11 @@ import pytest
 import conftest
 
 from nomad_tpu import mock
+from nomad_tpu.ops.batch_sched import ENCODE_STAGES, EXPAND_STAGES
 from nomad_tpu.server import Server, ServerConfig
+from nomad_tpu.server.plan_apply import APPLY_STAGES
 from nomad_tpu.structs import structs as s
+from nomad_tpu.utils import telemetry
 from nomad_tpu.utils.telemetry import (EXACT_WINDOW, InmemSink, Telemetry,
                                        _Histogram, render_prometheus)
 
@@ -222,15 +226,23 @@ class TestServedBatchKeys:
            "worker.invoke_scheduler.finalize.status",
            "plan.queue_wait", "plan.evaluate", "plan.commit_wait",
            "plan.apply", "plan.wake", "broker.wait",
-           "http.request.PUT.jobs", "job.register"])
+           "http.request.PUT.jobs", "job.register"]
+        # ISSUE 39: the cycle, the stages inside plan.apply, encode and
+        # expand, the hand-back, and the three CPU clocks
+        + ["worker.dequeue", "worker.release", "worker.ack", "worker.cycle",
+           "worker.cycle.unnamed", "plan.respond", "plan.evaluate.cpu",
+           "plan.apply.cpu", "worker.invoke_scheduler.cpu"]
+        + ["plan.apply." + st for st in APPLY_STAGES]
+        + ["worker.invoke_scheduler.encode." + st for st in ENCODE_STAGES]
+        + ["worker.invoke_scheduler.expand." + st for st in EXPAND_STAGES])
 
     @pytest.fixture(scope="class")
     def latest(self):
         with conftest.served_job(count=2) as (agent, _job, _eval_id):
             sink = agent.server.metrics.sink
-            # .batch closes just after the ack the helper waited for
+            # the cycle closes just after the ack the helper waited for
             assert wait_until(
-                lambda: "nomad.worker.invoke_scheduler.batch"
+                lambda: "nomad.worker.cycle"
                 in sink.latest()["SampleTotals"], 10.0)
             yield sink.latest()
 
@@ -244,7 +256,9 @@ class TestServedBatchKeys:
         assert latest["CounterTotals"]["nomad.plan.allocs_committed"] == 2
 
     @pytest.mark.parametrize("key", ["worker.invoke_scheduler.commit",
-                                     "worker.invoke_scheduler.fetch"])
+                                     "worker.invoke_scheduler.fetch",
+                                     "worker.invoke_scheduler.phase1",
+                                     "worker.invoke_scheduler.phase2"])
     def test_overlapping_samples_are_gone(self, latest, key):
         assert "nomad." + key not in latest["SampleTotals"]
 
@@ -267,6 +281,91 @@ class TestServedBatchKeys:
                     ("prepare", "encode", "device", "expand", "finalize"))
         assert batch == pytest.approx(tot[k], rel=0.05)
         assert tot[k] <= tot[k + ".batch"]
+
+
+    @pytest.mark.parametrize("key", ["runtime.gc_pause_ms",
+                                     "runtime.gc_full_pause_ms"])
+    def test_collector_pauses_are_published_with_every_batch(
+            self, latest, key):
+        """By 0.0 when nothing was collected: the key is always there."""
+        assert latest["CounterTotals"]["nomad." + key] >= 0.0
+
+
+class TestCollectorPauses:
+    """The process-wide ``gc.callbacks`` entry (utils/telemetry.py)."""
+
+    @pytest.fixture
+    def alone(self, monkeypatch):
+        """No watcher of another test's (a server it never shut down)."""
+        monkeypatch.setattr(telemetry, "_gc_watchers", set())
+        had = telemetry._on_gc in gc.callbacks
+        if had:
+            gc.callbacks.remove(telemetry._on_gc)
+        yield
+        assert telemetry._on_gc not in gc.callbacks
+        if had:
+            gc.callbacks.append(telemetry._on_gc)
+
+    def test_a_forced_collection_raises_both_totals(self, alone):
+        owner = object()
+        telemetry.watch_gc(owner)
+        try:
+            before = telemetry.GC_PAUSE_MS, telemetry.GC_FULL_PAUSE_MS
+            gc.collect()
+            assert telemetry.GC_PAUSE_MS > before[0]
+            assert telemetry.GC_FULL_PAUSE_MS > before[1]
+            young = telemetry.GC_FULL_PAUSE_MS
+            gc.collect(0)       # a young collection is not a full one
+            assert telemetry.GC_FULL_PAUSE_MS == young
+            sink = InmemSink()
+            m = Telemetry(sink=sink)
+            telemetry.publish_gc_pauses(m)
+            telemetry.publish_gc_pauses(m)      # nothing since: by 0.0
+            totals = sink.latest()["CounterTotals"]
+            assert totals["nomad.runtime.gc_pause_ms"] > 0.0
+            assert 0.0 < totals["nomad.runtime.gc_full_pause_ms"] \
+                <= totals["nomad.runtime.gc_pause_ms"]
+            gc.collect()
+            telemetry.publish_gc_pauses(m)
+            after = sink.latest()["CounterTotals"]
+            assert after["nomad.runtime.gc_full_pause_ms"] \
+                > totals["nomad.runtime.gc_full_pause_ms"]
+        finally:
+            telemetry.unwatch_gc(owner)
+
+    def test_two_servers_share_one_callback_and_the_last_removes_it(
+            self, alone):
+        first = Server(ServerConfig(num_schedulers=0))
+        second = Server(ServerConfig(num_schedulers=0))
+        try:
+            assert gc.callbacks.count(telemetry._on_gc) == 1
+            first.shutdown()
+            assert gc.callbacks.count(telemetry._on_gc) == 1
+        finally:
+            first.shutdown()
+            second.shutdown()
+        assert telemetry._on_gc not in gc.callbacks
+
+    def test_a_full_collection_is_a_span_when_armed(self, alone):
+        from nomad_tpu.utils import tracing
+
+        owner = object()
+        telemetry.watch_gc(owner)
+        tracing.enable()
+        try:
+            gc.collect()
+            # not from the callback, which may run under the tracer's lock
+            assert not [x for x in tracing.recent(10)
+                        if x["Name"] == "runtime.gc"]
+            telemetry.publish_gc_pauses(Telemetry(sink=InmemSink()))
+            (sp,) = [x for x in tracing.recent(10)
+                     if x["Name"] == "runtime.gc"]
+            assert sp["Attrs"]["generation"] == 2
+            assert sp["Attrs"]["collected"] >= 0
+            assert sp["End"] > sp["Start"]
+        finally:
+            tracing.disable()
+            telemetry.unwatch_gc(owner)
 
 
 class TestServerEmitters:

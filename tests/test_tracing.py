@@ -12,7 +12,9 @@ import pytest
 import conftest
 
 from nomad_tpu import fault, mock
+from nomad_tpu.ops.batch_sched import ENCODE_STAGES, EXPAND_STAGES
 from nomad_tpu.server import Server, ServerConfig
+from nomad_tpu.server.plan_apply import APPLY_STAGES
 from nomad_tpu.structs import structs as s
 from nomad_tpu.utils import tracing
 
@@ -149,6 +151,70 @@ class TestTracerMechanics:
         (out,) = [x for x in tracing.recent(5) if x["Name"] == "finished"]
         assert (out["Start"], out["End"]) == (t0, t0 + 1.0)
         assert tracing.TRACER.current() is None
+
+    def test_stages_tile_accumulate_and_lay_back_to_back(self):
+        st = tracing.Stages("fam.")
+        t0 = st.begin("a", 10.0)
+        st.begin("b", 10.5)
+        st.begin("a", 11.0)             # begun again: accumulates
+        assert st.end(12.0) == 12.0
+        assert st.seconds == {"a": 1.5, "b": 0.5}
+        st.carve("c", 0.25, "a")        # a callee's own stamps inside a
+        st.carve("d", 9.0, "b")         # never more than the stage holds
+        assert st.seconds == {"a": 1.25, "b": 0.0, "c": 0.25, "d": 0.5}
+        assert sum(st.seconds.values()) == 12.0 - t0
+        parent = tracing.TRACER.record("fam", t0, 12.0).span_id
+        st.lay(t0, ("a", "c", "missing", "d"), parent)
+        laid = [sp for sp in tracing.recent(10)
+                if sp["Name"].startswith("fam.")]
+        assert [sp["Name"] for sp in laid] == ["fam.a", "fam.c",
+                                               "fam.missing", "fam.d"]
+        assert all(sp["ParentID"] == parent for sp in laid)
+        assert laid[0]["Start"] == t0 and laid[-1]["End"] == 12.0
+        for a, b in zip(laid, laid[1:]):
+            assert a["End"] == b["Start"]
+
+    def test_live_stages_are_children_of_the_reserved_parent(self):
+        st = tracing.Stages("dev.", live=True)
+        with st:
+            t0 = st.begin("x")
+            tracing.event("inside")
+            st.begin("y")
+        tracing.record("dev", t0, tracing.now(), span_id=st.parent_id)
+        by = {sp["Name"]: sp for sp in tracing.recent(10)}
+        assert by["dev.x"]["ParentID"] == by["dev.y"]["ParentID"] \
+            == by["dev"]["SpanID"]
+        assert by["inside"]["ParentID"] == by["dev.x"]["SpanID"]
+        assert by["dev.x"]["End"] == by["dev.y"]["Start"]
+        assert set(st.seconds) == {"x", "y"}
+
+    @pytest.mark.parametrize("armed", [True, False])
+    def test_timed_sample_and_span_share_their_stamps(self, armed):
+        from nomad_tpu.utils.telemetry import InmemSink, Telemetry
+
+        if not armed:
+            tracing.disable()
+        sink = InmemSink()
+        calls = []
+        with tracing.timed(Telemetry(sink=sink), "stage.x", cpu=True,
+                           attrs=lambda: calls.append(1) or {"k": "v"},
+                           parent_id=7) as t:
+            sum(range(20000))
+        totals = sink.latest()["SampleTotals"]
+        count, total = totals["nomad.stage.x"]
+        assert count == 1
+        assert total == pytest.approx((t.end - t.start) * 1000.0)
+        cpu = totals["nomad.stage.x.cpu"][1]
+        assert 0.0 < cpu <= total + 1.0
+        if not armed:
+            assert calls == [] and t.span is tracing.NOOP
+            assert t.span_id == 0
+            return
+        (sp,) = [x for x in tracing.recent(5) if x["Name"] == "stage.x"]
+        assert (sp["Start"], sp["End"]) == (t.start, t.end)
+        assert sp["SpanID"] == t.span_id and sp["ParentID"] == 7
+        assert sp["Attrs"]["k"] == "v"
+        assert sp["Attrs"]["cpu_ms"] == pytest.approx(cpu, abs=1e-3)
 
     def test_fault_fire_correlation(self):
         with fault.scenario({"seed": 3, "faults": [
@@ -324,6 +390,148 @@ class TestServedBatchSpanTree:
         assert register["ParentID"] == first["SpanID"]
         assert first["Start"] <= register["Start"]
         assert register["End"] <= first["End"]
+
+
+K = "worker.invoke_scheduler"
+# parent sample → the samples that tile it (ISSUE 39)
+FAMILIES = {
+    "plan.apply": ["plan.apply." + st for st in APPLY_STAGES],
+    K + ".encode": [K + ".encode." + st for st in ENCODE_STAGES],
+    K + ".expand": [K + ".expand." + st for st in EXPAND_STAGES],
+    "worker.cycle": ["worker.dequeue", "worker.wait_for_index",
+                     "worker.snapshot", K, "worker.release", "worker.ack",
+                     "worker.cycle.unnamed"],
+}
+# span family → (parent span, prefix, stages laid back to back over it)
+LAID = [("plan.apply", "plan.apply.", APPLY_STAGES),
+        ("batch.encode", "batch.encode.", ENCODE_STAGES),
+        ("batch.metrics", "batch.metrics.", EXPAND_STAGES)]
+# span → its parent, among the spans ISSUE 39 adds
+PARENTS = ([(prefix + st, parent) for parent, prefix, names in LAID
+            for st in names]
+           + [("plan.respond", "worker.submit_plan"),
+              ("worker.release", "worker.process_batch"),
+              ("worker.ack", "worker.process_batch")])
+
+
+class TestServedStagesTile:
+    """One job served by a durable agent, tracer armed: every parent
+    stage ISSUE 39 opened is tiled by its children, as samples (their
+    sums) and as spans (their parents, across the applier's thread
+    hand-off included)."""
+
+    @pytest.fixture(scope="class")
+    def served(self, tmp_path_factory):
+        tracing.enable()
+        try:
+            with conftest.served_job(
+                    data_dir=tmp_path_factory.mktemp("tile")) as (
+                        agent, _job, eval_id):
+                sink = agent.server.metrics.sink
+                assert wait_until(
+                    lambda: "nomad.worker.cycle"
+                    in sink.latest()["SampleTotals"], timeout=10.0)
+                yield {"totals": {k[len("nomad."):]: v for k, v in
+                                  sink.latest()["SampleTotals"].items()},
+                       "timeline": tracing.trace_for_eval(eval_id),
+                       "all": tracing.recent(4096)}
+        finally:
+            tracing.disable()
+
+    @pytest.mark.parametrize("parent", sorted(FAMILIES))
+    def test_children_sum_to_their_parent(self, served, parent):
+        tot = served["totals"]
+        count, whole = tot[parent]
+        assert count == 1               # one batch, one submission
+        for child in FAMILIES[parent]:
+            assert tot[child][0] == 1, child
+            assert tot[child][1] >= 0.0, child
+        assert sum(tot[c][1] for c in FAMILIES[parent]) == pytest.approx(
+            whole, rel=1e-9, abs=1e-9)
+
+    @pytest.mark.parametrize("child,parent", PARENTS)
+    def test_new_spans_hang_under_their_parents(self, served, child,
+                                                parent):
+        (sp,) = [x for x in served["all"] if x["Name"] == child]
+        (up,) = [x for x in served["all"] if x["Name"] == parent]
+        assert sp["ParentID"] == up["SpanID"]
+        assert up["Start"] <= sp["Start"]
+        assert sp["End"] <= up["End"] + 1e-9
+
+    @pytest.mark.parametrize("parent,prefix,names", LAID)
+    def test_stage_spans_lie_back_to_back_over_the_parent(
+            self, served, parent, prefix, names):
+        (up,) = [x for x in served["all"] if x["Name"] == parent]
+        kids = [[x for x in served["all"] if x["Name"] == prefix + n][0]
+                for n in names]
+        assert kids[0]["Start"] == up["Start"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["End"] == b["Start"]
+        assert kids[-1]["End"] == pytest.approx(up["End"], abs=1e-9)
+
+    @pytest.mark.parametrize("name", ["plan.evaluate", "plan.apply",
+                                      "batch.schedule"])
+    def test_cpu_time_beside_wall_time(self, served, name):
+        (sp,) = [x for x in served["all"] if x["Name"] == name]
+        assert 0.0 <= sp["Attrs"]["cpu_ms"] <= sp["DurationMs"] + 1.0
+        key = {"batch.schedule": K}.get(name, name)
+        assert served["totals"][key + ".cpu"][1] == pytest.approx(
+            sp["Attrs"]["cpu_ms"], abs=1e-3)
+
+    def test_one_raft_apply_sample_a_call_one_span_an_entry(self, served):
+        """The plan's entry: a ``raft.apply`` span with its index under
+        ``plan.apply`` covering the sequencer wait and the FSM apply
+        alone (no longer than plan.apply.fsm), and the sample counted per
+        ``apply_many`` call with ``raft.fsync``."""
+        (up,) = [x for x in served["all"] if x["Name"] == "plan.apply"]
+        (fsm,) = [x for x in served["all"]
+                  if x["Name"] == "plan.apply.fsm"]
+        (entry,) = [x for x in served["all"] if x["Name"] == "raft.apply"
+                    and x["ParentID"] == up["SpanID"]]
+        assert entry["Attrs"]["index"] > 0
+        assert entry["Attrs"]["msg_type"] == "APPLY_PLAN_RESULTS"
+        assert up["Start"] <= entry["Start"] and entry["End"] <= up["End"]
+        assert entry["DurationMs"] <= fsm["DurationMs"] + 1e-3
+        tot = served["totals"]
+        assert tot["raft.apply"][0] == tot["raft.fsync"][0]
+
+    def test_respond_ends_where_wake_starts(self, served):
+        by = {sp["Name"]: sp for sp in served["all"]}
+        assert by["plan.apply"]["End"] == by["plan.respond"]["Start"]
+        assert by["plan.respond"]["End"] == by["plan.wake"]["Start"]
+
+    def test_the_cycle_spans(self, served):
+        by = {sp["Name"]: sp for sp in served["all"]}
+        cycle, deq = by["worker.cycle"], by["worker.dequeue"]
+        assert cycle["Start"] == deq["Start"]
+        assert deq["End"] <= by["worker.process_batch"]["Start"]
+        assert by["worker.process_batch"]["End"] <= cycle["End"]
+        # today's parentage stands: the batch's span is still a root
+        assert by["worker.process_batch"]["ParentID"] == 0
+        assert by["worker.ack"]["Attrs"]["num_evals"] == 1
+
+
+def test_disarmed_no_new_site_allocates_a_span(monkeypatch):
+    """The sites ISSUE 39 added cost their stamps and samples while the
+    tracer is disarmed: not one Span is built for a served job."""
+    tracing.disable()
+    built = []
+    init = tracing.Span.__init__
+
+    def counting(self, *args, **kwargs):
+        built.append(args[2] if len(args) > 2 else "?")
+        init(self, *args, **kwargs)
+
+    monkeypatch.setattr(tracing.Span, "__init__", counting)
+    with conftest.served_job() as (agent, _job, _eval_id):
+        sink = agent.server.metrics.sink
+        assert wait_until(lambda: "nomad.worker.cycle"
+                          in sink.latest()["SampleTotals"], timeout=10.0)
+        totals = sink.latest()["SampleTotals"]
+    for parent, children in FAMILIES.items():
+        for key in [parent] + children:
+            assert "nomad." + key in totals, key
+    assert built == []
 
 
 class TestTraceHTTP:
