@@ -18,6 +18,7 @@ import dataclasses
 import typing
 from typing import Any, Dict, Optional, Type
 
+from ..structs.structs import NodeScores
 from ..utils.names import go_name
 
 _HINTS_CACHE: Dict[type, Dict[str, Any]] = {}
@@ -59,6 +60,10 @@ def to_wire(v: Any) -> Any:
         # Lazily-generated slab columns (structs._LazyStrs) materialize
         # to plain string lists on the wire.
         return list(v)
+    if type(v) is NodeScores:
+        # A device-path score map (structs.NodeScores): a copy of its
+        # dictionary (dict(v) would ask it key by key).
+        return dict(v.as_dict())
     return v
 
 

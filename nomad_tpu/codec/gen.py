@@ -26,7 +26,10 @@ Layout (little-endian throughout):
               written from an indexed column (structs.NodeColumn: rows
               of a table of pre-packed ids, gathered): the same bytes as
               from the list of its strings, decoded as a plain list
-- Dict[str,X] varint count + (str, X) pairs
+- Dict[str,X] varint count + (str, X) pairs; a Dict[str,float] may be
+              written from structs.NodeScores (node rows and their
+              scores, gathered): the same bytes as from its dictionary,
+              decoded as a plain dict
 - Any         tagged value tree (see ``_val``), which also carries whole
               raft log payloads: dicts/lists/scalars plus any registered
               dataclass (tag 9 + type id + flat body)
@@ -271,6 +274,10 @@ def _emit_enc(src: _Src, ind: int, expr: str, plan: tuple) -> None:
         src.emit(ind, f"_uv(w, len({t}))")
         src.emit(ind, f"for {u} in {t}:")
         _emit_enc(src, ind + 1, u, plan[1])
+    elif kind == "dict" and plan[1] == ("float",):
+        t = src.tmp()
+        src.emit(ind, f"{t} = {expr}")
+        src.emit(ind, f"_uv(w, len({t})); w += _scores({t})")
     elif kind == "dict":
         t, k, u, kb = src.tmp(), src.tmp(), src.tmp(), src.tmp()
         src.emit(ind, f"{t} = {expr}")
@@ -340,7 +347,8 @@ def _field_plans(cls: type) -> List[Tuple[str, tuple]]:
 _NAMESPACE: Dict[str, Any] = {
     "_uv": _uv, "_zz": _zz, "_pd": _pd, "_duv": _duv, "_dzz": _dzz,
     "_dstr": _dstr, "_dbytes": _dbytes, "_dby": _dby, "_dd": _dd,
-    "_strs": _strs, "_dstrs": _dstrs, "_E": _ENCODERS, "_D": _DECODERS,
+    "_strs": _strs, "_dstrs": _dstrs, "_scores": native.pack_scores,
+    "_E": _ENCODERS, "_D": _DECODERS,
 }
 
 

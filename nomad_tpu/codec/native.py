@@ -16,14 +16,20 @@ peer quietly.  ``NOMAD_TPU_NO_NATIVE=1`` forces the twin.
 
 A slab committed by the device path carries its node column as integers
 (structs.NodeColumn); ``pack_column`` writes the same bytes for it by
-one gather of the fleet's pre-packed ids, under the same guard.
+one gather of the fleet's pre-packed ids, under the same guard.  Its
+prototype's score map is integers and floats too (structs.NodeScores);
+``pack_scores`` writes a ``Dict[str, float]``'s pairs, that one by the
+same gather with the scores' own bytes beside each key.
 """
 from __future__ import annotations
 
 import ctypes
 import logging
 import os
+import struct
 from typing import List, Tuple
+
+from ..structs.structs import NodeScores
 
 logger = logging.getLogger("nomad_tpu.codec")
 
@@ -32,6 +38,7 @@ GUARD_MISMATCHES = 0
 NATIVE_PACKS = 0
 NATIVE_UNPACKS = 0
 COLUMN_PACKS = 0
+SCORE_PACKS = 0
 
 _guard_counter = 0
 _native_disabled = False
@@ -47,9 +54,9 @@ def guard_every() -> int:
 
 def reset_counters() -> None:
     global GUARD_RUNS, GUARD_MISMATCHES, NATIVE_PACKS, NATIVE_UNPACKS
-    global COLUMN_PACKS, _guard_counter, _native_disabled
+    global COLUMN_PACKS, SCORE_PACKS, _guard_counter, _native_disabled
     GUARD_RUNS = GUARD_MISMATCHES = 0
-    NATIVE_PACKS = NATIVE_UNPACKS = COLUMN_PACKS = 0
+    NATIVE_PACKS = NATIVE_UNPACKS = COLUMN_PACKS = SCORE_PACKS = 0
     _guard_counter = 0
     _native_disabled = False
 
@@ -110,6 +117,25 @@ def _py_pack_strs(encoded: List[bytes]) -> bytes:
             n >>= 7
         w.append(n)
         w += e
+    return bytes(w)
+
+
+_pd = struct.Struct("<d").pack
+
+
+def _py_pack_scores(scores) -> bytes:
+    """A ``Dict[str, float]``'s pairs as the struct codec lays them:
+    varint length + utf8 key, 8-byte little-endian double."""
+    w = bytearray()
+    for k, x in scores.items():
+        e = k.encode("utf-8")
+        n = len(e)
+        while n > 0x7F:
+            w.append(0x80 | (n & 0x7F))
+            n >>= 7
+        w.append(n)
+        w += e
+        w += _pd(x)
     return bytes(w)
 
 
@@ -182,21 +208,26 @@ def _guard_due() -> bool:
     return True
 
 
-def _packed_table(table):
+def _packed_table(table, suffix: str = ""):
     """Each id of ``table`` (structs.NodeTable) as ``pack_strs`` writes
     it, made once per table: one fixed-width ``S`` array when every
     packed id has the same length (a uuid fleet, ``node-00001``), else a
-    list of ``bytes``."""
-    packed = table.packed
+    list of ``bytes``.  With a ``suffix``, each id + suffix: a score
+    map's keys, kept per suffix beside the plain ids."""
+    packed = table.packed_keys.get(suffix) if suffix else table.packed
     if packed is None:
         import numpy as np
 
-        packed = [_py_pack_strs([s.encode("utf-8")]) for s in table.ids]
+        packed = [_py_pack_strs([(s + suffix).encode("utf-8")])
+                  for s in table.ids]
         widths = set(map(len, packed))
         if len(widths) == 1:
             packed = np.frombuffer(b"".join(packed),
                                    dtype=f"S{widths.pop()}")
-        table.packed = packed
+        if suffix:
+            table.packed_keys[suffix] = packed
+        else:
+            table.packed = packed
     return packed
 
 
@@ -221,6 +252,49 @@ def pack_column(col) -> bytes:
         twin = _py_pack_strs([s.encode("utf-8") for s in col])
         if result != twin:
             _note_mismatch("pack_column")
+            return twin
+    return result
+
+
+def _gather_pairs(keys, idx, values) -> bytes:
+    """``keys[i]`` then ``values``' own 8 bytes, for every ``i`` of
+    ``idx`` in order: one record array for fixed-width keys."""
+    import numpy as np
+
+    values = np.asarray(values, dtype="<f8")
+    if type(keys) is list:
+        vb = values.tobytes()
+        out = [b""] * (2 * len(idx))
+        out[0::2] = map(keys.__getitem__, idx.tolist())
+        out[1::2] = [vb[i:i + 8] for i in range(0, len(vb), 8)]
+        return b"".join(out)
+    rec = np.empty(len(idx), dtype=[("k", keys.dtype), ("v", "<f8")])
+    rec["k"] = keys[idx]
+    rec["v"] = values
+    return rec.tobytes()
+
+
+def pack_scores(scores) -> bytes:
+    """The pairs of a ``Dict[str, float]`` field; caller writes the
+    count.  For a structs.NodeScores the bytes the loop over its
+    dictionary would write, from its integers and floats by one gather
+    of the table's pre-packed keys: no string is made or encoded.
+    Guarded like ``pack_column``, and turned off with it."""
+    global SCORE_PACKS
+    if (type(scores) is not NodeScores or _native_disabled
+            or not len(scores)):
+        return _py_pack_scores(scores)
+    result = _gather_pairs(_packed_table(scores.table, scores.BINPACK),
+                           scores.idx, scores.binpack)
+    if len(scores.anti_pos):
+        result += _gather_pairs(
+            _packed_table(scores.table, scores.ANTI_AFFINITY),
+            scores.idx[scores.anti_pos], scores.anti)
+    SCORE_PACKS += 1
+    if _guard_due():
+        twin = _py_pack_scores(scores)
+        if result != twin:
+            _note_mismatch("pack_scores")
             return twin
     return result
 

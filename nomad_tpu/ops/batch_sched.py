@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import hashlib
 import logging
+import threading
 import time
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
@@ -284,6 +285,24 @@ ENCODE_STAGES = ("nodes", "resident", "specs", "sparse", "pack")
 EXPAND_STAGES = ("preempt", "slots", "scores", "failures")
 
 
+# structs.SCORE_MAPS_BUILT as of the last batch that published it.
+_score_maps_published = 0
+_score_maps_l = threading.Lock()
+
+
+def _publish_score_maps(metrics) -> None:
+    """Counter ``batch.score_maps_built`` by what the process's total
+    gained since the last batch's call (as telemetry.publish_gc_pauses:
+    a NodeScores is turned into strings on whichever thread reads it;
+    0 when nobody did, so the key is always there)."""
+    global _score_maps_published
+    with _score_maps_l:
+        built = s.SCORE_MAPS_BUILT
+        gained = built - _score_maps_published
+        _score_maps_published = built
+    metrics.incr_counter("batch.score_maps_built", gained)
+
+
 class _PreparedBatch:
     """One batch between prepare and complete: the host-phase outputs
     plus the in-flight device handle (schedule_stream pipelining keeps
@@ -406,6 +425,7 @@ class TPUBatchScheduler:
         # The collector's pauses since the worker's last batch, on
         # whichever thread they fell (0.0 when nothing was collected).
         telemetry.publish_gc_pauses(m)
+        _publish_score_maps(m)
         # Device-path phases only when the kernel actually ran: oracle-
         # routed or ask-less batches would otherwise flood the percentile
         # windows with zeros exactly when the device path is degraded.
@@ -437,6 +457,7 @@ class TPUBatchScheduler:
             m.incr_counter("batch.constraint_row_reuse",
                            stats.constraint_row_reuse)
             m.incr_counter("batch.dp_specs", stats.dp_specs)
+            m.incr_counter("batch.score_columns", stats.score_columns)
             m.incr_counter("batch.dp_dense_specs", stats.dp_dense_specs)
             m.incr_counter("batch.multi_round_specs",
                            stats.multi_round_specs)
@@ -817,6 +838,7 @@ class TPUBatchScheduler:
             stats.constraint_rows_seconds = kstats["constraint_rows_seconds"]
             stats.constraint_row_reuse = kstats["constraint_row_reuse"]
             stats.dp_specs = kstats["dp_specs"]
+            stats.score_columns = kstats["score_columns"]
             stats.dp_dense_specs = kstats["dp_dense_specs"]
             stats.commit_seconds = kstats.get("commit_seconds", 0.0)
             stats.dispatch_seconds = kstats.get("dispatch_seconds", 0.0)
@@ -1644,7 +1666,6 @@ class TPUBatchScheduler:
         node_table = getattr(ct, "_node_table", None)
         if node_table is None:
             node_table = s.NodeTable(ct.node_ids)
-        node_id_arr = node_table.ids
         total_asks = int(sum(sp.count for sp in spec_list))
         exp_off, exp_idx = decode_mod.expand_coo(
             coo_rows, coo_cols, coo_counts, len(spec_list), ct.n_real,
@@ -1653,6 +1674,11 @@ class TPUBatchScheduler:
             s_off, s_col, s_sc, s_co = decode_mod.last_scores(
                 coo_rows, coo_cols, coo_scores, coo_coll,
                 len(spec_list), ct.n_real, breaker=self.breaker)
+            # What a score map holds, once for the batch: the scores as
+            # the doubles .tolist() would give, and whether any node had
+            # a same-job collision at all.
+            s_sc = s_sc.astype(np.float64)
+            any_co = bool((s_co > 0).any())
 
         # used_after is reconstructed host-side from used0 + committed
         # placements × asks — exact (integer adds, same order-free sum the
@@ -1772,6 +1798,7 @@ class TPUBatchScheduler:
         # uniform fleets fail by the hundreds with identical signatures,
         # so the vectorized-but-per-spec forensics run once per shape.
         fail_cache: Dict[Tuple, s.AllocMetric] = {}
+        score_columns = 0
         # Stamps per spec, none per allocation: two for a spec placed
         # whole, four for one that left asks unplaced.
         for u, sp in enumerate(spec_list):
@@ -1820,22 +1847,22 @@ class TPUBatchScheduler:
             # (matrix-mode semantics: commit_scores[u, n] was
             # overwritten per commit; score_node ADDS, so summed
             # per-commit scores would break the 0-18 ScoreFit bound).
-            # The dict is built in bulk — one key per committed node —
-            # instead of a score_node call per entry (70k python calls
-            # at the north-star shape).
+            # The map stays those arrays (structs.NodeScores: a dict to
+            # whoever reads one; the log codec writes it from the
+            # integers): two slices a spec, no string, no boxed float.
             if with_scores:
                 s_lo, s_hi = int(s_off[u]), int(s_off[u + 1])
                 if s_hi > s_lo:
-                    ids = node_id_arr[s_col[s_lo:s_hi]].tolist()
-                    m.scores = {
-                        nid + ".binpack": sc for nid, sc in
-                        zip(ids, s_sc[s_lo:s_hi].tolist())}
-                    co_seg = s_co[s_lo:s_hi]
-                    if (co_seg > 0).any():
-                        pen = float(sp.anti_affinity_penalty)
-                        for j in np.nonzero(co_seg > 0)[0].tolist():
-                            m.scores[ids[j] + ".job-anti-affinity"] = \
-                                -pen * int(co_seg[j])
+                    anti_pos = anti = ()
+                    if any_co:
+                        co_seg = s_co[s_lo:s_hi]
+                        anti_pos = np.nonzero(co_seg > 0)[0]
+                        anti = (-float(sp.anti_affinity_penalty)
+                                * co_seg[anti_pos].astype(np.float64))
+                    m.scores = s.NodeScores(
+                        node_table, s_col[s_lo:s_hi], s_sc[s_lo:s_hi],
+                        anti_pos, anti)
+                    score_columns += 1
             if n_unplaced > 0:
                 exp.begin("failures")
                 placed_row = np.zeros(ct.n_real, dtype=np.int32)
@@ -1870,6 +1897,8 @@ class TPUBatchScheduler:
             "constraint_rows_seconds": sum(b - a for a, b in st.row_stamps),
             "constraint_row_reuse": st.rows_reused,
             "dp_specs": dp_specs,
+            # The specs whose scores were handed over as arrays.
+            "score_columns": score_columns,
             # Of those, the ones whose program read its per-value tables
             # in the dense form: all or none, by the dispatched v_pad.
             "dp_dense_specs": (dp_specs if kernels.dp_dense(
@@ -2529,6 +2558,9 @@ class BatchStats:
         self.constraint_row_reuse = 0
         self.dp_specs = 0
         self.dp_dense_specs = 0
+        # Specs whose AllocMetric.scores stayed the device's arrays
+        # (structs.NodeScores).
+        self.score_columns = 0
         # Fused score-and-commit path (PR 6): whether this batch ran the
         # single-dispatch/single-fetch program, the wall time of that
         # dispatch (upload → device compute → result transfer), the wall

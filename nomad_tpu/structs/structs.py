@@ -16,6 +16,7 @@ import os as _os
 import threading as _threading
 import time
 import uuid
+from collections.abc import Mapping
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
@@ -903,7 +904,9 @@ class AllocMetric:
         m.constraint_filtered = dict(self.constraint_filtered)
         m.class_exhausted = dict(self.class_exhausted)
         m.dimension_exhausted = dict(self.dimension_exhausted)
-        m.scores = dict(self.scores)
+        # A NodeScores is immutable: shared, not turned into strings.
+        if type(self.scores) is not NodeScores:
+            m.scores = dict(self.scores)
         return m
 
     def evaluate_node(self) -> None:
@@ -925,6 +928,8 @@ class AllocMetric:
 
     def score_node(self, node: Node, name: str, score: float) -> None:
         key = f"{node.id}.{name}"
+        if type(self.scores) is NodeScores:
+            self.scores = dict(self.scores.as_dict())
         self.scores[key] = self.scores.get(key, 0.0) + score
 
 
@@ -1396,7 +1401,7 @@ class NodeTable:
     once with the fleet's static tensors (ops/encode), not per batch; a
     slab that outlives the encoding keeps it alive."""
 
-    __slots__ = ("ids", "packed", "_perms")
+    __slots__ = ("ids", "packed", "packed_keys", "_perms")
 
     # Row permutations kept, one per index dict asked about (the store's
     # mirror, the resident mirror, a snapshot's private copy).
@@ -1409,6 +1414,9 @@ class NodeTable:
         # Each id as the struct codec writes it; filled by the codec the
         # first time it packs a column of this table (codec/native.py).
         self.packed = None
+        # Each id with a score key's suffix, as the codec writes the
+        # key: {suffix: array or list}, filled the same way.
+        self.packed_keys: dict = {}
         self._perms: tuple = ()
 
     def rows_in(self, index: Dict[str, int]):
@@ -1475,6 +1483,94 @@ class NodeColumn(_LazyStrs):
         return NotImplemented
 
     __hash__ = None
+
+
+# NodeScores turned into their dictionary of strings, process-wide, on
+# whichever thread asked (the batch worker publishes what it gained).
+SCORE_MAPS_BUILT = 0
+
+
+class NodeScores(Mapping):
+    """A spec's commit-time scores as the device returned them: rows
+    ``idx`` of the encoded fleet ``table`` and their float64 binpack
+    scores, plus the sparse anti-affinity part (positions ``anti_pos``
+    of ``idx`` and the penalties ``anti``; usually empty).  ``idx``
+    names no row twice (decode.last_scores keeps a node's last commit),
+    so the arrays' lengths are the dictionary's.  To a reader
+    it IS the dictionary ``AllocMetric.scores`` holds on the oracle
+    path: ``<node id>.binpack`` for every row in order, then
+    ``<node id>.job-anti-affinity`` in position order; made by one
+    gather on first such use and kept.  The log codec writes the same
+    bytes from the integers (codec/native.pack_scores) and makes no
+    string.  Read-only: ``AllocMetric.score_node`` replaces it by its
+    dictionary before it adds."""
+
+    __slots__ = ("table", "idx", "binpack", "anti_pos", "anti", "_map")
+
+    BINPACK = ".binpack"
+    ANTI_AFFINITY = ".job-anti-affinity"
+
+    def __init__(self, table: NodeTable, idx, binpack,
+                 anti_pos=(), anti=()) -> None:
+        self.table = table
+        self.idx = idx
+        self.binpack = binpack
+        self.anti_pos = anti_pos
+        self.anti = anti
+        self._map: Optional[Dict[str, float]] = None
+
+    def as_dict(self) -> Dict[str, float]:
+        """The scores as the dictionary of their strings, made once and
+        kept; shared, so read-only (``dict(x)`` is a copy)."""
+        m = self._map
+        if m is None:
+            global SCORE_MAPS_BUILT
+            ids = self.table.ids[self.idx]
+            m = dict(zip((ids + self.BINPACK).tolist(),
+                         self.binpack.tolist()))
+            if len(self.anti_pos):
+                m.update(zip((ids[self.anti_pos]
+                              + self.ANTI_AFFINITY).tolist(),
+                             self.anti.tolist()))
+            self._map = m
+            SCORE_MAPS_BUILT += 1
+        return m
+
+    def __len__(self) -> int:
+        return len(self.idx) + len(self.anti_pos)
+
+    def __getitem__(self, key):
+        return self.as_dict()[key]
+
+    def __iter__(self):
+        return iter(self.as_dict())
+
+    def __contains__(self, key) -> bool:
+        return key in self.as_dict()
+
+    def get(self, key, default=None):
+        return self.as_dict().get(key, default)
+
+    def keys(self):
+        return self.as_dict().keys()
+
+    def values(self):
+        return self.as_dict().values()
+
+    def items(self):
+        return self.as_dict().items()
+
+    def __eq__(self, other):
+        if isinstance(other, NodeScores):
+            other = other.as_dict()
+        if isinstance(other, dict):
+            return self.as_dict() == other
+        return NotImplemented
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return f"NodeScores({self.as_dict()!r})"
 
 
 @dataclass
