@@ -85,6 +85,11 @@ _CLUSTER_CACHE = LRU(4)
 # starts a new entry.
 _VOCABULARY = LRU(4)
 
+# Whether every node's networks are simple enough for the device's port
+# accounting (TPUBatchScheduler._cluster_networks_simple), by (store
+# lineage, nodes-table raft index).
+_NETWORKS_SIMPLE = LRU(4)
+
 
 def _widen_vocabulary(key, attr_targets, literals):
     targets, lits = _VOCABULARY.get(key, ((), {}))
@@ -462,6 +467,8 @@ class TPUBatchScheduler:
             m.incr_counter("batch.multi_round_specs",
                            stats.multi_round_specs)
             m.incr_counter("batch.spec_passes", stats.spec_passes)
+            m.incr_counter("batch.net_usage_walks", stats.net_usage_walks)
+            m.incr_counter("batch.net_delta_words", stats.net_delta_words)
             # Bytes are a COUNTER (rate-derivable total), not a sample:
             # the percentile histogram's buckets are ms-calibrated and
             # would quantize MB-scale values into the top bucket.
@@ -491,6 +498,11 @@ class TPUBatchScheduler:
                          stats.finalize_submit_seconds * 1000.0)
             m.add_sample("worker.invoke_scheduler.finalize.status",
                          stats.finalize_status_seconds * 1000.0)
+            # Inside build: the network offers.
+            m.add_sample("worker.invoke_scheduler.finalize.offers",
+                         stats.finalize_offers_seconds * 1000.0)
+            m.incr_counter("batch.net_offer_failures",
+                           stats.net_offer_failures)
         m.add_sample("worker.invoke_scheduler.asks", stats.num_asks)
         # Residency counters: per-batch samples plus the process-lifetime
         # gauges (ops/resident.py module counters).
@@ -872,6 +884,8 @@ class TPUBatchScheduler:
         stats.full_reencodes = 1 if res_info.get("full_reencode") else 0
         stats.staleness_fences = 1 if res_info.get("fence") else 0
         stats.delta_apply_seconds = res_info.get("delta_apply_s", 0.0)
+        stats.net_usage_walks = res_info.get("net_walks", 0)
+        stats.net_delta_words = res_info.get("net_delta_words", 0)
 
     def _route_through_oracle(self, scheds) -> None:
         """Degraded path: process each eval with the CPU GenericScheduler
@@ -910,7 +924,21 @@ class TPUBatchScheduler:
     def _cluster_networks_simple(self) -> bool:
         """Device port accounting assumes ≤1 network device per node with a
         single-IP CIDR (the common fingerprinted shape); anything richer
-        keeps the oracle's per-IP iteration (network.go:245)."""
+        keeps the oracle's per-IP iteration (network.go:245).  Read once
+        per nodes table: kept by (store lineage, nodes-table raft index),
+        which a node's registration or update moves."""
+        table_index = getattr(self.state, "table_index", None)
+        key = (getattr(self.state, "store_uid", None),
+               table_index("nodes") if table_index is not None else None)
+        simple = (_NETWORKS_SIMPLE.get(key)
+                  if None not in key else None)
+        if simple is None:
+            simple = self._walk_networks_simple()
+            if None not in key:
+                _NETWORKS_SIMPLE.put(key, simple)
+        return simple
+
+    def _walk_networks_simple(self) -> bool:
         import ipaddress
         for node in self.state.nodes(None):
             nets = [nr for nr in (node.resources.networks or []) if nr.device]
@@ -1023,6 +1051,54 @@ class TPUBatchScheduler:
             return used, set(ref_touched)
         return used, touched
 
+    def _with_net_usage(self, ct, base, net_used, spec_list, *, held: bool,
+                        info: Dict):
+        """``ct`` with what the fleet's allocations hold on their nodes'
+        networks (``net_used``, the resident network mirror's rows) and,
+        per node, one bit for each static port the batch's specs ask for,
+        set where the port is reserved or held — the only ports a pass
+        can collide on (dynamic ones are counted, and picked at
+        finalize).  ``held``: ``ct.port_words`` already carries what the
+        allocations hold (the walk built it); otherwise the nodes'
+        reservations only, and the asked ports' holders come from a walk
+        (``info["net_walks"]``).  Returns ``(ct, port_bits)``:
+        ``{port: bit}`` in the order the bits are laid out."""
+        import dataclasses as _dc
+
+        ports = sorted({p for sp in spec_list if sp.net_active
+                        for p in sp.resv_ports})
+        port_bits = {p: j for j, p in enumerate(ports)}
+        words = encode.pow2_bucket(max(1, -(-len(ports) // 32)), minimum=1)
+        bits = np.zeros((ct.n_pad, len(ports)), dtype=bool)
+        for p, j in port_bits.items():
+            bits[:, j] = (ct.port_words[:, p >> 5] >> np.uint32(p & 31)) & 1
+        if ports and not held:
+            info["net_walks"] = info.get("net_walks", 0) + 1
+            node_index = base._node_index  # type: ignore[attr-defined]
+            for nid, rows in self._live_allocs_by_node().items():
+                i = node_index.get(nid)
+                if i is None:
+                    continue
+                for row in rows:
+                    for tr in row.task_resources.values():
+                        if not tr.networks:
+                            continue
+                        nr = tr.networks[0]
+                        for port in nr.reserved_ports + nr.dynamic_ports:
+                            j = port_bits.get(port.value)
+                            if j is not None:
+                                bits[i, j] = True
+        port_words = np.zeros((ct.n_pad, words), dtype=np.uint32)
+        for j in range(len(ports)):
+            port_words[:, j >> 5] |= bits[:, j].astype(np.uint32) << np.uint32(
+                j & 31)
+        new = _dc.replace(
+            ct, port_words=port_words,
+            bw_used=(base.bw_used + net_used[:, 0]).astype(np.int32),
+            dyn_free=(base.dyn_free - net_used[:, 1]).astype(np.int32))
+        encode._carry_host_attrs(ct, new)
+        return new, port_bits
+
     def _dispatch_device(self, spec_list: List[encode.PlacementSpec]):
         """Host encode + async device dispatch: everything up to (but
         not including) the blocking fetch.  Returns the in-flight handle
@@ -1041,6 +1117,9 @@ class TPUBatchScheduler:
 
         attr_targets, literals = encode.collect_attr_targets(spec_list)
         with_networks = any(sp.net_active for sp in spec_list)
+        # The node mesh keeps its own network protocol: per touched node
+        # sparse rows over the static port bitmaps (parallel/sharded.py).
+        mesh_net = with_networks and self.mesh is not None
         # Node-axis pad multiple: the TPU lane width (128), raised to a
         # common multiple of the mesh size when this scheduler schedules
         # over a Mesh — MISSING-filled pad shards are infeasible by
@@ -1083,7 +1162,8 @@ class TPUBatchScheduler:
         # eligible — O(changed allocs) via the state store's usage-delta
         # feed — otherwise the full O(cluster) walk + layer.
         resident_info: Dict = {}
-        use_resident = (resident.enabled() and not with_networks
+        net_used = None         # [n_pad, NET_DIMS]: what allocs' networks hold
+        use_resident = (resident.enabled() and not mesh_net
                         and cache_key is not None
                         and getattr(self.state, "alloc_log_since", None)
                         is not None)
@@ -1100,9 +1180,11 @@ class TPUBatchScheduler:
                 self._live_allocs_by_node, breaker=self.breaker,
                 shards=(self.mesh.devices.size
                         if self.mesh is not None else 0),
-                usage_fn=lambda: self._columnar_usage(base))
+                usage_fn=lambda: self._columnar_usage(base),
+                with_net=with_networks)
             enc.begin("specs")
             ct = encode.with_usage(base, used)
+            net_used = resident_info.pop("net", None)
             # The preemption pass only needs WHICH nodes may carry live
             # allocs (it re-materializes candidate rows from state);
             # avoid the full row walk the resident path just saved.
@@ -1125,8 +1207,19 @@ class TPUBatchScheduler:
                 touched = sorted(i for i in (node_index.get(nid)
                                              for nid in allocs_by_node)
                                  if i is not None)
+                if with_networks:
+                    resident_info["net_walks"] = 1
+                    net_used = np.stack(
+                        [ct.bw_used - base.bw_used,
+                         base.dyn_free - ct.dyn_free], axis=1)
             enc.begin("specs")
-        st = encode.encode_specs(spec_list, ct, all_nodes)
+        port_bits = None
+        if with_networks and not mesh_net:
+            ct, port_bits = self._with_net_usage(
+                ct, base, net_used, spec_list,
+                held=not use_resident, info=resident_info)
+        st = encode.encode_specs(spec_list, ct, all_nodes,
+                                 port_bits=port_bits)
         # The batch's shape plan, or the compiled plan of its shape class
         # that covers it (kernels.choose_plan: a drain's tail batch runs
         # the full batches' program instead of compiling its own).
@@ -1197,8 +1290,9 @@ class TPUBatchScheduler:
                           used_base=base.used.astype(np.int32))
         if with_networks:
             static.update(bw_cap=ct.bw_cap, bw_used_base=base.bw_used,
-                          dyn_free_base=base.dyn_free,
-                          port_words_base=base.port_words)
+                          dyn_free_base=base.dyn_free)
+            if mesh_net:
+                static.update(port_words_base=base.port_words)
 
         # Sparse usage deltas over the static reserved-only baseline: one
         # row per node carrying live allocs this batch (``touched`` comes
@@ -1237,6 +1331,9 @@ class TPUBatchScheduler:
                 dtype=np.int32),
         }
         if with_networks:
+            dyn.update(net_active=st.net_active, net_mbits=st.net_mbits,
+                       dyn_need=st.dyn_need, resv_words=st.resv_words)
+        if mesh_net:
             u_bw = np.zeros(k_u, dtype=np.int32)
             u_dyn = np.zeros(k_u, dtype=np.int32)
             u_ports = np.zeros((k_u, ct.port_words.shape[1]),
@@ -1245,9 +1342,14 @@ class TPUBatchScheduler:
                 u_bw[:len(touched)] = ct.bw_used[tr] - base.bw_used[tr]
                 u_dyn[:len(touched)] = ct.dyn_free[tr] - base.dyn_free[tr]
                 u_ports[:len(touched)] = ct.port_words[tr]
-            dyn.update(net_active=st.net_active, net_mbits=st.net_mbits,
-                       dyn_need=st.dyn_need, resv_words=st.resv_words,
-                       u_bw=u_bw, u_dyn=u_dyn, u_ports=u_ports)
+            dyn.update(u_bw=u_bw, u_dyn=u_dyn, u_ports=u_ports)
+        elif with_networks:
+            # What the fleet's allocations hold, whole, unless the
+            # resident network mirror's device twin is loaned below; the
+            # ports the batch asks for are usage too, and ride here when
+            # there are any (else the static pack holds the zero rows).
+            dyn.update(bw_used=ct.bw_used, dyn_free=ct.dyn_free)
+            (dyn if port_bits else static)["port_words"] = ct.port_words
         if with_dp:
             dyn.update(dp_col=st.dp_col, dp_active=st.dp_active,
                        dp_used=st.dp_used)
@@ -1279,6 +1381,13 @@ class TPUBatchScheduler:
             # degrade to the single-chip program below, at its own plan.
             _, slot_m, max_nnz, _ = self._natural_plan(spec_list, ct, st,
                                                        mesh=False)
+            if mesh_net:
+                # The single-chip protocol, over every port (the specs'
+                # reserved ports are numbered as they are).
+                del static["port_words_base"]
+                del dyn["u_bw"], dyn["u_dyn"], dyn["u_ports"]
+                dyn.update(bw_used=ct.bw_used, dyn_free=ct.dyn_free,
+                           port_words=ct.port_words)
 
         # Donated device-resident usage mirror (ISSUE 13): when the
         # resident slot exactly matches this batch's (key, allocs
@@ -1289,15 +1398,26 @@ class TPUBatchScheduler:
         # The mesh path has its own sharded twin of this loan inside
         # _dispatch_mesh (ISSUE 14); this branch is the single-chip
         # layout only.
-        used_dev = None
+        used_dev = net_dev = None
         res_key = snap_index = None
         if use_resident and self.mesh is None:
             res_key = cache_key[:2] + (base.n_pad,)
             snap_index = self.state.table_index("allocs")
             used_dev = resident.take_device_used(res_key, snap_index,
                                                  used)
+            if used_dev is not None and net_used is not None:
+                net_dev = resident.take_device_used(
+                    res_key, snap_index, net_used, what="net")
         if used_dev is not None:
             del dyn["u_rows"], dyn["u_vals"]
+        if net_dev is not None:
+            del dyn["bw_used"], dyn["dyn_free"]
+        if with_networks:
+            resident_info["net_delta_words"] = resident_info.get(
+                "net_delta_words", 0) + sum(
+                    dyn[k].size for k in ("bw_used", "dyn_free",
+                                          "port_words", "u_bw", "u_dyn",
+                                          "u_ports") if k in dyn)
 
         sbuf, meta_s = xfer.pack_host(static)
         dbuf, meta_d = xfer.pack_host(dyn)
@@ -1333,9 +1453,9 @@ class TPUBatchScheduler:
             # emitting ONE packed result buffer, fetched in a single
             # transfer by _fetch_device (the aux overflow source stays
             # device-resident, touched only on window overflow).
-            fused_buf, fused_aux, feas, fused_meta, used_out = \
+            fused_buf, fused_aux, feas, fused_meta, used_out, net_out = \
                 kernels.fused_pass(
-                    static_dev, dyn_dev, used_dev,
+                    static_dev, dyn_dev, used_dev, net_dev,
                     meta_s=meta_s, meta_d=meta_d, u_pad=st.u_pad,
                     n_pad=ct.n_pad, with_networks=with_networks,
                     with_dp=with_dp, with_scores=with_scores,
@@ -1345,6 +1465,9 @@ class TPUBatchScheduler:
                 # The kernel aliased the donated mirror back out — return
                 # the loan so the next batch's delta apply lands in place.
                 resident.give_device_used(res_key, snap_index, used_out)
+            if net_out is not None:
+                resident.give_device_used(res_key, snap_index, net_out,
+                                          what="net")
         # Device pass is dispatched (JAX async); the blocking fetch lives
         # in _fetch_device so a pipelining caller can overlap host work.
         return {
@@ -2268,7 +2391,8 @@ class TPUBatchScheduler:
         t_in = t_sub = time.perf_counter()
         for ev, sched in scheds:
             t_built = self._finalize_build(
-                ev, sched, specs, expanded, per_spec_metrics, net_index_cache)
+                ev, sched, specs, expanded, per_spec_metrics, net_index_cache,
+                stats)
             if tr is not None:
                 tr.record("batch.finalize.build", t_sub, t_built,
                           eval_id=ev.id)
@@ -2304,13 +2428,18 @@ class TPUBatchScheduler:
         stats.finalize_build_seconds = t_sub - t_in
         stats.finalize_submit_seconds = t_st - t_sub
         stats.finalize_status_seconds = t_out - t_st
+        if tr is not None and stats.finalize_offers_seconds:
+            # Laid from the build pass's start, its length the offers'
+            # total over the batch (Stages.lay's convention).
+            tr.record("batch.finalize.offers", t_in,
+                      t_in + stats.finalize_offers_seconds)
         if tr is not None:
             ids = tracing.eval_id_attrs((ev for ev, _ in scheds), len(scheds))
             tr.record("batch.finalize.submit", t_sub, t_st, **ids)
             tr.record("batch.finalize.status", t_st, t_out, **ids)
 
     def _finalize_build(self, ev, sched, specs, expanded, per_spec_metrics,
-                        net_index_cache) -> float:
+                        net_index_cache, stats) -> float:
         """Slots → plan (slab or per-alloc), blocked and follow-up evals.
         Returns the stamp at which the plan stood ready to submit."""
         # Prototype alloc per spec: the metric, task_resources, resources and
@@ -2390,6 +2519,7 @@ class TPUBatchScheduler:
                     # values): the device reserved ports/bandwidth/dyn
                     # capacity; the host picks the actual port numbers
                     # (rank.go:199 assign + network.go:245).
+                    t_offer = time.perf_counter()
                     idx = self._net_index(slots[i], net_index_cache)
                     task_resources = {}
                     total = s.Resources(disk_mb=tg.ephemeral_disk.size_mb)
@@ -2409,7 +2539,10 @@ class TPUBatchScheduler:
                             res.networks = [offer]
                         task_resources[t.name] = res
                         total.add(res)
+                    stats.finalize_offers_seconds += (time.perf_counter()
+                                                      - t_offer)
                     if offer_failed:
+                        stats.net_offer_failures += 1
                         continue
                     alloc.task_resources = task_resources
                     alloc.resources = total
@@ -2540,6 +2673,11 @@ class BatchStats:
         self.finalize_build_seconds = 0.0
         self.finalize_submit_seconds = 0.0
         self.finalize_status_seconds = 0.0
+        # Inside the build pass: picking concrete ports for network asks
+        # (the node's NetworkIndex and assign_network), summed over the
+        # batch, and the slots whose offer could not be made.
+        self.finalize_offers_seconds = 0.0
+        self.net_offer_failures = 0
         self.total_seconds = 0.0
         # CPU time of the worker's thread between total_seconds' two
         # stamps (time.thread_time: waits for the interpreter lock, the
@@ -2610,6 +2748,13 @@ class BatchStats:
         self.delta_rows = 0
         self.full_reencodes = 0
         self.staleness_fences = 0
+        # Network usage (ops/resident.py NET_DIMS): walks of every live
+        # alloc the batch's network state needed (a build, a fence, the
+        # off-mirror path, the holders of an asked static port), and the
+        # network-usage words sent to the device (the mirror's delta
+        # upload, or rows uploaded whole).
+        self.net_usage_walks = 0
+        self.net_delta_words = 0
         # Host time of THIS batch's prepare phase that ran while the
         # previous batch's device pass was still in flight
         # (schedule_stream double-buffering; 0 on the serial path).

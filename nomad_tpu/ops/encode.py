@@ -691,8 +691,9 @@ def with_usage(ct: ClusterTensors, used) -> ClusterTensors:
     """Clone the static cluster tensors with a caller-provided usage
     matrix — the device-resident delta path's twin of apply_alloc_usage
     (ops/resident.py maintains ``used`` incrementally instead of walking
-    every live alloc).  Network accounting keeps the static baseline;
-    the resident path is gated to batches without network asks."""
+    every live alloc).  Network accounting keeps the static baseline
+    (TPUBatchScheduler._with_net_usage layers the resident network
+    mirror on for a batch with network asks)."""
     import dataclasses as _dc
 
     new = _dc.replace(ct, used=used)
@@ -824,7 +825,8 @@ class SpecTensors:
     precomp: np.ndarray          # [u_pad, n_pad] bool — non-vectorizable ANDs
     job_index: np.ndarray        # [u_pad] int32 — same-job specs share a row
     job_ids: List[str]
-    # Network asks (zeros when the batch has none; w matches ct.port_words):
+    # Network asks (zeros when the batch has none; w matches ct.port_words,
+    # whose bits are ports or, with ``port_bits``, the batch's ports):
     net_active: np.ndarray = None   # [u_pad] bool
     net_mbits: np.ndarray = None    # [u_pad] int32
     dyn_need: np.ndarray = None     # [u_pad] int32 — dynamic + resv-in-dyn
@@ -845,10 +847,15 @@ def encode_specs(
     ct: ClusterTensors,
     nodes: Sequence[s.Node],
     spec_pad_multiple: int = 8,
+    port_bits: Optional[Dict[int, int]] = None,
 ) -> SpecTensors:
     """Lower specs to tensors; split constraints into vectorizable triples
     and host-precomputed boolean rows (cached per computed class, mirroring
-    EvalCache / FeasibilityWrapper semantics)."""
+    EvalCache / FeasibilityWrapper semantics).
+
+    ``port_bits``: the bit of each static port the batch asks for in
+    ``ct.port_words`` (TPUBatchScheduler._with_net_usage); None when the
+    words are the whole port space, a bit per port."""
     u_real = len(specs)
     u_pad = pow2_bucket(u_real, spec_pad_multiple)
     k_max = pow2_bucket(
@@ -922,12 +929,13 @@ def encode_specs(
                 dc_mask[u, code] = True
         job_index[u] = job_row.setdefault(sp.job.id, len(job_row))
 
-        if sp.net_active and w > 1:
+        if sp.net_active and (w > 1 or port_bits is not None):
             net_active[u] = True
             net_mbits[u] = sp.net_mbits
             dyn_need[u] = sp.dyn_count + sp.resv_in_dyn
             for p in set(sp.resv_ports):
-                resv_words[u, p >> 5] |= np.uint32(1 << (p & 31))
+                b = p if port_bits is None else port_bits[p]
+                resv_words[u, b >> 5] |= np.uint32(1 << (b & 31))
 
         if sp.dp_target is not None:
             col = ct.attr_index.get(sp.dp_target)

@@ -904,11 +904,12 @@ def pack_scalars(nnz, passes: PassCounts) -> jnp.ndarray:
 @functools.partial(jax.jit, static_argnames=(
     "meta_s", "meta_d", "u_pad", "n_pad", "with_networks", "with_dp",
     "with_scores", "max_rounds", "slot_m", "use_used_dev"),
-    donate_argnums=(2,))
+    donate_argnums=(2, 3))
 def _device_schedule(
     static_buf: jnp.ndarray,          # packed uint8, device-cached (xfer)
     dyn_buf: jnp.ndarray,             # packed uint8, per-batch upload
     used_dev: jnp.ndarray,            # [n_pad, 4] int32 DONATED mirror
+    net_dev: jnp.ndarray,             # [n_pad, 2] int32 DONATED, or None
     *,
     meta_s,
     meta_d,
@@ -935,7 +936,17 @@ def _device_schedule(
     via donated scatter-adds) instead of baseline+deltas — no per-batch
     usage upload, no materialized sum, and the caller gets the aliased
     array back to return to the resident slot.  With it off the donated
-    slot is a [1, 4] dummy."""
+    slot is a [1, 4] dummy.
+
+    Network asks (``with_networks``) read each node's bandwidth in use
+    and free dynamic ports as the static baseline (the node's own
+    reservation) plus what its live allocations hold: ``net_dev``, the
+    DONATED device twin of the resident network mirror, aliased back out
+    like ``used_dev``, or, without it (None), the rows the host uploaded
+    whole (``bw_used``, ``dyn_free``).  ``port_words`` holds, per node,
+    only the static ports the batch's specs ask for (one bit each, in the
+    order ``encode.encode_specs`` numbered them): no argument's shape
+    depends on how many nodes carry allocations."""
     from . import xfer
 
     d = xfer.unpack_device(static_buf, meta_s)
@@ -969,27 +980,22 @@ def _device_schedule(
         # Alloc usage arrives as sparse (node, 4-dim) deltas over the
         # static reserved-only baseline; -1 rows are padding.  Padding
         # routes to an out-of-bounds index under mode="drop" — clipping
-        # it to a real row would put DUPLICATE indices in the scatter,
-        # and for the port-word SET below a padding row's identity write
-        # could then race with (and clobber) a real touched-node write.
+        # it to a real row would put DUPLICATE indices in the scatter.
         uvalid = d["u_rows"] >= 0
         uidx = jnp.where(uvalid, d["u_rows"], jnp.int32(n_pad))
         used0 = d["used_base"].at[uidx].add(d["u_vals"], mode="drop")
     net = None
     if with_networks:
-        assert not use_used_dev, \
-            "device-resident usage mirror is gated to non-network batches"
-        bw_used = d["bw_used_base"].at[uidx].add(d["u_bw"], mode="drop")
-        dyn_free = d["dyn_free_base"].at[uidx].add(d["u_dyn"], mode="drop")
-        # Port bitmaps are REPLACED per touched node (the host re-derives
-        # the full set for nodes with allocs), not OR-merged.
-        port_words = d["port_words_base"].at[uidx].set(
-            d["u_ports"], mode="drop")
+        if net_dev is not None:
+            bw_used = d["bw_used_base"] + net_dev[:, 0]
+            dyn_free = d["dyn_free_base"] - net_dev[:, 1]
+        else:
+            bw_used, dyn_free = d["bw_used"], d["dyn_free"]
         net = NetTensors(
             active=d["net_active"], mbits=d["net_mbits"],
             dyn_need=d["dyn_need"], resv_words=d["resv_words"],
             bw_cap=d["bw_cap"], bw_used=bw_used,
-            dyn_free=dyn_free, port_words=port_words)
+            dyn_free=dyn_free, port_words=d["port_words"])
     dp = None
     if with_dp:
         dp = DPTensors(col=d["dp_col"], active=d["dp_active"],
@@ -1000,10 +1006,10 @@ def _device_schedule(
         d["penalty"], d["dh"], d["ji"], job_counts, key,
         max_rounds=max_rounds, net=net, dp=dp, with_scores=with_scores,
         slot_m=slot_m)
-    # The donated mirror rides back out UNCHANGED so XLA aliases it
-    # input→output: the caller re-installs the very same device buffer
+    # The donated mirrors ride back out UNCHANGED so XLA aliases them
+    # input→output: the caller re-installs the very same device buffers
     # into the resident slot (zero copies across the batch round-trip).
-    return result, feas, used_dev
+    return result, feas, used_dev, net_dev
 
 
 def _slots_coo_gather(slots: jnp.ndarray, slot_scores: jnp.ndarray,
@@ -1131,11 +1137,12 @@ def fused_layout(u_pad: int, *, window_nnz: int, with_scores: bool,
     "meta_s", "meta_d", "u_pad", "n_pad", "with_networks", "with_dp",
     "with_scores", "max_nnz", "max_rounds", "slot_m", "compact_u16",
     "window_nnz", "use_used_dev"),
-    donate_argnums=(2,))
+    donate_argnums=(2, 3))
 def _fused_score_commit(
     static_buf: jnp.ndarray,
     dyn_buf: jnp.ndarray,
     used_dev: jnp.ndarray,
+    net_dev: jnp.ndarray,
     *,
     meta_s,
     meta_d,
@@ -1156,9 +1163,11 @@ def _fused_score_commit(
     compaction (from the commit-aligned slot record when slot_m) →
     single packed result buffer.  ``used_dev`` is the DONATED
     device-resident usage mirror (a [1, 4] dummy when use_used_dev is
-    off), returned aliased as the last output."""
-    result, feas, used_out = _device_schedule(
-        static_buf, dyn_buf, used_dev, meta_s=meta_s, meta_d=meta_d,
+    off) and ``net_dev`` the donated network mirror (or None), returned
+    aliased as the last two outputs."""
+    result, feas, used_out, net_out = _device_schedule(
+        static_buf, dyn_buf, used_dev, net_dev, meta_s=meta_s,
+        meta_d=meta_d,
         u_pad=u_pad, n_pad=n_pad, with_networks=with_networks,
         with_dp=with_dp, with_scores=with_scores, max_rounds=max_rounds,
         slot_m=slot_m, use_used_dev=use_used_dev)
@@ -1186,13 +1195,14 @@ def _fused_score_commit(
         "scalars": pack_scalars(nnz, result.passes),
         "coo": coo_win,
     })
-    return buf, aux, feas, used_out
+    return buf, aux, feas, used_out, net_out
 
 
 def fused_pass(
     static_buf: jnp.ndarray,
     dyn_buf: jnp.ndarray,
     used_dev: jnp.ndarray = None,
+    net_dev: jnp.ndarray = None,
     *,
     meta_s,
     meta_d,
@@ -1215,7 +1225,8 @@ def fused_pass(
     failure-forensics rows.  ``used_dev`` (optional) is the donated
     device-resident usage mirror; ``used_out`` is the aliased buffer to
     hand back to ops/resident.py (None when no mirror was passed — the
-    sparse-delta upload path)."""
+    sparse-delta upload path); ``net_dev`` / ``net_out`` the same for
+    the network mirror."""
     compact_u16 = (not with_scores and u_pad <= 65536
                    and n_pad <= 65536 and max_rounds < 65536)
     window_nnz = fused_window(max_nnz, with_scores=with_scores,
@@ -1226,16 +1237,18 @@ def fused_pass(
     with program_call("fused_pass", (
             meta_s, meta_d, u_pad, n_pad, with_networks, with_dp,
             with_scores, max_nnz, max_rounds, slot_m, compact_u16,
-            window_nnz, use_used_dev)):
-        buf, aux, feas, used_out = _fused_score_commit(
-            static_buf, dyn_buf, used_dev, meta_s=meta_s, meta_d=meta_d,
+            window_nnz, use_used_dev, net_dev is not None)):
+        buf, aux, feas, used_out, net_out = _fused_score_commit(
+            static_buf, dyn_buf, used_dev, net_dev, meta_s=meta_s,
+            meta_d=meta_d,
             u_pad=u_pad, n_pad=n_pad, with_networks=with_networks,
             with_dp=with_dp, with_scores=with_scores, max_nnz=max_nnz,
             max_rounds=max_rounds, slot_m=slot_m, compact_u16=compact_u16,
             window_nnz=window_nnz, use_used_dev=use_used_dev)
     meta = fused_layout(u_pad, window_nnz=window_nnz,
                         with_scores=with_scores, compact_u16=compact_u16)
-    return buf, aux, feas, meta, (used_out if use_used_dev else None)
+    return (buf, aux, feas, meta, (used_out if use_used_dev else None),
+            net_out)
 
 
 @functools.partial(jax.jit, static_argnames=("u_pad", "n_pad"))

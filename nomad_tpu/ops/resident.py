@@ -29,10 +29,15 @@ Correctness machinery:
   mismatch on the event stream, and the batch proceeds on the fresh
   full encode — corruption degrades, never mis-places.
 
-Scope: usage rows only (capacity/attrs/eligibility invalidate via the
-nodes-table index in the cache key), and only batches WITHOUT network
-asks — port-bitmap deltas are not expressible in the feed, so network
-batches keep the full-encode path.
+Scope: usage rows (capacity/attrs/eligibility invalidate via the
+nodes-table index in the cache key) and, once a batch with network asks
+has asked for it (``acquire(with_net=True)``), what the live allocations'
+networks hold on each node: Mbit and ports in the dynamic range
+(``NET_DIMS``, ``structs.alloc_net_vec``), folded from the same feed one
+delta per allocation write, with a device twin of its own.  A fleet that
+never sees a network ask never builds it.  Port VALUES are not mirrored:
+the host picks dynamic ports at finalize, and a batch that asks for a
+static port reads who holds it from the state (ops/batch_sched.py).
 
 Env knobs:
 
@@ -63,6 +68,10 @@ from ..utils import tracing
 logger = logging.getLogger("nomad_tpu.ops.resident")
 
 RES_DIMS = 4
+# Per node, over its live allocations: bandwidth in use (Mbit) and ports
+# held in the dynamic range (structs.alloc_net_vec); the node's own
+# reservation is in the static tensors' baseline.
+NET_DIMS = 2
 
 
 def enabled() -> bool:
@@ -172,7 +181,8 @@ class ResidentState:
     """One cached (static key → usage matrix) residency slot."""
 
     __slots__ = ("key", "used", "alloc_index", "touched", "hits",
-                 "delta_rows", "since_guard", "used_dev", "dev_mesh")
+                 "delta_rows", "since_guard", "used_dev", "dev_mesh",
+                 "net", "net_dev")
 
     def __init__(self, key: Tuple, used: np.ndarray, alloc_index: int,
                  touched: set):
@@ -195,6 +205,11 @@ class ResidentState:
         # single-chip mirror must never flow into the sharded kernel or
         # vice versa.
         self.dev_mesh = None
+        # The network mirror ([n_pad, NET_DIMS] int64) at the same
+        # alloc_index, None until a network batch asks for it; its
+        # device twin (int32, single-chip) is loaned like ``used_dev``.
+        self.net = None
+        self.net_dev = None
 
 
 # Single residency slot (the steady-state workload schedules one cluster
@@ -272,7 +287,7 @@ def _mesh_key(mesh):
 
 
 def take_device_used(key: Tuple, snap_index: int, host_used: np.ndarray,
-                     mesh=None):
+                     mesh=None, what: str = "used"):
     """Loan the device usage mirror out for donation into the kernel.
 
     Returns the [n_pad, 4] int32 device array — installed from
@@ -288,20 +303,27 @@ def take_device_used(key: Tuple, snap_index: int, host_used: np.ndarray,
     per shard under ``NamedSharding(mesh, P(NODE_AXIS))``.  A held
     handle whose placement differs from the request is dropped and
     reinstalled: a single-chip buffer must never flow into the sharded
-    kernel or vice versa."""
+    kernel or vice versa.
+
+    ``what="net"`` loans the network mirror's twin the same way
+    (single-chip only; ``host_used`` is then the [n_pad, NET_DIMS]
+    network mirror)."""
     global DEV_INSTALLS, DEV_H2D_BYTES
     if not device_mirror_enabled():
         return None
+    attr = what + "_dev"
     with _LOCK:
         st = _STATE
         if (st is None or st.key != key
                 or st.alloc_index != snap_index):
             return None
-        dev = st.used_dev
-        st.used_dev = None
-        if dev is not None and _mesh_key(st.dev_mesh) != _mesh_key(mesh):
-            dev = None          # placement mismatch: reinstall below
-        st.dev_mesh = mesh
+        dev = getattr(st, attr)
+        setattr(st, attr, None)
+        if what == "used":
+            if (dev is not None
+                    and _mesh_key(st.dev_mesh) != _mesh_key(mesh)):
+                dev = None      # placement mismatch: reinstall below
+            st.dev_mesh = mesh
     if dev is None:
         import jax
 
@@ -327,15 +349,17 @@ def take_device_used(key: Tuple, snap_index: int, host_used: np.ndarray,
     return dev
 
 
-def give_device_used(key: Tuple, snap_index: int, dev) -> None:
+def give_device_used(key: Tuple, snap_index: int, dev,
+                     what: str = "used") -> None:
     """Hand the loaned (kernel-aliased) device mirror back.  Dropped
     when the slot moved on while the loan was out — the mirror is then
     reinstalled from host at the next take."""
+    attr = what + "_dev"
     with _LOCK:
         st = _STATE
-        if (st is not None and st.key == key and st.used_dev is None
+        if (st is not None and st.key == key and getattr(st, attr) is None
                 and st.alloc_index == snap_index):
-            st.used_dev = dev
+            setattr(st, attr, dev)
 
 
 def check_quant_roundtrip(exact: np.ndarray, quantized: np.ndarray,
@@ -404,7 +428,7 @@ def _apply_device_deltas(used_dev, rows, vals, mesh=None):
         k_b = pow2_bucket(k)
         rows_b = np.full(k_b, -1, dtype=np.int32)
         rows_b[:k] = rows
-        vals_b = np.zeros((k_b, RES_DIMS), dtype=np.int32)
+        vals_b = np.zeros((k_b, vals.shape[1]), dtype=np.int32)
         vals_b[:k] = vals
         DEV_APPLIES += 1
         DEV_H2D_BYTES += rows_b.nbytes + vals_b.nbytes
@@ -438,7 +462,7 @@ def _feed_rows(entries, node_index: Dict[str, int]
     vecs: List[Tuple] = []
     counts: List[int] = []
     for entry in entries:
-        if len(entry) == 3:     # (index, node_id, delta): one row
+        if len(entry) != 2:     # (index, node_id, delta[, net])
             singles.append(entry[1])
             vecs.append(entry[2])
             counts.append(1)
@@ -456,6 +480,39 @@ def _feed_rows(entries, node_index: Dict[str, int]
             else np.zeros(0, dtype=np.int64))
     vals = np.repeat(np.array(vecs, dtype=np.int64).reshape(-1, RES_DIMS),
                      counts, axis=0)
+    known = rows >= 0
+    if not known.all():
+        rows, vals = rows[known], vals[known]
+    return rows, vals
+
+
+def _feed_net_rows(entries, node_index: Dict[str, int]
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+    """The feed's network deltas as ``(rows int64[k], vals int64[k,
+    NET_DIMS])``: one row per allocation write that changed what a
+    node's networks hold (a single row's fourth element; a slab whose
+    prototype holds any, over its node column), in feed order; writes on
+    nodes the fleet does not hold are dropped."""
+    from ..state.columnar import gather_index
+    from ..structs.structs import alloc_net_vec
+
+    nids: List[str] = []
+    vecs: List[Tuple] = []
+    parts: List[np.ndarray] = []
+    for entry in entries:
+        if len(entry) == 4:
+            nids.append(entry[1])
+            vecs.append(entry[3])
+        elif len(entry) == 2:
+            vec = alloc_net_vec(entry[1].proto)
+            if vec != (0, 0):
+                parts.append(gather_index(node_index, entry[1].node_ids))
+                parts.append(np.array([vec], dtype=np.int64).repeat(
+                    len(entry[1].node_ids), axis=0))
+    rows = np.concatenate([gather_index(node_index, nids)] + parts[::2])
+    vals = np.concatenate(
+        [np.array(vecs, dtype=np.int64).reshape(-1, NET_DIMS)]
+        + parts[1::2])
     known = rows >= 0
     if not known.all():
         rows, vals = rows[known], vals[known]
@@ -495,6 +552,39 @@ def _full_usage(base, rows_fn) -> Tuple[np.ndarray, set]:
     return used, touched
 
 
+def _full_net(base, rows_fn) -> np.ndarray:
+    """The reference rebuild of the network mirror from a full state
+    walk, on ops/encode.apply_alloc_usage's basis and independent of the
+    feed: per node, the Mbit of its live allocs' first task networks,
+    and the ports in the dynamic range they hold, as a set, less those
+    the node itself reserves (the static baseline's)."""
+    from ..structs.network import MAX_DYNAMIC_PORT, MIN_DYNAMIC_PORT
+
+    net = np.zeros((base.n_pad, NET_DIMS), dtype=np.int64)
+    node_index = base._node_index  # type: ignore[attr-defined]
+    nodes = base._nodes            # type: ignore[attr-defined]
+    for nid, rows in rows_fn().items():
+        i = node_index.get(nid)
+        if i is None:
+            continue
+        mbits, held = 0, set()
+        for row in rows:
+            for tr in row.task_resources.values():
+                if tr.networks:
+                    nr = tr.networks[0]
+                    mbits += nr.mbits
+                    held.update(p.value for p in
+                                nr.reserved_ports + nr.dynamic_ports
+                                if MIN_DYNAMIC_PORT <= p.value
+                                < MAX_DYNAMIC_PORT)
+        if held and nodes[i].reserved is not None:
+            for nr in nodes[i].reserved.networks or []:
+                held.difference_update(
+                    p.value for p in nr.reserved_ports + nr.dynamic_ports)
+        net[i] = (mbits, len(held))
+    return net
+
+
 def _usage_source(base, rows_fn, usage_fn) -> Tuple[np.ndarray, set]:
     """Full live-usage rows for a cold build / fence / feed-gap rebuild:
     the columnar mirror slice when the caller supplied one (O(changed)
@@ -512,7 +602,8 @@ def _usage_source(base, rows_fn, usage_fn) -> Tuple[np.ndarray, set]:
 
 
 def acquire(state, cache_key: Tuple, base, rows_fn,
-            breaker=None, shards: int = 0, usage_fn=None
+            breaker=None, shards: int = 0, usage_fn=None,
+            with_net: bool = False
             ) -> Tuple[np.ndarray, List[int], Dict]:
     """Produce the live usage matrix for this batch.
 
@@ -531,19 +622,43 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
     only its slice, so attribution is what operators need to map a
     mismatch to hardware).
 
+    ``with_net``: the batch has network asks; the network mirror is
+    built (one walk) if the slot has none yet and handed back as
+    ``info["net"]`` ([n_pad, NET_DIMS] int64).  Once built it is folded
+    with every batch's feed, and the guard holds it to ``_full_net``
+    from the same walk as the usage.
+
     Returns ``(used int64 [n_pad, 4], touched_rows sorted list, info)``
     where info carries the BatchStats counters:
     ``resident_hit``/``delta_rows``/``full_reencode``/``fence``/
     ``guard_ran``/``guard_mismatch`` (+ ``guard_bad_shards`` on a
-    sharded mismatch).
+    sharded mismatch), and ``net_walks`` (walks the network mirror
+    itself needed: a build, a fence, a rebuild).
     """
     global _STATE, HITS, FULL_REENCODES, STALENESS_FALLBACKS
     global GUARD_RUNS, GUARD_MISMATCHES
 
     info = {"resident_hit": False, "delta_rows": 0, "full_reencode": False,
             "fence": False, "guard_ran": False, "guard_mismatch": False,
-            "delta_apply_s": 0.0}
+            "delta_apply_s": 0.0, "net_walks": 0}
     snap_index = state.table_index("allocs")
+    walked: List[Dict] = []
+
+    def walk():
+        # One state walk a batch at most, shared by the usage and the
+        # network rebuilds and by the guard.
+        if not walked:
+            walked.append(rows_fn())
+        return walked[0]
+
+    def net_walk() -> np.ndarray:
+        info["net_walks"] += 1
+        return _full_net(base, walk)
+
+    def fenced(used, touched):
+        if with_net:
+            info["net"] = net_walk()
+        return used, sorted(touched), info
 
     with _LOCK:
         st = _STATE
@@ -557,12 +672,12 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
             STALENESS_FALLBACKS += 1
             info["fence"] = True
             info["full_reencode"] = True
-            used, touched = _usage_source(base, rows_fn, usage_fn)
+            used, touched = _usage_source(base, walk, usage_fn)
             tracing.event("resident.fence", snap_nodes_index=cache_key[1],
                           cached_nodes_index=st.key[1])
             _publish("staleness_fence", SnapshotNodesIndex=cache_key[1],
                      CachedNodesIndex=st.key[1])
-            return used, sorted(touched), info
+            return fenced(used, touched)
         if st is not None and st.key == cache_key:
             if snap_index < st.alloc_index:
                 # Staleness fence: this snapshot predates the resident
@@ -571,22 +686,25 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                 STALENESS_FALLBACKS += 1
                 info["fence"] = True
                 info["full_reencode"] = True
-                used, touched = _usage_source(base, rows_fn, usage_fn)
+                used, touched = _usage_source(base, walk, usage_fn)
                 tracing.event("resident.fence", snap_index=snap_index,
                               cached_index=st.alloc_index)
                 _publish("staleness_fence", SnapshotIndex=snap_index,
                          CachedIndex=st.alloc_index)
-                return used, sorted(touched), info
+                return fenced(used, touched)
 
             entries = (state.alloc_log_since(st.alloc_index)
                        if snap_index > st.alloc_index else [])
             if entries is not None:
                 used = st.used
-                rows, vals = _feed_rows(
-                    entries, base._node_index)  # type: ignore[attr-defined]
+                node_index = base._node_index  # type: ignore[attr-defined]
+                rows, vals = _feed_rows(entries, node_index)
                 np.add.at(used, rows, vals)
                 st.touched.update(np.unique(rows).tolist())
                 track_dev = st.used_dev is not None
+                if st.net is not None:
+                    net_rows, net_vals = _feed_net_rows(entries, node_index)
+                    np.add.at(st.net, net_rows, net_vals)
                 st.alloc_index = snap_index
                 st.hits += 1
                 st.delta_rows += len(rows)
@@ -614,12 +732,21 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                         rows = np.append(rows, row)
                         vals = np.concatenate([vals, vec])
 
-                if track_dev:
+                if track_dev or st.net_dev is not None:
                     import time as _time
 
                     t_da = _time.monotonic()
-                    st.used_dev = _apply_device_deltas(
-                        st.used_dev, rows, vals, mesh=st.dev_mesh)
+                    if track_dev:
+                        st.used_dev = _apply_device_deltas(
+                            st.used_dev, rows, vals, mesh=st.dev_mesh)
+                    if st.net_dev is not None and len(net_rows):
+                        from .encode import pow2_bucket
+
+                        st.net_dev = _apply_device_deltas(
+                            st.net_dev, net_rows, net_vals)
+                        # The upload: the padded rows and their values.
+                        info["net_delta_words"] = (pow2_bucket(
+                            len(net_rows)) * (1 + NET_DIMS))
                     info["delta_apply_s"] = _time.monotonic() - t_da
 
                 every = guard_every()
@@ -627,31 +754,36 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                     st.since_guard = 0
                     GUARD_RUNS += 1
                     info["guard_ran"] = True
-                    if st.used_dev is not None:
+                    for attr, host in (("used_dev", used),
+                                       ("net_dev", st.net)):
+                        dev = getattr(st, attr)
+                        if dev is None:
+                            continue
                         # Device-mirror drift guard: the donated buffer
                         # must bit-match the host mirror it twins —
                         # drift here is an aliasing/donation bug (or
                         # real device corruption), caught independently
                         # of the host-vs-walk compare below.
-                        dev_host = np.asarray(st.used_dev)
+                        dev_host = np.asarray(dev)
                         if not np.array_equal(
-                                dev_host.astype(np.int64), used):
+                                dev_host.astype(np.int64), host):
                             global DEV_GUARD_MISMATCHES
                             DEV_GUARD_MISMATCHES += 1
                             bad_mask = (dev_host.astype(np.int64)
-                                        != used).any(axis=1)
+                                        != host).any(axis=1)
                             bad = int(bad_mask.sum())
                             dev_bad_shards: List[int] = []
-                            if shards > 0:
-                                n_l = max(1, used.shape[0] // shards)
+                            if shards > 0 and attr == "used_dev":
+                                n_l = max(1, host.shape[0] // shards)
                                 dev_bad_shards = sorted(
                                     {int(r) // n_l
                                      for r in np.nonzero(bad_mask)[0]})
                             logger.error(
-                                "device usage mirror diverged from the "
+                                "device %s mirror diverged from the "
                                 "host mirror on %d rows%s; dropping the "
                                 "donated buffer and feeding the breaker",
-                                bad,
+                                "usage" if attr == "used_dev" else
+                                "network", bad,
                                 (f" (mesh shards {dev_bad_shards})"
                                  if dev_bad_shards else ""))
                             tracing.event("resident.device_mismatch",
@@ -661,13 +793,18 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                                      Shards=dev_bad_shards)
                             if breaker is not None:
                                 breaker.record(False)
-                            st.used_dev = None
-                    ref_used, ref_touched = _full_usage(base, rows_fn)
-                    if not np.array_equal(used, ref_used):
+                            setattr(st, attr, None)
+                    ref_used, ref_touched = _full_usage(base, walk)
+                    ref_net = (_full_net(base, walk)
+                               if st.net is not None else None)
+                    bad_rows = np.nonzero(
+                        (used != ref_used).any(axis=1))[0]
+                    if ref_net is not None:
+                        bad_rows = np.union1d(bad_rows, np.nonzero(
+                            (st.net != ref_net).any(axis=1))[0])
+                    if len(bad_rows):
                         GUARD_MISMATCHES += 1
                         info["guard_mismatch"] = True
-                        bad_rows = np.nonzero(
-                            (used != ref_used).any(axis=1))[0]
                         bad = int(len(bad_rows))
                         bad_shards: List[int] = []
                         if shards > 0:
@@ -691,6 +828,9 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                         _STATE = None
                         info["resident_hit"] = False
                         info["full_reencode"] = True
+                        if with_net:
+                            info["net"] = (ref_net if ref_net is not None
+                                           else net_walk())
                         return ref_used, sorted(ref_touched), info
                     if breaker is not None:
                         breaker.record(True)
@@ -698,6 +838,10 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                     # rows whose allocs all stopped drop out.
                     st.touched = set(ref_touched)
 
+                if with_net:
+                    if st.net is None:
+                        st.net = net_walk()
+                    info["net"] = st.net.copy()
                 # Hand the caller a copy: the resident matrix keeps
                 # advancing under later batches while the device pass /
                 # forensics of THIS batch still read their snapshot.
@@ -708,8 +852,11 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                   else ("key_change" if st is not None else "cold"))
         FULL_REENCODES += 1
         info["full_reencode"] = True
-        used, touched = _usage_source(base, rows_fn, usage_fn)
+        used, touched = _usage_source(base, walk, usage_fn)
         _STATE = ResidentState(cache_key, used, snap_index, set(touched))
+        if with_net:
+            _STATE.net = net_walk()
+            info["net"] = _STATE.net.copy()
         tracing.event("resident.full_reencode", reason=reason,
                       alloc_index=snap_index)
         if reason != "cold":

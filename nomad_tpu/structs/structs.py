@@ -211,6 +211,25 @@ def alloc_usage_vec(alloc) -> "Tuple[int, int, int, int]":
     return (cpu, mem, disk, iops)
 
 
+def alloc_net_vec(alloc) -> "Tuple[int, int]":
+    """What an alloc's networks hold on its node, (Mbit, ports in the
+    dynamic range), over the first network of each task: the basis of
+    ops/encode.apply_alloc_usage's network accounting.  The state
+    store's usage-delta feed logs it beside ``alloc_usage_vec`` and the
+    resident network mirror (ops/resident.py) folds it."""
+    from .network import MAX_DYNAMIC_PORT, MIN_DYNAMIC_PORT
+
+    mbits = ports = 0
+    for tr in alloc.task_resources.values():
+        if tr.networks:
+            nr = tr.networks[0]
+            mbits += nr.mbits
+            for p in nr.reserved_ports + nr.dynamic_ports:
+                if MIN_DYNAMIC_PORT <= p.value < MAX_DYNAMIC_PORT:
+                    ports += 1
+    return (mbits, ports)
+
+
 @dataclass
 class Port:
     label: str = ""

@@ -72,10 +72,24 @@ def sink(tmp_path_factory):
             assert conftest.wait_for(
                 lambda: "nomad.breaker.oracle_routed"
                 in srv.metrics.sink.latest()["CounterTotals"], 30.0)
+        after_reject = srv.metrics.sink.latest()
+        # batch.net_* and finalize.offers: one job with mock.job()'s
+        # network ask (50 Mbit, dynamic ports http and admin), on a node
+        # that has its network.
+        srv.node_register(mock.node())
+        net_job = mock.job()
+        net_job.task_groups[0].count = 2
+        conftest.put_job(agent, net_job)
+        offers = "nomad.worker.invoke_scheduler.finalize.offers"
+        assert conftest.wait_for(
+            lambda: srv.metrics.sink.latest()["SampleTotals"].get(
+                offers, (0, 0.0))[1] > 0, 60.0)
+        assert len(srv.state.allocs_by_job(None, net_job.id, True)) == 2
         latest = srv.metrics.sink.latest()
         yield {"samples": set(latest["SampleTotals"]),
                "counters": set(latest["CounterTotals"]),
-               "before_reject": before_reject, "latest": latest}
+               "before_reject": before_reject,
+               "after_reject": after_reject, "latest": latest}
 
 
 def test_there_are_sink_metrics():
@@ -92,6 +106,28 @@ def test_metric_file_names_a_published_key(sink, spec):
     else:
         assert spec["key"] in sink["counters"], spec
         assert spec["per"] in sink["samples"], spec
+
+
+NET_KEYS = [
+    # (kind, key, check): the network job's batch picked its offers (a
+    # sample of the time), made every one (no failure) and built the
+    # resident network mirror with the one walk a cold build takes.  Two
+    # of the four have no metric file (``BENCHMARK.json`` holds at most
+    # 128 per-layer metrics), so only this test holds their keys.
+    ("SampleTotals", "nomad.worker.invoke_scheduler.finalize.offers",
+     lambda v: v[1] > 0),
+    ("CounterTotals", "nomad.batch.net_offer_failures", lambda v: v == 0),
+    ("CounterTotals", "nomad.batch.net_usage_walks", lambda v: v >= 1),
+    ("CounterTotals", "nomad.batch.net_delta_words", lambda v: v >= 0),
+]
+
+
+@pytest.mark.parametrize("kind,key,check", NET_KEYS,
+                         ids=[k for _, k, _ in NET_KEYS])
+def test_a_served_network_job_publishes_its_network_keys(sink, kind, key,
+                                                         check):
+    totals = sink["latest"][kind]
+    assert key in totals and check(totals[key]), (key, totals.get(key))
 
 
 def test_fit_recheck_publishes_its_routes_and_its_guard(sink):
@@ -160,6 +196,6 @@ def test_fused_counter_counts_the_batches_the_device_answered(sink):
 
     calls0, device0, fused0, routed0 = read(sink["before_reject"])
     assert device0 >= 1 and fused0 == device0 == calls0
-    calls1, device1, fused1, routed1 = read(sink["latest"])
+    calls1, device1, fused1, routed1 = read(sink["after_reject"])
     assert calls1 == calls0 + 1 and routed1 == routed0 + 1
     assert (device1, fused1) == (device0, fused0)

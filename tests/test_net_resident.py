@@ -1,0 +1,349 @@
+"""The resident network mirror (ops/resident.py ``NET_DIMS``): a batch
+with network asks takes the resident path, folds what the allocations'
+networks hold from the state store's feed one delta per allocation write,
+and runs a device program whose shapes do not depend on how many nodes
+carry allocations.
+
+On the CPU at a small size: 64 ``mock.node()``s, a standing load of
+port-holding allocations on 8 of them, then batches of ``mock.job()``
+with its network ask (50 Mbit, dynamic ports http and admin), seeded.
+"""
+import random
+
+import numpy as np
+import pytest
+
+from nomad_tpu import mock
+from nomad_tpu.ops import kernels, resident
+from nomad_tpu.ops.batch_sched import TPUBatchScheduler
+from nomad_tpu.scheduler import Harness
+from nomad_tpu.scheduler.generic import GenericScheduler
+from nomad_tpu.scheduler.stack import GenericStack
+from nomad_tpu.structs import structs as s
+from nomad_tpu.structs.network import MAX_DYNAMIC_PORT, MIN_DYNAMIC_PORT
+from nomad_tpu.utils import tracing
+
+NODES, STANDING = 64, 8
+
+
+@pytest.fixture(autouse=True)
+def _fresh_resident(monkeypatch):
+    monkeypatch.setenv("NOMAD_TPU_RESIDENT", "1")
+    monkeypatch.setenv("NOMAD_TPU_RNG_SEED", "41")
+    resident.reset_counters()
+    yield
+    resident.reset_counters()
+
+
+def reg_eval(job):
+    return s.Evaluation(
+        id=s.generate_uuid(), priority=job.priority, type=job.type,
+        triggered_by=s.EVAL_TRIGGER_JOB_REGISTER, job_id=job.id,
+        status=s.EVAL_STATUS_PENDING)
+
+
+def net_job(count, reserved=()):
+    """``mock.job()``, its network ask kept, at ``count``; ``reserved``
+    adds static ports to the ask."""
+    job = mock.job()
+    job.task_groups[0].count = count
+    nr = job.task_groups[0].tasks[0].resources.networks[0]
+    nr.reserved_ports = [s.Port(f"r{p}", p) for p in reserved]
+    return job
+
+
+def held_alloc(owner, node, cpu, mbits=10, reserved=(), dynamic=()):
+    """A live allocation holding bandwidth and ports on ``node``."""
+    net = s.NetworkResource(
+        device="eth0", ip="192.168.0.100", mbits=mbits,
+        reserved_ports=[s.Port(f"r{p}", p) for p in reserved],
+        dynamic_ports=[s.Port(f"d{p}", p) for p in dynamic])
+    alloc = mock.alloc()
+    alloc.job, alloc.job_id, alloc.node_id = owner, owner.id, node.id
+    alloc.task_group = "web"
+    alloc.task_resources = {"web": s.Resources(cpu=cpu, memory_mb=64,
+                                               networks=[net])}
+    alloc.resources = s.Resources(cpu=cpu, memory_mb=64, networks=[net])
+    return alloc
+
+
+def standing_fleet(seed):
+    """64 nodes; the first 8 each carry one port-holding allocation of a
+    size of its own (so that their scores differ): a dynamic-range port
+    on each, and port 8080 on every other one."""
+    h = Harness()
+    nodes = []
+    for _ in range(NODES):
+        node = mock.node()
+        h.state.upsert_node(h.next_index(), node)
+        nodes.append(node)
+    owner = mock.job()
+    h.state.upsert_job(h.next_index(), owner)
+    rng = random.Random(seed)
+    allocs = [held_alloc(owner, node, cpu=100 + 53 * i,
+                         reserved=(8080,) if i % 2 else (),
+                         dynamic=(21000 + rng.randrange(1000),))
+              for i, node in enumerate(nodes[:STANDING])]
+    h.state.upsert_allocs(h.next_index(), allocs)
+    return h, nodes
+
+
+def device_batch(h, jobs):
+    for j in jobs:
+        h.state.upsert_job(h.next_index(), j)
+    h.last_snapshot = h.snapshot()
+    sched = TPUBatchScheduler(h.logger, h.last_snapshot, h)
+    return sched.schedule_batch([reg_eval(j) for j in jobs])
+
+
+def oracle_batch(h, jobs):
+    for j in jobs:
+        h.state.upsert_job(h.next_index(), j)
+    for j in jobs:
+        GenericScheduler(h.logger, h.snapshot(), h,
+                         batch=False).process(reg_eval(j))
+
+
+@pytest.fixture
+def exhaustive_oracle(monkeypatch):
+    """GenericScheduler with its candidate limit lifted to the fleet: the
+    best node over all of them, as the device path places."""
+    set_nodes = GenericStack.set_nodes
+
+    def every_node(self, base_nodes):
+        set_nodes(self, base_nodes)
+        self.limit.set_limit(max(2, len(base_nodes)))
+
+    monkeypatch.setattr(GenericStack, "set_nodes", every_node)
+
+
+def live(h, job_id=None):
+    allocs = (h.state.allocs_by_job(None, job_id, True) if job_id
+              else h.state.allocs(None))
+    return [a for a in allocs if not a.terminal_status()]
+
+
+def node_order(h):
+    return [n.id for n in h.state.nodes(None)]
+
+
+def load_profile(h):
+    """Sorted (cpu, Mbit) in use per node: equal between two runs that
+    differ only in how ties between like nodes were broken."""
+    per = {nid: [0, 0] for nid in node_order(h)}
+    for a in live(h):
+        per[a.node_id][0] += s.alloc_usage_vec(a)[0]
+        per[a.node_id][1] += s.alloc_net_vec(a)[0]
+    return sorted(map(tuple, per.values()))
+
+
+def recompute_net(state):
+    """[nodes, 2] Mbit and dynamic-range ports the live allocations hold,
+    from the state store, by node order."""
+    order = [n.id for n in state.nodes(None)]
+    out = np.zeros((len(order), 2), dtype=np.int64)
+    for i, nid in enumerate(order):
+        mbits, held = 0, set()
+        for a in state.allocs_by_node(None, nid):
+            if a.terminal_status():
+                continue
+            for tr in a.task_resources.values():
+                if tr.networks:
+                    nr = tr.networks[0]
+                    mbits += nr.mbits
+                    held |= {p.value for p in
+                             nr.reserved_ports + nr.dynamic_ports
+                             if MIN_DYNAMIC_PORT <= p.value < MAX_DYNAMIC_PORT}
+        out[i] = (mbits, len(held))
+    return out
+
+
+def assert_mirror_is_the_state(h):
+    """The mirror as the last batch read it, against the state store of
+    that batch's snapshot."""
+    st = resident._STATE
+    assert st is not None and st.net is not None
+    want = recompute_net(h.last_snapshot)
+    n = len(want)
+    np.testing.assert_array_equal(st.net[:n], want)
+    assert not st.net[n:].any()
+    np.testing.assert_array_equal(np.asarray(st.net_dev)[:n], want)
+
+
+def fused_signatures():
+    return {sig for kind, sig in kernels._COMPILE_SIGS
+            if kind == "fused_pass"}
+
+
+def touched_nodes(h):
+    return len({a.node_id for a in live(h)})
+
+
+@pytest.mark.parametrize("seed", [3, 4])
+def test_placements_equal_the_oracle_s(seed, exhaustive_oracle):
+    """Per job the same count, and after every batch the same load on
+    the fleet up to which of like nodes took it (score ties)."""
+    h_d, _ = standing_fleet(seed)
+    h_o, _ = standing_fleet(seed)
+    for b in range(4):
+        jobs = [net_job(10 + 5 * b) for _ in range(2)]
+        stats = device_batch(h_d, [j.copy() for j in jobs])
+        oracle_batch(h_o, jobs)
+        assert stats.fused == 1 and stats.oracle_routed == 0
+        for j in jobs:
+            assert len(live(h_d, j.id)) == len(live(h_o, j.id)) == j.task_groups[0].count
+        assert load_profile(h_d) == load_profile(h_o)
+
+
+@pytest.mark.parametrize("seed", [5, 6])
+def test_every_port_in_range_and_unique_per_node(seed):
+    h, nodes = standing_fleet(seed)
+    for _ in range(3):
+        device_batch(h, [net_job(20), net_job(20)])
+    by_node = {}
+    for a in live(h):
+        for tr in a.task_resources.values():
+            for nr in tr.networks:
+                by_node.setdefault(a.node_id, []).extend(
+                    p.value for p in nr.reserved_ports + nr.dynamic_ports)
+                assert all(MIN_DYNAMIC_PORT <= p.value < MAX_DYNAMIC_PORT
+                           for p in nr.dynamic_ports)
+    for node in nodes:
+        ports = by_node.get(node.id, []) + [22]
+        assert len(ports) == len(set(ports)), node.id
+
+
+@pytest.mark.parametrize("guard_every", ["0", "1"])
+def test_the_mirror_is_the_state_after_every_batch(guard_every, monkeypatch):
+    """Folded (the guard off) or guarded at every batch, the resident
+    network mirror and its device twin equal a recompute from the state
+    store: as batches place, after allocations stop and free their
+    ports, and after a node registers (a rebuild: one walk)."""
+    monkeypatch.setenv("NOMAD_TPU_RESIDENT_GUARD_EVERY", guard_every)
+    h, nodes = standing_fleet(7)
+    stats = device_batch(h, [net_job(12), net_job(12)])
+    assert stats.net_usage_walks == 1          # the cold build
+    assert_mirror_is_the_state(h)
+    for _ in range(2):
+        stats = device_batch(h, [net_job(12), net_job(12)])
+        assert stats.resident_hits == 1 and stats.net_usage_walks == 0
+        assert stats.net_delta_words > 0
+        assert_mirror_is_the_state(h)
+    # Half of one job's allocations stop: their ports and Mbit free.
+    job_id = live(h)[-1].job_id
+    stopped = []
+    for a in live(h, job_id)[::2]:
+        done = s._fast_copy(a)
+        done.client_status = s.ALLOC_CLIENT_STATUS_COMPLETE
+        stopped.append(done)
+    h.state.update_allocs_from_client(h.next_index(), stopped)
+    stats = device_batch(h, [net_job(12)])
+    assert stats.resident_hits == 1 and stats.net_usage_walks == 0
+    assert_mirror_is_the_state(h)
+    # A node registers: the nodes table moves, the mirror is rebuilt.
+    h.state.upsert_node(h.next_index(), mock.node())
+    stats = device_batch(h, [net_job(12)])
+    assert stats.net_usage_walks == 1
+    assert_mirror_is_the_state(h)
+    stats = device_batch(h, [net_job(12)])
+    assert stats.net_usage_walks == 0
+    assert_mirror_is_the_state(h)
+    assert resident.GUARD_MISMATCHES == 0
+    assert resident.DEV_GUARD_MISMATCHES == 0
+
+
+def test_the_program_does_not_grow_with_the_nodes_in_use():
+    """Batches of the same two jobs' shape while the nodes that carry
+    allocations go from 8 to 60: one fused-program signature."""
+    h, _ = standing_fleet(8)
+    device_batch(h, [net_job(30), net_job(30)])
+    first = fused_signatures()
+    seen = [touched_nodes(h)]
+    while touched_nodes(h) < 60:
+        stats = device_batch(h, [net_job(30), net_job(30)])
+        assert stats.fused == 1 and stats.net_usage_walks == 0
+        seen.append(touched_nodes(h))
+        assert len(seen) < 12, seen
+    assert seen[0] < 60 <= seen[-1]
+    assert fused_signatures() == first
+
+
+@pytest.mark.parametrize("how", ["held_static", "held_dynamic", "in_batch"])
+def test_a_static_port_ask_avoids_a_used_port(how):
+    """A job asking a static port lands on no node where it is in use:
+    held by a standing allocation as a static port (8080, on every other
+    standing node) or as a dynamic one, or taken by a job earlier in the
+    same batch."""
+    h, nodes = standing_fleet(9)
+    port = {"held_static": 8080, "in_batch": 9090}.get(how)
+    if how == "held_dynamic":
+        port = next(p.value for a in live(h) for tr in
+                    a.task_resources.values() for nr in tr.networks
+                    for p in nr.dynamic_ports)
+    device_batch(h, [net_job(10)])          # the mirror is warm
+    users = {a.node_id for a in live(h) for tr in a.task_resources.values()
+             for nr in tr.networks
+             for p in nr.reserved_ports + nr.dynamic_ports
+             if p.value == port}
+    jobs = [net_job(30, reserved=(port,))]
+    if how == "in_batch":
+        jobs.append(net_job(30, reserved=(port,)))
+    stats = device_batch(h, jobs)
+    assert stats.fused == 1 and stats.oracle_routed == 0
+    assert stats.net_usage_walks == 1       # who holds the asked port
+    holders = {}
+    for j in jobs:
+        placed = live(h, j.id)
+        assert len(placed) == 30
+        for a in placed:
+            assert a.node_id not in users, (how, a.node_id)
+            holders.setdefault(a.node_id, 0)
+            holders[a.node_id] += 1
+    assert max(holders.values()) == 1      # the port once a node
+    if how != "in_batch":
+        assert users
+
+
+def test_a_multi_ip_node_sends_network_specs_to_the_oracle(monkeypatch):
+    """The gate's answer is kept by the nodes table's raft index: a
+    second batch on the same fleet walks no node, and a node that
+    registers with a multi-IP CIDR sends the next network batch to the
+    oracle."""
+    h, _ = standing_fleet(10)
+    walks, routed = [], []
+    walk = TPUBatchScheduler._walk_networks_simple
+    route = TPUBatchScheduler._route_through_oracle
+    monkeypatch.setattr(TPUBatchScheduler, "_walk_networks_simple",
+                        lambda self: walks.append(1) or walk(self))
+    monkeypatch.setattr(
+        TPUBatchScheduler, "_route_through_oracle",
+        lambda self, scheds: routed.extend(ev.id for ev, _ in scheds)
+        or route(self, scheds))
+    for _ in range(2):
+        device_batch(h, [net_job(5)])
+    assert len(walks) == 1 and not routed
+    node = mock.node()
+    node.resources.networks[0].cidr = "10.0.0.0/24"
+    h.state.upsert_node(h.next_index(), node)
+    job = net_job(5)
+    device_batch(h, [job])
+    assert len(walks) == 2 and len(routed) == 1
+    assert len(live(h, job.id)) == 5
+
+
+def test_the_offers_are_a_span_when_the_tracer_is_armed():
+    """``batch.finalize.offers``: one span a batch, as long as the time
+    the batch's offers took (``K.finalize.offers``), none failed."""
+    h, _ = standing_fleet(11)
+    tracing.enable()
+    try:
+        stats = device_batch(h, [net_job(10), net_job(10)])
+        spans = [sp for sp in tracing.recent(1000)
+                 if sp["Name"] == "batch.finalize.offers"]
+    finally:
+        tracing.disable()
+    assert stats.finalize_offers_seconds > 0
+    assert stats.net_offer_failures == 0
+    (span,) = spans
+    assert span["End"] - span["Start"] == pytest.approx(
+        stats.finalize_offers_seconds)
