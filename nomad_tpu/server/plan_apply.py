@@ -27,14 +27,18 @@ reads live alloc rows that an in-flight plan could still change.
 
 Group submission (ISSUE 32): a queue item is one SUBMISSION, one plan or
 a batch's plans in spec order (PlanQueue.enqueue_group).  Consecutive
-plans that propose nothing but slab placements without network are
-decided by ONE fit re-check over all their rows (usage only grows along
-the run, so if every touched node fits with all of the run's rows added,
-each plan fits in sequence and commits whole; if not, the pass decides
-nothing and the plans take the single-plan route one by one), committed
-as one raft entry PER PLAN written back to back under one fsync
-(RaftLog.apply_many), and answered each with its own result.  The
-single-plan route is the run of one.
+plans that propose nothing but NEW placements carrying combined
+resources (slab prototypes, or per-object allocations not yet in the
+store), with or without ports and bandwidth, are decided by ONE fit
+re-check over all their nodes.  Adding placements only grows a node's
+usage, its bandwidth and its set of held ports, so if every touched
+node fits with all of the run's placements added, no port is held twice
+and no dimension is exceeded by any prefix either: each plan fits in
+sequence and commits whole.  If not, the pass decides nothing and the
+plans take the single-plan route one by one.  A group is committed as
+one raft entry PER PLAN written back to back under one fsync
+(RaftLog.apply_many), and each plan is answered with its own result.
+The single-plan route is the run of one.
 """
 from __future__ import annotations
 
@@ -293,13 +297,21 @@ class PlanApplier:
 
     @staticmethod
     def _groupable(plan: s.Plan) -> bool:
-        """Whether all the plan proposes is slab placements that the
-        array route of the fit re-check decides (_fit_columnar's own
-        test): then it can be decided together with its neighbours."""
-        return not (plan.node_update or plan.node_allocation
-                    or plan.node_preemptions or plan.all_at_once) and all(
-            slab.proto.resources is not None and not _has_ports(slab.proto)
-            for slab in plan.alloc_slabs)
+        """Whether all the plan proposes is new placements that carry
+        combined resources: slab prototypes, and per-object allocations
+        not yet in the store (``create_index`` 0), ports or not.  Then
+        the run only adds to each node's usage and held ports, so one
+        re-check with all of the run's placements added decides every
+        plan of it (module docstring).  An in-place update is a copy of
+        a stored row with ``resources`` None and that row's
+        ``create_index``: it replaces the row, can shrink or grow it,
+        and is never grouped; nor are evictions, preemptions or a gang."""
+        return not (plan.node_update or plan.node_preemptions
+                    or plan.all_at_once) and all(
+            slab.proto.resources is not None
+            for slab in plan.alloc_slabs) and all(
+            alloc.resources is not None and not alloc.create_index
+            for allocs in plan.node_allocation.values() for alloc in allocs)
 
     @classmethod
     def _runs(cls, pairs: List[Tuple[s.Plan, PlanFuture]]):
@@ -479,22 +491,32 @@ class PlanApplier:
     def _evaluate_plans(self, snap, plans: List[s.Plan]
                         ) -> Optional[List[s.PlanResult]]:
         """One fit re-check for a run of plans.  One plan: evaluate_plan.
-        Several (all groupable: slab placements only): their slabs are
-        re-checked as ONE plan's, by the routes that are there, against
-        one read of the overlay and of the mirror.  Usage only grows
-        along the run, so if every touched node fits with all of the
-        run's rows added, every plan fits in sequence and each commits
-        whole; if any does not, the pass decides nothing (None) and the
-        caller takes the plans one by one.  ``nomad.plan.submitted``
-        counts the plans each pass decided."""
+        Several (all groupable: new placements only): their slabs, and
+        their per-object allocations merged per node in submission
+        order, are re-checked as ONE plan's, by the routes that are
+        there (a node where ports are placed takes the per-node route,
+        allocs_fit over existing + proposed), against one read of the
+        overlay and of the mirror.  Usage, bandwidth and held ports only
+        grow along the run, so if every touched node fits with all of
+        the run's placements added, every plan fits in sequence and each
+        commits whole; if any does not, the pass decides nothing (None,
+        counted by ``nomad.plan.group_undecided``) and the caller takes
+        the plans one by one.  ``nomad.plan.submitted`` counts the plans
+        each pass decided."""
         if len(plans) == 1:
             results = [self.evaluate_plan(snap, plans[0])]
         else:
             together = s.Plan(alloc_slabs=[
                 slab for plan in plans for slab in plan.alloc_slabs])
+            merged = together.node_allocation
+            for plan in plans:
+                for node_id, allocs in plan.node_allocation.items():
+                    merged.setdefault(node_id, []).extend(allocs)
             fits = self._evaluate_nodes(snap, together, len(plans))
             results = ([self._whole(plan) for plan in plans]
                        if fits.all_fit() else None)
+            if results is None:
+                self.metrics.incr_counter("plan.group_undecided")
         self.metrics.incr_counter("plan.submitted", len(results or ()))
         return results
 
@@ -629,11 +651,22 @@ class PlanApplier:
     def _evaluate_nodes_walk(self, snap, plan: s.Plan,
                              node_ids: List[str], slab_adds: Dict,
                              overlay: Dict[str, list]) -> Dict[str, bool]:
-        if len(node_ids) >= VECTORIZE_THRESHOLD:
-            return self._evaluate_nodes_vectorized(snap, plan, node_ids,
-                                                   slab_adds, overlay)
-        return {nid: self._evaluate_node_plan(snap, plan, nid, slab_adds,
-                                              overlay=overlay)
+        """allocs_fit's verdict per node.  A node where the plan or the
+        overlay places network resources is decided by
+        _evaluate_node_plan straight away (the vectorized route would
+        compute that verdict too, after array work and a jit call on
+        this thread); the others by one kernel call when there are
+        VECTORIZE_THRESHOLD of them."""
+        arrays = [nid for nid in node_ids
+                  if not any(_has_ports(a) for a in itertools.chain(
+                      plan.node_allocation.get(nid, ()),
+                      (p for p, _ in slab_adds.get(nid, ())),
+                      (p for p, _ in overlay.get(nid, ()))))]
+        fits = (self._evaluate_nodes_vectorized(snap, plan, arrays,
+                                                slab_adds, overlay)
+                if len(arrays) >= VECTORIZE_THRESHOLD else {})
+        return {nid: fits[nid] if nid in fits else self._evaluate_node_plan(
+                    snap, plan, nid, slab_adds, overlay=overlay)
                 for nid in node_ids}
 
     def _fit_columnar(self, snap, plan: s.Plan, cols,
@@ -914,18 +947,15 @@ class PlanApplier:
                         has_networks = has_networks or bool(tr.networks)
             for proto, cnt in slab_here:
                 used[i] += cnt * res_vec(proto.resources)
-                has_networks = has_networks or bool(
-                    proto.resources is not None and proto.resources.networks)
             for proto, cnt in overlay.get(node_id, ()):
                 # An in-flight per-object alloc may still carry only its
                 # per-task resources: the canonical usage basis sums them.
                 used[i] += cnt * _usage(proto)
-                # Overlay entries with port reservations route the node
-                # to the scalar fallback, where allocs_fit accounts them.
-                has_networks = has_networks or _has_ports(proto)
             if has_networks:
                 # Port/bandwidth accounting stays host-side: full scalar
-                # re-check for nodes with network reservations.
+                # re-check for nodes whose existing allocations hold
+                # network resources (the walk keeps nodes where ports
+                # are placed away from this route).
                 scalar_fallback[node_id] = self._evaluate_node_plan(
                     snap, plan, node_id, slab_adds, overlay=overlay)
 

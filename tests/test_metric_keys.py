@@ -86,10 +86,21 @@ def sink(tmp_path_factory):
                 offers, (0, 0.0))[1] > 0, 60.0)
         assert len(srv.state.allocs_by_job(None, net_job.id, True)) == 2
         latest = srv.metrics.sink.latest()
+        # plan.group_undecided: two such hogs' plans as one submission,
+        # whose group pass finds the node unfit and decides nothing.
+        group = []
+        for _ in range(2):
+            hog = hog.copy()
+            hog.id = s.generate_uuid()
+            group.append(s.Plan(eval_id=s.generate_uuid(), job=job))
+            group[-1].append_alloc(hog)
+        futures = srv.plan_queue.enqueue_group(group)
+        assert all(f.wait(30.0).refresh_index > 0 for f in futures)
         yield {"samples": set(latest["SampleTotals"]),
                "counters": set(latest["CounterTotals"]),
                "before_reject": before_reject,
-               "after_reject": after_reject, "latest": latest}
+               "after_reject": after_reject, "latest": latest,
+               "undecided": srv.metrics.sink.latest()}
 
 
 def test_there_are_sink_metrics():
@@ -128,6 +139,16 @@ def test_a_served_network_job_publishes_its_network_keys(sink, kind, key,
                                                          check):
     totals = sink["latest"][kind]
     assert key in totals and check(totals[key]), (key, totals.get(key))
+
+
+def test_a_group_pass_that_decides_nothing_is_counted(sink):
+    """Counter ``plan.group_undecided`` (no metric file: ``per_layer`` is
+    full) counts the group passes that found a node unfit and decided
+    nothing: none while the served jobs ran (a plan a batch), one for
+    the two hogs' plans submitted as one group."""
+    key = "nomad.plan.group_undecided"
+    assert sink["latest"]["CounterTotals"].get(key, 0) == 0
+    assert sink["undecided"]["CounterTotals"][key] == 1
 
 
 def test_fit_recheck_publishes_its_routes_and_its_guard(sink):

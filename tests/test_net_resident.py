@@ -21,7 +21,12 @@ from nomad_tpu.scheduler.generic import GenericScheduler
 from nomad_tpu.scheduler.stack import GenericStack
 from nomad_tpu.structs import structs as s
 from nomad_tpu.structs.network import MAX_DYNAMIC_PORT, MIN_DYNAMIC_PORT
+from nomad_tpu.server.fsm import FSM
+from nomad_tpu.server.plan_apply import PlanApplier
+from nomad_tpu.server.plan_queue import PlanQueue
+from nomad_tpu.server.raft import RaftLog
 from nomad_tpu.utils import tracing
+from nomad_tpu.utils.telemetry import InmemSink, Telemetry
 
 NODES, STANDING = 64, 8
 
@@ -67,13 +72,14 @@ def held_alloc(owner, node, cpu, mbits=10, reserved=(), dynamic=()):
     return alloc
 
 
-def standing_fleet(seed):
-    """64 nodes; the first 8 each carry one port-holding allocation of a
-    size of its own (so that their scores differ): a dynamic-range port
-    on each, and port 8080 on every other one."""
+def standing_fleet(seed, n_nodes=NODES):
+    """64 nodes (or ``n_nodes``); the first 8 each carry one
+    port-holding allocation of a size of its own (so that their scores
+    differ): a dynamic-range port on each, and port 8080 on every other
+    one."""
     h = Harness()
     nodes = []
-    for _ in range(NODES):
+    for _ in range(n_nodes):
         node = mock.node()
         h.state.upsert_node(h.next_index(), node)
         nodes.append(node)
@@ -347,3 +353,104 @@ def test_the_offers_are_a_span_when_the_tracer_is_armed():
     (span,) = spans
     assert span["End"] - span["Start"] == pytest.approx(
         stats.finalize_offers_seconds)
+
+
+class AppliedPlanner:
+    """The harness as planner, but a batch's plans go through a real
+    plan queue and applier on the harness's store, as one submission
+    (``submit_plans``), the way a served batch worker hands them over."""
+
+    def __init__(self, h):
+        self.h = h
+        self.sink = InmemSink()
+        raft = RaftLog(FSM(state=h.state))
+        # the log goes on from the harness's own indexes
+        raft._last_index = raft._applied = h.next_index() + 1000
+        raft._apply_next = raft._last_index + 1
+        self.applier = PlanApplier(PlanQueue(), raft,
+                                   metrics=Telemetry(self.sink))
+        self.submissions = []
+
+    def submit_plans(self, plans):
+        self.submissions.append(len(plans))
+        futures = self.applier.plan_queue.enqueue_group(plans)
+        return [(future.wait(30.0), None) for future in futures]
+
+    def submit_plan(self, plan):
+        return self.submit_plans([plan])[0]
+
+    def __getattr__(self, name):        # eval updates: the harness's
+        return getattr(self.h, name)
+
+
+def test_a_batch_of_network_jobs_is_one_group_at_the_applier(monkeypatch):
+    """One scheduler batch of port-asking jobs, its plans one submission
+    to the real applier: ONE fit re-check decides them all (one
+    ``plan.evaluate`` sample, ``plan.submitted`` = the batch's plans),
+    every port is held once on its node, and the guard's walk over the
+    group's port-bearing nodes (more than ``VECTORIZE_THRESHOLD``)
+    decides them with allocs_fit: no ``batch_allocs_fit`` call on the
+    applier's thread, nothing compiled during the submission."""
+    import jax.monitoring as mon
+
+    from nomad_tpu.server import plan_apply
+
+    monkeypatch.setenv("NOMAD_TPU_COLUMNAR_GUARD_EVERY", "1")
+    h, nodes = standing_fleet(12, n_nodes=160)
+    jobs = [net_job(60) for _ in range(8)]
+    for j in jobs:
+        h.state.upsert_job(h.next_index(), j)
+    planner = AppliedPlanner(h)
+    kernel_calls, compiles = [], []
+    batch_allocs_fit = kernels.batch_allocs_fit
+    monkeypatch.setattr(kernels, "batch_allocs_fit", lambda *a: (
+        kernel_calls.append(1), batch_allocs_fit(*a))[1])
+    submit = planner.submit_plans
+
+    def counted(plans):
+        def on_event(event, secs, **_kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(secs)
+
+        mon.register_event_duration_secs_listener(on_event)
+        try:
+            return submit(plans)
+        finally:
+            mon.unregister_event_duration_listener(on_event)
+
+    planner.submit_plans = counted
+    planner.applier.plan_queue.set_enabled(True)
+    planner.applier.start()
+    try:
+        stats = TPUBatchScheduler(h.logger, h.snapshot(), planner
+                                  ).schedule_batch([reg_eval(j) for j in jobs])
+    finally:
+        planner.applier.plan_queue.set_enabled(False)
+        planner.applier.stop()
+    assert stats.fused == 1 and stats.oracle_routed == 0
+    assert stats.net_offer_failures == 0
+    assert planner.submissions == [len(jobs)]
+    latest = planner.sink.latest()
+    counters, samples = latest["CounterTotals"], latest["SampleTotals"]
+    assert samples["nomad.plan.evaluate"][0] == 1
+    assert counters["nomad.plan.submitted"] == len(jobs)
+    assert counters.get("nomad.plan.conflict", 0) == 0
+    assert counters.get("nomad.plan.group_undecided", 0) == 0
+    assert samples["nomad.plan.evaluate.guard"][0] == 1
+    assert samples["nomad.plan.apply"][0] == 1
+    touched = {a.node_id for j in jobs for a in live(h, j.id)}
+    assert counters["nomad.plan.fit.rows_scalar"] == len(touched) \
+        >= plan_apply.VECTORIZE_THRESHOLD
+    assert kernel_calls == [] and compiles == []
+    by_node = {}
+    for j in jobs:
+        placed = live(h, j.id)
+        assert len(placed) == j.task_groups[0].count
+    for a in live(h):
+        for tr in a.task_resources.values():
+            for nr in tr.networks:
+                by_node.setdefault(a.node_id, []).extend(
+                    p.value for p in nr.reserved_ports + nr.dynamic_ports)
+    for nid, ports in by_node.items():
+        ports = ports + [22]
+        assert len(ports) == len(set(ports)), nid

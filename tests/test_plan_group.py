@@ -39,6 +39,7 @@ FSYNC = "nomad.raft.fsync"
 GUARD = "nomad.plan.evaluate.guard"
 ROWS_ARRAY = "nomad.plan.fit.rows_array"
 ROWS_SCALAR = "nomad.plan.fit.rows_scalar"
+UNDECIDED = "nomad.plan.group_undecided"
 WIDE = columnar.ARRAY_MIN_ROWS + 16
 
 
@@ -115,11 +116,84 @@ def case_split_by_a_per_object_plan(w):
 
 
 def case_split_by_a_network_plan(w):
+    """A slab whose prototype reserves a static port: a new placement
+    like any other, so since ports stopped mattering to grouping the
+    four fit in one pass."""
     net = _placing(w, w.ids[:3])
     net.alloc_slabs[0].proto.resources.networks = [s.NetworkResource(
         device="eth0", mbits=10, reserved_ports=[s.Port("main", 6000)])]
     return [_placing(w, w.ids[:5]), net, _placing(w, w.ids[3:8]),
             _placing(w, w.ids[2:9])]
+
+
+def case_split_by_an_in_place_update(w):
+    """A copy of a stored row, ``resources`` None and the row's own
+    ``create_index``: it replaces the row, so it is never grouped."""
+    inplace = w.plan()
+    update = w.store.alloc_by_id(None, w.objects[2].id).copy()
+    assert update.create_index
+    update.eval_id, update.job, update.resources = inplace.eval_id, None, None
+    update.task_resources = {"web": s.Resources(cpu=350, memory_mb=200)}
+    inplace.append_alloc(update)
+    return [_placing(w, w.ids[:5]), inplace, _placing(w, w.ids[3:8]),
+            _placing(w, w.ids[2:9])]
+
+
+def _ported(w, node_id, static=(), dynamic=(), mbits=10, cpu=50):
+    """A new per-object allocation as the batch path builds one for a
+    network ask: combined resources and a concrete offer on the node's
+    device and address."""
+    alloc = _alloc(w.job, node_id, cpu, 10)
+    net = s.NetworkResource(
+        device="eth0", ip="192.168.0.100", mbits=mbits,
+        reserved_ports=[s.Port(f"s{p}", p) for p in static],
+        dynamic_ports=[s.Port(f"d{p}", p) for p in dynamic])
+    alloc.task_resources["web"].networks = [net]
+    alloc.resources.networks = [net.copy()]
+    return alloc
+
+
+def _per_object(w, allocs):
+    plan = w.plan()
+    for alloc in allocs:
+        alloc.eval_id = plan.eval_id
+        plan.append_alloc(alloc)
+    return plan
+
+
+def case_ports_all_fit(w):
+    """Six plans of port-asking allocations, a static port and two
+    dynamic ones each, on overlapping nodes; no port twice on a node."""
+    ports = iter(range(21000, 22000))
+    plans = []
+    for i in range(6):
+        plans.append(_per_object(w, [
+            _ported(w, nid, static=(7000 + i,),
+                    dynamic=(next(ports), next(ports)))
+            for nid in w.rng.sample(w.ids, 4)]))
+    return plans
+
+
+def case_ports_static_collision(w):
+    """Two plans reserve static port 8000 on one node: the first takes
+    it, and in sequence the second loses that node."""
+    node = w.ids[3]
+    return [_per_object(w, [_ported(w, nid, dynamic=(21000 + i,))
+                            for i, nid in enumerate(w.ids[:4])]),
+            _per_object(w, [_ported(w, nid, static=(8000,))
+                            for nid in [node] + w.ids[5:7]]),
+            _per_object(w, [_ported(w, nid, static=(8000,))
+                            for nid in [node] + w.ids[7:9]])]
+
+
+def case_ports_over_bandwidth(w):
+    """Each fits the node's 1,000 Mbit alone, the two together do not."""
+    node = w.ids[6]
+    return [_per_object(w, [_ported(w, node, mbits=600),
+                            _ported(w, w.ids[1], mbits=600)]),
+            _per_object(w, [_ported(w, w.ids[2], mbits=100)]),
+            _per_object(w, [_ported(w, node, mbits=500),
+                            _ported(w, w.ids[3], mbits=500)])]
 
 
 def case_gang_plan_among_them(w):
@@ -144,11 +218,17 @@ def case_wide_overfill(w):
 
 CASES = {name[5:]: fn for name, fn in sorted(globals().items())
          if name.startswith("case_")}
-NETWORKED = {"split_by_a_network_plan"}
+NETWORKED = {"split_by_a_network_plan", "ports_all_fit",
+             "ports_static_collision", "ports_over_bandwidth"}
 # name -> (plans the group pass decides in one pass, evaluate passes);
 # None = the pass decides nothing and every plan goes alone.
 ONE_PASS = {"all_fit": (6, 1), "all_fit_two_slabs_a_plan": (4, 1),
-            "wide_all_fit": (5, 1)}
+            "wide_all_fit": (5, 1), "split_by_a_network_plan": (4, 1),
+            "ports_all_fit": (6, 1)}
+# name -> group passes that decided nothing (``nomad.plan.group_undecided``)
+UNDECIDED_PASSES = dict.fromkeys(
+    ["draining_node", "ports_over_bandwidth", "ports_static_collision",
+     "two_plans_overfill_a_node", "unknown_node", "wide_overfill"], 1)
 
 
 def _world(case, seed):
@@ -211,11 +291,45 @@ def as_one_group(w, plans):
         return [f.wait(30.0) for f in queue.enqueue_group(plans)]
 
 
+def ports(alloc):
+    return sorted((n.mbits, *(p.value for p in n.reserved_ports
+                              + n.dynamic_ports))
+                  for tr in alloc.task_resources.values()
+                  for n in tr.networks)
+
+
+def logged(w):
+    """What each entry the world's log applies says, without the random
+    ids and the time stamps: its index, type and plan, and per
+    allocation its node and ports; filled in as the log applies."""
+    entries = []
+    fsm_apply = w.applier.raft.fsm.apply
+
+    def apply(index, msg_type, payload):
+        entries.append((index, msg_type.name, payload.get("eval_id"),
+                        sorted((a.node_id, ports(a))
+                               for a in payload.get("allocs", ())),
+                        [sorted(collections.Counter(slab.node_ids).items())
+                         for slab in payload.get("slabs", ())]))
+        return fsm_apply(index, msg_type, payload)
+
+    w.applier.raft.fsm.apply = apply
+    return entries
+
+
+def by_plan(entries, plans):
+    """``logged``'s entries with each plan's eval id as its position in
+    the submission."""
+    pos = {plan.eval_id: i for i, plan in enumerate(plans)}
+    return [(index, kind, pos.get(eval_id), *rest)
+            for index, kind, eval_id, *rest in entries]
+
+
 def totals(w):
     latest = w.sink.latest()
     counters, samples = latest["CounterTotals"], latest["SampleTotals"]
     return {key: counters.get(key, 0)
-            for key in (SUBMITTED, ROWS_ARRAY, ROWS_SCALAR)} | {
+            for key in (SUBMITTED, ROWS_ARRAY, ROWS_SCALAR, UNDECIDED)} | {
         key: samples.get(key, (0, 0.0))[0]
         for key in (EVALUATE, APPLY, GUARD)}
 
@@ -234,9 +348,15 @@ def test_group_results_equal_the_sequence(case, guard, seed, monkeypatch):
     mismatches = columnar.USAGE_GUARD_MISMATCHES
     ref, got = _world(case, seed), _world(case, seed)
     ref_plans, got_plans = CASES[case](ref), CASES[case](got)
+    ref_log, got_log = logged(ref), logged(got)
     want = [shape(r) for r in one_after_another(ref, ref_plans)]
     results = as_one_group(got, got_plans)
     assert [shape(r) for r in results] == want
+    # the log the sequence writes: an entry per plan that commits, in
+    # the submission's order, the same allocations and ports
+    assert by_plan(got_log, got_plans) == by_plan(ref_log, ref_plans)
+    assert len(got_log) == sum(1 for r in results
+                               if r.alloc_slabs or r.node_allocation)
     assert usage_by_node(got) == usage_by_node(ref)
     assert got.applier.raft.applied_index() == ref.applier.raft.applied_index()
     assert columnar.USAGE_GUARD_MISMATCHES == mismatches
@@ -247,6 +367,7 @@ def test_group_results_equal_the_sequence(case, guard, seed, monkeypatch):
     n = len(got_plans)
     seen = totals(got)
     assert seen[SUBMITTED] == n == totals(ref)[SUBMITTED]
+    assert seen[UNDECIDED] == UNDECIDED_PASSES.get(case, 0)
     if case in ONE_PASS:
         assert (n, seen[EVALUATE]) == ONE_PASS[case]
         assert seen[APPLY] == 1
@@ -272,11 +393,32 @@ def test_the_overfilled_node_costs_the_second_plan_alone():
     assert totals(w)[SUBMITTED] == len(plans)
 
 
+@pytest.mark.parametrize("case,node", [("ports_static_collision", 3),
+                                       ("ports_over_bandwidth", 6)])
+def test_a_node_the_group_overfills_on_ports_costs_the_last_plan_alone(
+        case, node):
+    """The pass decides nothing, once; then in sequence the earlier plan
+    commits whole and the later one loses the contested node, keeping
+    its others."""
+    w = _world(case, 5)
+    plans = CASES[case](w)
+    results = as_one_group(w, plans)
+    assert totals(w)[UNDECIDED] == 1
+    assert totals(w)[EVALUATE] == 1 + len(plans)
+    for whole, plan in zip(results[:-1], plans):
+        assert not whole.refresh_index
+        assert whole.node_allocation == plan.node_allocation
+    last = results[-1]
+    assert last.refresh_index >= last.alloc_index > results[-2].alloc_index
+    assert set(last.node_allocation) == set(plans[-1].node_allocation) - {
+        w.ids[node]}
+
+
 @pytest.mark.parametrize("case,runs", [
     ("split_by_a_preemption_plan", [2, 1, 2]),
     ("split_by_a_node_update_plan", [1, 1, 2]),
     ("split_by_a_per_object_plan", [2, 1, 1]),
-    ("split_by_a_network_plan", [1, 1, 2]),
+    ("split_by_an_in_place_update", [1, 1, 2]),
     ("gang_plan_among_them", [1, 1, 2]),
 ])
 def test_a_plan_that_is_not_groupable_ends_the_run(case, runs):
