@@ -469,6 +469,7 @@ class TPUBatchScheduler:
             m.incr_counter("batch.spec_passes", stats.spec_passes)
             m.incr_counter("batch.net_usage_walks", stats.net_usage_walks)
             m.incr_counter("batch.net_delta_words", stats.net_delta_words)
+            m.incr_counter("batch.port_columns", stats.port_columns)
             # Bytes are a COUNTER (rate-derivable total), not a sample:
             # the percentile histogram's buckets are ms-calibrated and
             # would quantize MB-scale values into the top bucket.
@@ -886,6 +887,7 @@ class TPUBatchScheduler:
         stats.delta_apply_seconds = res_info.get("delta_apply_s", 0.0)
         stats.net_usage_walks = res_info.get("net_walks", 0)
         stats.net_delta_words = res_info.get("net_delta_words", 0)
+        stats.port_columns = res_info.get("port_columns", 0)
 
     def _route_through_oracle(self, scheds) -> None:
         """Degraded path: process each eval with the CPU GenericScheduler
@@ -1051,43 +1053,37 @@ class TPUBatchScheduler:
             return used, set(ref_touched)
         return used, touched
 
-    def _with_net_usage(self, ct, base, net_used, spec_list, *, held: bool,
-                        info: Dict):
+    @staticmethod
+    def _asked_ports(spec_list) -> List[int]:
+        """The static ports the batch's network specs ask for, sorted:
+        the order of their bits."""
+        return sorted({p for sp in spec_list if sp.net_active
+                       for p in sp.resv_ports})
+
+    def _with_net_usage(self, ct, base, net_used, spec_list, *,
+                        port_held: Optional[np.ndarray]):
         """``ct`` with what the fleet's allocations hold on their nodes'
         networks (``net_used``, the resident network mirror's rows) and,
         per node, one bit for each static port the batch's specs ask for,
         set where the port is reserved or held — the only ports a pass
         can collide on (dynamic ones are counted, and picked at
-        finalize).  ``held``: ``ct.port_words`` already carries what the
-        allocations hold (the walk built it); otherwise the nodes'
-        reservations only, and the asked ports' holders come from a walk
-        (``info["net_walks"]``).  Returns ``(ct, port_bits)``:
-        ``{port: bit}`` in the order the bits are laid out."""
+        finalize).  ``port_held``: None when ``ct.port_words`` already
+        carries what the allocations hold (the walk built it); otherwise
+        ``ct.port_words`` is the nodes' reservations only, and this is
+        where the allocations hold each asked port (``[n_pad, ports]``
+        bool, from the resident mirror's port columns).  Returns ``(ct,
+        port_bits)``: ``{port: bit}`` in the order the bits are laid
+        out."""
         import dataclasses as _dc
 
-        ports = sorted({p for sp in spec_list if sp.net_active
-                        for p in sp.resv_ports})
+        ports = self._asked_ports(spec_list)
         port_bits = {p: j for j, p in enumerate(ports)}
         words = encode.pow2_bucket(max(1, -(-len(ports) // 32)), minimum=1)
         bits = np.zeros((ct.n_pad, len(ports)), dtype=bool)
         for p, j in port_bits.items():
             bits[:, j] = (ct.port_words[:, p >> 5] >> np.uint32(p & 31)) & 1
-        if ports and not held:
-            info["net_walks"] = info.get("net_walks", 0) + 1
-            node_index = base._node_index  # type: ignore[attr-defined]
-            for nid, rows in self._live_allocs_by_node().items():
-                i = node_index.get(nid)
-                if i is None:
-                    continue
-                for row in rows:
-                    for tr in row.task_resources.values():
-                        if not tr.networks:
-                            continue
-                        nr = tr.networks[0]
-                        for port in nr.reserved_ports + nr.dynamic_ports:
-                            j = port_bits.get(port.value)
-                            if j is not None:
-                                bits[i, j] = True
+        if port_held is not None:
+            bits |= port_held
         port_words = np.zeros((ct.n_pad, words), dtype=np.uint32)
         for j in range(len(ports)):
             port_words[:, j >> 5] |= bits[:, j].astype(np.uint32) << np.uint32(
@@ -1181,16 +1177,18 @@ class TPUBatchScheduler:
                 shards=(self.mesh.devices.size
                         if self.mesh is not None else 0),
                 usage_fn=lambda: self._columnar_usage(base),
-                with_net=with_networks)
+                with_net=with_networks, ports=self._asked_ports(spec_list))
             enc.begin("specs")
             ct = encode.with_usage(base, used)
             net_used = resident_info.pop("net", None)
+            port_held = resident_info.pop("port_held", None)
             # The preemption pass only needs WHICH nodes may carry live
             # allocs (it re-materializes candidate rows from state);
             # avoid the full row walk the resident path just saved.
             self._allocs_by_node = _TouchedNodeIds(base.node_ids, touched)
         else:
             enc.begin("resident")       # off the mirror: the usage walk
+            port_held = None            # the walk's port words hold all
             cu = (self._columnar_usage(base)
                   if not with_networks else None)
             if cu is not None:
@@ -1214,12 +1212,16 @@ class TPUBatchScheduler:
                          base.dyn_free - ct.dyn_free], axis=1)
             enc.begin("specs")
         port_bits = None
+        port_stamps = []
         if with_networks and not mesh_net:
+            t_ports = tracing.now()
             ct, port_bits = self._with_net_usage(
-                ct, base, net_used, spec_list,
-                held=not use_resident, info=resident_info)
+                ct, base, net_used, spec_list, port_held=port_held)
+            if port_bits:
+                port_stamps.append((t_ports, tracing.now()))
         st = encode.encode_specs(spec_list, ct, all_nodes,
                                  port_bits=port_bits)
+        st.port_stamps = port_stamps
         # The batch's shape plan, or the compiled plan of its shape class
         # that covers it (kernels.choose_plan: a drain's tail batch runs
         # the full batches' program instead of compiling its own).
@@ -2038,6 +2040,9 @@ class TPUBatchScheduler:
             for a, b in st.row_stamps:
                 tr.record("batch.encode.constraint_rows", a, b,
                           parent_id=parent)
+            for a, b in st.port_stamps:
+                tr.record("batch.encode.static_ports", a, b,
+                          parent_id=parent)
             tr.record("batch.device", t1, t1 + device_seconds,
                       span_id=stages.parent_id, rounds=rounds)
             parent = tr.record(
@@ -2750,11 +2755,13 @@ class BatchStats:
         self.staleness_fences = 0
         # Network usage (ops/resident.py NET_DIMS): walks of every live
         # alloc the batch's network state needed (a build, a fence, the
-        # off-mirror path, the holders of an asked static port), and the
+        # off-mirror path, the first ask of a static port), the
         # network-usage words sent to the device (the mirror's delta
-        # upload, or rows uploaded whole).
+        # upload, or rows uploaded whole), and the asked static ports
+        # whose holders came from the mirror's port columns.
         self.net_usage_walks = 0
         self.net_delta_words = 0
+        self.port_columns = 0
         # Host time of THIS batch's prepare phase that ran while the
         # previous batch's device pass was still in flight
         # (schedule_stream double-buffering; 0 on the serial path).

@@ -840,6 +840,9 @@ class SpecTensors:
     # served from a kept row with no node and no class evaluated.
     row_stamps: List[Tuple[float, float]] = field(default_factory=list)
     rows_reused: int = 0
+    # (start, end) of the build of the batch's static-port bits
+    # (TPUBatchScheduler._with_net_usage), where it asks any.
+    port_stamps: List[Tuple[float, float]] = field(default_factory=list)
 
 
 def encode_specs(

@@ -35,9 +35,15 @@ has asked for it (``acquire(with_net=True)``), what the live allocations'
 networks hold on each node: Mbit and ports in the dynamic range
 (``NET_DIMS``, ``structs.alloc_net_vec``), folded from the same feed one
 delta per allocation write, with a device twin of its own.  A fleet that
-never sees a network ask never builds it.  Port VALUES are not mirrored:
-the host picks dynamic ports at finalize, and a batch that asks for a
-static port reads who holds it from the state (ops/batch_sched.py).
+never sees a network ask never builds it.  Beside it, one column per
+static port value a batch has asked for (``acquire(ports=...)``): how
+many of each node's live allocations hold that value, as any port of
+theirs.  A column is built by one walk the batch that first asks for it,
+then folded from the feed's port values (``structs.alloc_net_held``; a
+write's entry carries them when it takes or frees a port); the guard
+holds every column to the walk too.  A fleet that never asks for a
+static port builds no column and folds nothing more.  Dynamic port
+values are not mirrored: the host picks them at finalize.
 
 Env knobs:
 
@@ -182,7 +188,7 @@ class ResidentState:
 
     __slots__ = ("key", "used", "alloc_index", "touched", "hits",
                  "delta_rows", "since_guard", "used_dev", "dev_mesh",
-                 "net", "net_dev")
+                 "net", "net_dev", "ports")
 
     def __init__(self, key: Tuple, used: np.ndarray, alloc_index: int,
                  touched: set):
@@ -210,6 +216,9 @@ class ResidentState:
         # device twin (int32, single-chip) is loaned like ``used_dev``.
         self.net = None
         self.net_dev = None
+        # {static port value: int32 [n_pad] holders per node} at the same
+        # alloc_index, one column per value a batch has asked for.
+        self.ports: Dict[int, np.ndarray] = {}
 
 
 # Single residency slot (the steady-state workload schedules one cluster
@@ -462,7 +471,7 @@ def _feed_rows(entries, node_index: Dict[str, int]
     vecs: List[Tuple] = []
     counts: List[int] = []
     for entry in entries:
-        if len(entry) != 2:     # (index, node_id, delta[, net])
+        if len(entry) != 2:     # (index, node_id, delta[, net[, ports]])
             singles.append(entry[1])
             vecs.append(entry[2])
             counts.append(1)
@@ -500,7 +509,7 @@ def _feed_net_rows(entries, node_index: Dict[str, int]
     vecs: List[Tuple] = []
     parts: List[np.ndarray] = []
     for entry in entries:
-        if len(entry) == 4:
+        if len(entry) >= 4:
             nids.append(entry[1])
             vecs.append(entry[3])
         elif len(entry) == 2:
@@ -517,6 +526,60 @@ def _feed_net_rows(entries, node_index: Dict[str, int]
     if not known.all():
         rows, vals = rows[known], vals[known]
     return rows, vals
+
+
+def _feed_port_rows(entries, node_index: Dict[str, int]
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+    """The feed's port values as ``(rows int64[k], values int64[k])``:
+    one per port an allocation write took (``v``) or freed (``-v``), a
+    single row's fifth element or a slab prototype's ports over its node
+    column, in feed order; writes on nodes the fleet does not hold are
+    dropped."""
+    from ..state.columnar import gather_index
+    from ..structs.structs import alloc_net_held
+
+    nids: List[str] = []
+    values: List[int] = []
+    parts: List[np.ndarray] = []
+    for entry in entries:
+        if len(entry) == 5:
+            nids.extend([entry[1]] * len(entry[4]))
+            values.extend(entry[4])
+        elif len(entry) == 2:
+            ports = alloc_net_held(entry[1].proto)[1]
+            if ports:
+                idx = gather_index(node_index, entry[1].node_ids)
+                parts.append(np.repeat(idx, len(ports)))
+                parts.append(np.tile(np.asarray(ports, dtype=np.int64),
+                                     len(idx)))
+    rows = np.concatenate([gather_index(node_index, nids)] + parts[::2])
+    vals = np.concatenate([np.asarray(values, dtype=np.int64)]
+                          + parts[1::2])
+    known = rows >= 0
+    if not known.all():
+        rows, vals = rows[known], vals[known]
+    return rows, vals
+
+
+def _fold_ports(columns: Dict[int, np.ndarray], entries,
+                node_index: Dict[str, int]) -> None:
+    """Fold the feed's port values into the columns they are of."""
+    rows, vals = _feed_port_rows(entries, node_index)
+    if not len(rows):
+        return
+    for port, col in columns.items():
+        np.add.at(col, rows[vals == port], 1)
+        np.subtract.at(col, rows[vals == -port], 1)
+
+
+def _port_held(columns: Dict[int, np.ndarray], ports, n_pad: int
+               ) -> np.ndarray:
+    """[n_pad, len(ports)] bool: where some live allocation holds each
+    of ``ports``, in that order."""
+    out = np.zeros((n_pad, len(ports)), dtype=bool)
+    for j, port in enumerate(ports):
+        out[:, j] = columns[port] > 0
+    return out
 
 
 def _publish(etype_reason: str, **payload) -> None:
@@ -552,15 +615,19 @@ def _full_usage(base, rows_fn) -> Tuple[np.ndarray, set]:
     return used, touched
 
 
-def _full_net(base, rows_fn) -> np.ndarray:
+def _full_net(base, rows_fn, ports=()
+              ) -> Tuple[np.ndarray, Dict[int, np.ndarray]]:
     """The reference rebuild of the network mirror from a full state
     walk, on ops/encode.apply_alloc_usage's basis and independent of the
     feed: per node, the Mbit of its live allocs' first task networks,
     and the ports in the dynamic range they hold, as a set, less those
-    the node itself reserves (the static baseline's)."""
+    the node itself reserves (the static baseline's); and, in the same
+    pass, a column for each of ``ports``: per node, how many of those
+    networks' ports hold the value."""
     from ..structs.network import MAX_DYNAMIC_PORT, MIN_DYNAMIC_PORT
 
     net = np.zeros((base.n_pad, NET_DIMS), dtype=np.int64)
+    columns = {p: np.zeros(base.n_pad, dtype=np.int32) for p in ports}
     node_index = base._node_index  # type: ignore[attr-defined]
     nodes = base._nodes            # type: ignore[attr-defined]
     for nid, rows in rows_fn().items():
@@ -573,16 +640,18 @@ def _full_net(base, rows_fn) -> np.ndarray:
                 if tr.networks:
                     nr = tr.networks[0]
                     mbits += nr.mbits
-                    held.update(p.value for p in
-                                nr.reserved_ports + nr.dynamic_ports
-                                if MIN_DYNAMIC_PORT <= p.value
-                                < MAX_DYNAMIC_PORT)
+                    for p in nr.reserved_ports + nr.dynamic_ports:
+                        if MIN_DYNAMIC_PORT <= p.value < MAX_DYNAMIC_PORT:
+                            held.add(p.value)
+                        col = columns.get(p.value)
+                        if col is not None:
+                            col[i] += 1
         if held and nodes[i].reserved is not None:
             for nr in nodes[i].reserved.networks or []:
                 held.difference_update(
                     p.value for p in nr.reserved_ports + nr.dynamic_ports)
         net[i] = (mbits, len(held))
-    return net
+    return net, columns
 
 
 def _usage_source(base, rows_fn, usage_fn) -> Tuple[np.ndarray, set]:
@@ -603,7 +672,7 @@ def _usage_source(base, rows_fn, usage_fn) -> Tuple[np.ndarray, set]:
 
 def acquire(state, cache_key: Tuple, base, rows_fn,
             breaker=None, shards: int = 0, usage_fn=None,
-            with_net: bool = False
+            with_net: bool = False, ports=()
             ) -> Tuple[np.ndarray, List[int], Dict]:
     """Produce the live usage matrix for this batch.
 
@@ -626,23 +695,29 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
     built (one walk) if the slot has none yet and handed back as
     ``info["net"]`` ([n_pad, NET_DIMS] int64).  Once built it is folded
     with every batch's feed, and the guard holds it to ``_full_net``
-    from the same walk as the usage.
+    from the same walk as the usage.  ``ports``: the static port values
+    the batch asks for (with ``with_net``); ``info["port_held"]`` is
+    then ``[n_pad, len(ports)]`` bool, set where a live allocation
+    holds the value, read from the mirror's columns (a column the slot
+    lacks is built first, all of a batch's in one walk).
 
     Returns ``(used int64 [n_pad, 4], touched_rows sorted list, info)``
     where info carries the BatchStats counters:
     ``resident_hit``/``delta_rows``/``full_reencode``/``fence``/
     ``guard_ran``/``guard_mismatch`` (+ ``guard_bad_shards`` on a
-    sharded mismatch), and ``net_walks`` (walks the network mirror
-    itself needed: a build, a fence, a rebuild).
+    sharded mismatch), ``net_walks`` (walks the network mirror
+    itself needed: a build, a fence, a rebuild, a port column's build)
+    and ``port_columns`` (asked ports served from the mirror's columns).
     """
     global _STATE, HITS, FULL_REENCODES, STALENESS_FALLBACKS
     global GUARD_RUNS, GUARD_MISMATCHES
 
     info = {"resident_hit": False, "delta_rows": 0, "full_reencode": False,
             "fence": False, "guard_ran": False, "guard_mismatch": False,
-            "delta_apply_s": 0.0, "net_walks": 0}
+            "delta_apply_s": 0.0, "net_walks": 0, "port_columns": 0}
     snap_index = state.table_index("allocs")
     walked: List[Dict] = []
+    ports = sorted(ports) if with_net else []
 
     def walk():
         # One state walk a batch at most, shared by the usage and the
@@ -651,13 +726,19 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
             walked.append(rows_fn())
         return walked[0]
 
-    def net_walk() -> np.ndarray:
+    def net_walk(new_ports=()) -> Tuple[np.ndarray, Dict[int, np.ndarray]]:
         info["net_walks"] += 1
-        return _full_net(base, walk)
+        return _full_net(base, walk, new_ports)
+
+    def served(net, columns, from_mirror):
+        info["net"] = net
+        info["port_held"] = _port_held(columns, ports, base.n_pad)
+        if from_mirror:
+            info["port_columns"] = len(ports)
 
     def fenced(used, touched):
         if with_net:
-            info["net"] = net_walk()
+            served(*net_walk(ports), False)
         return used, sorted(touched), info
 
     with _LOCK:
@@ -705,6 +786,8 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                 if st.net is not None:
                     net_rows, net_vals = _feed_net_rows(entries, node_index)
                     np.add.at(st.net, net_rows, net_vals)
+                if st.ports:
+                    _fold_ports(st.ports, entries, node_index)
                 st.alloc_index = snap_index
                 st.hits += 1
                 st.delta_rows += len(rows)
@@ -795,13 +878,17 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                                 breaker.record(False)
                             setattr(st, attr, None)
                     ref_used, ref_touched = _full_usage(base, walk)
-                    ref_net = (_full_net(base, walk)
-                               if st.net is not None else None)
+                    ref_net, ref_ports = (_full_net(base, walk, st.ports)
+                                          if st.net is not None
+                                          else (None, {}))
                     bad_rows = np.nonzero(
                         (used != ref_used).any(axis=1))[0]
                     if ref_net is not None:
                         bad_rows = np.union1d(bad_rows, np.nonzero(
                             (st.net != ref_net).any(axis=1))[0])
+                    for port, col in ref_ports.items():
+                        bad_rows = np.union1d(bad_rows, np.nonzero(
+                            st.ports[port] != col)[0])
                     if len(bad_rows):
                         GUARD_MISMATCHES += 1
                         info["guard_mismatch"] = True
@@ -829,8 +916,7 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                         info["resident_hit"] = False
                         info["full_reencode"] = True
                         if with_net:
-                            info["net"] = (ref_net if ref_net is not None
-                                           else net_walk())
+                            served(*net_walk(ports), False)
                         return ref_used, sorted(ref_touched), info
                     if breaker is not None:
                         breaker.record(True)
@@ -839,9 +925,13 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
                     st.touched = set(ref_touched)
 
                 if with_net:
-                    if st.net is None:
-                        st.net = net_walk()
-                    info["net"] = st.net.copy()
+                    new_ports = [p for p in ports if p not in st.ports]
+                    if st.net is None or new_ports:
+                        net, columns = net_walk(new_ports)
+                        if st.net is None:
+                            st.net = net
+                        st.ports.update(columns)
+                    served(st.net.copy(), st.ports, True)
                 # Hand the caller a copy: the resident matrix keeps
                 # advancing under later batches while the device pass /
                 # forensics of THIS batch still read their snapshot.
@@ -855,8 +945,8 @@ def acquire(state, cache_key: Tuple, base, rows_fn,
         used, touched = _usage_source(base, walk, usage_fn)
         _STATE = ResidentState(cache_key, used, snap_index, set(touched))
         if with_net:
-            _STATE.net = net_walk()
-            info["net"] = _STATE.net.copy()
+            _STATE.net, _STATE.ports = net_walk(ports)
+            served(_STATE.net.copy(), _STATE.ports, True)
         tracing.event("resident.full_reencode", reason=reason,
                       alloc_index=snap_index)
         if reason != "cold":

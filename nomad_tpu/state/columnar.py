@@ -428,7 +428,7 @@ class ClusterColumns:
         self._own_usage()
         row_of, n, u = self.row_of, self.n, self.usage
         for entry in entries:
-            if len(entry) != 2:     # (index, node_id, delta[, net])
+            if len(entry) != 2:     # (index, node_id, delta[, net[, ports]])
                 self._add_row(u, row_of.get(entry[1]), n, entry[2])
                 continue
             slab = entry[1]         # (index, slab): its node column

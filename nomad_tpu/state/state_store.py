@@ -43,6 +43,12 @@ _NO_NODE = s.Node()
 # (a slab entry weighs len(slab)).
 ALLOC_LOG_CAP = _knobs.get_int("NOMAD_TPU_ALLOC_LOG_CAP")
 
+
+def _released(ports: Tuple[int, ...]) -> Tuple[int, ...]:
+    """The feed's port values for a write that frees ``ports``."""
+    return tuple(-v for v in ports)
+
+
 # Number of historical job versions retained (reference: structs.go
 # JobTrackedVersions = 6).
 JOB_TRACKED_VERSIONS = 6
@@ -1238,8 +1244,9 @@ class StateStore:
             row = alloc
         if index and not row.terminal_status():
             c, m, d, i = self._usage_vec(row)
-            bw, dyn = self._net_vec(row)
-            self._log_usage(index, node_id, (-c, -m, -d, -i), (-bw, -dyn))
+            (bw, dyn), ports = self._net_held(row)
+            self._log_usage(index, node_id, (-c, -m, -d, -i), (-bw, -dyn),
+                            _released(ports))
             self._ns_fold(row.namespace, -c, -m, -d, -i, -1)
         self._idx_discard(self._allocs_by_node, node_id, alloc_id)
         self._idx_discard(self._allocs_by_job, job_id, alloc_id)
@@ -1381,7 +1388,7 @@ class StateStore:
     # replaying the feed lands on bit-identical usage rows.
 
     _usage_vec = staticmethod(s.alloc_usage_vec)
-    _net_vec = staticmethod(s.alloc_net_vec)
+    _net_held = staticmethod(s.alloc_net_held)
 
     def _log_ensure_owned(self) -> None:
         """Copy-on-write for a snapshot's shared log prefix: the first
@@ -1413,15 +1420,20 @@ class StateStore:
 
     def _log_usage(self, index: int, node_id: str,
                    delta: Tuple[int, int, int, int],
-                   net: Tuple[int, int] = (0, 0)) -> None:
+                   net: Tuple[int, int] = (0, 0),
+                   ports: Tuple[int, ...] = ()) -> None:
         """One row's entry: ``(index, node_id, delta)``, with the change
         of what its networks hold (``structs.alloc_net_vec``) as a fourth
-        element when there is one."""
-        if (delta == (0, 0, 0, 0) and net == (0, 0)) or not node_id:
+        element when there is one, and as a fifth, when the write takes
+        or frees a port, the port values: ``v`` taken, ``-v`` freed."""
+        if ((delta == (0, 0, 0, 0) and net == (0, 0) and not ports)
+                or not node_id):
             return
         self._log_ensure_owned()
-        self._alloc_log.append((index, node_id, delta) if net == (0, 0)
-                               else (index, node_id, delta, net))
+        self._alloc_log.append(
+            (index, node_id, delta, net, ports) if ports
+            else (index, node_id, delta) if net == (0, 0)
+            else (index, node_id, delta, net))
         self._alloc_log_len += 1
         self._alloc_log_weight += 1
         self._log_trim()
@@ -1450,31 +1462,34 @@ class StateStore:
         new_live = not updated.terminal_status()
         if old_live and new_live and existing.node_id == updated.node_id:
             ov, nv = self._usage_vec(existing), self._usage_vec(updated)
-            on, nn = self._net_vec(existing), self._net_vec(updated)
+            on, op = self._net_held(existing)
+            nn, np_ = self._net_held(updated)
             self._log_usage(index, updated.node_id,
                             (nv[0] - ov[0], nv[1] - ov[1],
                              nv[2] - ov[2], nv[3] - ov[3]),
-                            (nn[0] - on[0], nn[1] - on[1]))
+                            (nn[0] - on[0], nn[1] - on[1]),
+                            () if op == np_ else _released(op) + np_)
             if nv != ov:
                 self._ns_fold(updated.namespace, nv[0] - ov[0],
                               nv[1] - ov[1], nv[2] - ov[2], nv[3] - ov[3], 0)
             return
         if old_live:
             c, m, d, i = self._usage_vec(existing)
-            bw, dyn = self._net_vec(existing)
+            (bw, dyn), ports = self._net_held(existing)
             self._log_usage(index, existing.node_id, (-c, -m, -d, -i),
-                            (-bw, -dyn))
+                            (-bw, -dyn), _released(ports))
             self._ns_fold(existing.namespace, -c, -m, -d, -i, -1)
         if new_live:
             v = self._usage_vec(updated)
-            self._log_usage(index, updated.node_id, v,
-                            self._net_vec(updated))
+            net, ports = self._net_held(updated)
+            self._log_usage(index, updated.node_id, v, net, ports)
             self._ns_fold(updated.namespace, v[0], v[1], v[2], v[3], 1)
 
     def alloc_log_since(self, index: int) -> Optional[List[tuple]]:
         """The usage-delta log's raw entries with raft index > ``index``
-        — ``(index, node_id, delta[, net])`` per single row (``net`` when
-        what its networks hold changed), ``(index, slab)`` per bulk
+        — ``(index, node_id, delta[, net[, ports]])`` per single row
+        (``net`` when what its networks hold changed, ``ports`` when the
+        write took or freed a port), ``(index, slab)`` per bulk
         insert, unexpanded — or None when the log can no longer
         answer.  The array readers' feed (columnar.fold_usage, the
         resident mirror): a slab stays one entry, so nothing is paid per

@@ -217,17 +217,28 @@ def alloc_net_vec(alloc) -> "Tuple[int, int]":
     ops/encode.apply_alloc_usage's network accounting.  The state
     store's usage-delta feed logs it beside ``alloc_usage_vec`` and the
     resident network mirror (ops/resident.py) folds it."""
+    return alloc_net_held(alloc)[0]
+
+
+def alloc_net_held(alloc) -> "Tuple[Tuple[int, int], Tuple[int, ...]]":
+    """``alloc_net_vec`` and, from the same pass, the values of every
+    port the alloc's networks hold (first network of each task, reserved
+    and dynamic, 0 left out): what the resident mirror's port columns
+    count, one holder per value."""
     from .network import MAX_DYNAMIC_PORT, MIN_DYNAMIC_PORT
 
-    mbits = ports = 0
+    mbits = in_dyn = 0
+    ports = []
     for tr in alloc.task_resources.values():
         if tr.networks:
             nr = tr.networks[0]
             mbits += nr.mbits
             for p in nr.reserved_ports + nr.dynamic_ports:
-                if MIN_DYNAMIC_PORT <= p.value < MAX_DYNAMIC_PORT:
-                    ports += 1
-    return (mbits, ports)
+                if p.value:
+                    ports.append(p.value)
+                    if MIN_DYNAMIC_PORT <= p.value < MAX_DYNAMIC_PORT:
+                        in_dyn += 1
+    return (mbits, in_dyn), tuple(ports)
 
 
 @dataclass

@@ -85,6 +85,18 @@ def sink(tmp_path_factory):
             lambda: srv.metrics.sink.latest()["SampleTotals"].get(
                 offers, (0, 0.0))[1] > 0, 60.0)
         assert len(srv.state.allocs_by_job(None, net_job.id, True)) == 2
+        # batch.port_columns: one more, its network asking a static port
+        # beside the dynamic ones.
+        static_job = mock.job()
+        static_job.task_groups[0].count = 1
+        static_job.task_groups[0].tasks[0].resources.networks[0] \
+            .reserved_ports = [s.Port("lb", 8889)]
+        conftest.put_job(agent, static_job)
+        columns = "nomad.batch.port_columns"
+        assert conftest.wait_for(
+            lambda: srv.metrics.sink.latest()["CounterTotals"].get(
+                columns, 0) > 0, 60.0)
+        assert len(srv.state.allocs_by_job(None, static_job.id, True)) == 1
         latest = srv.metrics.sink.latest()
         # plan.group_undecided: two such hogs' plans as one submission,
         # whose group pass finds the node unfit and decides nothing.
@@ -122,14 +134,17 @@ def test_metric_file_names_a_published_key(sink, spec):
 NET_KEYS = [
     # (kind, key, check): the network job's batch picked its offers (a
     # sample of the time), made every one (no failure) and built the
-    # resident network mirror with the one walk a cold build takes.  Two
-    # of the four have no metric file (``BENCHMARK.json`` holds at most
-    # 128 per-layer metrics), so only this test holds their keys.
+    # resident network mirror with the one walk a cold build takes; the
+    # static-port job's batch read its port's holders from the mirror's
+    # column.  Three of the five have no metric file (``BENCHMARK.json``
+    # holds at most 128 per-layer metrics), so only this test holds their
+    # keys.
     ("SampleTotals", "nomad.worker.invoke_scheduler.finalize.offers",
      lambda v: v[1] > 0),
     ("CounterTotals", "nomad.batch.net_offer_failures", lambda v: v == 0),
     ("CounterTotals", "nomad.batch.net_usage_walks", lambda v: v >= 1),
     ("CounterTotals", "nomad.batch.net_delta_words", lambda v: v >= 0),
+    ("CounterTotals", "nomad.batch.port_columns", lambda v: v >= 1),
 ]
 
 

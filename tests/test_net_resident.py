@@ -258,6 +258,116 @@ def test_the_mirror_is_the_state_after_every_batch(guard_every, monkeypatch):
     assert resident.DEV_GUARD_MISMATCHES == 0
 
 
+def recompute_ports(state, ports):
+    """{port: [nodes] holders}: how many ports of the live allocations'
+    first task networks hold each value, from the state store, by node
+    order."""
+    order = [n.id for n in state.nodes(None)]
+    out = {p: np.zeros(len(order), dtype=np.int64) for p in ports}
+    for i, nid in enumerate(order):
+        for a in state.allocs_by_node(None, nid):
+            if a.terminal_status():
+                continue
+            for tr in a.task_resources.values():
+                if tr.networks:
+                    nr = tr.networks[0]
+                    for p in nr.reserved_ports + nr.dynamic_ports:
+                        if p.value in out:
+                            out[p.value][i] += 1
+    return out
+
+
+def assert_columns_are_the_state(h, ports):
+    """The mirror holds a column for each of ``ports`` and no other, each
+    equal to a recompute from the last batch's snapshot."""
+    st = resident._STATE
+    assert st is not None and sorted(st.ports) == sorted(ports)
+    for port, want in recompute_ports(h.last_snapshot, ports).items():
+        n = len(want)
+        np.testing.assert_array_equal(st.ports[port][:n], want)
+        assert not st.ports[port][n:].any()
+
+
+def static_jobs(*ports, count=8):
+    return [net_job(count, reserved=(p,)) for p in ports]
+
+
+@pytest.mark.parametrize("guard_every", ["0", "1"])
+def test_the_port_columns_are_the_state_after_every_batch(guard_every,
+                                                          monkeypatch):
+    """Folded (the guard off) or guarded at every batch, each asked
+    static port's column equals a recompute from the state store: built
+    with the mirror in its one walk, folded as batches place, after
+    allocations stop and free their ports, and after a node registers
+    (the rebuild's one walk).  A warm batch walks nothing and serves
+    every port it asks from a column."""
+    monkeypatch.setenv("NOMAD_TPU_RESIDENT_GUARD_EVERY", guard_every)
+    h, nodes = standing_fleet(13)
+    # 8080 is held on every other standing node already.
+    stats = device_batch(h, static_jobs(8889, 8080))
+    assert stats.net_usage_walks == 1 and stats.port_columns == 2
+    assert_columns_are_the_state(h, [8080, 8889])
+    for _ in range(2):
+        stats = device_batch(h, static_jobs(8889, 8080))
+        assert stats.resident_hits == 1 and stats.net_usage_walks == 0
+        assert stats.port_columns == 2
+        assert_columns_are_the_state(h, [8080, 8889])
+    # Half of a job's allocations stop: their ports free.
+    job_id = live(h)[-1].job_id
+    stopped = []
+    for a in live(h, job_id)[::2]:
+        done = s._fast_copy(a)
+        done.client_status = s.ALLOC_CLIENT_STATUS_COMPLETE
+        stopped.append(done)
+    h.state.update_allocs_from_client(h.next_index(), stopped)
+    stats = device_batch(h, static_jobs(8889))
+    assert stats.resident_hits == 1 and stats.net_usage_walks == 0
+    assert stats.port_columns == 1
+    assert_columns_are_the_state(h, [8080, 8889])
+    # A node registers: the mirror is rebuilt with the asked port's
+    # column alone, in one walk; a port asked later costs one more.
+    h.state.upsert_node(h.next_index(), mock.node())
+    stats = device_batch(h, static_jobs(8889))
+    assert stats.net_usage_walks == 1 and stats.port_columns == 1
+    assert_columns_are_the_state(h, [8889])
+    stats = device_batch(h, static_jobs(8889, 8080))
+    assert stats.net_usage_walks == 1 and stats.port_columns == 2
+    assert_columns_are_the_state(h, [8080, 8889])
+    stats = device_batch(h, static_jobs(8080))
+    assert stats.net_usage_walks == 0 and stats.port_columns == 1
+    assert_columns_are_the_state(h, [8080, 8889])
+    assert resident.GUARD_MISMATCHES == 0
+    for port in (8080, 8889):
+        held = recompute_ports(h.state, [port])[port]
+        assert held.max() == 1      # the port once a node
+
+
+def test_a_drifted_port_column_is_caught_by_the_guard(monkeypatch):
+    """A column that drifted from the state (here a holder on every node
+    of the fleet that no allocation is) reads a mismatch at the guard's
+    walk: the mirror is dropped, the breaker fed, and the batch placed
+    from the walk, every port still once a node."""
+    monkeypatch.setenv("NOMAD_TPU_RESIDENT_GUARD_EVERY", "1")
+    h, _ = standing_fleet(16)
+    device_batch(h, static_jobs(8889, count=20))
+    resident._STATE.ports[8889][:NODES] += 1
+    stats = device_batch(h, static_jobs(8889, count=20))
+    assert resident.GUARD_MISMATCHES == 1
+    assert stats.fused == 1 and stats.port_columns == 0
+    assert recompute_ports(h.state, [8889])[8889].max() == 1
+    assert len(live(h)) == STANDING + 40
+
+
+def test_a_fleet_that_asks_no_static_port_builds_no_column():
+    """Dynamic ports only: the mirror folds Mbit and the dynamic-range
+    count, and builds no port column."""
+    h, _ = standing_fleet(14)
+    for _ in range(2):
+        stats = device_batch(h, [net_job(10), net_job(10)])
+        assert stats.port_columns == 0
+    assert resident._STATE.net is not None and resident._STATE.ports == {}
+
+
 def test_the_program_does_not_grow_with_the_nodes_in_use():
     """Batches of the same two jobs' shape while the nodes that carry
     allocations go from 8 to 60: one fused-program signature."""
@@ -279,14 +389,16 @@ def test_a_static_port_ask_avoids_a_used_port(how):
     """A job asking a static port lands on no node where it is in use:
     held by a standing allocation as a static port (8080, on every other
     standing node) or as a dynamic one, or taken by a job earlier in the
-    same batch."""
+    same batch.  The mirror is warm, the port's column with it, so the
+    batch reads who holds the port from the column and walks nothing."""
     h, nodes = standing_fleet(9)
     port = {"held_static": 8080, "in_batch": 9090}.get(how)
     if how == "held_dynamic":
         port = next(p.value for a in live(h) for tr in
                     a.task_resources.values() for nr in tr.networks
                     for p in nr.dynamic_ports)
-    device_batch(h, [net_job(10)])          # the mirror is warm
+    stats = device_batch(h, [net_job(2, reserved=(port,))])
+    assert stats.net_usage_walks == 1       # the mirror and the column
     users = {a.node_id for a in live(h) for tr in a.task_resources.values()
              for nr in tr.networks
              for p in nr.reserved_ports + nr.dynamic_ports
@@ -296,7 +408,12 @@ def test_a_static_port_ask_avoids_a_used_port(how):
         jobs.append(net_job(30, reserved=(port,)))
     stats = device_batch(h, jobs)
     assert stats.fused == 1 and stats.oracle_routed == 0
-    assert stats.net_usage_walks == 1       # who holds the asked port
+    assert stats.net_usage_walks == 0 and stats.port_columns == 1
+    # The column the batch read: a holder on each node that uses the
+    # port, static or dynamic.
+    order = node_order(h)
+    held = resident._STATE.ports[port]
+    assert {order[i] for i in np.nonzero(held)[0]} == users
     holders = {}
     for j in jobs:
         placed = live(h, j.id)
@@ -353,6 +470,27 @@ def test_the_offers_are_a_span_when_the_tracer_is_armed():
     (span,) = spans
     assert span["End"] - span["Start"] == pytest.approx(
         stats.finalize_offers_seconds)
+
+
+def test_the_static_port_bits_are_a_span_inside_encode():
+    """``batch.encode.static_ports``: one span for a batch that asks a
+    static port, none for one that does not, a child of ``batch.encode``
+    inside its stage ``specs``."""
+    h, _ = standing_fleet(15)
+    tracing.enable()
+    try:
+        device_batch(h, [net_job(10)])
+        device_batch(h, static_jobs(8889))
+        spans = tracing.recent(5000)
+    finally:
+        tracing.disable()
+    (span,) = [sp for sp in spans if sp["Name"] == "batch.encode.static_ports"]
+    (parent,) = [sp for sp in spans if sp["SpanID"] == span["ParentID"]]
+    assert parent["Name"] == "batch.encode"
+    (specs,) = [sp for sp in spans if sp["Name"] == "batch.encode.specs"
+                and sp["ParentID"] == parent["SpanID"]]
+    assert (specs["Start"] - 1e-6 <= span["Start"] <= span["End"]
+            <= specs["End"] + 1e-6)
 
 
 class AppliedPlanner:

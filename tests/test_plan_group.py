@@ -814,6 +814,19 @@ def test_a_groups_priority_is_its_highest_plans():
 # -- the served path ----------------------------------------------------------
 
 
+class _Parking(threading.Condition):
+    """A worker's pause condition that says when the worker waits on it:
+    parked, between two dequeues."""
+
+    def __init__(self, parked: threading.Event) -> None:
+        super().__init__()
+        self.parked = parked
+
+    def wait(self, timeout=None):
+        self.parked.set()
+        return super().wait(timeout)
+
+
 def test_a_batch_writes_its_statuses_once_before_any_ack():
     """Four jobs and one unblocked eval in one batch of the BatchWorker:
     one group on the plan queue, every nack clock paused while it waits
@@ -856,6 +869,10 @@ def test_a_batch_writes_its_statuses_once_before_any_ack():
         blocked_id = srv.state.eval_by_id(None, big_eval).blocked_eval
         assert blocked_id and conftest.wait_for(
             lambda: srv.blocked_evals.stats()["total_blocked"] == 1, 10.0)
+        # The first eval's ack follows its status write: it must land
+        # before the log below starts, or it reads as the batch's first.
+        assert conftest.wait_for(
+            lambda: srv.eval_broker.stats()["total_unacked"] == 0, 10.0)
 
         log = []
         fsm_apply = srv.raft.fsm.apply
@@ -907,9 +924,15 @@ def test_a_batch_writes_its_statuses_once_before_any_ack():
 
         srv.workers[0].apply_eval_updates = recording_updates
 
+        # Parked means waiting on the pause condition: a dequeue in
+        # progress has ended, so no eval below is taken before all five
+        # are ready.
+        parked = []
         for w in srv.workers:
+            parked.append(threading.Event())
+            w._pause_cond = _Parking(parked[-1])
             w.set_pause(True)
-        time.sleep(0.7)                     # a dequeue in progress ends
+        assert all(p.wait(10.0) for p in parked)
         small_node(700)             # unblocks: room for one more of two
         jobs = [job(1, cpu=50) for _ in range(4)]
         eval_ids = [srv.job_register(j)[1] for j in jobs]
