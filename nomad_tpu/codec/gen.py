@@ -48,7 +48,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..structs.structs import LazyNames, LazyUuids, NodeColumn, _LazyStrs
 from . import native
-from .schema import FINGERPRINT, MAGIC, TYPE_IDS, TYPES_BY_ID, VERSION
+from .schema import (FINGERPRINTS, MAGIC, TYPE_IDS, TYPES_BY_ID, VERSION,
+                     fields_of)
 
 
 class CodecError(ValueError):
@@ -183,7 +184,11 @@ def _dstrs(b: bytes, p: int):
 # -- per-type codegen --------------------------------------------------------
 
 _ENCODERS: List[Optional[Callable]] = [None] * len(TYPES_BY_ID)
-_DECODERS: List[Optional[Callable]] = [None] * len(TYPES_BY_ID)
+# Per layout version this build decodes (schema.FINGERPRINTS), its
+# decoders by type id; a frame is decoded wholly with its version's.
+_DECODERS_OF: Dict[int, List[Optional[Callable]]] = {
+    v: [None] * len(TYPES_BY_ID) for v in FINGERPRINTS}
+_DECODERS = _DECODERS_OF[VERSION]
 
 
 def _classify(hint) -> tuple:
@@ -335,13 +340,21 @@ def _emit_dec(src: _Src, ind: int, out: str, plan: tuple) -> None:
         src.emit(ind, f"{out}, p = _dval(b, p)")
 
 
-def _field_plans(cls: type) -> List[Tuple[str, tuple]]:
+def _field_plans(cls: type, version: int = VERSION
+                 ) -> List[Tuple[str, tuple]]:
     try:
         hints = typing.get_type_hints(cls)
     except Exception:
         hints = {}
     return [(f.name, _classify(hints.get(f.name, Any)))
-            for f in dataclasses.fields(cls)]
+            for f in fields_of(cls, version)]
+
+
+def _default_of(f: dataclasses.Field) -> Callable:
+    """A field's default as a callable (a fresh object per call)."""
+    if f.default_factory is not dataclasses.MISSING:
+        return f.default_factory
+    return lambda: f.default
 
 
 _NAMESPACE: Dict[str, Any] = {
@@ -370,6 +383,13 @@ def _build(tid: int) -> None:
     exec("\n".join(src.lines), ns)  # noqa: S102 — our own generated source
     _ENCODERS[tid] = ns[f"_enc_{tid}"]
 
+
+def _build_dec(tid: int, version: int) -> None:
+    """The decoder of type ``tid`` in the layout of ``version``: the
+    fields that version lacks are set to their defaults."""
+    cls = TYPES_BY_ID[tid]
+    plans = _field_plans(cls, version)
+    have = {fname for fname, _ in plans}
     src = _Src()
     src.emit(0, f"def _dec_{tid}(b, p):")
     outs = []
@@ -378,16 +398,24 @@ def _build(tid: int) -> None:
         outs.append((fname, out))
         _emit_dec(src, 1, out, plan)
     src.emit(1, "o = _new(_cls)")
-    pairs = ", ".join(f"{fname!r}: {out}" for fname, out in outs)
-    src.emit(1, f"o.__dict__ = {{{pairs}}}")
+    pairs = [f"{fname!r}: {out}" for fname, out in outs]
+    defaults = {}
+    for f in dataclasses.fields(cls):
+        if f.name not in have:
+            defaults[f.name] = _default_of(f)
+            pairs.append(f"{f.name!r}: _defaults[{f.name!r}]()")
+    src.emit(1, f"o.__dict__ = {{{', '.join(pairs)}}}")
     src.emit(1, "return o, p")
+    decoders = _DECODERS_OF[version]
     ns = dict(_NAMESPACE)
+    ns["_D"] = decoders
     ns["_val"] = _val
-    ns["_dval"] = _dval
+    ns["_dval"] = _DVAL_OF[version]
     ns["_new"] = object.__new__
     ns["_cls"] = cls
+    ns["_defaults"] = defaults
     exec("\n".join(src.lines), ns)  # noqa: S102
-    _DECODERS[tid] = ns[f"_dec_{tid}"]
+    decoders[tid] = ns[f"_dec_{tid}"]
 
 
 def _enc_thunk(tid: int) -> Callable:
@@ -397,16 +425,17 @@ def _enc_thunk(tid: int) -> Callable:
     return thunk
 
 
-def _dec_thunk(tid: int) -> Callable:
+def _dec_thunk(tid: int, version: int) -> Callable:
     def thunk(b, p):
-        _build(tid)
-        return _DECODERS[tid](b, p)
+        _build_dec(tid, version)
+        return _DECODERS_OF[version][tid](b, p)
     return thunk
 
 
 for _tid in range(len(TYPES_BY_ID)):
     _ENCODERS[_tid] = _enc_thunk(_tid)
-    _DECODERS[_tid] = _dec_thunk(_tid)
+    for _v, _decoders in _DECODERS_OF.items():
+        _decoders[_tid] = _dec_thunk(_tid, _v)
 
 
 # -- the tagged value tree (raft payloads / RPC envelopes / Any fields) -----
@@ -475,52 +504,62 @@ def _val(w: bytearray, v) -> None:
             raise CodecError(f"unencodable value type {t.__name__}")
 
 
-def _dval(b: bytes, p: int):
-    tag = _dby(b, p)
-    p += 1
-    if tag == _T_NONE:
-        return None, p
-    if tag == _T_FALSE:
-        return False, p
-    if tag == _T_TRUE:
-        return True, p
-    if tag == _T_INT:
-        return _dzz(b, p)
-    if tag == _T_FLOAT:
-        return _dd(b, p)
-    if tag == _T_STR:
-        return _dstr(b, p)
-    if tag == _T_BYTES:
-        return _dbytes(b, p)
-    if tag == _T_LIST:
-        n, p = _duv(b, p)
-        out = []
-        for _ in range(n):
-            x, p = _dval(b, p)
-            out.append(x)
-        return out, p
-    if tag == _T_DICT:
-        n, p = _duv(b, p)
-        out = {}
-        for _ in range(n):
-            k, p = _dval(b, p)
-            x, p = _dval(b, p)
-            out[k] = x
-        return out, p
-    if tag == _T_STRUCT:
-        tid, p = _duv(b, p)
-        if not 0 <= tid < len(TYPES_BY_ID):
-            raise CodecError(f"unknown struct type id {tid}")
-        return _DECODERS[tid](b, p)
-    if tag == _T_LAZY_UUIDS:
-        prefix, p = _dstr(b, p)
-        n, p = _duv(b, p)
-        return LazyUuids(n, prefix), p
-    if tag == _T_LAZY_NAMES:
-        prefix, p = _dstr(b, p)
-        n, p = _duv(b, p)
-        return LazyNames(n, prefix), p
-    raise CodecError(f"unknown value tag {tag}")
+def _make_dval(decoders: List[Optional[Callable]]) -> Callable:
+    """The value-tree decoder over one version's struct decoders."""
+
+    def _dval(b: bytes, p: int):
+        tag = _dby(b, p)
+        p += 1
+        if tag == _T_NONE:
+            return None, p
+        if tag == _T_FALSE:
+            return False, p
+        if tag == _T_TRUE:
+            return True, p
+        if tag == _T_INT:
+            return _dzz(b, p)
+        if tag == _T_FLOAT:
+            return _dd(b, p)
+        if tag == _T_STR:
+            return _dstr(b, p)
+        if tag == _T_BYTES:
+            return _dbytes(b, p)
+        if tag == _T_LIST:
+            n, p = _duv(b, p)
+            out = []
+            for _ in range(n):
+                x, p = _dval(b, p)
+                out.append(x)
+            return out, p
+        if tag == _T_DICT:
+            n, p = _duv(b, p)
+            out = {}
+            for _ in range(n):
+                k, p = _dval(b, p)
+                x, p = _dval(b, p)
+                out[k] = x
+            return out, p
+        if tag == _T_STRUCT:
+            tid, p = _duv(b, p)
+            if not 0 <= tid < len(TYPES_BY_ID):
+                raise CodecError(f"unknown struct type id {tid}")
+            return decoders[tid](b, p)
+        if tag == _T_LAZY_UUIDS:
+            prefix, p = _dstr(b, p)
+            n, p = _duv(b, p)
+            return LazyUuids(n, prefix), p
+        if tag == _T_LAZY_NAMES:
+            prefix, p = _dstr(b, p)
+            n, p = _duv(b, p)
+            return LazyNames(n, prefix), p
+        raise CodecError(f"unknown value tag {tag}")
+
+    return _dval
+
+
+_DVAL_OF: Dict[int, Callable] = {
+    v: _make_dval(decoders) for v, decoders in _DECODERS_OF.items()}
+_dval = _DVAL_OF[VERSION]
 
 
 # -- frames ------------------------------------------------------------------
@@ -533,7 +572,9 @@ def _dval(b: bytes, p: int):
 # everywhere: a peer built from a different struct schema gets a clean
 # CodecError ("run the schema-changing upgrade under NOMAD_TPU_CODEC=0",
 # the NTPUSNP2-style documented path), never a silently shifted layout.
-_HEADER = bytes((MAGIC, VERSION)) + FINGERPRINT
+# Every version a build decodes has a fingerprint of the same length; a
+# frame is decoded wholly with the layouts of its own version.
+_HEADER = bytes((MAGIC, VERSION)) + FINGERPRINTS[VERSION]
 _BODY_START = len(_HEADER)
 
 # Decode failures that indicate a malformed frame rather than a codec
@@ -563,22 +604,24 @@ def is_frame(blob: bytes) -> bool:
 
 
 def decode_frame(blob: bytes):
-    """Strict inverse of :func:`encode_frame`: rejects bad magic,
-    unknown versions, schema-fingerprint mismatches, truncation, and
-    trailing garbage."""
+    """Strict inverse of :func:`encode_frame`, and of the encoders of the
+    older layout versions this build still reads (schema.ADDED): rejects
+    bad magic, unknown versions, schema-fingerprint mismatches,
+    truncation, and trailing garbage."""
     if len(blob) < 2 or blob[0] != MAGIC:
         raise CodecError("bad frame magic")
-    if blob[1] != VERSION:
+    fingerprint = FINGERPRINTS.get(blob[1])
+    if fingerprint is None:
         raise CodecError(f"unsupported codec version {blob[1]}")
     if len(blob) < _BODY_START:
         raise CodecError("truncated frame header")
-    if blob[2:_BODY_START] != FINGERPRINT:
+    if blob[2:_BODY_START] != fingerprint:
         raise CodecError(
             "schema fingerprint mismatch: frame was encoded by a peer "
             "built from a different struct schema (run schema-changing "
             "upgrades under NOMAD_TPU_CODEC=0)")
     try:
-        v, p = _dval(blob, _BODY_START)
+        v, p = _DVAL_OF[blob[1]](blob, _BODY_START)
     except CodecError:
         raise
     except _DECODE_ERRORS as e:

@@ -25,7 +25,16 @@ from ..structs import structs as _structs
 MAGIC = 0xC1
 
 #: Flat-layout schema version carried in every frame after the magic.
-VERSION = 1
+#: 2: AllocSlab gained its network columns, ``ips`` and ``dyn_ports``.
+VERSION = 2
+
+#: The fields each version added to a type's layout, by type name.  A
+#: frame of an older version is decoded with the layouts it was written
+#: with (a type's fields less those added since; they are appended, so
+#: the old layout is a prefix) and those fields at their defaults.
+ADDED: Dict[int, Dict[str, Tuple[str, ...]]] = {
+    2: {"AllocSlab": ("ips", "dyn_ports")},
+}
 
 
 def _registry() -> List[Tuple[str, type]]:
@@ -52,21 +61,33 @@ def _type_repr(hint) -> str:
     return repr(hint)
 
 
-def schema_fingerprint() -> bytes:
-    """8-byte digest of every registered type's (name, fields, hints):
-    two peers agree on the flat layouts iff their fingerprints match."""
+def fields_of(cls: type, version: int = VERSION) -> List[dataclasses.Field]:
+    """``cls``'s fields in the layout of ``version``."""
+    later = {name for v, added in ADDED.items() if v > version
+             for name in added.get(cls.__name__, ())}
+    return [f for f in dataclasses.fields(cls) if f.name not in later]
+
+
+def schema_fingerprint(version: int = VERSION) -> bytes:
+    """8-byte digest of every registered type's (name, fields, hints) in
+    the layouts of ``version``: two peers agree on the flat layouts iff
+    their fingerprints match."""
     h = hashlib.sha256()
-    h.update(bytes([VERSION]))
+    h.update(bytes([version]))
     for name, cls in _REGISTRY:
         h.update(name.encode())
         try:
             hints = typing.get_type_hints(cls)
         except Exception:
             hints = {}
-        for f in dataclasses.fields(cls):
+        for f in fields_of(cls, version):
             h.update(f.name.encode())
             h.update(_type_repr(hints.get(f.name, "?")).encode())
     return h.digest()[:8]
 
 
-FINGERPRINT = schema_fingerprint()
+#: Every layout version this build decodes, with its fingerprint; it
+#: encodes the newest.
+FINGERPRINTS: Dict[int, bytes] = {
+    v: schema_fingerprint(v) for v in range(1, VERSION + 1)}
+FINGERPRINT = FINGERPRINTS[VERSION]

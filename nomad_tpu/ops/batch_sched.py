@@ -504,6 +504,7 @@ class TPUBatchScheduler:
                          stats.finalize_offers_seconds * 1000.0)
             m.incr_counter("batch.net_offer_failures",
                            stats.net_offer_failures)
+            m.incr_counter("batch.net_slab_rows", stats.net_slab_rows)
         m.add_sample("worker.invoke_scheduler.asks", stats.num_asks)
         # Residency counters: per-batch samples plus the process-lifetime
         # gauges (ops/resident.py module counters).
@@ -2365,7 +2366,9 @@ class TPUBatchScheduler:
         """Per-batch NetworkIndex for a node, seeded from state and mutated
         as offers commit — so concrete dynamic-port values assigned at
         finalize never collide within the batch (device-side capacity
-        accounting guarantees feasibility)."""
+        accounting guarantees feasibility).  The seed reads the networks
+        the node's live allocations hold, a network slab's rows from its
+        columns (StateStore.node_networks): no Allocation is built."""
         from ..structs.network import NetworkIndex
 
         idx = cache.get(node_id)
@@ -2374,9 +2377,8 @@ class TPUBatchScheduler:
             node = self.state.node_by_id(None, node_id)
             if node is not None:
                 idx.set_node(node)
-                live = [a for a in self.state.allocs_by_node(None, node_id)
-                        if not a.terminal_status()]
-                idx.add_allocs(live)
+                for held in self.state.node_networks(node_id):
+                    idx.add_held(*held)
             cache[node_id] = idx
         return idx
 
@@ -2507,54 +2509,10 @@ class TPUBatchScheduler:
                     )
                     sched.plan.append_slab(slab)
                     appended = k
-            else:
-                if names is None and k:
-                    names = [f"{sched.job.name}.{tg.name}[{i}]"
-                             for i in range(k)]
-                ids = s.generate_uuids(k) if k else []
-                append = sched.plan.append_alloc
-                import random as _random
-                net_rng = _random.Random(ev.id)
-                for i in range(k):
-                    alloc = fast_copy(proto)
-                    alloc.id = ids[i]
-                    alloc.name = names[i]
-                    alloc.node_id = slots[i]
-                    # Concrete per-task network offers (IP + dynamic port
-                    # values): the device reserved ports/bandwidth/dyn
-                    # capacity; the host picks the actual port numbers
-                    # (rank.go:199 assign + network.go:245).
-                    t_offer = time.perf_counter()
-                    idx = self._net_index(slots[i], net_index_cache)
-                    task_resources = {}
-                    total = s.Resources(disk_mb=tg.ephemeral_disk.size_mb)
-                    offer_failed = False
-                    for t in tg.tasks:
-                        res = t.resources.copy()
-                        ask_net = net_asks.get(t.name)
-                        if ask_net is not None:
-                            offer, err = idx.assign_network(ask_net, net_rng)
-                            if offer is None:
-                                self.logger.warning(
-                                    "batch: network offer failed on %s: %s",
-                                    slots[i], err)
-                                offer_failed = True
-                                break
-                            idx.add_reserved(offer)
-                            res.networks = [offer]
-                        task_resources[t.name] = res
-                        total.add(res)
-                    stats.finalize_offers_seconds += (time.perf_counter()
-                                                      - t_offer)
-                    if offer_failed:
-                        stats.net_offer_failures += 1
-                        continue
-                    alloc.task_resources = task_resources
-                    alloc.resources = total
-                    if prevs is not None and prevs[i]:
-                        alloc.previous_allocation = prevs[i]
-                    append(alloc)
-                    appended += 1
+            elif k:
+                appended = self._net_slabs(
+                    ev, sched, tg, proto, net_asks, slots[:k], names, prevs,
+                    net_index_cache, stats)
             # Placements won by the preemption pass: explicit allocs (not
             # slab rows — each carries eviction dependencies), with the
             # victims staged into Plan.node_preemptions so the applier
@@ -2601,6 +2559,65 @@ class TPUBatchScheduler:
             self.planner.create_eval(sched.next_eval)
 
         return time.perf_counter()
+
+    def _net_slabs(self, ev, sched, tg, proto, net_asks, slots, names,
+                   prevs, net_index_cache, stats) -> int:
+        """A port-asking spec's placements as network slabs: each slot's
+        concrete offers (IP and dynamic port values per networked task;
+        the device reserved ports, bandwidth and dynamic capacity, the
+        host picks the numbers: rank.go:199 assign + network.go:245)
+        become a row of the columns of one AllocSlab
+        (``AllocSlab.of_offers``), no Allocation per slot.  Rows whose
+        offers landed on the same devices share a slab.  A slot whose
+        offer fails drops its row and counts in ``net_offer_failures``.
+        Returns the rows written."""
+        import random as _random
+
+        net_rng = _random.Random(ev.id)
+        asks = [net_asks[t.name] for t in tg.tasks if t.name in net_asks]
+        # device of each networked task's offer -> (slot positions, offers)
+        groups: Dict[tuple, Tuple[List[int], List[list]]] = {}
+        t_offer = time.perf_counter()
+        for i, node_id in enumerate(slots):
+            idx = self._net_index(node_id, net_index_cache)
+            offers = []
+            for ask in asks:
+                offer, err = idx.assign_network(ask, net_rng)
+                if offer is None:
+                    self.logger.warning(
+                        "batch: network offer failed on %s: %s", node_id,
+                        err)
+                    break
+                idx.add_reserved(offer)
+                offers.append(offer)
+            if len(offers) < len(asks):
+                stats.net_offer_failures += 1
+                continue
+            kept, rows = groups.setdefault(
+                tuple(o.device for o in offers), ([], []))
+            kept.append(i)
+            rows.append(offers)
+        k = len(slots)
+        prefix = f"{sched.job.name}.{tg.name}"
+        placed = 0
+        for kept, rows in groups.values():
+            whole = len(kept) == k
+            sched.plan.append_slab(s.AllocSlab.of_offers(
+                proto, rows,
+                ids=s.LazyUuids(len(kept)),
+                names=(s.LazyNames(k, prefix) if names is None and whole
+                       else [names[i] if names is not None
+                             else f"{prefix}[{i}]" for i in kept]),
+                node_ids=(slots if whole
+                          else s.NodeColumn(slots.table, slots.idx[kept])
+                          if type(slots) is s.NodeColumn
+                          else [slots[i] for i in kept]),
+                prev_ids=([prevs[i] or "" for i in kept]
+                          if prevs is not None else [])))
+            placed += len(kept)
+        stats.finalize_offers_seconds += time.perf_counter() - t_offer
+        stats.net_slab_rows += placed
+        return placed
 
     def _finalize_settle(self, ev, sched, result, new_state) -> bool:
         """An eval's own result of the submission.  True when a conflict
@@ -2683,6 +2700,8 @@ class BatchStats:
         # batch, and the slots whose offer could not be made.
         self.finalize_offers_seconds = 0.0
         self.net_offer_failures = 0
+        # Rows written through network slabs (the offers' columns).
+        self.net_slab_rows = 0
         self.total_seconds = 0.0
         # CPU time of the worker's thread between total_seconds' two
         # stamps (time.thread_time: waits for the interpreter lock, the

@@ -499,9 +499,10 @@ def _feed_net_rows(entries, node_index: Dict[str, int]
                    ) -> Tuple[np.ndarray, np.ndarray]:
     """The feed's network deltas as ``(rows int64[k], vals int64[k,
     NET_DIMS])``: one row per allocation write that changed what a
-    node's networks hold (a single row's fourth element; a slab whose
-    prototype holds any, over its node column), in feed order; writes on
-    nodes the fleet does not hold are dropped."""
+    node's networks hold (a single row's fourth element; a network
+    slab's rows, ``AllocSlab.row_net_usage``, each like a single row; a
+    slab whose prototype holds any, over its node column), in feed
+    order; writes on nodes the fleet does not hold are dropped."""
     from ..state.columnar import gather_index
     from ..structs.structs import alloc_net_vec
 
@@ -513,11 +514,16 @@ def _feed_net_rows(entries, node_index: Dict[str, int]
             nids.append(entry[1])
             vecs.append(entry[3])
         elif len(entry) == 2:
-            vec = alloc_net_vec(entry[1].proto)
+            slab = entry[1]
+            if slab.ips:
+                nids.extend(slab.node_ids)
+                vecs.extend(slab.row_net_usage())
+                continue
+            vec = alloc_net_vec(slab.proto)
             if vec != (0, 0):
-                parts.append(gather_index(node_index, entry[1].node_ids))
+                parts.append(gather_index(node_index, slab.node_ids))
                 parts.append(np.array([vec], dtype=np.int64).repeat(
-                    len(entry[1].node_ids), axis=0))
+                    len(slab.node_ids), axis=0))
     rows = np.concatenate([gather_index(node_index, nids)] + parts[::2])
     vals = np.concatenate(
         [np.array(vecs, dtype=np.int64).reshape(-1, NET_DIMS)]
@@ -532,9 +538,10 @@ def _feed_port_rows(entries, node_index: Dict[str, int]
                     ) -> Tuple[np.ndarray, np.ndarray]:
     """The feed's port values as ``(rows int64[k], values int64[k])``:
     one per port an allocation write took (``v``) or freed (``-v``), a
-    single row's fifth element or a slab prototype's ports over its node
-    column, in feed order; writes on nodes the fleet does not hold are
-    dropped."""
+    single row's fifth element, a network slab's rows' own
+    (``AllocSlab.row_ports``, each like a single row) or a slab
+    prototype's over its node column, in feed order; writes on nodes the
+    fleet does not hold are dropped."""
     from ..state.columnar import gather_index
     from ..structs.structs import alloc_net_held
 
@@ -546,9 +553,15 @@ def _feed_port_rows(entries, node_index: Dict[str, int]
             nids.extend([entry[1]] * len(entry[4]))
             values.extend(entry[4])
         elif len(entry) == 2:
-            ports = alloc_net_held(entry[1].proto)[1]
+            slab = entry[1]
+            if slab.ips:
+                for nid, ports in zip(slab.node_ids, slab.row_ports()):
+                    nids.extend([nid] * len(ports))
+                    values.extend(ports)
+                continue
+            ports = alloc_net_held(slab.proto)[1]
             if ports:
-                idx = gather_index(node_index, entry[1].node_ids)
+                idx = gather_index(node_index, slab.node_ids)
                 parts.append(np.repeat(idx, len(ports)))
                 parts.append(np.tile(np.asarray(ports, dtype=np.int64),
                                      len(idx)))
@@ -636,16 +649,14 @@ def _full_net(base, rows_fn, ports=()
             continue
         mbits, held = 0, set()
         for row in rows:
-            for tr in row.task_resources.values():
-                if tr.networks:
-                    nr = tr.networks[0]
-                    mbits += nr.mbits
-                    for p in nr.reserved_ports + nr.dynamic_ports:
-                        if MIN_DYNAMIC_PORT <= p.value < MAX_DYNAMIC_PORT:
-                            held.add(p.value)
-                        col = columns.get(p.value)
-                        if col is not None:
-                            col[i] += 1
+            for _, _, m, values in row.held_networks():
+                mbits += m
+                for value in values:
+                    if MIN_DYNAMIC_PORT <= value < MAX_DYNAMIC_PORT:
+                        held.add(value)
+                    col = columns.get(value)
+                    if col is not None:
+                        col[i] += 1
         if held and nodes[i].reserved is not None:
             for nr in nodes[i].reserved.networks or []:
                 held.difference_update(

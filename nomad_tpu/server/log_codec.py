@@ -48,11 +48,20 @@ _TYPES: Dict[str, type] = {
 }
 _TYPES["PeriodicLaunch"] = PeriodicLaunch
 _TYPES["VaultAccessor"] = VaultAccessor
+# A network slab (an AllocSlab with ``ips`` and ``dyn_ports`` columns)
+# goes under a tag of its own: a build that predates the columns does
+# not know the tag and refuses the entry, where its ``from_wire`` would
+# drop the two columns and restore every row without its ports.
+_NET_SLAB = "AllocSlab.net"
+_TYPES[_NET_SLAB] = _structs.AllocSlab
 
 
 def _enc(v: Any) -> Any:
     if dataclasses.is_dataclass(v) and not isinstance(v, type):
-        return {_TAG: type(v).__name__, _DATA: to_wire(v)}
+        tag = type(v).__name__
+        if tag == "AllocSlab" and v.ips:
+            tag = _NET_SLAB
+        return {_TAG: tag, _DATA: to_wire(v)}
     if isinstance(v, dict):
         return {k: _enc(x) for k, x in v.items()}
     if isinstance(v, (list, tuple)):

@@ -55,7 +55,7 @@ import numpy as np
 from .. import fault
 from ..structs import structs as s
 from ..utils import tracing
-from ..structs.funcs import allocs_fit, remove_allocs
+from ..structs.funcs import allocs_fit
 from .fsm import MessageType
 from .plan_queue import PlanFuture, PlanQueue
 from ..utils.telemetry import NULL_TELEMETRY
@@ -79,6 +79,16 @@ def _has_ports(alloc: s.Allocation) -> bool:
     if alloc.resources is not None and alloc.resources.networks:
         return True
     return any(tr.networks for tr in alloc.task_resources.values())
+
+
+def _replaced_ids(plan: s.Plan, node_id: str) -> set:
+    """Ids of the rows on ``node_id`` that the plan takes out or
+    replaces: its stops, its preemptions and its in-place updates
+    (plan_apply.go:342, RemoveAllocs of all three)."""
+    return {a.id for a in itertools.chain(
+        plan.node_update.get(node_id, ()),
+        plan.node_preemptions.get(node_id, ()),
+        plan.node_allocation.get(node_id, ()))}
 
 
 def _usage(alloc: s.Allocation) -> np.ndarray:
@@ -122,8 +132,8 @@ class _InflightPlan:
                 for alloc in allocs:
                     out.setdefault(node_id, []).append((alloc, 1))
             for slab in self.result.alloc_slabs:
-                for node_id, cnt in slab.node_counts().items():
-                    out.setdefault(node_id, []).append((slab.proto, cnt))
+                for node_id, adds in slab.node_adds().items():
+                    out.setdefault(node_id, []).extend(adds)
             self._by_node = out
         return out
 
@@ -168,10 +178,13 @@ class _Fits:
     ``rows[i]`` (the array route) plus a per-node dict (the per-node
     routes).  The node-id dict callers read is only built on demand."""
 
-    __slots__ = ("cols", "rows", "fit", "scalar", "indexed")
+    __slots__ = ("cols", "rows", "fit", "scalar", "indexed", "adds")
 
     def __init__(self, cols=None, scalar: Optional[Dict[str, bool]] = None):
         self.cols = cols
+        # The plan's slab adds per node (PlanApplier._slab_node_adds),
+        # when a per-node route read them: the guard's run reuses them.
+        self.adds: Optional[Dict] = None
         # Slab rows whose mirror rows came from an indexed node column's
         # integers (no string handled).
         self.indexed = 0
@@ -575,12 +588,15 @@ class PlanApplier:
         return result
 
     @staticmethod
-    def _slab_node_adds(plan: s.Plan) -> Dict[str, List[Tuple[s.Allocation, int]]]:
-        """Per-node (proto, count) additions proposed by the plan's slabs."""
+    def _slab_node_adds(plan: s.Plan
+                        ) -> Dict[str, List[Tuple[s.Allocation, int]]]:
+        """Per-node (allocation, count) additions proposed by the plan's
+        slabs (``AllocSlab.node_adds``: a network slab's rows read in
+        place)."""
         out: Dict[str, List[Tuple[s.Allocation, int]]] = {}
         for slab in plan.alloc_slabs:
-            for nid, cnt in slab.node_counts().items():
-                out.setdefault(nid, []).append((slab.proto, cnt))
+            for nid, adds in slab.node_adds().items():
+                out.setdefault(nid, []).extend(adds)
         return out
 
     def _evaluate_nodes(self, snap, plan: s.Plan, n_plans: int = 1) -> _Fits:
@@ -608,8 +624,10 @@ class PlanApplier:
         cols = (columns_fn() if columns_fn is not None and colmod.enabled()
                 else None)
         if cols is None:
+            adds = self._slab_node_adds(plan)
             out = _Fits(scalar=self._walk(snap, plan, inflight,
-                                          self._touched(plan)))
+                                          self._touched(plan), adds))
+            out.adds = adds
         else:
             out = self._fit_columnar(snap, plan, cols, inflight)
         self.metrics.incr_counter("plan.fit.rows_array", len(out.rows))
@@ -643,9 +661,13 @@ class PlanApplier:
         return list(touched)
 
     def _walk(self, snap, plan: s.Plan, inflight: List[_InflightPlan],
-              node_ids: List[str]) -> Dict[str, bool]:
+              node_ids: List[str], adds: Optional[Dict] = None
+              ) -> Dict[str, bool]:
+        """``_evaluate_nodes_walk`` over ``node_ids``; ``adds``: the
+        plan's ``_slab_node_adds``, made here when not given."""
         return self._evaluate_nodes_walk(
-            snap, plan, node_ids, self._slab_node_adds(plan),
+            snap, plan, node_ids,
+            self._slab_node_adds(plan) if adds is None else adds,
             _pending_map(inflight, node_ids))
 
     def _evaluate_nodes_walk(self, snap, plan: s.Plan,
@@ -670,7 +692,8 @@ class PlanApplier:
                 for nid in node_ids}
 
     def _fit_columnar(self, snap, plan: s.Plan, cols,
-                      inflight: List[_InflightPlan]) -> _Fits:
+                      inflight: List[_InflightPlan],
+                      adds: Optional[Dict] = None) -> _Fits:
         """Fit re-check off the PR 9 columnar mirror: capacity, reserved,
         eligibility and LIVE USAGE come straight from the store's numpy
         columns (O(changed) fold) instead of walking every touched
@@ -744,9 +767,10 @@ class PlanApplier:
             out.fit = cols.eligible[uniq] & np.all(need <= cols.cap[uniq],
                                                    axis=1)
         if scalar:
+            out.adds = self._slab_node_adds(plan) if adds is None else adds
             out.scalar = self._fit_scalar_rows(
-                snap, plan, cols, usage, list(scalar),
-                self._slab_node_adds(plan), _pending_map(inflight, scalar))
+                snap, plan, cols, usage, list(scalar), out.adds,
+                _pending_map(inflight, scalar))
         return out
 
     def _fit_scalar_rows(self, snap, plan: s.Plan, cols, usage: np.ndarray,
@@ -782,9 +806,14 @@ class PlanApplier:
                 out[node_id] = False
                 continue
             need = cols.res[row] + usage[row]
-            for removal in list(plan.node_update.get(node_id, ())) + \
-                    list(plan.node_preemptions.get(node_id, ())):
-                live = snap.alloc_by_id(None, removal.id)
+            # The rows the plan takes out or replaces here: stops,
+            # preemptions and in-place updates (a stored row's copy with
+            # its create_index; a new placement has none).
+            for aid in {a.id for a in itertools.chain(
+                    plan.node_update.get(node_id, ()),
+                    plan.node_preemptions.get(node_id, ()),
+                    (a for a in adds if a.create_index))}:
+                live = snap.alloc_by_id(None, aid)
                 if (live is not None and not live.terminal_status()
                         and live.node_id == node_id):
                     need = need - _usage(live)
@@ -827,13 +856,18 @@ class PlanApplier:
                     colmod.add_node_counts(used, pos_of, ids, vec)
             agree = np.array_equal(
                 ready & np.all(used <= cap, axis=1), out.fit)
+        adds = out.adds
         if agree and scalar_ids:
-            agree = self._walk(snap, plan, inflight, scalar_ids) == out.scalar
+            agree = self._walk(snap, plan, inflight, scalar_ids,
+                               adds) == out.scalar
         if agree:
             return out
-        ref = self._walk(snap, plan, inflight, array_ids + scalar_ids)
+        if adds is None:
+            adds = self._slab_node_adds(plan)
+        ref = self._walk(snap, plan, inflight, array_ids + scalar_ids, adds)
         first = out.as_dict()
-        if self._fit_columnar(snap, plan, cols, inflight).as_dict() != ref:
+        if self._fit_columnar(snap, plan, cols, inflight,
+                              adds).as_dict() != ref:
             bad = [nid for nid, fit in ref.items() if first.get(nid) != fit]
             colmod.note_guard_mismatch(
                 "plan_fit", f"{len(bad)} node verdicts", Nodes=len(bad))
@@ -869,12 +903,9 @@ class PlanApplier:
         node = snap.node_by_id(None, node_id)
         if node is None or node.status != s.NODE_STATUS_READY or node.drain:
             return False
-        existing = snap.allocs_by_node_terminal(None, node_id, False)
-        remove = list(plan.node_update.get(node_id, []))
-        remove.extend(plan.node_preemptions.get(node_id, []))
-        remove.extend(plan.node_allocation.get(node_id, []))
-        proposed = remove_allocs(existing, remove)
-        proposed = proposed + list(plan.node_allocation.get(node_id, []))
+        proposed = snap.live_rows_on_node(node_id,
+                                          _replaced_ids(plan, node_id))
+        proposed.extend(plan.node_allocation.get(node_id, ()))
         for proto, cnt in slab_here:
             proposed.extend([proto] * cnt)
         # In-flight overlay: placements committed by pipelined siblings
@@ -929,12 +960,9 @@ class PlanApplier:
             capacity[i] = res_vec(node.resources)
             if node.reserved is not None:
                 used[i] += res_vec(node.reserved)
-            existing = snap.allocs_by_node_terminal(None, node_id, False)
-            remove = list(plan.node_update.get(node_id, []))
-            remove.extend(plan.node_preemptions.get(node_id, []))
-            remove.extend(plan.node_allocation.get(node_id, []))
-            proposed = remove_allocs(existing, remove)
-            proposed = proposed + list(plan.node_allocation.get(node_id, []))
+            proposed = snap.live_rows_on_node(
+                node_id, _replaced_ids(plan, node_id))
+            proposed.extend(plan.node_allocation.get(node_id, ()))
             has_networks = False
             for alloc in proposed:
                 if alloc.resources is not None:

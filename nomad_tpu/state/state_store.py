@@ -1235,10 +1235,12 @@ class StateStore:
         if alloc is None:
             return
         if type(alloc) is s.AllocSlab:
-            node_id = alloc.node_ids[alloc.id_index(alloc_id)]
+            i = alloc.id_index(alloc_id)
+            node_id = alloc.node_ids[i]
             proto = alloc.proto
             job_id, eval_id = proto.job_id, proto.eval_id
-            row = proto
+            # A network slab's row holds ports of its own.
+            row = alloc.materialize(i) if alloc.ips else proto
         else:
             node_id, job_id, eval_id = alloc.node_id, alloc.job_id, alloc.eval_id
             row = alloc
@@ -1283,6 +1285,50 @@ class StateStore:
         return [a for a in self.allocs_by_node(ws, node_id)
                 if a.terminal_status() == terminal]
 
+    def _node_entries(self, node_id: str):
+        """``(table value, alloc id)`` of every row on ``node_id``, the
+        deferred slab indexing flushed first.  Caller holds the lock."""
+        if self._pending_slabs:
+            self._materialize_pending()
+        get = self.allocs_table.get
+        for aid in self._idx_get(self._allocs_by_node, node_id):
+            v = get(aid)
+            if v is not None:
+                yield v, aid
+
+    def live_rows_on_node(self, node_id: str, leave_out=()) -> list:
+        """``allocs_by_node_terminal(None, node_id, False)`` less the
+        rows whose ids are in ``leave_out`` (what the plan stops,
+        preempts or updates in place), for the plan applier's per-node
+        re-check, which reads the rows of every node it touches, pass
+        after pass: a slab's row is its prototype (``AllocSlab.row``: a
+        network slab's read in place), none is materialized or cached
+        back into the table.  A plain slab's rows share one prototype,
+        so a row is left out by the id it is stored under, not by the
+        id of what stands for it."""
+        out = []
+        with self._lock:
+            for v, aid in self._node_entries(node_id):
+                if aid in leave_out:
+                    continue
+                if type(v) is s.AllocSlab:
+                    if v.proto.terminal_status():
+                        continue
+                    v = v.row(v.id_index(aid))
+                elif v.terminal_status():
+                    continue
+                out.append(v)
+        return out
+
+    def node_networks(self, node_id: str) -> List[tuple]:
+        """``held_networks()`` of every live row on ``node_id``, what
+        ``NetworkIndex.add_allocs`` reads: a network slab's rows from its
+        columns (``AllocSlab.row``), no Allocation materialized."""
+        out: List[tuple] = []
+        for row in self.live_rows_on_node(node_id):
+            out.extend(row.held_networks())
+        return out
+
     def allocs_by_job(self, ws: Optional[WatchSet], job_id: str, all_allocs: bool = False) -> List[s.Allocation]:
         """(state_store.go:1615).  When all_allocs is False, allocs from a
         previous incarnation of a re-registered job are filtered to the
@@ -1326,7 +1372,17 @@ class StateStore:
     # into a throwaway snapshot each batch would re-pay the per-alloc
     # cost the slabs exist to avoid.  These return the shared slab PROTO
     # as the row for slot entries (node_id supplied separately) — rows
-    # are read-only by contract.
+    # are read-only by contract.  A network slab's rows differ in their
+    # ports, so each is read in place (``structs.SlabRow``): these are
+    # the full walks the usage and port references read.
+
+    @staticmethod
+    def _slab_rows(slab: s.AllocSlab, positions):
+        """``(node_id, row)`` of ``slab``'s rows at ``positions``."""
+        if not slab.ips:
+            proto = slab.proto
+            return [(slab.node_ids[i], proto) for i in positions]
+        return [(slab.node_ids[i], s.SlabRow(slab, i)) for i in positions]
 
     def alloc_rows(self, ws: Optional[WatchSet] = None
                    ) -> List[Tuple[str, s.Allocation]]:
@@ -1338,9 +1394,7 @@ class StateStore:
             # Pending slabs (deferred indexing) have no replaced/removed
             # entries yet — emit their rows directly, no drain needed.
             for slab in self._pending_slabs:
-                proto = slab.proto
-                for nid in slab.node_ids:
-                    out.append((nid, proto))
+                out.extend(self._slab_rows(slab, range(len(slab))))
             seen_slabs = set()
             table = self.allocs_table
             for aid, v in table.items():
@@ -1351,10 +1405,9 @@ class StateStore:
                     # One pass over the slab's columns; ids whose table
                     # entry was replaced (client update) or removed are
                     # skipped — their real row is seen via its own entry.
-                    proto = v.proto
-                    for i, aid2 in enumerate(v.ids):
-                        if table.get(aid2) is v:
-                            out.append((v.node_ids[i], proto))
+                    out.extend(self._slab_rows(
+                        v, [i for i, aid2 in enumerate(v.ids)
+                            if table.get(aid2) is v]))
                 else:
                     out.append((v.node_id, v))
             return out
@@ -1367,15 +1420,13 @@ class StateStore:
         with self._lock:
             out = []
             for slab in self._pending_by_job.get(job_id, ()):
-                proto = slab.proto
-                for nid in slab.node_ids:
-                    out.append((nid, proto))
+                out.extend(self._slab_rows(slab, range(len(slab))))
             for aid in self._idx_get(self._allocs_by_job, job_id):
                 v = self.allocs_table.get(aid)
                 if v is None:
                     continue
                 if type(v) is s.AllocSlab:
-                    out.append((v.node_ids[v.id_index(aid)], v.proto))
+                    out.extend(self._slab_rows(v, (v.id_index(aid),)))
                 else:
                     out.append((v.node_id, v))
             return out
@@ -1845,16 +1896,10 @@ class StateStore:
             proto = slab.proto
             self._idx_append(self._allocs_by_job, proto.job_id, ids)
             self._idx_append(self._allocs_by_eval, proto.eval_id, ids)
-            # The per-alloc work — by-id table rows and per-node index
-            # cells — is DEFERRED to the first reader that needs it
-            # (_materialize_pending): bulk batch commits never query
-            # their own slabs in-batch, and this loop was the single
-            # largest host cost of the whole scheduling pass at 1M asks.
-            # The usage log gets ONE entry per slab for the same reason
-            # (expanded lazily by allocs_since readers).
+            # The usage log gets ONE entry per slab (expanded lazily by
+            # allocs_since readers).
             self._log_slab(index, slab)
-            self._pending_slabs.append(slab)
-            self._pending_by_job.setdefault(proto.job_id, []).append(slab)
+            self._install_slab(slab)
             if events is not None:
                 # ONE event per slab, not per alloc: a 1M-ask batch must
                 # not turn into 1M ring entries.  The count + job/eval
@@ -1871,6 +1916,21 @@ class StateStore:
                 jobs[proto.job_id] = jobs.get(proto.job_id) or forced
         self._set_job_statuses(index, jobs, eval_delete=False)
         self._bump("allocs", index)
+
+    def _install_slab(self, slab: s.AllocSlab) -> None:
+        """The per-alloc work of a slab — by-id table rows and per-node
+        index cells — is DEFERRED to the first reader that needs it
+        (_materialize_pending): bulk batch commits never query their own
+        slabs in-batch, and this loop was the single largest host cost
+        of the whole scheduling pass at 1M asks.  A network slab is
+        indexed now: the next batch's offers read the rows of each node
+        they land on (node_networks), and a deferred slab would cost
+        every batch's snapshot a drain of all of them."""
+        if slab.ips:
+            self._drain_slabs((slab,))
+            return
+        self._pending_slabs.append(slab)
+        self._pending_by_job.setdefault(slab.proto.job_id, []).append(slab)
 
     def _update_summary_bulk(self, index: int, proto: s.Allocation,
                              n: int) -> None:
@@ -2073,6 +2133,13 @@ class StateStore:
     #: Legacy blobs are bare msgpack maps whose first byte can never be
     #: ASCII "N", so an 8-byte prefix sniff is unambiguous.
     SNAP2_MAGIC = b"NTPUSNP2"
+    #: The same layout holding network slabs (their ``ips`` and
+    #: ``dyn_ports`` columns).  Written only then, so a build that
+    #: predates the columns refuses such a snapshot (an unknown magic
+    #: reaches its legacy msgpack decode, which raises) rather than
+    #: restoring those rows without their ports; every other snapshot
+    #: stays ``NTPUSNP2``.
+    SNAP3_MAGIC = b"NTPUSNP3"
 
     def persist(self) -> bytes:
         """Serialize all tables for an FSM snapshot (fsm.go:568
@@ -2107,9 +2174,11 @@ class StateStore:
         """v2: msgpack envelope of {tables, nodes SoA, standalone
         allocs, columnar slabs, numpy columns}.  Slabs are NOT
         materialized — their protos ship once and the string columns
-        ship as columns (lazy ones as generator specs), which is where
-        the 1M-alloc win lives; restore re-installs them as pending
-        slabs (the lazy-rehydration path readers already drain)."""
+        ship as columns (lazy ones as generator specs), a network slab's
+        two with them (magic ``NTPUSNP3``), which is where the 1M-alloc
+        win lives; restore re-installs them as pending slabs (the
+        lazy-rehydration path readers already drain; a network slab is
+        indexed at once)."""
         import msgpack
 
         from ..api.codec import to_wire
@@ -2142,13 +2211,17 @@ class StateStore:
                     jr = ref_job(proto.job)
                     proto = s._fast_copy(proto)
                     proto.job = None
-                return {"proto": to_wire(proto), "job_ref": jr,
-                        "ids": self._slab_col_spec(slab.ids),
-                        "names": self._slab_col_spec(slab.names),
-                        "node_ids": list(slab.node_ids),
-                        "prev_ids": self._slab_col_spec(slab.prev_ids),
-                        "ci": slab.create_index, "mi": slab.modify_index,
-                        "dead": dead}
+                doc = {"proto": to_wire(proto), "job_ref": jr,
+                       "ids": self._slab_col_spec(slab.ids),
+                       "names": self._slab_col_spec(slab.names),
+                       "node_ids": list(slab.node_ids),
+                       "prev_ids": self._slab_col_spec(slab.prev_ids),
+                       "ci": slab.create_index, "mi": slab.modify_index,
+                       "dead": dead}
+                if slab.ips:
+                    doc["ips"] = list(slab.ips)
+                    doc["dyn_ports"] = slab.dyn_ports
+                return doc
 
             for aid, v in table.items():
                 if type(v) is s.AllocSlab:
@@ -2254,7 +2327,9 @@ class StateStore:
             doc = {"tables": tables_blob, "nodes": node_soa,
                    "allocs": allocs_blob, "slabs": slab_docs,
                    "columns": col_blob, "colmeta": col_meta}
-            return self.SNAP2_MAGIC + msgpack.packb(doc, use_bin_type=True)
+            net = any("ips" in sd for sd in slab_docs)
+            magic = self.SNAP3_MAGIC if net else self.SNAP2_MAGIC
+            return magic + msgpack.packb(doc, use_bin_type=True)
 
     def _persist_legacy(self) -> bytes:
         """Legacy per-object msgpack snapshot (the pre-columnar format;
@@ -2315,7 +2390,7 @@ class StateStore:
         (fsm.go:582 Restore).  Sniffs the v2 magic; legacy msgpack blobs
         keep restoring through the old path (upgrade compatibility in
         both directions)."""
-        if blob[:len(cls.SNAP2_MAGIC)] == cls.SNAP2_MAGIC:
+        if blob[:len(cls.SNAP2_MAGIC)] in (cls.SNAP2_MAGIC, cls.SNAP3_MAGIC):
             return cls._restore_columnar(blob)
         from ..server.log_codec import decode_payload
 
@@ -2461,22 +2536,14 @@ class StateStore:
                 names=cls._slab_col_load(sd["names"]),
                 node_ids=sd["node_ids"],
                 prev_ids=cls._slab_col_load(sd["prev_ids"]),
-                create_index=sd["ci"], modify_index=sd["mi"])
+                create_index=sd["ci"], modify_index=sd["mi"],
+                ips=sd.get("ips") or [], dyn_ports=sd.get("dyn_ports") or b"")
             dead = sd.get("dead")
             if dead:
                 deadset = set(dead)
-                keep = [i for i in range(len(slab.ids))
-                        if i not in deadset]
-                slab = s.AllocSlab(
-                    proto=proto,
-                    ids=[slab.ids[i] for i in keep],
-                    names=[slab.names[i] for i in keep],
-                    node_ids=[slab.node_ids[i] for i in keep],
-                    prev_ids=([slab.prev_ids[i] for i in keep]
-                              if slab.prev_ids else []),
-                    create_index=sd["ci"], modify_index=sd["mi"])
-            store._pending_slabs.append(slab)
-            store._pending_by_job.setdefault(proto.job_id, []).append(slab)
+                slab = slab.take(i for i in range(len(slab.ids))
+                                 if i not in deadset)
+            store._install_slab(slab)
             store._idx_append(store._allocs_by_job, proto.job_id, slab.ids)
             store._idx_append(store._allocs_by_eval, proto.eval_id,
                               slab.ids)

@@ -57,33 +57,40 @@ class NetworkIndex:
         return collide
 
     def add_allocs(self, allocs: List[Allocation]) -> bool:
-        """Add the first network of each task resource (network.go:93)."""
+        """Add the first network of each task resource (network.go:93),
+        as each row's ``held_networks()`` gives them (a network slab's
+        row from its columns)."""
         collide = False
         for alloc in allocs:
-            for task_res in alloc.task_resources.values():
-                if not task_res.networks:
-                    continue
-                if self.add_reserved(task_res.networks[0]):
+            for held in alloc.held_networks():
+                if self.add_held(*held):
                     collide = True
         return collide
 
     def add_reserved(self, n: NetworkResource) -> bool:
         """Mark ports + bandwidth used; True on collision (network.go:111)."""
-        used = self.used_ports.get(n.ip)
+        return self.add_held(n.ip, n.device, n.mbits,
+                             [p.value for p in n.reserved_ports]
+                             + [p.value for p in n.dynamic_ports])
+
+    def add_held(self, ip: str, device: str, mbits: int, ports) -> bool:
+        """``add_reserved`` of a network given as its IP, device, Mbit
+        and port values (reserved, then dynamic)."""
+        used = self.used_ports.get(ip)
         if used is None:
             used = Bitmap(MAX_VALID_PORT)
-            self.used_ports[n.ip] = used
+            self.used_ports[ip] = used
 
         collide = False
-        for port in list(n.reserved_ports) + list(n.dynamic_ports):
-            if port.value < 0 or port.value >= MAX_VALID_PORT:
+        for value in ports:
+            if value < 0 or value >= MAX_VALID_PORT:
                 return True
-            if used.check(port.value):
+            if used.check(value):
                 collide = True
             else:
-                used.set(port.value)
+                used.set(value)
 
-        self.used_bandwidth[n.device] = self.used_bandwidth.get(n.device, 0) + n.mbits
+        self.used_bandwidth[device] = self.used_bandwidth.get(device, 0) + mbits
         return collide
 
     def _yield_ips(self):

@@ -13,6 +13,7 @@ from __future__ import annotations
 import copy as _copylib
 import dataclasses
 import os as _os
+import struct as _struct
 import threading as _threading
 import time
 import uuid
@@ -1015,6 +1016,21 @@ class Allocation:
             ALLOC_CLIENT_STATUS_LOST,
         )
 
+    def held_networks(self) -> List[tuple]:
+        """``(ip, device, Mbit, port values)`` of the first network of
+        each task, reserved ports before dynamic: what the readers of
+        held ports and bandwidth take of a row (``NetworkIndex``, the
+        resident network mirror's reference).  ``SlabRow`` reads a
+        network slab's row the same way, from the slab's columns."""
+        out = []
+        for tr in self.task_resources.values():
+            if tr.networks:
+                nr = tr.networks[0]
+                out.append((nr.ip, nr.device, nr.mbits,
+                            [p.value for p in nr.reserved_ports]
+                            + [p.value for p in nr.dynamic_ports]))
+        return out
+
     def client_terminal_status(self) -> bool:
         return self.client_status in (
             ALLOC_CLIENT_STATUS_COMPLETE,
@@ -1617,7 +1633,22 @@ class AllocSlab:
     state_store.go:1435), taken to its SoA conclusion.
 
     ``prev_ids`` uses "" for "no previous allocation" so the slab stays
-    a plain data-only msgpack tree on the replicated log (log_codec)."""
+    a plain data-only msgpack tree on the replicated log (log_codec).
+
+    A NETWORK slab (``ips`` not empty) holds placements whose tasks ask
+    for a network, each with its own offer.  The prototype's task
+    networks are then a template: the first network of each networked
+    task (the tasks whose prototype resources carry one, in the
+    prototype's task order) with the offer's device, Mbit and reserved
+    ports, its dynamic port labels at value 0 and no IP.  What differs
+    per row lives in two columns: ``ips``, each networked task's IP, row
+    by row (``[k, T]`` flattened, T networked tasks), and ``dyn_ports``,
+    the dynamic port values as int32 ``[k, n_dyn]`` packed
+    little-endian (the labels in template order over the networked
+    tasks).  ``materialize(i)`` builds the allocation with that row's
+    offers, its ``task_resources`` and combined ``resources`` as the
+    per-object form holds them.  A slab without networks carries
+    neither column."""
 
     proto: Optional[Allocation] = None
     ids: List[str] = field(default_factory=list)
@@ -1626,9 +1657,44 @@ class AllocSlab:
     prev_ids: List[str] = field(default_factory=list)
     create_index: int = 0
     modify_index: int = 0
+    ips: List[str] = field(default_factory=list)
+    dyn_ports: bytes = b""
 
     def __len__(self) -> int:
         return len(self.ids)
+
+    @classmethod
+    def of_offers(cls, proto: Allocation, offers: List[list],
+                  **columns) -> "AllocSlab":
+        """The network slab of rows whose networked tasks (those of
+        ``proto`` whose resources carry a network, in its task order)
+        got ``offers``, one list a row, every row's on the same devices:
+        a copy of ``proto`` whose task networks are the template (the
+        first row's offers without IP and dynamic port values) and
+        whose resources are their sum, the offers' IPs and dynamic port
+        values as the two columns.  ``columns``: ids, names, node_ids,
+        prev_ids."""
+        task_resources = dict(proto.task_resources)
+        networked = [name for name, tr in task_resources.items()
+                     if tr.networks]
+        for name, offer in zip(networked, offers[0]):
+            tn = offer.copy()
+            tn.ip = ""
+            for p in tn.dynamic_ports:
+                p.value = 0
+            tr = task_resources[name].copy()
+            tr.networks = [tn]
+            task_resources[name] = tr
+        total = Resources(disk_mb=proto.shared_resources.disk_mb
+                          if proto.shared_resources else 0)
+        for tr in task_resources.values():
+            total.add(tr)
+        template = _fast_copy(proto)
+        template.task_resources = task_resources
+        template.resources = total
+        dyn = [p.value for row in offers for o in row for p in o.dynamic_ports]
+        return cls(proto=template, ips=[o.ip for row in offers for o in row],
+                   dyn_ports=_struct.pack(f"<{len(dyn)}i", *dyn), **columns)
 
     def materialize(self, i: int) -> Allocation:
         a = _fast_copy(self.proto)
@@ -1640,7 +1706,113 @@ class AllocSlab:
         a.create_index = self.create_index
         a.modify_index = self.modify_index
         a.alloc_modify_index = self.modify_index
+        if self.ips:
+            a.task_resources, a.resources = self._row_resources(i)
         return a
+
+    # -- network slabs ---------------------------------------------------
+
+    def _template(self) -> tuple:
+        """``(tasks, T, n_dyn)`` of a network slab: per prototype task
+        ``(name, resources, template network or None)``; kept on the
+        slab (an undeclared attr, so it stays off the wire codec)."""
+        t = getattr(self, "_tmpl", None)
+        if t is None:
+            tasks = [(name, tr, tr.networks[0] if tr.networks else None)
+                     for name, tr in self.proto.task_resources.items()]
+            nets = [tn for _, _, tn in tasks if tn is not None]
+            t = self._tmpl = (tasks, len(nets),
+                              sum(len(tn.dynamic_ports) for tn in nets))
+        return t
+
+    def _row_ports(self, i: int, n_dyn: int) -> tuple:
+        return (_struct.unpack_from(f"<{n_dyn}i", self.dyn_ports,
+                                    4 * n_dyn * i) if n_dyn else ())
+
+    def _row_resources(self, i: int) -> tuple:
+        """Row ``i``'s task resources and combined resources, built as
+        the per-object form builds them: each networked task's resources
+        with the row's offer as its one network, summed by
+        ``Resources.add`` from the task group's disk."""
+        tasks, n_net, n_dyn = self._template()
+        dyn = self._row_ports(i, n_dyn)
+        shared = self.proto.shared_resources
+        total = Resources(disk_mb=shared.disk_mb if shared else 0)
+        task_resources: Dict[str, Resources] = {}
+        j = n_net * i
+        q = 0
+        for name, tr, tn in tasks:
+            if tn is not None:
+                n = len(tn.dynamic_ports)
+                offer = NetworkResource(
+                    tn.device, tn.cidr, self.ips[j], tn.mbits,
+                    [Port(p.label, p.value) for p in tn.reserved_ports],
+                    [Port(p.label, v)
+                     for p, v in zip(tn.dynamic_ports, dyn[q:q + n])])
+                tr = Resources(tr.cpu, tr.memory_mb, tr.disk_mb, tr.iops,
+                               [offer])
+                j += 1
+                q += n
+            task_resources[name] = tr
+            total.add(tr)
+        return task_resources, total
+
+    def row_net_usage(self) -> List[tuple]:
+        """What each row's networks hold, as ``alloc_net_vec`` reads an
+        allocation: (Mbit, ports in the dynamic range) per row, from the
+        template and one read of the port column."""
+        from .network import MAX_DYNAMIC_PORT, MIN_DYNAMIC_PORT
+
+        k = len(self)
+        tasks, _, n_dyn = self._template()
+        nets = [tn for _, _, tn in tasks if tn is not None]
+        mbits = sum(tn.mbits for tn in nets)
+        fixed = sum(1 for tn in nets for p in tn.reserved_ports
+                    if MIN_DYNAMIC_PORT <= p.value < MAX_DYNAMIC_PORT)
+        dyn = self._row_ports(0, k * n_dyn)
+        if all(MIN_DYNAMIC_PORT <= v < MAX_DYNAMIC_PORT for v in dyn):
+            # Every dynamic port the offers pick is in the range.
+            return [(mbits, fixed + n_dyn)] * k
+        return [(mbits, fixed + sum(
+                    1 for v in dyn[r * n_dyn:(r + 1) * n_dyn]
+                    if MIN_DYNAMIC_PORT <= v < MAX_DYNAMIC_PORT))
+                for r in range(k)]
+
+    def row_ports(self) -> List[tuple]:
+        """The port values each row holds, as ``alloc_net_held`` reads an
+        allocation (first network of each task, 0 left out; reserved
+        before dynamic), from the template and one read of the port
+        column."""
+        k = len(self)
+        tasks, _, n_dyn = self._template()
+        fixed = tuple(p.value for _, _, tn in tasks if tn is not None
+                      for p in tn.reserved_ports if p.value)
+        dyn = self._row_ports(0, k * n_dyn)
+        rows = [fixed + dyn[r * n_dyn:(r + 1) * n_dyn] for r in range(k)]
+        if 0 in dyn:
+            rows = [tuple(v for v in row if v) for row in rows]
+        return rows
+
+    def row_networks(self, i: int) -> List[tuple]:
+        """``Allocation.held_networks`` of row ``i``, without
+        materializing it."""
+        tasks, n_net, n_dyn = self._template()
+        dyn = self._row_ports(i, n_dyn)
+        out = []
+        j = n_net * i
+        q = 0
+        for _, _, tn in tasks:
+            if tn is None:
+                continue
+            n = len(tn.dynamic_ports)
+            out.append((self.ips[j], tn.device, tn.mbits,
+                        [p.value for p in tn.reserved_ports]
+                        + list(dyn[q:q + n])))
+            j += 1
+            q += n
+        return out
+
+    # -- rows ------------------------------------------------------------
 
     def id_index(self, alloc_id: str) -> int:
         """Column index of an alloc id; the reverse map is built lazily on
@@ -1661,19 +1833,109 @@ class AllocSlab:
             counts[nid] = counts.get(nid, 0) + 1
         return counts
 
+    def row(self, i: int):
+        """Row ``i`` as a reader of usage and held networks sees it: the
+        prototype, or for a network slab the row read in place
+        (``SlabRow``)."""
+        return SlabRow(self, i) if self.ips else self.proto
+
+    def node_adds(self) -> Dict[str, List[tuple]]:
+        """Per node, ``(row, count)`` pairs that stand for the slab's
+        rows there: the prototype and its count, or for a network slab
+        each row read in place (its ports are its own)."""
+        if not self.ips:
+            return {nid: [(self.proto, cnt)]
+                    for nid, cnt in self.node_counts().items()}
+        out: Dict[str, List[tuple]] = {}
+        for i, nid in enumerate(self.node_ids):
+            out.setdefault(nid, []).append((SlabRow(self, i), 1))
+        return out
+
+    def take(self, rows) -> "AllocSlab":
+        """The slab of rows ``rows`` (positions, in order), every column
+        cut alike."""
+        rows = list(rows)
+        ips = self.ips
+        dyn_ports = self.dyn_ports
+        if ips:
+            _, n_net, n_dyn = self._template()
+            ips = [ips[n_net * i + j] for i in rows for j in range(n_net)]
+            w = 4 * n_dyn
+            dyn_ports = b"".join(dyn_ports[w * i:w * i + w] for i in rows)
+        return AllocSlab(
+            proto=self.proto,
+            ids=[self.ids[i] for i in rows],
+            names=[self.names[i] for i in rows],
+            node_ids=[self.node_ids[i] for i in rows],
+            prev_ids=[self.prev_ids[i] for i in rows] if self.prev_ids else [],
+            create_index=self.create_index,
+            modify_index=self.modify_index,
+            ips=ips,
+            dyn_ports=dyn_ports,
+        )
+
     def filter_nodes(self, keep: set) -> "AllocSlab":
         """Slab restricted to placements on ``keep`` nodes (partial plan
         commit, plan_apply.go:242)."""
-        idx = [i for i, nid in enumerate(self.node_ids) if nid in keep]
-        return AllocSlab(
-            proto=self.proto,
-            ids=[self.ids[i] for i in idx],
-            names=[self.names[i] for i in idx],
-            node_ids=[self.node_ids[i] for i in idx],
-            prev_ids=[self.prev_ids[i] for i in idx] if self.prev_ids else [],
-            create_index=self.create_index,
-            modify_index=self.modify_index,
-        )
+        return self.take(i for i, nid in enumerate(self.node_ids)
+                         if nid in keep)
+
+
+class SlabRow:
+    """Row ``i`` of a network slab read in place, for the readers of an
+    allocation's usage and held networks (the plan applier's per-node
+    fit re-check, the full walks of the usage and network references),
+    which would otherwise build an Allocation per row they read.  Its
+    fields are the prototype's but ``id``, ``name``, ``node_id``, the
+    indexes and ``task_resources``, which are the row's (the last built
+    on first read, as ``materialize`` builds it); ``resources`` is the
+    prototype's, whose four quantities are the row's and whose networks
+    are the template.  ``held_networks()`` reads its networks from the
+    columns.  Not a dataclass: it never reaches the log or the API."""
+
+    __slots__ = ("slab", "i", "_task_resources")
+
+    def __init__(self, slab: AllocSlab, i: int) -> None:
+        self.slab = slab
+        self.i = i
+        self._task_resources: Optional[Dict[str, Resources]] = None
+
+    def __getattr__(self, name):
+        return getattr(self.slab.proto, name)
+
+    @property
+    def id(self) -> str:
+        return self.slab.ids[self.i]
+
+    @property
+    def name(self) -> str:
+        return self.slab.names[self.i]
+
+    @property
+    def node_id(self) -> str:
+        return self.slab.node_ids[self.i]
+
+    @property
+    def create_index(self) -> int:
+        return self.slab.create_index
+
+    @property
+    def modify_index(self) -> int:
+        return self.slab.modify_index
+
+    @property
+    def resources(self) -> Optional[Resources]:
+        return self.slab.proto.resources
+
+    @property
+    def task_resources(self) -> Dict[str, Resources]:
+        tr = self._task_resources
+        if tr is None:
+            tr = self._task_resources = self.slab._row_resources(self.i)[0]
+        return tr
+
+    def held_networks(self) -> List[tuple]:
+        return self.slab.row_networks(self.i)
 
 
 @dataclass

@@ -136,15 +136,18 @@ NET_KEYS = [
     # sample of the time), made every one (no failure) and built the
     # resident network mirror with the one walk a cold build takes; the
     # static-port job's batch read its port's holders from the mirror's
-    # column.  Three of the five have no metric file (``BENCHMARK.json``
-    # holds at most 128 per-layer metrics), so only this test holds their
-    # keys.
+    # column; the network job's two placements (at least: the sink may
+    # be read before the static-port job's batch has published all of
+    # its keys) were written as network slab rows.  Four of the six
+    # have no metric file (``BENCHMARK.json`` holds at most 128
+    # per-layer metrics), so only this test holds their keys.
     ("SampleTotals", "nomad.worker.invoke_scheduler.finalize.offers",
      lambda v: v[1] > 0),
     ("CounterTotals", "nomad.batch.net_offer_failures", lambda v: v == 0),
     ("CounterTotals", "nomad.batch.net_usage_walks", lambda v: v >= 1),
     ("CounterTotals", "nomad.batch.net_delta_words", lambda v: v >= 0),
     ("CounterTotals", "nomad.batch.port_columns", lambda v: v >= 1),
+    ("CounterTotals", "nomad.batch.net_slab_rows", lambda v: v >= 2),
 ]
 
 

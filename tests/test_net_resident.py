@@ -592,3 +592,65 @@ def test_a_batch_of_network_jobs_is_one_group_at_the_applier(monkeypatch):
     for nid, ports in by_node.items():
         ports = ports + [22]
         assert len(ports) == len(set(ports)), nid
+
+
+def test_a_batch_commits_its_port_asking_placements_as_network_slabs(
+        monkeypatch):
+    """Every placement of a port-asking job is a row of a network slab
+    (``batch.net_slab_rows`` = the allocations placed, no per-object
+    allocation in the plans), the resident guard at every hit holds the
+    mirror's Mbit, dynamic counts and port columns folded from those
+    slabs to the walk with no mismatch, and finalize seeds each node's
+    ``NetworkIndex`` from the earlier slabs' columns: no Allocation of a
+    network-slab row is materialized inside ``_finalize_build``."""
+    monkeypatch.setenv("NOMAD_TPU_RESIDENT_GUARD_EVERY", "1")
+    h, _ = standing_fleet(17)
+    inside, materialized, read = [], [], []
+    materialize = s.AllocSlab.materialize
+    row_networks = s.AllocSlab.row_networks
+    build = TPUBatchScheduler._finalize_build
+
+    def counted_materialize(slab, i):
+        if inside:
+            materialized.append(i)
+        return materialize(slab, i)
+
+    def counted_row_networks(slab, i):
+        if inside:
+            read.append(i)
+        return row_networks(slab, i)
+
+    def counted_build(self, *args, **kwargs):
+        inside.append(1)
+        try:
+            return build(self, *args, **kwargs)
+        finally:
+            inside.pop()
+
+    monkeypatch.setattr(s.AllocSlab, "materialize", counted_materialize)
+    monkeypatch.setattr(s.AllocSlab, "row_networks", counted_row_networks)
+    monkeypatch.setattr(TPUBatchScheduler, "_finalize_build", counted_build)
+    runs = resident.GUARD_RUNS
+    batches = []
+    for _ in range(3):
+        jobs = static_jobs(8889, 8080, count=10) + [net_job(10)]
+        stats = device_batch(h, jobs)
+        assert stats.fused == 1 and stats.net_offer_failures == 0
+        plans = h.plans[-len(jobs):]
+        assert all(not plan.node_allocation for plan in plans)
+        assert all(slab.ips for plan in plans for slab in plan.alloc_slabs)
+        assert stats.net_slab_rows == sum(
+            len(slab) for plan in plans for slab in plan.alloc_slabs) == 30
+        assert_columns_are_the_state(h, [8080, 8889])
+        assert_mirror_is_the_state(h)
+        batches.append(jobs)
+    # (Read by job only now: a by-id read caches the rows it makes.)
+    for jobs in batches:
+        assert all(len(live(h, j.id)) == 10 for j in jobs)
+    assert read
+    assert not materialized, len(materialized)
+    assert resident.GUARD_RUNS > runs
+    assert resident.GUARD_MISMATCHES == 0
+    assert resident.DEV_GUARD_MISMATCHES == 0
+    for port in (8080, 8889):
+        assert recompute_ports(h.state, [port])[port].max() == 1

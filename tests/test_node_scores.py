@@ -248,9 +248,13 @@ def test_codec_guard_catches_a_wrong_key_table(counters):
 
 def test_schema_fingerprint_and_version_are_the_parents():
     """``AllocMetric.scores`` keeps its declared type: frames written
-    before this object existed decode, and the parent reads ours."""
-    assert schema.FINGERPRINT.hex() == "539ce714e9f74795"
-    assert schema.VERSION == 1
+    before this object existed decode.  The layout version they were
+    written in keeps its fingerprint; version 2 only appended
+    ``AllocSlab``'s network columns (a version-1 build refuses its
+    frames by their version byte)."""
+    assert schema.FINGERPRINTS[1].hex() == "539ce714e9f74795"
+    assert schema.VERSION == 2
+    assert schema.ADDED == {2: {"AllocSlab": ("ips", "dyn_ports")}}
     assert s.AllocMetric().scores == {} and type(s.AllocMetric().scores) is dict
 
 

@@ -193,6 +193,55 @@ def case_preemption_stale(w):
     return plan, dict(scalar=1, partial=True)
 
 
+def _slab_row(w, ev_id):
+    """A row of the stored plain slab ``ev_id`` (drained or still
+    pending), built from the slab itself so the store's table keeps the
+    slab as its entry."""
+    slabs = [*w.store.allocs_table.values(), *w.store._pending_slabs]
+    slab = next(v for v in slabs if type(v) is s.AllocSlab
+                and v.proto.eval_id == ev_id)
+    return slab.materialize(w.rng.randrange(len(slab)))
+
+
+def case_slab_row_evicted_makes_room(w):
+    """The stopped row is a slab's: the node fits the new ask only once
+    that row is taken out by its own id.  The ask reserves a port, so
+    the node is decided by allocs_fit over the store's rows, with no
+    by-id read of the victim before it."""
+    victim = _slab_row(w, "ev-old-1")
+    ask = _alloc(w.job, victim.node_id,
+                 w.free_cpu(victim.node_id) + victim.resources.cpu, 64)
+    ask.resources.networks = [s.NetworkResource(
+        device="eth0", mbits=10, reserved_ports=[s.Port("main", 6000)])]
+    plan = w.plan()
+    plan.append_update(victim, s.ALLOC_DESIRED_STATUS_STOP, "test")
+    plan.append_alloc(ask)
+    return plan, dict(scalar=1, partial=False)
+
+
+def case_slab_row_preempted_makes_room(w):
+    victim = _slab_row(w, "ev-old-1")
+    ask = w.free_cpu(victim.node_id) + victim.resources.cpu
+    plan = w.plan(priority=80)
+    plan.append_preempted_alloc(victim)
+    plan.append_alloc(_alloc(w.job, victim.node_id, ask, 64))
+    return plan, dict(scalar=1, partial=False)
+
+
+def case_slab_row_updated_in_place(w):
+    """An in-place update of a pending slab's row that grows it into all
+    of its node's free cpu: the old row must not count beside it."""
+    victim = _slab_row(w, "ev-old-2")
+    updated = victim.copy()
+    updated.resources = None
+    updated.task_resources = {"web": s.Resources(
+        cpu=victim.resources.cpu + w.free_cpu(victim.node_id),
+        memory_mb=victim.resources.memory_mb)}
+    plan = w.plan()
+    plan.append_alloc(updated)
+    return plan, dict(scalar=1, partial=False)
+
+
 def case_port_reserving_alloc(w):
     plan = w.plan()
     ported = mock.alloc()
@@ -332,7 +381,7 @@ CASES = {name[5:]: fn for name, fn in sorted(globals().items())
          if name.startswith("case_")}
 WIDE = {"many_rows": 80}
 NETWORKED = {"port_reserving_alloc", "port_collision", "slab_with_ports",
-             "inflight_overlay_with_ports"}
+             "inflight_overlay_with_ports", "slab_row_evicted_makes_room"}
 
 
 # -- the reference ----------------------------------------------------------

@@ -196,6 +196,47 @@ def case_ports_over_bandwidth(w):
                             _ported(w, w.ids[3], mbits=500)])]
 
 
+def _net_placing(w, node_ids, static=(), mbits=10):
+    """A plan of one network slab as the batch path builds one
+    (``AllocSlab.of_offers``): per row an offer on the node's device and
+    address, the static ports asked and two dynamic ports of its own."""
+    plan = w.plan()
+    proto = _alloc(w.job, "", 50, 10)
+    proto.id, proto.name, proto.eval_id = "", "", plan.eval_id
+    ask = s.NetworkResource(
+        mbits=mbits, reserved_ports=[s.Port(f"s{p}", p) for p in static],
+        dynamic_ports=[s.Port("d0", 0), s.Port("d1", 0)])
+    proto.task_resources["web"].networks = [ask]
+    rows = []
+    for _ in node_ids:
+        d0, d1 = w.rng.sample(range(21000, 60000), 2)
+        rows.append([s.NetworkResource(
+            device="eth0", ip="192.168.0.100", mbits=mbits,
+            reserved_ports=[s.Port(f"s{p}", p) for p in static],
+            dynamic_ports=[s.Port("d0", d0), s.Port("d1", d1)])])
+    plan.append_slab(s.AllocSlab.of_offers(
+        proto, rows, ids=s.generate_uuids(len(node_ids)),
+        names=[f"{w.job.id}.web[{i}]" for i in range(len(node_ids))],
+        node_ids=list(node_ids)))
+    return plan
+
+
+def case_net_slabs_all_fit(w):
+    """Six plans of network slabs, a static port of its own and two
+    dynamic ones a row, on overlapping nodes."""
+    return [_net_placing(w, w.rng.sample(w.ids, 4), static=(7000 + i,))
+            for i in range(6)]
+
+
+def case_net_slabs_static_collision(w):
+    """Two network slabs reserve static port 8000 on one node: in
+    sequence the later plan loses that node."""
+    node = w.ids[3]
+    return [_net_placing(w, w.ids[:4]),
+            _net_placing(w, [node] + w.ids[5:7], static=(8000,)),
+            _net_placing(w, [node] + w.ids[7:9], static=(8000,))]
+
+
 def case_gang_plan_among_them(w):
     return [_placing(w, w.ids[:4]), _placing(w, w.ids[4:8], all_at_once=True),
             _placing(w, w.ids[2:6]), _placing(w, w.ids[3:9])]
@@ -219,16 +260,18 @@ def case_wide_overfill(w):
 CASES = {name[5:]: fn for name, fn in sorted(globals().items())
          if name.startswith("case_")}
 NETWORKED = {"split_by_a_network_plan", "ports_all_fit",
-             "ports_static_collision", "ports_over_bandwidth"}
+             "ports_static_collision", "ports_over_bandwidth",
+             "net_slabs_all_fit", "net_slabs_static_collision"}
 # name -> (plans the group pass decides in one pass, evaluate passes);
 # None = the pass decides nothing and every plan goes alone.
 ONE_PASS = {"all_fit": (6, 1), "all_fit_two_slabs_a_plan": (4, 1),
             "wide_all_fit": (5, 1), "split_by_a_network_plan": (4, 1),
-            "ports_all_fit": (6, 1)}
+            "ports_all_fit": (6, 1), "net_slabs_all_fit": (6, 1)}
 # name -> group passes that decided nothing (``nomad.plan.group_undecided``)
 UNDECIDED_PASSES = dict.fromkeys(
     ["draining_node", "ports_over_bandwidth", "ports_static_collision",
-     "two_plans_overfill_a_node", "unknown_node", "wide_overfill"], 1)
+     "net_slabs_static_collision", "two_plans_overfill_a_node",
+     "unknown_node", "wide_overfill"], 1)
 
 
 def _world(case, seed):
@@ -301,7 +344,8 @@ def ports(alloc):
 def logged(w):
     """What each entry the world's log applies says, without the random
     ids and the time stamps: its index, type and plan, and per
-    allocation its node and ports; filled in as the log applies."""
+    allocation its node and ports (a network slab's rows included);
+    filled in as the log applies."""
     entries = []
     fsm_apply = w.applier.raft.fsm.apply
 
@@ -310,7 +354,9 @@ def logged(w):
                         sorted((a.node_id, ports(a))
                                for a in payload.get("allocs", ())),
                         [sorted(collections.Counter(slab.node_ids).items())
-                         for slab in payload.get("slabs", ())]))
+                         for slab in payload.get("slabs", ())],
+                        [sorted((a.node_id, ports(a)) for a in slab.allocs())
+                         for slab in payload.get("slabs", ()) if slab.ips]))
         return fsm_apply(index, msg_type, payload)
 
     w.applier.raft.fsm.apply = apply
@@ -412,6 +458,37 @@ def test_a_node_the_group_overfills_on_ports_costs_the_last_plan_alone(
     assert last.refresh_index >= last.alloc_index > results[-2].alloc_index
     assert set(last.node_allocation) == set(plans[-1].node_allocation) - {
         w.ids[node]}
+
+
+def test_a_node_the_group_overfills_with_network_slabs_costs_the_last_plan_alone():
+    """As with per-object allocations: the pass decides nothing, once;
+    in sequence the later slab loses the contested node, and its partial
+    commit keeps each row's ports and IP with the row's node (the slab
+    cut by rows, every column alike), in the result and in the store."""
+    w = _world("net_slabs_static_collision", 5)
+    plans = case_net_slabs_static_collision(w)
+    results = as_one_group(w, plans)
+    assert totals(w)[UNDECIDED] == 1
+    assert totals(w)[EVALUATE] == 1 + len(plans)
+    for whole, plan in zip(results[:-1], plans):
+        assert not whole.refresh_index
+        assert whole.alloc_slabs == plan.alloc_slabs
+    last = results[-1]
+    assert last.refresh_index >= last.alloc_index > results[-2].alloc_index
+    (cut,) = last.alloc_slabs
+    (asked,) = plans[-1].alloc_slabs
+    assert list(cut.node_ids) == w.ids[7:9] == list(asked.node_ids)[1:]
+    assert len(cut.ips) == 2 and len(cut.dyn_ports) == 4 * 2 * 2
+    for j, i in enumerate((1, 2)):
+        got, want = cut.materialize(j), asked.materialize(i)
+        assert (got.id, got.node_id, got.task_resources, got.resources) \
+            == (want.id, want.node_id, want.task_resources, want.resources)
+        stored = w.store.alloc_by_id(None, got.id)
+        assert (stored.node_id, stored.task_resources) \
+            == (got.node_id, got.task_resources)
+    holders = [a for a in w.store.allocs_by_node(None, w.ids[3])
+               if 8000 in ports(a)[0][1:]]
+    assert len(holders) == 1
 
 
 @pytest.mark.parametrize("case,runs", [
