@@ -413,8 +413,8 @@ class PassCounts(NamedTuple):
 
 
 def spec_major(place_pass, carry, remaining_of, u_pad: int, max_rounds: int):
-    """The placement loop, shared by the single-chip program and the two
-    mesh programs (parallel/sharded.py): the batch's specs in order
+    """The placement loop, shared by the single-chip program and the mesh
+    program (parallel/sharded.py): the batch's specs in order
     (host pre-sorts by priority desc — the broker's priority heap,
     eval_broker.go:43), each placed to its end before the next starts.
 
@@ -473,6 +473,23 @@ class DPTensors(NamedTuple):
     active: jnp.ndarray      # [U] bool
     used0: jnp.ndarray       # [U, V] bool
     attr_values: jnp.ndarray  # [N, K] int32 — node attribute codes
+
+
+def net_tensors(d: dict, bw_used, dyn_free, port_words) -> NetTensors:
+    """A batch's ``NetTensors`` from its unpacked program buffers ``d``
+    (the per-spec asks, each node's bandwidth cap) and the per-node state
+    the program made of them."""
+    return NetTensors(
+        active=d["net_active"], mbits=d["net_mbits"],
+        dyn_need=d["dyn_need"], resv_words=d["resv_words"],
+        bw_cap=d["bw_cap"], bw_used=bw_used,
+        dyn_free=dyn_free, port_words=port_words)
+
+
+def dp_tensors(d: dict) -> DPTensors:
+    """A batch's ``DPTensors`` from its unpacked program buffers ``d``."""
+    return DPTensors(col=d["dp_col"], active=d["dp_active"],
+                     used0=d["dp_used"], attr_values=d["attr"])
 
 
 def _disabled_net(u_pad: int, n_pad: int) -> NetTensors:
@@ -600,6 +617,254 @@ def dp_columns(attr_values: jnp.ndarray, col: jnp.ndarray) -> jnp.ndarray:
         return attr_values.T[jnp.clip(col, 0, attr_values.shape[1] - 1)]
 
 
+class PassSeam:
+    """Where one placement pass differs when its node table is one shard
+    of a node mesh (``parallel/sharded.MeshSeam``).  This is the single
+    chip's side, where every node is local: each method is the identity
+    or the local reduction the single-chip program always made, so the
+    seam traces no op of its own."""
+
+    def __init__(self, node_idx: jnp.ndarray, n_nodes: int):
+        self.node_idx = node_idx          # [N] int32 global id of each row
+        self.slot_ids = node_idx          # what a slot records for a row
+        self.big_idx = jnp.int32(n_nodes + 1)  # above every node id
+
+    def total(self, local: jnp.ndarray) -> jnp.ndarray:
+        """A count over the local rows, as the count over every node."""
+        return local
+
+    def any_node(self, mask: jnp.ndarray) -> jnp.ndarray:
+        """Whether any node holds ``mask``."""
+        return jnp.any(mask)
+
+    def select(self, scored: jnp.ndarray, ok: jnp.ndarray,
+               k: jnp.ndarray) -> jnp.ndarray:
+        """The local rows among the k highest-scored ok nodes."""
+        return _select_top_k(scored, ok, k)
+
+    def value_max(self, table: jnp.ndarray) -> jnp.ndarray:
+        """A per-value table of maxima, over every node."""
+        return table
+
+    def value_min(self, table: jnp.ndarray) -> jnp.ndarray:
+        """A per-value table of minima, over every node."""
+        return table
+
+    def value_union(self, hit: jnp.ndarray) -> jnp.ndarray:
+        """A per-value bitset, or-ed over every node."""
+        return hit
+
+    def slot_offset(self, offset: jnp.ndarray,
+                    placed: jnp.ndarray) -> jnp.ndarray:
+        """Where the local rows' commits start in the spec's slot row:
+        ``offset`` allocs placed before this pass, ``placed`` of them
+        committed on the local rows this pass."""
+        return offset
+
+
+def placement_pass(feas, capacity, denom, ask, count, penalty,
+                   distinct_hosts, job_index, net: NetTensors,
+                   dp: DPTensors, jit_seed, seam: PassSeam, *,
+                   use_net: bool, use_dp: bool, with_scores: bool,
+                   slot_m: int):
+    """``place_pass(carry, u) -> (carry, placed, ran)`` for ``spec_major``:
+    one pass of spec ``u`` over the node table, written once for the
+    single-chip program and the fused mesh program, which hands over
+    its node shard and a ``seam`` of its own.  The carry is ``(used,
+    job_counts, remaining, placements, bw_used, port_words, dyn_free,
+    dp_used, commit_scores, commit_coll, slots, slot_scores,
+    slot_coll)``.
+
+    A pass commits the spec's top-k scored nodes (k = remaining count,
+    bounded by feasible nodes), one alloc per node.  Network accounting
+    per (spec, node): bandwidth fit, reserved-port bitmap conflict, and
+    dynamic-port-capacity checks, with commit updates to all three
+    (rank.go:190-238; concrete dynamic port *values* are assigned
+    host-side at finalize, which device-side capacity accounting makes
+    safe).  distinct_property: a per-spec used-value bitset masks
+    feasibility; a within-pass per-value best (``ValueCodes``: dense up
+    to ``DP_DENSE_MAX_V`` value codes, scatter/gather above) keeps only
+    the best-ranked node per property value (propertyset.go:150), and
+    the nodes it drops wait for the spec's next pass."""
+    v_pad = dp.used0.shape[1]
+    node_idx = seam.node_idx
+    big_idx = seam.big_idx
+    dp_codes = dp_columns(dp.attr_values, dp.col) if use_dp else None
+
+    def place_pass(carry, u):
+        def try_place(carry):
+            (used, job_counts, remaining_count, placements,
+             bw_used, port_words, dyn_free, dp_used, commit_scores,
+             commit_coll, slots, slot_scores, slot_coll) = carry
+
+            with jax.named_scope("prefix"):
+                cap_left = capacity - used                   # [N, 4]
+                fits = jnp.all(ask[u][None, :] <= cap_left, axis=1)
+                collisions = job_counts[job_index[u]]        # [N] int32
+                ok = feas[u] & fits
+                ok = ok & jnp.where(distinct_hosts[u], collisions == 0,
+                                    True)
+
+                # Network feasibility (bandwidth + reserved conflicts +
+                # dynamic capacity); statically absent when the batch has
+                # no network asks.
+                if use_net:
+                    bw_ok = bw_used + net.mbits[u] <= net.bw_cap
+                    resv_hit = jnp.any(
+                        (port_words & net.resv_words[u][None, :]) != 0,
+                        axis=1)
+                    dyn_ok = dyn_free >= net.dyn_need[u]
+                    ok = ok & jnp.where(net.active[u],
+                                        bw_ok & ~resv_hit & dyn_ok, True)
+
+            # distinct_property feasibility: node must have the property
+            # and its value must be unused (propertyset.go:150).
+            if use_dp:
+                with jax.named_scope("dp_feasible"):
+                    codes = dp_codes[u]                           # [N]
+                    vc = value_codes(codes, v_pad)
+                    dp_ok = ((codes != MISSING)
+                             & ~dp_used_lookup(vc, dp_used[u]))
+                    ok = ok & jnp.where(dp.active[u], dp_ok, True)
+            else:
+                codes = None
+
+            # Commit the top-k scored nodes (k = remaining count, bounded
+            # by feasible nodes) — one alloc per node this pass.
+            k = jnp.minimum(remaining_count[u],
+                            seam.total(jnp.sum(ok).astype(jnp.int32)))
+            carry, placed = lax.cond(
+                k > 0, lambda c: commit(c, ok, collisions, codes, k),
+                lambda c: (c, jnp.int32(0)), carry)
+            # Capacity early-exit, per spec: a pass over a fleet on which
+            # no node has room for the ask on capacity alone does not
+            # count (net/dp/constraints are stricter, so this is a
+            # necessary condition only and placements are unchanged).
+            return carry, placed, seam.any_node(fits)
+
+        def commit(carry, ok, collisions, codes, k):
+            (used, job_counts, remaining_count, placements,
+             bw_used, port_words, dyn_free, dp_used, commit_scores,
+             commit_coll, slots, slot_scores, slot_coll) = carry
+            with jax.named_scope("score"):
+                base_score = _score_fit(used, ask[u], denom)
+                score = (base_score
+                         - penalty[u] * collisions.astype(jnp.float32))
+                score = score + tie_jitter(jit_seed, u, node_idx)
+                scored = jnp.where(ok, score, NEG_INF)
+
+            # The k best ok nodes, ties to the lowest node index: on one
+            # chip a radix select instead of a full argsort (same
+            # selection, ~100x less device work at N≈50k).
+            with jax.named_scope("select"):
+                sel = seam.select(scored, ok, k)
+
+            # Within-pass value dedup for distinct_property: among
+            # selected nodes sharing a property value, keep only the
+            # best-scored (ties by lowest node index — stable-sort order).
+            if use_dp:
+                with jax.named_scope("dp_dedup"):
+                    # Made anew from the codes, not handed over from
+                    # try_place: a [v_pad, N] operand of the cond would
+                    # be written out and read back once a pass.
+                    vc = value_codes(codes, v_pad)
+                    neg = jnp.float32(NEG_INF)
+                    sel_score = jnp.where(sel, scored, neg)
+                    best_per_code = seam.value_max(dp_best_per_value(
+                        vc, sel_score, neg, largest=True))
+                    cand_dp = sel & (sel_score >= dp_read_back(
+                        vc, best_per_code, neg, largest=True))
+                    best_idx = seam.value_min(dp_best_per_value(
+                        vc, jnp.where(cand_dp, node_idx, big_idx), big_idx,
+                        largest=False))
+                    keep_dp = cand_dp & (node_idx == dp_read_back(
+                        vc, best_idx, big_idx, largest=False))
+                    sel = jnp.where(dp.active[u], keep_dp, sel)
+
+            with jax.named_scope("commit_usage"):
+                sel_i = sel.astype(jnp.int32)
+                placed = jnp.sum(sel_i)
+                used = used + sel_i[:, None] * ask[u][None, :]
+                job_counts = job_counts.at[job_index[u]].add(sel_i)
+                if not slot_m:
+                    # The dense [U, N] placement matrix only feeds the
+                    # matrix-form compaction; in slot mode the slot record
+                    # IS the placement output, so the carry compiles away.
+                    placements = placements.at[u].add(sel_i)
+
+            if slot_m:
+                # Compact slot record: append this commit's node indices
+                # to spec u's slot row in ascending-node order — the COO
+                # payload is built from this, no nonzero pass later.
+                with jax.named_scope("slots"):
+                    pos = jnp.cumsum(sel.astype(jnp.int32))
+                    offset = seam.slot_offset(        # placed so far
+                        count[u] - remaining_count[u], placed)
+                    dest = jnp.where(sel, offset + pos - 1,
+                                     jnp.int32(slot_m))
+                    slots = slots.at[u, dest].set(seam.slot_ids,
+                                                  mode="drop")
+                    if with_scores:
+                        # Commit-aligned score record: same dest scatter,
+                        # so the [U, N] score carries below compile away.
+                        slot_scores = slot_scores.at[u, dest].set(
+                            base_score, mode="drop")
+                        slot_coll = slot_coll.at[u, dest].set(
+                            collisions, mode="drop")
+
+            with jax.named_scope("commit_usage"):
+                placed = seam.total(placed)
+                remaining_count = remaining_count.at[u].add(-placed)
+
+                if use_net:
+                    commit_net = net.active[u]
+                    bw_used = bw_used + jnp.where(commit_net,
+                                                  sel_i * net.mbits[u], 0)
+                    port_words = jnp.where(
+                        (commit_net & sel)[:, None],
+                        port_words | net.resv_words[u][None, :],
+                        port_words)
+                    dyn_free = dyn_free - jnp.where(
+                        commit_net, sel_i * net.dyn_need[u], 0)
+            if use_dp:
+                with jax.named_scope("dp_update"):
+                    dp_upd = seam.value_union(
+                        dp_used_update(vc, sel & dp.active[u]))
+                    dp_used = lax.dynamic_update_index_in_dim(
+                        dp_used, dp_used[u] | dp_upd, u, axis=0)
+            # Commit-time AllocMetric side-outputs: pure binpack score and
+            # the collision count behind any anti-affinity penalty.
+            if with_scores and not slot_m:
+                with jax.named_scope("commit_usage"):
+                    commit_scores = commit_scores.at[u].set(jnp.where(
+                        sel, base_score, commit_scores[u]))
+                    commit_coll = commit_coll.at[u].set(jnp.where(
+                        sel, collisions, commit_coll[u]))
+            return (used, job_counts, remaining_count, placements,
+                    bw_used, port_words, dyn_free, dp_used,
+                    commit_scores, commit_coll, slots, slot_scores,
+                    slot_coll), placed
+
+        # Two-level skip, both REAL branches on TPU (the loop over specs
+        # is sequential, not vmapped, so lax.cond doesn't get batched
+        # into a select):
+        #  - outer: remaining_count[u] == 0 (a padding row, or a spec
+        #    with nothing to place) skips even the feasibility/fit
+        #    prefix — a scalar test;
+        #  - inner (in try_place): k == 0 (no feasible node under
+        #    remaining capacity) skips the scoring transcendentals and
+        #    the top-k select.
+        # Neither branch commits anything, so placements stay
+        # bit-identical to the unguarded kernel.  Both predicates are
+        # global counts, the same on every shard of a mesh, so the
+        # shards take one branch together.
+        return lax.cond(
+            carry[2][u] > 0, try_place,
+            lambda c: (c, jnp.int32(0), jnp.bool_(False)), carry)
+
+    return place_pass
+
+
 def placement_rounds(
     feas: jnp.ndarray,         # [U, N] bool — static feasibility
     used0: jnp.ndarray,        # [N, 4] int32 — usage incl. reserved
@@ -671,189 +936,19 @@ def _placement_rounds_impl(
     its top-k scored nodes under remaining capacity; the spec is passed
     over again until its count is met, a pass places nothing (capacity
     exhausted) or ``max_rounds`` passes are spent.  ``rounds`` in the
-    result is the most passes any one spec took.
-
-    Network accounting per (spec, node): bandwidth fit, reserved-port
-    bitmap conflict, and dynamic-port-capacity checks, with commit updates
-    to all three (rank.go:190-238; concrete dynamic port *values* are
-    assigned host-side at finalize, which device-side capacity accounting
-    makes safe).  distinct_property: a per-spec used-value bitset masks
-    feasibility; a within-pass per-value best (``ValueCodes``: dense up
-    to ``DP_DENSE_MAX_V`` value codes, scatter/gather above) keeps only
-    the best-ranked node per property value (propertyset.go:150), and
-    the nodes it drops wait for the spec's next pass.
+    result is the most passes any one spec took.  What one pass does,
+    network and distinct_property accounting included, is
+    ``placement_pass``'s.
     """
     u_pad, n_pad = feas.shape
-    v_pad = dp.used0.shape[1]
 
     jit_seed = jitter_seed(rng_key)
-    node_idx = jnp.arange(n_pad, dtype=jnp.int32)
-    big_idx = jnp.int32(n_pad + 1)
-    dp_codes = dp_columns(dp.attr_values, dp.col) if use_dp else None
-
-    def place_pass(carry, u):
-        def try_place(carry):
-            (used, job_counts, remaining_count, placements,
-             bw_used, port_words, dyn_free, dp_used, commit_scores,
-             commit_coll, slots, slot_scores, slot_coll) = carry
-
-            with jax.named_scope("prefix"):
-                cap_left = capacity - used                   # [N, 4]
-                fits = jnp.all(ask[u][None, :] <= cap_left, axis=1)
-                collisions = job_counts[job_index[u]]        # [N] int32
-                ok = feas[u] & fits
-                ok = ok & jnp.where(distinct_hosts[u], collisions == 0,
-                                    True)
-
-                # Network feasibility (bandwidth + reserved conflicts +
-                # dynamic capacity); statically absent when the batch has
-                # no network asks.
-                if use_net:
-                    bw_ok = bw_used + net.mbits[u] <= net.bw_cap
-                    resv_hit = jnp.any(
-                        (port_words & net.resv_words[u][None, :]) != 0,
-                        axis=1)
-                    dyn_ok = dyn_free >= net.dyn_need[u]
-                    ok = ok & jnp.where(net.active[u],
-                                        bw_ok & ~resv_hit & dyn_ok, True)
-
-            # distinct_property feasibility: node must have the property
-            # and its value must be unused (propertyset.go:150).
-            if use_dp:
-                with jax.named_scope("dp_feasible"):
-                    codes = dp_codes[u]                           # [N]
-                    vc = value_codes(codes, v_pad)
-                    dp_ok = ((codes != MISSING)
-                             & ~dp_used_lookup(vc, dp_used[u]))
-                    ok = ok & jnp.where(dp.active[u], dp_ok, True)
-            else:
-                codes = None
-
-            # Commit the top-k scored nodes (k = remaining count, bounded
-            # by feasible nodes) — one alloc per node this pass.
-            k = jnp.minimum(remaining_count[u],
-                            jnp.sum(ok).astype(jnp.int32))
-            carry, placed = lax.cond(
-                k > 0, lambda c: commit(c, ok, collisions, codes, k),
-                lambda c: (c, jnp.int32(0)), carry)
-            # Capacity early-exit, per spec: a pass over a fleet on which
-            # no node has room for the ask on capacity alone does not
-            # count (net/dp/constraints are stricter, so this is a
-            # necessary condition only and placements are unchanged).
-            return carry, placed, jnp.any(fits)
-
-        def commit(carry, ok, collisions, codes, k):
-            (used, job_counts, remaining_count, placements,
-             bw_used, port_words, dyn_free, dp_used, commit_scores,
-             commit_coll, slots, slot_scores, slot_coll) = carry
-            with jax.named_scope("score"):
-                base_score = _score_fit(used, ask[u], denom)
-                score = (base_score
-                         - penalty[u] * collisions.astype(jnp.float32))
-                score = score + tie_jitter(jit_seed, u, node_idx)
-                scored = jnp.where(ok, score, NEG_INF)
-
-            # Threshold bisection instead of a full argsort: same
-            # selection, same tie order, ~100x less device work at N≈50k.
-            with jax.named_scope("select"):
-                sel = _select_top_k(scored, ok, k)
-
-            # Within-pass value dedup for distinct_property: among
-            # selected nodes sharing a property value, keep only the
-            # best-scored (ties by lowest node index — stable-sort order).
-            if use_dp:
-                with jax.named_scope("dp_dedup"):
-                    # Made anew from the codes, not handed over from
-                    # try_place: a [v_pad, N] operand of the cond would
-                    # be written out and read back once a pass.
-                    vc = value_codes(codes, v_pad)
-                    neg = jnp.float32(NEG_INF)
-                    sel_score = jnp.where(sel, scored, neg)
-                    best_per_code = dp_best_per_value(
-                        vc, sel_score, neg, largest=True)
-                    cand_dp = sel & (sel_score >= dp_read_back(
-                        vc, best_per_code, neg, largest=True))
-                    best_idx = dp_best_per_value(
-                        vc, jnp.where(cand_dp, node_idx, big_idx), big_idx,
-                        largest=False)
-                    keep_dp = cand_dp & (node_idx == dp_read_back(
-                        vc, best_idx, big_idx, largest=False))
-                    sel = jnp.where(dp.active[u], keep_dp, sel)
-
-            with jax.named_scope("commit_usage"):
-                sel_i = sel.astype(jnp.int32)
-                placed = jnp.sum(sel_i)
-                used = used + sel_i[:, None] * ask[u][None, :]
-                job_counts = job_counts.at[job_index[u]].add(sel_i)
-                if not slot_m:
-                    # The dense [U, N] placement matrix only feeds the
-                    # matrix-form compaction; in slot mode the slot record
-                    # IS the placement output, so the carry compiles away.
-                    placements = placements.at[u].add(sel_i)
-
-            if slot_m:
-                # Compact slot record: append this commit's node indices
-                # to spec u's slot row in ascending-node order — the COO
-                # payload is built from this, no nonzero pass later.
-                with jax.named_scope("slots"):
-                    pos = jnp.cumsum(sel.astype(jnp.int32))
-                    offset = count[u] - remaining_count[u]  # placed so far
-                    dest = jnp.where(sel, offset + pos - 1,
-                                     jnp.int32(slot_m))
-                    slots = slots.at[u, dest].set(node_idx, mode="drop")
-                    if with_scores:
-                        # Commit-aligned score record: same dest scatter,
-                        # so the [U, N] score carries below compile away.
-                        slot_scores = slot_scores.at[u, dest].set(
-                            base_score, mode="drop")
-                        slot_coll = slot_coll.at[u, dest].set(
-                            collisions, mode="drop")
-
-            with jax.named_scope("commit_usage"):
-                remaining_count = remaining_count.at[u].add(-placed)
-
-                if use_net:
-                    commit_net = net.active[u]
-                    bw_used = bw_used + jnp.where(commit_net,
-                                                  sel_i * net.mbits[u], 0)
-                    port_words = jnp.where(
-                        (commit_net & sel)[:, None],
-                        port_words | net.resv_words[u][None, :],
-                        port_words)
-                    dyn_free = dyn_free - jnp.where(
-                        commit_net, sel_i * net.dyn_need[u], 0)
-            if use_dp:
-                with jax.named_scope("dp_update"):
-                    dp_upd = dp_used_update(vc, sel & dp.active[u])
-                    dp_used = lax.dynamic_update_index_in_dim(
-                        dp_used, dp_used[u] | dp_upd, u, axis=0)
-            # Commit-time AllocMetric side-outputs: pure binpack score and
-            # the collision count behind any anti-affinity penalty.
-            if with_scores and not slot_m:
-                with jax.named_scope("commit_usage"):
-                    commit_scores = commit_scores.at[u].set(jnp.where(
-                        sel, base_score, commit_scores[u]))
-                    commit_coll = commit_coll.at[u].set(jnp.where(
-                        sel, collisions, commit_coll[u]))
-            return (used, job_counts, remaining_count, placements,
-                    bw_used, port_words, dyn_free, dp_used,
-                    commit_scores, commit_coll, slots, slot_scores,
-                    slot_coll), placed
-
-        # Two-level skip, both REAL branches on TPU (the loop over specs
-        # is sequential, not vmapped, so lax.cond doesn't get batched
-        # into a select):
-        #  - outer: remaining_count[u] == 0 (a padding row, or a spec
-        #    with nothing to place) skips even the feasibility/fit
-        #    prefix — a scalar test;
-        #  - inner (in try_place): k == 0 (no feasible node under
-        #    remaining capacity) skips the scoring transcendentals and
-        #    the top-k select.
-        # Neither branch commits anything, so placements stay
-        # bit-identical to the unguarded kernel.
-        return lax.cond(
-            carry[2][u] > 0, try_place,
-            lambda c: (c, jnp.int32(0), jnp.bool_(False)), carry)
+    place_pass = placement_pass(
+        feas, capacity, denom, ask, count, penalty, distinct_hosts,
+        job_index, net, dp, jit_seed,
+        PassSeam(jnp.arange(n_pad, dtype=jnp.int32), n_pad),
+        use_net=use_net, use_dp=use_dp, with_scores=with_scores,
+        slot_m=slot_m)
 
     placements0 = jnp.zeros((u_pad, n_pad) if not slot_m else (1, 1),
                             dtype=jnp.int32)
@@ -891,8 +986,8 @@ def _placement_rounds_impl(
     )
 
 
-# The result buffer's scalar row, in order (shared by the three programs'
-# packers and the host's decode).
+# The result buffer's scalar row, in order (shared by the two fused
+# programs' packers and the host's decode).
 SCALARS = ("nnz", "rounds", "spec_passes", "multi_round_specs")
 
 
@@ -991,15 +1086,8 @@ def _device_schedule(
             dyn_free = d["dyn_free_base"] - net_dev[:, 1]
         else:
             bw_used, dyn_free = d["bw_used"], d["dyn_free"]
-        net = NetTensors(
-            active=d["net_active"], mbits=d["net_mbits"],
-            dyn_need=d["dyn_need"], resv_words=d["resv_words"],
-            bw_cap=d["bw_cap"], bw_used=bw_used,
-            dyn_free=dyn_free, port_words=d["port_words"])
-    dp = None
-    if with_dp:
-        dp = DPTensors(col=d["dp_col"], active=d["dp_active"],
-                       used0=d["dp_used"], attr_values=d["attr"])
+        net = net_tensors(d, bw_used, dyn_free, d["port_words"])
+    dp = dp_tensors(d) if with_dp else None
     key = jax.random.PRNGKey(d["rng_seed"][0])
     result = placement_rounds(
         feas, used0, d["cap"], d["denom"], d["ask"], d["count"],

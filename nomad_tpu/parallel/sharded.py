@@ -1,11 +1,11 @@
 """Device-mesh scale-out of the batch scheduler (SURVEY.md §2.9).
 
 The scaling axis of this workload is nodes × task-groups, and the node axis
-is embarrassingly shardable: each device scores its node shard, reduces to a
-local top-k per spec, and the k·D candidates are all-gathered over ICI —
-the moral equivalent of sequence parallelism for this workload.  The
-sequential commit loop then runs on the merged candidate set (U × k·D ≪
-U × N), preserving capacity feedback.
+is embarrassingly shardable: each device holds a node shard and runs the
+single-chip placement pass (``ops/kernels.placement_pass``) over it; only
+the few operations that need the whole fleet go through ``MeshSeam``: a
+local top-k per spec whose k·D candidates are all-gathered over ICI, and
+the ``psum`` / ``pmax`` / ``pmin`` of counts and per-value tables.
 
 Multi-slice (DCN) is the analogue of the reference's multi-region
 federation (nomad/rpc.go:263 forwardRegion): each slice owns a region's
@@ -21,33 +21,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.kernels import (
-    DPTensors,
-    NetTensors,
-    PlacementResult,
-    _score_fit,
-    dp_best_per_value,
-    dp_columns,
-    dp_read_back,
-    dp_used_lookup,
-    dp_used_update,
+    NEG_INF,
+    PassSeam,
+    _disabled_dp,
+    _disabled_net,
+    dp_tensors,
     jitter_seed,
+    net_tensors,
     pack_scalars,
+    placement_pass,
     spec_major,
-    tie_jitter,
-    value_codes,
 )
-from ..ops.encode import MISSING
 
-NEG_INF = -1e30
-
-# Mesh axis names: 'nodes' shards the node dimension of the score matrix
-# (intra-slice, rides ICI); 'batch' is reserved for sharding the spec axis
-# across data-parallel replicas.
+# Mesh axis name: 'nodes' shards the node dimension of the score matrix
+# (intra-slice, rides ICI).
 NODE_AXIS = "nodes"
-BATCH_AXIS = "batch"
 
 
 def make_node_mesh(devices=None) -> Mesh:
@@ -62,259 +53,90 @@ def _mark_varying(x):
     return lax.pcast(x, (NODE_AXIS,), to="varying")
 
 
-def sharded_placement_rounds(
-    mesh: Mesh,
-    feas: jax.Array,           # [U, N] bool — sharded on N
-    used0: jax.Array,          # [N, 4] int32
-    capacity: jax.Array,       # [N, 4] int32
-    denom: jax.Array,          # [N, 2] float32
-    ask: jax.Array,            # [U, 4] int32 — replicated
-    count: jax.Array,          # [U] int32
-    penalty: jax.Array,        # [U] float32
-    distinct_hosts: jax.Array, # [U] bool
-    job_index: jax.Array,      # [U] int32 → row in job_counts
-    job_counts0: jax.Array,    # [J, N] int32 — sharded on N
-    rng_key: jax.Array,
-    k_cand: int = 64,
-    max_rounds: int = 256,
-    net: NetTensors = None,
-    dp: DPTensors = None,
-) -> PlacementResult:
-    """The single-chip `placement_rounds` semantics, node-sharded over the
-    mesh: anti-affinity collisions, distinct_hosts, per-(job,node) counts,
-    network port/bandwidth accounting, distinct_property and the
-    spec-major capacity-feedback loop (``kernels.spec_major``: each spec
-    placed to its end before the next) all run on sharded state.
+class MeshSeam(PassSeam):
+    """The node mesh's side of the pass seam, inside the mapped function:
+    this shard holds ``n_l`` rows of an ``n_pad``-node table, and what
+    needs the whole fleet is a collective over ``NODE_AXIS``.  Every
+    collective's result is replicated, so the pass's ``lax.cond`` skips
+    take one branch on every shard."""
 
-    Per spec, each shard scores its node shard (binpack − penalty·collisions
-    + the same jitter the single-chip kernel uses), takes a local top-k_cand,
-    and the k_cand·D candidates are all-gathered over ICI; the global top-k
-    selection and shard-local commit follow.  As long as a spec commits
-    ≤ k_cand allocs in a pass (one alloc per node per pass — the
-    anti-affinity bound), the selection is *identical* to the single-chip
-    kernel's full-argsort commit, including tie-breaks: gathered candidate
-    order is (shard, local index) = global node order, and both paths use
-    stable sorts.  Specs needing more than k_cand·D per pass under-commit
-    that pass and finish in their later passes (progress loop).
+    def __init__(self, shard, n_l: int, n_pad: int, k_cand: int):
+        super().__init__(shard * n_l + jnp.arange(n_l, dtype=jnp.int32),
+                         n_pad)
+        # ``global index + 1``, 0 for empty: the shards' slot records are
+        # disjoint, and ONE end-of-loop psum merges them.
+        self.slot_ids = self.node_idx + 1
+        self.shard = shard
+        self.k_cand = k_cand
+        self.d = n_pad // n_l
 
-    ``net`` shards its per-node state (bw_cap/bw_used/dyn_free/port_words)
-    over the mesh and replicates the per-spec asks — feasibility and
-    commits are shard-local, mirroring ops/kernels.py (rank.go:190-238).
-    ``dp`` replicates the per-spec used-value bitsets; the within-pass
-    best-per-value dedup runs as pmax/pmin all-reduces over the value
-    axis, between the single-chip kernel's own per-value table and its
-    read-back (``kernels.ValueCodes``), so every shard keeps the winner
-    the single-chip pass picks (propertyset.go:150).
+    def total(self, local):
+        return lax.psum(local, NODE_AXIS)
 
-    Ref: scheduler/rank.go:247 (anti-affinity), feasible.go:148
-    (distinct_hosts), SURVEY.md §2.9 node-axis sharding.
-    """
-    u_pad, n_pad = feas.shape
-    d = mesh.devices.size
-    assert n_pad % d == 0, (
-        f"mesh size {d} must divide node axis {n_pad} (pad N up)")
-    k_cand = min(k_cand, n_pad // d)
-    use_net = net is not None
-    use_dp = dp is not None
-    if net is None:
-        net = NetTensors(
-            active=jnp.zeros(1, dtype=bool),
-            mbits=jnp.zeros(1, dtype=jnp.int32),
-            dyn_need=jnp.zeros(1, dtype=jnp.int32),
-            resv_words=jnp.zeros((1, 1), dtype=jnp.uint32),
-            bw_cap=jnp.zeros(n_pad, dtype=jnp.int32),
-            bw_used=jnp.zeros(n_pad, dtype=jnp.int32),
-            dyn_free=jnp.zeros(n_pad, dtype=jnp.int32),
-            port_words=jnp.zeros((n_pad, 1), dtype=jnp.uint32),
-        )
-    if dp is None:
-        dp = DPTensors(
-            col=jnp.full(1, -1, dtype=jnp.int32),
-            active=jnp.zeros(1, dtype=bool),
-            used0=jnp.zeros((1, 1), dtype=bool),
-            attr_values=jnp.full((n_pad, 1), MISSING, dtype=jnp.int32),
-        )
-    v_pad = dp.used0.shape[1]
+    def any_node(self, mask):
+        return lax.psum(jnp.any(mask).astype(jnp.int32), NODE_AXIS) > 0
 
-    # Identical tie-break jitter to the single-chip kernel: the hash is
-    # keyed on the GLOBAL node index, so each shard computes its slice
-    # directly — no [U, N] matrix to materialize or shard.
-    jit_seed = jitter_seed(rng_key)
+    def select(self, scored, ok, k):
+        # Local top-k_cand → ICI all-gather → global rank → this shard's
+        # slice: the only cross-shard traffic of a pass is [D, k_cand]
+        # floats.  Exact because k ≤ remaining ≤ count ≤ k_cand (or
+        # k_cand is the whole shard): the global top-k lies inside the
+        # gathered candidates, whose order (shard, local index) is the
+        # global node order, and both sorts are stable, so the selection
+        # and its tie order are the single chip's.
+        loc_scores, loc_idx = lax.top_k(scored, self.k_cand)
+        all_scores = lax.all_gather(loc_scores, NODE_AXIS, tiled=True)
+        c_total = self.k_cand * self.d
+        order = jnp.argsort(-all_scores)
+        ranks = jnp.zeros(c_total, dtype=jnp.int32).at[order].set(
+            jnp.arange(c_total, dtype=jnp.int32))
+        sel_cand = (all_scores > NEG_INF / 2) & (ranks < k)
+        my_sel = lax.dynamic_slice(
+            sel_cand, (self.shard * self.k_cand,), (self.k_cand,))
+        return (jnp.zeros(scored.shape[0], dtype=bool).at[loc_idx].set(my_sel)
+                & ok)
 
-    @functools.partial(
-        jax.shard_map,
-        mesh=mesh,
-        in_specs=(P(None, NODE_AXIS), P(NODE_AXIS), P(NODE_AXIS),
-                  P(NODE_AXIS), P(None), P(None), P(None), P(None),
-                  P(None), P(None, NODE_AXIS), P(),
-                  # net: per-spec replicated, per-node sharded
-                  P(None), P(None), P(None), P(None),
-                  P(NODE_AXIS), P(NODE_AXIS), P(NODE_AXIS), P(NODE_AXIS),
-                  # dp: per-spec replicated, node attrs sharded
-                  P(None), P(None), P(None), P(NODE_AXIS)),
-        out_specs=(P(None, NODE_AXIS), P(None), P(NODE_AXIS), P()),
-    )
-    def _run(feas_l, used_l, cap_l, denom_l, ask_r, count_r, penalty_r,
-             dh_r, job_index_r, jc_l, jit_seed_r,
-             net_active_r, net_mbits_r, dyn_need_r, resv_words_r,
-             bw_cap_l, bw_used_l0, dyn_free_l0, port_words_l0,
-             dp_col_r, dp_active_r, dp_used0_r, dp_attr_l):
-        n_l = used_l.shape[0]
-        shard = lax.axis_index(NODE_AXIS)
-        c_total = k_cand * d
-        big_idx = jnp.int32(n_pad + 1)
-        gidx = shard * n_l + jnp.arange(n_l, dtype=jnp.int32)
-        dp_codes_l = dp_columns(dp_attr_l, dp_col_r) if use_dp else None
+    def value_max(self, table):
+        return lax.pmax(table, NODE_AXIS)
 
-        def place_pass(carry, u):
-            (used, jc, remaining, placements,
-             bw_used, port_words, dyn_free, dp_used) = carry
-            cap_left = cap_l - used
-            fits = jnp.all(ask_r[u][None, :] <= cap_left, axis=1)
-            collisions = jc[job_index_r[u]]            # [N_l] int32
-            ok = feas_l[u] & fits
-            ok = ok & jnp.where(dh_r[u], collisions == 0, True)
+    def value_min(self, table):
+        return lax.pmin(table, NODE_AXIS)
 
-            if use_net:
-                bw_ok = bw_used + net_mbits_r[u] <= bw_cap_l
-                resv_hit = jnp.any(
-                    (port_words & resv_words_r[u][None, :]) != 0, axis=1)
-                dyn_ok = dyn_free >= dyn_need_r[u]
-                ok = ok & jnp.where(net_active_r[u],
-                                    bw_ok & ~resv_hit & dyn_ok, True)
+    def value_union(self, hit):
+        return lax.psum(hit.astype(jnp.int32), NODE_AXIS) > 0
 
-            if use_dp:
-                codes = dp_codes_l[u]                  # [N_l]
-                vc = value_codes(codes, v_pad)
-                dp_ok = ((codes != MISSING)
-                         & ~dp_used_lookup(vc, dp_used[u]))
-                ok = ok & jnp.where(dp_active_r[u], dp_ok, True)
-
-            score = _score_fit(used, ask_r[u], denom_l)
-            score = score - penalty_r[u] * collisions.astype(jnp.float32)
-            score = score + tie_jitter(jit_seed_r, u, gidx)
-            scored = jnp.where(ok, score, NEG_INF)
-
-            # Local top-k_cand, then the ICI all-gather: the only
-            # cross-shard traffic in the hot loop is [D, k_cand] floats.
-            loc_scores, loc_idx = lax.top_k(scored, k_cand)
-            all_scores = lax.all_gather(
-                loc_scores, NODE_AXIS, tiled=True)     # [D*k_cand]
-            n_ok, n_fits = lax.psum(
-                jnp.stack([jnp.sum(ok.astype(jnp.int32)),
-                           jnp.sum(fits.astype(jnp.int32))]), NODE_AXIS)
-            k = jnp.minimum(remaining[u], n_ok)
-            # The single-chip pass's two skips, as what they count: a
-            # pass with nothing left to place, or over a fleet with no
-            # room for the ask on capacity alone, did not run.
-            ran = (remaining[u] > 0) & (n_fits > 0)
-
-            order = jnp.argsort(-all_scores)
-            ranks = jnp.zeros(c_total, dtype=jnp.int32).at[order].set(
-                jnp.arange(c_total, dtype=jnp.int32))
-            sel_cand = (all_scores > NEG_INF / 2) & (ranks < k)
-            my_sel = lax.dynamic_slice(sel_cand, (shard * k_cand,), (k_cand,))
-            sel = jnp.zeros(n_l, dtype=bool).at[loc_idx].set(my_sel) & ok
-
-            if use_dp:
-                # Cross-shard within-pass value dedup: the best-scored
-                # selected node per property value wins globally (ties by
-                # lowest GLOBAL node index), via pmax/pmin over the value
-                # axis — bit-identical to the single-chip per-value best.
-                neg = jnp.float32(NEG_INF)
-                sel_score = jnp.where(sel, scored, neg)
-                best_g = lax.pmax(
-                    dp_best_per_value(vc, sel_score, neg, largest=True),
-                    NODE_AXIS)
-                cand_dp = sel & (sel_score >= dp_read_back(
-                    vc, best_g, neg, largest=True))
-                idx_g = lax.pmin(
-                    dp_best_per_value(
-                        vc, jnp.where(cand_dp, gidx, big_idx), big_idx,
-                        largest=False),
-                    NODE_AXIS)
-                keep = cand_dp & (gidx == dp_read_back(
-                    vc, idx_g, big_idx, largest=False))
-                sel = jnp.where(dp_active_r[u], keep, sel)
-
-            sel_i = sel.astype(jnp.int32)
-            used = used + sel_i[:, None] * ask_r[u][None, :]
-            jc = jc.at[job_index_r[u]].add(sel_i)
-            placements = placements.at[u].add(sel_i)
-            placed = lax.psum(jnp.sum(sel_i), NODE_AXIS)
-            remaining = remaining.at[u].add(-placed)
-
-            if use_net:
-                commit_net = net_active_r[u]
-                bw_used = bw_used + jnp.where(commit_net,
-                                              sel_i * net_mbits_r[u], 0)
-                port_words = jnp.where(
-                    (commit_net & sel)[:, None],
-                    port_words | resv_words_r[u][None, :], port_words)
-                dyn_free = dyn_free - jnp.where(
-                    commit_net, sel_i * dyn_need_r[u], 0)
-            if use_dp:
-                dp_upd = lax.psum(
-                    dp_used_update(vc, sel & dp_active_r[u]).astype(
-                        jnp.int32), NODE_AXIS) > 0
-                dp_used = lax.dynamic_update_index_in_dim(
-                    dp_used, dp_used[u] | dp_upd, u, axis=0)
-
-            return (used, jc, remaining, placements,
-                    bw_used, port_words, dyn_free, dp_used), placed, ran
-
-        placements0 = _mark_varying(
-            jnp.zeros((u_pad, n_l), dtype=jnp.int32))
-        carry = (used_l, jc_l, count_r, placements0,
-                 bw_used_l0, port_words_l0, dyn_free_l0, dp_used0_r)
-        (used, jc, remaining, placements, _bw, _pw, _df, _dpu), passes = \
-            spec_major(place_pass, carry, lambda c: c[2], u_pad, max_rounds)
-        return placements, remaining, used, passes.most
-
-    placements, unplaced, used_after, rounds = _run(
-        feas, used0, capacity, denom, ask, count, penalty, distinct_hosts,
-        job_index, job_counts0, jit_seed,
-        net.active, net.mbits, net.dyn_need, net.resv_words,
-        net.bw_cap, net.bw_used, net.dyn_free, net.port_words,
-        dp.col, dp.active, dp.used0, dp.attr_values)
-    return PlacementResult(
-        placements=placements, unplaced=unplaced,
-        used_after=used_after, rounds=rounds)
+    def slot_offset(self, offset, placed):
+        # The single chip's ascending-node commit order: allocs placed
+        # so far + the lower shards' commits of this pass.
+        counts = lax.all_gather(placed, NODE_AXIS)            # [D]
+        lower = jnp.arange(self.d, dtype=jnp.int32) < self.shard
+        return offset + jnp.sum(jnp.where(lower, counts, 0))
 
 
-# -- fused single-dispatch mesh pass (ISSUE 8 tentpole) ---------------------
+# -- fused single-dispatch mesh program ------------------------------------
 #
 # The multi-device twin of ops/kernels.fused_pass: ONE device dispatch
 # over node-sharded packed static buffers + a replicated dynamic buffer
 # runs unpack (+ dequantize) → per-shard usage-delta scatter-adds →
-# per-shard feasibility → the local-top-k + ICI-all-gather capacity-
-# feedback commit loop (spec-major, ops/kernels.spec_major: the one loop
-# all three programs share) → a commit-ordered slot record → slot→COO gather
-# → ONE packed result buffer (replicated, fetched from one device).
+# per-shard feasibility → the single-chip spec-major loop and pass
+# (ops/kernels.spec_major, placement_pass) over the shard, through
+# ``MeshSeam`` → a commit-ordered slot record → slot→COO gather → ONE
+# packed result buffer (replicated, fetched from one device).
 #
-# Exactness: per pass a spec commits at most ``remaining ≤ count``
-# allocs, so with ``k_cand ≥ max(count)`` (or k_cand == the whole shard)
-# the global top-``remaining`` of any pass lies inside the gathered
-# local top-k_cand candidates — the selection, tie-jitter (keyed on
-# GLOBAL node index) and commit order are bit-identical to the
-# single-chip kernel.  batch_sched sizes k_cand that way, so the mesh
-# path is exact by construction, not within a budget.
-#
-# Slot-record merge: each shard records ITS OWN committed nodes at their
-# global commit positions (per-commit position = allocs placed so far +
-# lower-shard count prefix + within-shard ascending-node rank — the
-# single-chip kernel's ascending-node commit order), encoded as
-# ``global_index + 1`` with 0 for empty, so positions are disjoint
-# across shards and ONE end-of-loop psum produces the replicated
-# [U, M] record the COO gather (ops/kernels._slots_coo_gather, the very
-# same expression the single-chip fused program uses) consumes.
+# Exactness: batch_sched sizes ``k_cand ≥ max(count)`` (or the whole
+# shard), which ``MeshSeam.select`` needs to pick the single chip's
+# nodes; the tie-jitter is keyed on the GLOBAL node index.  Each shard
+# records its own commits at their global positions (``slot_offset``)
+# as ``global index + 1``, and one end-of-loop psum merges the disjoint
+# partials into the replicated [U, M] record the COO gather
+# (ops/kernels._slots_coo_gather, the single-chip program's own) reads.
 
 # Compiled sharded-fused programs keyed by (mesh devices, metas, static
-# shape/flags): the production hot loop must not re-trace per batch the
-# way the legacy eager shard_map side path did.  Touch-on-hit LRU with
-# eviction accounting (utils/lru.py): a long-lived server seeing many
-# mesh/meta shapes recycles programs instead of growing without bound,
-# and the batch.program_cache_evictions gauge shows it happening.
+# shape/flags): the production hot loop must not re-trace per batch.
+# Touch-on-hit LRU with eviction accounting (utils/lru.py): a long-lived
+# server seeing many mesh/meta shapes recycles programs instead of
+# growing without bound, and the batch.program_cache_evictions gauge
+# shows it happening.
 from ..utils.lru import LRU as _LRU
 
 _FUSED_MESH_CACHE = _LRU(16)
@@ -397,21 +219,16 @@ def sharded_fused_pass(
             (used_out if use_used_dev else None))
 
 
+
 def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
                          with_networks, with_dp, with_scores, slot_m,
                          k_cand, max_rounds, window_nnz, compact_u16,
                          use_used_dev=False):
     from ..ops import xfer
-    from ..ops.kernels import (
-        _score_fit as score_fit,
-        _slots_coo_gather,
-        feasibility_matrix,
-    )
+    from ..ops.kernels import _slots_coo_gather, feasibility_matrix
 
     d = mesh.devices.size
     n_l = n_pad // d
-    c_total = k_cand * d
-    big_idx = jnp.int32(n_pad + 1)
 
     @functools.partial(
         jax.shard_map,
@@ -437,7 +254,6 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
         dd = dict(zip(dd.keys(),
                       lax.optimization_barrier(tuple(dd.values()))))
         shard = lax.axis_index(NODE_AXIS)
-        gidx = shard * n_l + jnp.arange(n_l, dtype=jnp.int32)
 
         if use_used_dev:
             # The shard's usage state IS its slice of the donated
@@ -472,148 +288,39 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
             ds["attr"], ds["elig"], ds["dc"], dd["c_attr"], dd["c_op"],
             dd["c_rhs"], dd["dc_mask"], precomp)
 
+        both = {**ds, **dd}
         if with_networks:
-            bw_used0 = ds["bw_used_base"].at[uidx].add(
-                dd["u_bw"], mode="drop")
-            dyn_free0 = ds["dyn_free_base"].at[uidx].add(
-                dd["u_dyn"], mode="drop")
-            port_words0 = ds["port_words_base"].at[uidx].set(
-                dd["u_ports"], mode="drop")
+            net = net_tensors(
+                both,
+                ds["bw_used_base"].at[uidx].add(dd["u_bw"], mode="drop"),
+                ds["dyn_free_base"].at[uidx].add(dd["u_dyn"], mode="drop"),
+                ds["port_words_base"].at[uidx].set(dd["u_ports"],
+                                                   mode="drop"))
         else:
-            bw_used0 = jnp.zeros(n_l, dtype=jnp.int32)
-            dyn_free0 = jnp.zeros(n_l, dtype=jnp.int32)
-            port_words0 = jnp.zeros((n_l, 1), dtype=jnp.uint32)
-        if with_dp:
-            dp_used_init = dd["dp_used"]
-            v_pad = dp_used_init.shape[1]
-            dp_codes_l = dp_columns(ds["attr"], dd["dp_col"])
-        else:
-            dp_used_init = jnp.zeros((1, 1), dtype=bool)
-            v_pad = 1
+            net = _disabled_net(u_pad, n_l)
+        dp = dp_tensors(both) if with_dp else _disabled_dp(u_pad, n_l)
 
-        cap_l = ds["cap"]
-        denom_l = ds["denom"]
-        ask_r = dd["ask"]
         count_r = dd["count"]
-        key = jax.random.PRNGKey(dd["rng_seed"][0])
-        jit_seed_r = jitter_seed(key)
-        d_arange = jnp.arange(d, dtype=jnp.int32)
+        jit_seed = jitter_seed(jax.random.PRNGKey(dd["rng_seed"][0]))
+        place_pass = placement_pass(
+            feas_l, ds["cap"], ds["denom"], dd["ask"], count_r,
+            dd["penalty"], dd["dh"], dd["ji"], net, dp, jit_seed,
+            MeshSeam(shard, n_l, n_pad, k_cand), use_net=with_networks,
+            use_dp=with_dp, with_scores=with_scores, slot_m=slot_m)
 
-        def place_pass(carry, u):
-            (used, jc, remaining, bw_used, port_words, dyn_free, dp_used,
-             slots, sscores, scoll) = carry
-            cap_left = cap_l - used
-            fits = jnp.all(ask_r[u][None, :] <= cap_left, axis=1)
-            collisions = jc[dd["ji"][u]]
-            ok = feas_l[u] & fits
-            ok = ok & jnp.where(dd["dh"][u], collisions == 0, True)
-
-            if with_networks:
-                bw_ok = bw_used + dd["net_mbits"][u] <= ds["bw_cap"]
-                resv_hit = jnp.any(
-                    (port_words & dd["resv_words"][u][None, :]) != 0,
-                    axis=1)
-                dyn_ok = dyn_free >= dd["dyn_need"][u]
-                ok = ok & jnp.where(dd["net_active"][u],
-                                    bw_ok & ~resv_hit & dyn_ok, True)
-            if with_dp:
-                codes = dp_codes_l[u]
-                vc = value_codes(codes, v_pad)
-                dp_ok = ((codes != MISSING)
-                         & ~dp_used_lookup(vc, dp_used[u]))
-                ok = ok & jnp.where(dd["dp_active"][u], dp_ok, True)
-
-            base_score = score_fit(used, ask_r[u], denom_l)
-            score = (base_score
-                     - dd["penalty"][u] * collisions.astype(jnp.float32))
-            score = score + tie_jitter(jit_seed_r, u, gidx)
-            scored = jnp.where(ok, score, NEG_INF)
-
-            # Local top-k_cand → ICI all-gather → global top-k select
-            # (identical to sharded_placement_rounds; exact because
-            # k ≤ remaining ≤ count ≤ k_cand).
-            loc_scores, loc_idx = lax.top_k(scored, k_cand)
-            all_scores = lax.all_gather(loc_scores, NODE_AXIS, tiled=True)
-            n_ok, n_fits = lax.psum(
-                jnp.stack([jnp.sum(ok.astype(jnp.int32)),
-                           jnp.sum(fits.astype(jnp.int32))]), NODE_AXIS)
-            k = jnp.minimum(remaining[u], n_ok)
-            ran = (remaining[u] > 0) & (n_fits > 0)
-            order = jnp.argsort(-all_scores)
-            ranks = jnp.zeros(c_total, dtype=jnp.int32).at[order].set(
-                jnp.arange(c_total, dtype=jnp.int32))
-            sel_cand = (all_scores > NEG_INF / 2) & (ranks < k)
-            my_sel = lax.dynamic_slice(
-                sel_cand, (shard * k_cand,), (k_cand,))
-            sel = jnp.zeros(n_l, dtype=bool).at[loc_idx].set(my_sel) & ok
-
-            if with_dp:
-                neg = jnp.float32(NEG_INF)
-                sel_score = jnp.where(sel, scored, neg)
-                best_g = lax.pmax(
-                    dp_best_per_value(vc, sel_score, neg, largest=True),
-                    NODE_AXIS)
-                cand_dp = sel & (sel_score >= dp_read_back(
-                    vc, best_g, neg, largest=True))
-                idx_g = lax.pmin(
-                    dp_best_per_value(
-                        vc, jnp.where(cand_dp, gidx, big_idx), big_idx,
-                        largest=False),
-                    NODE_AXIS)
-                keep = cand_dp & (gidx == dp_read_back(
-                    vc, idx_g, big_idx, largest=False))
-                sel = jnp.where(dd["dp_active"][u], keep, sel)
-
-            sel_i = sel.astype(jnp.int32)
-            placed_l = jnp.sum(sel_i)
-            counts_g = lax.all_gather(placed_l, NODE_AXIS)      # [D]
-            # The total comes from psum, not jnp.sum(counts_g): an
-            # all_gather result is typed node-varying, and ``remaining``
-            # / the while-loop progress flag must stay replicated-typed
-            # to match their carry inputs and the P() out_specs.
-            placed = lax.psum(placed_l, NODE_AXIS)
-            # Global commit positions in the single-chip kernel's
-            # ascending-node order: allocs placed so far + lower-shard
-            # prefix + within-shard ascending-node rank.
-            prefix = jnp.sum(jnp.where(d_arange < shard, counts_g, 0))
-            offset = count_r[u] - remaining[u]
-            pos_l = jnp.cumsum(sel_i)
-            dest = jnp.where(sel, offset + prefix + pos_l - 1,
-                             jnp.int32(slot_m))
-            slots = slots.at[u, dest].set(gidx + 1, mode="drop")
-            if with_scores:
-                sscores = sscores.at[u, dest].set(base_score, mode="drop")
-                scoll = scoll.at[u, dest].set(collisions, mode="drop")
-
-            used = used + sel_i[:, None] * ask_r[u][None, :]
-            jc = jc.at[dd["ji"][u]].add(sel_i)
-            remaining = remaining.at[u].add(-placed)
-            if with_networks:
-                commit_net = dd["net_active"][u]
-                bw_used = bw_used + jnp.where(
-                    commit_net, sel_i * dd["net_mbits"][u], 0)
-                port_words = jnp.where(
-                    (commit_net & sel)[:, None],
-                    port_words | dd["resv_words"][u][None, :], port_words)
-                dyn_free = dyn_free - jnp.where(
-                    commit_net, sel_i * dd["dyn_need"][u], 0)
-            if with_dp:
-                dp_upd = lax.psum(
-                    dp_used_update(vc, sel & dd["dp_active"][u]).astype(
-                        jnp.int32), NODE_AXIS) > 0
-                dp_used = lax.dynamic_update_index_in_dim(
-                    dp_used, dp_used[u] | dp_upd, u, axis=0)
-            return (used, jc, remaining, bw_used, port_words, dyn_free,
-                    dp_used, slots, sscores, scoll), placed, ran
-
+        # ``placement_pass``'s carry.  Slot mode leaves the [U, N]
+        # placement and score carries as (1, 1) placeholders; a slot is
+        # 0 until a shard records ``global index + 1`` in it.
+        one = jnp.zeros((1, 1), dtype=jnp.int32)
         sscore_shape = (u_pad, slot_m) if with_scores else (1, 1)
-        carry = (used0, jc0, count_r,
-                 bw_used0, port_words0, dyn_free0, dp_used_init,
+        carry = (used0, jc0, count_r, one,
+                 net.bw_used, net.port_words, net.dyn_free, dp.used0,
+                 one.astype(jnp.float32), one,
                  _mark_varying(jnp.zeros((u_pad, slot_m), dtype=jnp.int32)),
                  _mark_varying(jnp.zeros(sscore_shape, dtype=jnp.float32)),
                  _mark_varying(jnp.zeros(sscore_shape, dtype=jnp.int32)))
-        (used, jc, remaining, _bw, _pw, _df, _dpu, slots_p, sscores_p,
-         scoll_p), passes = spec_major(
+        (_used, _jc, remaining, _pl, _bw, _pw, _df, _dpu, _cs, _cc,
+         slots_p, sscores_p, scoll_p), passes = spec_major(
             place_pass, carry, lambda c: c[2], u_pad, max_rounds)
 
         # Disjoint per-shard partials → ONE psum yields the replicated
@@ -638,29 +345,3 @@ def _build_fused_mesh_fn(mesh, *, meta_s, meta_d, u_pad, n_pad,
     # the dummy it is neither donated nor meaningful.
     return jax.jit(_run,
                    donate_argnums=(2,) if use_used_dev else ())
-
-
-def sharded_schedule_step(
-    mesh: Mesh,
-    feas: jax.Array,
-    used: jax.Array,
-    capacity: jax.Array,
-    denom: jax.Array,
-    ask: jax.Array,
-    count: jax.Array,
-    k: int = 64,
-) -> Tuple[jax.Array, jax.Array]:
-    """Convenience wrapper: one full-semantics scheduling step over the mesh
-    with default job bookkeeping (one job per spec, standard service
-    anti-affinity penalty, no distinct_hosts)."""
-    u_pad, n_pad = feas.shape
-    result = sharded_placement_rounds(
-        mesh, feas, used, capacity, denom, ask, count,
-        penalty=jnp.full((u_pad,), 20.0, dtype=jnp.float32),
-        distinct_hosts=jnp.zeros((u_pad,), dtype=bool),
-        job_index=jnp.arange(u_pad, dtype=jnp.int32),
-        job_counts0=jnp.zeros((u_pad, n_pad), dtype=jnp.int32),
-        rng_key=jax.random.PRNGKey(0),
-        k_cand=k,
-    )
-    return result.placements, result.used_after

@@ -203,6 +203,70 @@ class TestMeshBitIdentity:
         assert pm == placements_with_scores(hs[1], jobs)
         assert sum(len(v) for v in pm.values()) == 6
 
+    def test_mesh_contended_multi_pass_identical(self, mesh, monkeypatch):
+        """A contended batch over nodes on three shards: a spec asks
+        more allocs than there are nodes, so it takes three or more
+        passes (one alloc per node a pass), and the fleet fills before
+        the last job is done.  Placements, scores and pass counts stay
+        bit-identical to the single chip."""
+        rng = random.Random(31)
+        nodes = [make_node(rng) for _ in range(40)]
+        jobs = [make_job(count) for count in (100, 60)]
+        for job in jobs:
+            for t in job.task_groups[0].tasks:
+                t.resources.cpu, t.resources.memory_mb = 1200, 256
+        hs = []
+        for _ in range(2):
+            h = Harness()
+            for node in nodes:
+                h.state.upsert_node(h.next_index(), node.copy())
+            for job in jobs:
+                h.state.upsert_job(h.next_index(), job)
+            hs.append(h)
+        st_m = run_batch(hs[0], jobs, monkeypatch, mesh=mesh, seed=31)
+        st_s = run_batch(hs[1], jobs, monkeypatch, seed=31)
+        assert st_m.mesh_shards == 8 and st_s.mesh_shards == 0
+        assert st_s.rounds >= 3
+        assert ((st_m.rounds, st_m.spec_passes, st_m.multi_round_specs)
+                == (st_s.rounds, st_s.spec_passes, st_s.multi_round_specs))
+        pm = placements_with_scores(hs[0], jobs)
+        assert pm == placements_with_scores(hs[1], jobs)
+        placed = sum(len(v) for v in pm.values())
+        assert 100 < placed < 160, "the fleet must fill mid-batch"
+
+    def test_mesh_static_port_identical(self, mesh, monkeypatch):
+        """Static ports on the mesh: three ``mock.job()``s each ask port
+        8889 beside their dynamic ones, 48 allocs for 40 nodes on three
+        shards.  A node holds the port once, so the reserved-port bits
+        bind placement; it stays bit-identical to the single chip."""
+        nodes = []
+        for _ in range(40):
+            node = mock.node()       # keeps its mock networks
+            node.compute_class()
+            nodes.append(node)
+        jobs = []
+        for _ in range(3):
+            job = mock.job()
+            job.task_groups[0].count = 16
+            job.task_groups[0].tasks[0].resources.networks[0] \
+                .reserved_ports = [s.Port("lb", 8889)]
+            jobs.append(job)
+        hs = []
+        for _ in range(2):
+            h = Harness()
+            for node in nodes:
+                h.state.upsert_node(h.next_index(), node.copy())
+            for job in jobs:
+                h.state.upsert_job(h.next_index(), job)
+            hs.append(h)
+        st_m = run_batch(hs[0], jobs, monkeypatch, mesh=mesh, seed=8)
+        run_batch(hs[1], jobs, monkeypatch, seed=8)
+        assert st_m.device_ran and st_m.mesh_shards == 8
+        pm = placements_with_scores(hs[0], jobs)
+        assert pm == placements_with_scores(hs[1], jobs)
+        on = [node_id for v in pm.values() for node_id, _ in v]
+        assert len(on) == len(set(on)) == 40
+
     @pytest.mark.slow
     @pytest.mark.parametrize("seed", range(8))
     def test_mesh_fuzz_bit_identical(self, mesh, seed, monkeypatch):
@@ -497,3 +561,25 @@ class TestMeshDonatedMirror:
             assert resident.DEV_GUARD_MISMATCHES == 0
         assert out[0] == out[1]
         resident.reset_counters()
+
+
+@pytest.mark.slow
+def test_multichip_dryrun_composition(mesh):
+    """``__graft_entry__``'s multichip dry run, in process on the
+    8-device virtual mesh: two batches through the fused mesh program
+    with the resident delta feed.  Deadline-guarded, so that a hang
+    fails here instead of stalling the suite."""
+    import signal
+
+    import __graft_entry__ as g  # repo root is on sys.path via conftest
+
+    def _timeout(signum, frame):
+        raise TimeoutError("the multichip dry run exceeded 120s")
+
+    old = signal.signal(signal.SIGALRM, _timeout)
+    signal.alarm(120)
+    try:
+        g._dryrun_multichip_impl(8)
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
